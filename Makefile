@@ -2,8 +2,10 @@
 # commit; the slow tier (multihost subprocess tests, MXU interpret-mode
 # kernel matrix, reference-consistency differential tests) must pass
 # before a round is declared done. Both run on CPU via tests/conftest.py
-# (virtual 8-device mesh); bench.py is the only thing that touches the
-# real accelerator.
+# (virtual 8-device mesh). What runs on the chip: chip_smoke.py (the
+# quickest proof the main path still starts there; --rehearse-cpu checks
+# its control flow first), bench.py and bench_serve.py — each one
+# process, each refusing any platform but "tpu" unless told --cpu.
 
 PY ?= python
 
@@ -53,7 +55,7 @@ kernels:
 # sort-free jaxprs (tests/test_partition_scan.py,
 # tests/test_level_pipeline.py, docs/Performance.md "Level
 # pipelining"). Count-based, never wall-clock: green means the
-# structure that produced the BENCH_r06 numbers is intact
+# structure the partition and the staged grower rely on is intact
 perf:
 	$(PY) -m pytest tests/ -x -q -m "perf and not slow"
 

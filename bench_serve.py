@@ -10,9 +10,14 @@ degradation ladder, not just the sunny path. The headline value is the
 highest achieved QPS among stages that held p99 < 10ms; shed /
 fallback / failover / deadline-miss counts ride as side channels.
 
+Runs on the TPU JAX finds; any other platform is refused unless --cpu
+is given. Any exception, and any request answered by the host fallback,
+is a non-zero exit.
+
 Output contract (mirrors bench.py):
 - one single-line JSON metric record on stdout:
-  {"metric": "serve_sustained_qps_p99lt10ms", "value": ..., "unit":
+  {"platform": ..., "device_kind": ..., "device_count": ...,
+   "metric": "serve_sustained_qps_p99lt10ms", "value": ..., "unit":
    "qps", "p99_ms": ..., "shed": ..., "fallback": ..., "failovers":
    ..., "deadline_misses": ...}
 - `# serve detail:` lines on stderr;
@@ -229,28 +234,30 @@ def run_multimodel_bench():
     }
 
 
-def main():
+def main(argv=None):
+    argv = sys.argv[1:] if argv is None else argv
+    from bench import device_record
+    from lightgbm_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
+    record = device_record(allow_cpu="--cpu" in argv)
     rnd = _next_round()
-    cmd = "python bench_serve.py"
-    try:
-        record = run_bench()
-        if os.environ.get("SERVE_MM", "1") != "0":
-            record.update(run_multimodel_bench())
-        rc = 0
-        line = json.dumps(record)
-        print(line)
-    except Exception as exc:        # unusable sample, honest record
-        rc = 1
-        record = None
-        line = f"# serve bench failed: {type(exc).__name__}: {exc}"
-        print(line, file=sys.stderr)
-    wrapped = {"n": rnd, "cmd": cmd, "rc": rc, "tail": line,
-               "parsed": record}
+    record.update(run_bench())
+    if os.environ.get("SERVE_MM", "1") != "0":
+        record.update(run_multimodel_bench())
+    line = json.dumps(record)
+    print(line)
+    # a sample answered by the host is not a device measurement
+    rc = 1 if record["fallback"] else 0
+    wrapped = {"n": rnd, "cmd": "python bench_serve.py " + " ".join(argv),
+               "rc": rc, "tail": line, "parsed": record}
     out = os.path.join(REPO, f"SERVE_r{rnd:02d}.json")
     with open(out, "w") as fh:
         json.dump(wrapped, fh, indent=1)
         fh.write("\n")
     print(f"# serve record -> {out}", file=sys.stderr)
+    if rc:
+        print(f"# serve bench: {record['fallback']} request(s) fell back "
+              f"to the host", file=sys.stderr)
     return rc
 
 

@@ -1,7 +1,7 @@
 """EFB wide-sparse on-chip benchmark (VERDICT r3 item 3 done-criterion).
 
-Same shape as the round-3 measurement (docs/PerfNotes.md): 200k x 1000,
-~95% sparse via 20-feature exclusive groups, max_bin=63, 63 leaves.
+200k x 1000, ~95% sparse via 20-feature exclusive groups, max_bin=63,
+63 leaves.
 Compares the portable EFB grower, the MXU path with the segmented
 bundle-space scan (round-4 default), and optionally the round-3
 expansion fallback.

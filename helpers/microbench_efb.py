@@ -1,6 +1,5 @@
-"""Per-component timing of the EFB MXU path at the wide-sparse shape
-(docs/PerfNotes.md round 4) — locates the deficit vs the portable
-grower without in-jit guesswork."""
+"""Per-component timing of the EFB MXU path at the wide-sparse shape —
+locates the deficit vs the portable grower without in-jit guesswork."""
 
 import os
 import sys

@@ -7,7 +7,7 @@ Python control flow on traced values, and must not force a host sync
 (`float()`, `bool()`, `.item()`, `np.asarray()` ...) on a traced value
 inside the jitted body. Dtype discipline: no float64 (and no implicit
 promotion to it) inside jitted bodies — device accumulators are
-explicit f32 (config `hist_dtype`, docs/PerfNotes.md).
+explicit f32 (config `hist_dtype`).
 
 What does NOT fire, by design:
 

@@ -3,8 +3,8 @@
 PERF001 guards the round-6 partition win: the slot-grouped scatter
 kernels used to order rows with `jnp.argsort` — O(N log N) work per
 level where the blocked-prefix-sum scan partition does O(N) with the
-per-slot counts the router already emits (docs/PerfNotes.md round 6,
-Parallel Scan on Ascend arXiv:2505.15112).  A sort quietly
+per-slot counts the router already emits (docs/Performance.md "Row
+partitioning", Parallel Scan on Ascend arXiv:2505.15112).  A sort quietly
 reintroduced into any registered device hot-path function would
 silently reinstate the old cost at exactly the shapes where it hurts
 (N = millions of rows, every tree level), so the manifest below pins
@@ -101,5 +101,5 @@ class PerfHotPathSortRule(Rule):
                         f"argsort in device hot path "
                         f"'{node.name}' ({name}): the scan partition "
                         f"keeps this path O(N); see "
-                        f"docs/PerfNotes.md round 6"))
+                        f"docs/Performance.md"))
         return out

@@ -220,8 +220,9 @@ class TraceF64Rule(_TraceRule):
 class TraceCallbackRule(_TraceRule):
     id = "TRACE003"
     doc = ("traced hot entry contains a host callback primitive "
-           "(pure_callback/io_callback/debug_callback) — each one is a "
-           "device->host round trip serializing the dispatch pipeline")
+           "(pure_callback/io_callback/debug_callback/debug_print) — "
+           "each one is a device->host round trip serializing the "
+           "dispatch pipeline")
 
     def check_project(self, files, ctx) -> List[Finding]:
         bundle = _bundle(files, ctx)

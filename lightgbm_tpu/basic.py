@@ -769,8 +769,8 @@ class Booster:
         (the fused on-device scan, boosting/fused.py) when the
         configuration allows, else a plain update() loop. Semantically
         identical to calling update() num_iterations times; the win is
-        host-boundary amortization on remoted accelerators. Returns True
-        if training cannot continue."""
+        one dispatch and at most one host sync per block instead of per
+        tree. Returns True if training cannot continue."""
         self._model = None
         return self.gbdt.train_many(num_iterations)
 

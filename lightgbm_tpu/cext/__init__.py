@@ -1,19 +1,25 @@
 """ctypes bridge to the native host runtime (lightgbm_tpu/cext/binning.cpp).
 
 Reference analog: the C++ data layer (DatasetLoader/Parser/BinMapper hot
-paths). The library builds lazily on first import with the system compiler
-(g++ -O3 -shared); everything degrades gracefully to the NumPy
-implementations when a compiler is unavailable.
+paths). The libraries are built from the tracked sources at first use
+with the system compiler (g++ -O3 -shared) into this directory — no
+binary is committed. A build is reused while the hash of its source,
+recorded beside it at build time, still matches; file times mean
+nothing after a checkout or a copy. Without a working compiler the
+NumPy implementations take over, and a warning says why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional, Tuple
 
 import numpy as np
+
+from ..utils.log import Log
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "binning.cpp")
@@ -23,36 +29,56 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _source_hash(src: str) -> str:
+    with open(src, "rb") as fh:
+        return hashlib.sha256(fh.read()).hexdigest()
+
+
 def _load_or_build(src: str, lib_path: str,
                    flag_sets=((),)) -> Optional[ctypes.CDLL]:
-    """Load lib_path, rebuilding from src when stale; None on failure.
-
-    Degrades gracefully: a missing source next to a prebuilt .so loads
-    the .so; no compiler at all returns None (NumPy fallbacks take over).
-    """
-    have_src = os.path.exists(src)
-    stale = have_src and (
-        not os.path.exists(lib_path) or
-        os.path.getmtime(lib_path) < os.path.getmtime(src))
-    if stale:
-        built = False
+    """Load lib_path, first building it from src unless the hash
+    recorded at its last build (lib_path + ".sha256") matches the
+    source; None — with a warning naming the cause — when it cannot be
+    built or loaded."""
+    want = _source_hash(src)
+    stamp = lib_path + ".sha256"
+    have = ""
+    if os.path.exists(lib_path) and os.path.exists(stamp):
+        with open(stamp) as fh:
+            have = fh.read().strip()
+    if have != want:
+        # build beside the target and rename: concurrent first users
+        # (test workers, 2-rank harnesses) never load a half-written file
+        tmp = "%s.%d.tmp" % (lib_path, os.getpid())
+        err = "no flag set tried"
         for flags in flag_sets:
+            cmd = (["g++", "-O3", "-shared", "-fPIC", "-std=c++17"]
+                   + list(flags) + [src, "-o", tmp])
             try:
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17"]
-                    + list(flags) + [src, "-o", lib_path],
-                    check=True, capture_output=True, timeout=120)
-                built = True
+                subprocess.run(cmd, check=True, capture_output=True,
+                               timeout=120)
+            except subprocess.CalledProcessError as exc:
+                err = exc.stderr.decode(errors="replace").strip()[-400:]
+            except (OSError, subprocess.TimeoutExpired) as exc:
+                err = "%s: %s" % (type(exc).__name__, exc)
+            else:
+                os.replace(tmp, lib_path)
+                with open(stamp, "w") as fh:
+                    fh.write(want + "\n")
                 break
-            except Exception:
-                continue
-        if not built:
+        else:
+            if os.path.exists(tmp):
+                os.remove(tmp)
+            Log.warning("native library %s not built (%s); the NumPy "
+                        "implementations are used",
+                        os.path.basename(lib_path), err)
             return None
-    if not os.path.exists(lib_path):
-        return None
     try:
         return ctypes.CDLL(lib_path)
-    except OSError:
+    except OSError as exc:
+        Log.warning("native library %s not loaded (%s); the NumPy "
+                    "implementations are used",
+                    os.path.basename(lib_path), exc)
         return None
 
 

@@ -544,6 +544,8 @@ def _model_to_if_else(model) -> str:
 
 def main(argv: Optional[List[str]] = None) -> int:
     argv = argv if argv is not None else sys.argv[1:]
+    from .utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     try:
         Application(argv).run()
     except Exception as e:  # mirror main.cpp catch-all

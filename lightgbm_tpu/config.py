@@ -426,10 +426,9 @@ _PARAMS: List[ParamSpec] = [
        "pass and fixup sweeps when the doubling schedule already grew "
        "at least this fraction of overshoot*num_leaves leaves (0 = "
        "always chase the full overshoot). The bridge is an s_max-wide "
-       "histogram sweep (~65 ms at the Higgs bench shape) that runs "
-       "exactly for the mid/late-boosting trees whose throttled last "
-       "pass under-commits; 0.93 measured +6% throughput for ~2.4e-4 "
-       "AUC@115 (docs/PerfNotes.md round 4)"),
+       "histogram sweep that runs exactly for the mid/late-boosting "
+       "trees whose throttled last pass under-commits; gating it "
+       "trades a few 1e-4 of AUC for skipping that sweep"),
     _p("tail_split_cap", int, 8, (), lambda v: v >= 0,
        "hybrid growth throttle for the batched TPU grower: once fewer "
        "leaves remain than splittable candidates, commit at most this "
@@ -440,12 +439,10 @@ _PARAMS: List[ParamSpec] = [
        desc="route EFB-bundled training through the MXU growth path: "
             "bundle-space histogram kernels, the segmented bundle-space "
             "split scan (split_bundled.py), and bundle-range routing. "
-            "Parity-tested, but the portable scatter grower measured "
-            "FASTER on every bundled shape tried (docs/PerfNotes.md "
-            "round 4: bundling is exactly the transformation that makes "
-            "scatter updates cheap, while the one-hot-matmul histogram "
-            "still pays per padded lane) — so bundled data defaults to "
-            "the portable grower"),
+            "Parity-tested, but bundling is exactly the transformation "
+            "that makes scatter updates cheap, while the one-hot-matmul "
+            "histogram still pays per padded lane — so bundled data "
+            "defaults to the portable grower"),
     _p("efb_segmented_scan", bool, True, (),
        desc="scan bundled histograms directly per sub-feature segment "
             "([S, Fb, Bb] stays bundle-sized; split_bundled.py). false "
@@ -497,9 +494,7 @@ _PARAMS: List[ParamSpec] = [
             "per-level observation point (the level_pipeline trace "
             "span). Byte-identical models to the default monolithic "
             "one-dispatch-per-tree grower, which stays the parity "
-            "oracle and remains the right shape for remoted "
-            "accelerators where every dispatch pays a tunnel "
-            "round-trip. Serial MXU growth only: the sharded grower "
+            "oracle. Serial MXU growth only: the sharded grower "
             "and the fused multi-tree scan ignore it"),
     _p("level_pipeline_lookahead", int, 4, (), lambda v: v >= 1,
        "speculative fixup stages enqueued per chunk before the "

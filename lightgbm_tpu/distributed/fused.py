@@ -53,9 +53,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ..parallel.learner import shard_map
 
 __all__ = ["build_sharded_fused_train", "objective_row_state"]
 

@@ -43,10 +43,10 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.comm import CommSpec
-from ..parallel.learner import shard_map
 
 __all__ = ["check_hist_agg_fault", "build_feature_shards",
            "reduce_scatter_hist", "feature_shard_width"]

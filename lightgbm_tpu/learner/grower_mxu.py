@@ -38,7 +38,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..utils.log import Log
+from ..utils.log import LightGBMError
 from .grower import _init_tree, TreeArrays
 from .histogram import build_histograms
 from .histogram_mxu import (_round_up, build_histograms_mxu_auto, fits_v2,
@@ -51,7 +51,7 @@ from .split import (BestSplits, SplitHyperParams, find_best_splits,
                     leaf_gain, leaf_output, _split_gain)
 from .split_kernel import find_best_splits_kernel, kernel_supports
 
-__all__ = ["grow_tree_mxu"]
+__all__ = ["grow_tree_mxu", "HistAutotuneError"]
 
 
 def _prune_to_best_first(tree: TreeArrays, row_node: jax.Array, *,
@@ -179,6 +179,18 @@ def _kernel_cap(s: int) -> int:
     return min(s, s // 2 + 8)
 
 
+class HistAutotuneError(LightGBMError):
+    """hist_backend=auto met a histogram kernel that does not build."""
+
+    def __init__(self, errors: dict, timings_ms: dict):
+        self.errors = dict(errors)
+        self.timings_ms = dict(timings_ms)
+        super().__init__(
+            "hist_backend autotune: " + "; ".join(
+                "%s backend failed (%s)" % kv
+                for kv in sorted(self.errors.items())))
+
+
 def autotune_hist_backend(bins, *, num_slots: int, bmax: int,
                           num_features: int = 0, double_prec: bool = True,
                           quantized: bool = True, const_hess: float = 0.0,
@@ -193,7 +205,9 @@ def autotune_hist_backend(bins, *, num_slots: int, bmax: int,
     grow_tree_mxu dispatch because the backend is a static (jit) arg;
     the result is pinned for the whole run and recorded in
     observability (boosting/gbdt.py). A backend that fails to compile
-    or run times as +inf, so the other one wins."""
+    or run is a broken kernel, not a slow one: both are still tried so
+    the report is complete, then HistAutotuneError carries each
+    exception's text and the timings taken, and nothing is chosen."""
     n = bins.shape[0]
     g = jnp.linspace(-127.0, 127.0, n, dtype=jnp.float32)
     g = jnp.round(g) if quantized else g * 1e-2
@@ -214,21 +228,19 @@ def autotune_hist_backend(bins, *, num_slots: int, bmax: int,
             num_features=num_features, const_hess=const_hess,
             row_block=row_block_scatter)
 
-    timings = {}
+    timings, errors = {}, {}
     for name, fn in (("mxu", _mxu), ("pallas", _pallas)):
         try:
             jax.block_until_ready(fn())       # compile + warm
             t0 = time.perf_counter()
             jax.block_until_ready(fn())
             timings[name] = (time.perf_counter() - t0) * 1e3
-        except Exception as exc:  # pragma: no cover - device-specific
-            Log.warning("hist_backend autotune: %s backend failed (%s)",
-                        name, exc)
-            timings[name] = float("inf")
-    choice = min(timings, key=timings.get)
-    if timings[choice] == float("inf"):
-        choice = "mxu"
-    return choice, timings
+        except Exception as exc:
+            errors[name] = "%s: %s" % (type(exc).__name__, exc)
+            last = exc
+    if errors:
+        raise HistAutotuneError(errors, timings) from last
+    return min(timings, key=timings.get), timings
 
 
 #: index of the done flag in the growth state tuple (shared with the
@@ -248,13 +260,12 @@ def growth_plan(*, num_leaves: int, overshoot: float = 0.0,
     on the host without tracing anything. _make_grow_core consumes the
     same plan, so the two drivers cannot disagree on the schedule.
 
-    Tuning history (docs/PerfNotes.md rounds 3-4): with overshoot the
-    fixup frontier runs FULL-width (s_fix = min(LGBM_TPU_SFIX, s_max),
-    default 512) — the round-3 late-tree decay (2.69 -> 2.3 trees/s)
-    was narrow fixup frontiers chasing 65-200 leftover splits; the
-    bridge gate (growth_bridge_gate) skips the s_max-wide bridge sweep
-    once num_leaves >= gate * L_g, never gating below the actual leaf
-    budget so the prune keeps its num_leaves target."""
+    With overshoot the fixup frontier runs FULL-width (s_fix =
+    min(LGBM_TPU_SFIX, s_max), default 512): narrow fixup frontiers
+    chasing 65-200 leftover splits made late trees slower than early
+    ones; the bridge gate (growth_bridge_gate) skips the s_max-wide
+    bridge sweep once num_leaves >= gate * L_g, never gating below the
+    actual leaf budget so the prune keeps its num_leaves target."""
     over = overshoot if overshoot and overshoot >= 1.0 else 0.0
     if over:
         tail_split_cap = 0
@@ -578,13 +589,12 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             if quant:
                 h = h * hist_scale
             return _allred(h), rn
-        # measured on v5e: small frontiers run ~15% cheaper at half
-        # blocks, large ones prefer the wider block. EFB keeps rb=1024
-        # in BOTH modes: expansion's original-feature route side needs
-        # the VMEM headroom (a 2048 block compiled to a real 136 MB
-        # OOM at 250-column bundles), and for bundle-range mode larger
-        # adaptive blocks measured WORSE (0.059 vs 0.182 trees/s on the
-        # low-cardinality shape, docs/PerfNotes.md round 4)
+        # small frontiers run cheaper at half blocks, large ones
+        # prefer the wider block. EFB keeps rb=1024 in BOTH modes:
+        # expansion's original-feature route side needs the VMEM
+        # headroom (a 2048 block compiled to a real 136 MB OOM at
+        # 250-column bundles), and bundle-range mode did not gain from
+        # larger adaptive blocks
         rw = f if (efb is not None and not efb_seg) else 0
         if efb is not None:
             rb = 1024
@@ -1229,8 +1239,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     One monolithic jit program: the doubling schedule, the bridge pass
     and the data-dependent fixup while_loop all run in ONE device
-    dispatch (zero host syncs per tree — the right shape for a remoted
-    accelerator, docs/PerfNotes.md round 3). The level-pipelined
+    dispatch (zero host syncs per tree). The level-pipelined
     driver (grower_pipeline.py, config level_pipeline=true) dispatches
     the SAME passes as separate stage programs with speculative
     host-side fixup dispatch; this function is its byte-parity oracle.

@@ -2,8 +2,7 @@
 
 The expansion design (efb.expand_histograms + split.find_best_splits)
 materializes an [S, F, Bmax, 3] tensor per growth pass — at wide F that
-tensor dominates the pass (measured 0.09 vs 0.16 trees/s against the
-portable grower at 200k x 1000, docs/PerfNotes.md round 3). The
+tensor dominates the pass. The
 reference never expands: FeatureHistogram scans each sub-feature's
 offset range of the bundled histogram directly (feature_histogram.hpp
 offset scans over feature_group.h:25 ranges; bundling at

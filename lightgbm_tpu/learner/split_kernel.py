@@ -34,10 +34,8 @@ from .split import (BestSplits, SplitHyperParams, _gain_given_output,
 
 __all__ = ["find_best_splits_kernel", "kernel_supports"]
 
-# jax < 0.5 names the params class TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-_COMPILER_PARAMS = _CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=100 * 1024 * 1024)
 
 # per-slot output columns (selection only; gains/outputs recomputed in
 # XLA from the picked sums — see kernel tail comment)

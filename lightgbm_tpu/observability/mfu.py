@@ -14,9 +14,8 @@ grower (grower_mxu.py) runs a deterministic doubling schedule
 S = 2, 4, ..., s_max plus one full-capacity bridge pass, with sibling
 subtraction halving the slots actually built per pass — so the MAC
 count of a whole tree is a static function of the config, summed here
-by `tree_macs`. Data-dependent fixup passes (measured ~0 at the bench
-posture, docs/PerfNotes.md round 4) are excluded: the estimate is a
-slight LOWER bound on device work, so the derived TFLOP/s and MFU
+by `tree_macs`. Data-dependent fixup passes are excluded: the estimate
+is a slight LOWER bound on device work, so the derived TFLOP/s and MFU
 never overstate utilization. Routing matmul flops are negligible next
 to the histogram (module docstring) and are likewise excluded.
 
@@ -36,6 +35,7 @@ from typing import Dict, Optional
 
 __all__ = ["hist_channels", "histogram_macs", "tree_macs",
            "achieved_tflops", "mfu_fraction", "device_peak_tflops",
+           "peak_tflops_of_kind",
            "DeviceUtilization"]
 
 # bf16 peak TFLOP/s per chip, by jax device_kind substring (most
@@ -49,6 +49,18 @@ _PEAK_TFLOPS_BF16 = (
     ("v3", 123.0),
     ("v2", 45.0),
 )
+
+
+def peak_tflops_of_kind(device_kind: str) -> Optional[float]:
+    """bf16 peak from the table alone, None for a device_kind it does
+    not hold (chip_smoke.py treats that as an error, not as 0.0)."""
+    kind = str(device_kind).lower()
+    if "tpu" not in kind and not kind.startswith("v"):
+        return None
+    for pat, tf in _PEAK_TFLOPS_BF16:
+        if pat in kind:
+            return tf
+    return None
 
 
 def device_peak_tflops(device=None) -> float:
@@ -65,15 +77,10 @@ def device_peak_tflops(device=None) -> float:
         if device is None:
             import jax
             device = jax.devices()[0]
-        kind = str(getattr(device, "device_kind", "")).lower()
+        kind = getattr(device, "device_kind", "")
     except Exception:
         return 0.0
-    if "tpu" not in kind and not kind.startswith("v"):
-        return 0.0
-    for pat, tf in _PEAK_TFLOPS_BF16:
-        if pat in kind:
-            return tf
-    return 0.0
+    return peak_tflops_of_kind(kind) or 0.0
 
 
 def hist_channels(*, double_prec: bool = True, quantized: bool = False,

@@ -2,7 +2,7 @@
 
 Host-side spans (observability/trace.py) time the *dispatch*; the
 device work behind it — the MXU histogram matmuls vs the scatter
-kernels that the BENCH_r06 two-point protocol wants to attribute
+kernels that the two-point protocol wants to attribute
 (docs/Performance.md) — only shows up in a ``jax.profiler`` trace.
 This module brackets ``jax.profiler.start_trace``/``stop_trace``
 around spans whose name matches the ``profile_spans`` glob(s), with a

@@ -237,6 +237,8 @@ class ObservabilityRegistry:
             out["is_" + name] = int(hb["choice"] == name)
         for name, ms in sorted((hb.get("timings_ms") or {}).items()):
             out[str(name) + "_ms"] = round(float(ms), 3)
+        for name, text in sorted((hb.get("errors") or {}).items()):
+            out[str(name) + "_error"] = text
         return out
 
     def collective_snapshot(self) -> Dict:
@@ -335,16 +337,21 @@ class ObservabilityRegistry:
 
     # -- training hooks (called from boosting/gbdt.py) ------------------
     def record_hist_autotune(self, choice: str, timings_ms: Dict,
-                             autotuned: bool) -> None:
+                             autotuned: bool,
+                             errors: Optional[Dict] = None) -> None:
         """Pin the resolved histogram backend (+ per-backend autotune
         timings, ms). Recorded even when disabled — this is one-shot
         startup configuration, not per-iteration telemetry, and the
-        bench JSON tail reads it regardless of the enable flag."""
+        bench JSON tail reads it regardless of the enable flag.
+        `errors` maps a backend that failed to build to its exception
+        text (the autotune then chose nothing and training stopped)."""
         with self._lock:
             self._hist_backend = {
                 "choice": str(choice), "autotuned": bool(autotuned),
                 "timings_ms": {str(k): float(v)
-                               for k, v in (timings_ms or {}).items()}}
+                               for k, v in (timings_ms or {}).items()},
+                "errors": {str(k): str(v)[:2000]
+                           for k, v in (errors or {}).items()}}
 
     # -- collective-watchdog hooks (reliability/watchdog.py) ------------
     # recorded even when disabled, like record_hist_autotune: watchdog

@@ -58,7 +58,11 @@ def guarded_allgather(x, label: str = "allgather") -> np.ndarray:
     rides along the same way: a rank resumed from a stale membership
     record would otherwise exchange rows sharded for the WRONG world —
     every gather cross-checks epochs and raises on divergence
-    (distributed/elastic.py, stale-epoch rejection)."""
+    (distributed/elastic.py, stale-epoch rejection).
+
+    Returns ``[nproc, *x.shape]`` on every world size: one process
+    stacks a leading axis of 1 exactly like N processes stack N, so
+    callers index ranks the same way whether or not peers exist."""
     import time
     from jax.experimental import multihost_utils
     from ..reliability.watchdog import collective_guard

@@ -21,17 +21,8 @@ import functools
 from typing import Optional
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map_exp(f, **kw)
 
 from ..learner.grower import grow_tree
 from .comm import CommSpec

@@ -27,11 +27,11 @@ def provision_virtual_devices(n_devices: int) -> None:
 
     Must run BEFORE the first backend touch: once any jax.devices() call
     initializes a backend, the CPU device count is latched for the process.
-    jax may be pre-imported by the harness, so env vars alone are too
-    late — the jax.config updates are what actually take effect. This
-    permanently switches the process (and, via os.environ, subprocesses)
-    to the CPU platform; it is a one-shot test/dryrun provision, not a
-    runtime mode toggle.
+    jax is usually imported already by then, which latches the
+    environment variables — the jax.config updates are what actually
+    take effect. This permanently switches the process (and, via
+    os.environ, subprocesses) to the CPU platform; it is a one-shot
+    test/dryrun provision, not a runtime mode toggle.
     """
     try:
         from jax._src import xla_bridge as _xb
@@ -52,10 +52,7 @@ def provision_virtual_devices(n_devices: int) -> None:
         os.environ.get("XLA_FLAGS", "") +
         f" --xla_force_host_platform_device_count={n_devices}").strip()
     os.environ["JAX_PLATFORMS"] = "cpu"
-    try:
-        jax.config.update("jax_num_cpu_devices", n_devices)
-    except (AttributeError, KeyError):
-        pass  # older jax without this config: XLA_FLAGS alone works pre-init
+    jax.config.update("jax_num_cpu_devices", n_devices)
     jax.config.update("jax_platforms", "cpu")
     # verify the provision actually took: when the initialized-backend
     # detection above is unavailable (private API moved) and some
@@ -96,17 +93,9 @@ def _enable_cpu_collectives() -> None:
     it before the backend initializes. Only applies when the process
     is pinned to CPU (multi-process CPU tests, the chaos harness);
     TPU runs keep the default ICI/DCN transport."""
-    plat = os.environ.get("JAX_PLATFORMS") or ""
-    try:
-        plat = plat or (jax.config.jax_platforms or "")
-    except AttributeError:
-        pass
-    if "cpu" not in plat:
-        return
-    try:
+    plat = os.environ.get("JAX_PLATFORMS") or jax.config.jax_platforms or ""
+    if "cpu" in plat:
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, KeyError, ValueError):
-        pass    # older jax: no such config (and no CPU collectives)
 
 
 def init_distributed(coordinator_address: Optional[str] = None,

@@ -2,8 +2,8 @@
 
 The non-pipelined block loop pulls each valid set's FULL per-iteration
 score matrix to the host ([block, N] or [block, N, C] f32) and runs
-metrics.py on it — on a remoted accelerator that transfer dwarfs the
-metric arithmetic. Here the metric reductions themselves ride the
+metrics.py on it — a device-to-host copy the next dispatch waits
+behind. Here the metric reductions themselves ride the
 device: one vmapped dispatch per valid set turns the trajectory into a
 [block, n_metrics] f32 array, so the early-stop/callback protocol syncs
 a few hundred bytes per block instead of the score matrices.

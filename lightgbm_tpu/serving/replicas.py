@@ -117,17 +117,14 @@ class ReplicaSet:
         from .breaker import CircuitBreaker
         if not forest.supported:
             return cls([], name=name)
-        try:
-            import jax
-            devices = jax.local_devices()
-        except Exception:       # no backend: single logical replica
-            devices = [None]
+        import jax
+        devices = jax.local_devices()
         if n_replicas <= 0:
             n_replicas = len(devices)
         replicas: List[Replica] = []
         for i in range(max(int(n_replicas), 1)):
-            dev = devices[i % len(devices)] if devices else None
-            if i == 0 or dev is None or len(devices) == 1:
+            dev = devices[i % len(devices)]
+            if i == 0 or len(devices) == 1:
                 # replica 0 keeps the already-built arrays; a 1-device
                 # host shares them too (identical placement, and the
                 # bucket cache stays warm across replicas)

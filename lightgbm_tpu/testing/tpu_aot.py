@@ -1,0 +1,316 @@
+"""Compile for a TPU that is not there: the pre-flight before chip time.
+
+libtpu can describe a topology without owning a chip, and JAX lowers and
+compiles for the devices of that description like for real ones. So
+whether Mosaic accepts a kernel (VMEM, tiling, unaligned slices), what a
+program's compile costs and how much device memory it asks for are all
+known in the sandbox; only *running* needs the chip. Nothing here
+executes on a device, so nothing here is a speed.
+
+    python -m lightgbm_tpu.testing.tpu_aot               # smoke's shapes
+    python -m lightgbm_tpu.testing.tpu_aot --rows 65536 --leaves 31
+
+compiles every ``pallas_call`` site, the per-iteration grower, the fused
+multi-tree scan, the serving predictor and the four-device data-parallel
+grower the way chip_smoke.py will meet them, and prints one line per
+program. tests/test_tpu_aot.py runs
+the kernel sites at a small shape (slow tier).
+"""
+
+from __future__ import annotations
+
+import time
+from typing import Callable, Dict, List, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+__all__ = ["TOPOLOGY", "topology_devices", "compile_for", "kernel_sites"]
+
+#: one v5e host: four chips in a 2x2 mesh
+TOPOLOGY = "v5e:2x2"
+
+
+def topology_devices(name: str = TOPOLOGY) -> Sequence:
+    """Devices of the described topology (no chip is touched). Raises
+    whatever libtpu raises when it cannot describe one — the caller
+    decides whether that is a skip or an error."""
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(
+        topology_name=name, platform="tpu").devices
+
+
+def _on(device, x):
+    """ShapeDtypeStruct of `x` placed on `device`; a spec that already
+    says where it lives (a sharded argument) is kept."""
+    from jax.sharding import SingleDeviceSharding
+    if isinstance(x, jax.ShapeDtypeStruct) and x.sharding is not None:
+        return x
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                sharding=SingleDeviceSharding(device))
+
+
+def compile_for(device, fn: Callable, *args):
+    """Trace, lower and compile ``fn(*args)`` for `device` (a topology
+    device). `fn` is a plain or an already-jitted function (whose
+    donation and static arguments are then kept); `args` are arrays or
+    ShapeDtypeStructs, of which only shapes and dtypes are read.
+    Returns (compiled, seconds)."""
+    t0 = time.perf_counter()
+    specs = jax.tree_util.tree_map(lambda a: _on(device, a), args)
+    jitted = fn if hasattr(fn, "trace") else jax.jit(fn)
+    compiled = jitted.trace(*specs).lower().compile()
+    return compiled, time.perf_counter() - t0
+
+
+def _sds(shape, dtype, sharding=None):
+    return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                sharding=sharding)
+
+
+def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
+                 quantized: bool = True) -> Dict[str, Tuple[Callable, List]]:
+    """One (fn, args) per ``pallas_call`` site in learner/, shaped like
+    a growth pass over `rows` x `features` bins with `slots` frontier
+    slots. The route tables are sized for 2*slots nodes."""
+    from ..learner import histogram_mxu as hm
+    from ..learner.histogram_pallas import build_histograms_scatter
+    from ..learner.split import SplitHyperParams
+    from ..learner.split_kernel import find_best_splits_kernel
+
+    n, f, s = rows, features, slots
+    m_pad = hm._round_up(2 * s, 128)
+    bpad = hm._round_up(bmax, 128)
+    bins = _sds((n, f), jnp.uint8)
+    vec = _sds((n,), jnp.float32)
+    ivec = _sds((n,), jnp.int32)
+    tbl = _sds((m_pad, hm._N_COLS), jnp.float32)
+    member = _sds((m_pad, bpad), jnp.float32)
+    feat_tbl = _sds((f, 2), jnp.float32)
+    hist_kw = dict(num_slots=s, bmax=bmax, quantized=quantized)
+    hp = SplitHyperParams()
+    svec = _sds((s,), jnp.float32)
+    fvec = _sds((f,), jnp.float32)
+
+    def hist_v1(b, g, h, c, sl):
+        return hm.build_histograms_mxu(b, g, h, c, sl, **hist_kw)
+
+    def hist_v2(b, g, h, c, sl):
+        return hm.build_histograms_mxu_v2(b, g, h, c, sl, **hist_kw)
+
+    def hist_scatter(b, g, h, c, sl):
+        return build_histograms_scatter(b, g, h, c, sl, **hist_kw)
+
+    def fused_route_hist(b, g, h, c, node, t, mem, ft):
+        return hm.fused_route_hist_mxu(b, g, h, c, node, t, mem, ft,
+                                       has_cat=False, **hist_kw)
+
+    def route_rows(b, node, t, mem, ft):
+        return hm.route_rows_mxu(b, node, t, mem, ft, emit_counts=True,
+                                 num_slots=s)
+
+    def node_sums(node, g, h, c):
+        return hm.node_sums_mxu(node, g, h, c, num_nodes=2 * s)
+
+    def node_values(node, vals):
+        return hm.node_values_mxu(node, vals)
+
+    def split_scan(hist, pg, ph, pc, po, nb, minan, isc, fm):
+        return find_best_splits_kernel(hist, pg, ph, pc, po, nb, minan,
+                                       isc, fm, hp)
+
+    return {
+        "build_histograms_mxu": (hist_v1, [bins, vec, vec, vec, ivec]),
+        "build_histograms_mxu_v2": (hist_v2, [bins, vec, vec, vec, ivec]),
+        "build_histograms_scatter": (hist_scatter,
+                                     [bins, vec, vec, vec, ivec]),
+        "fused_route_hist_mxu": (fused_route_hist,
+                                 [bins, vec, vec, vec, ivec, tbl, member,
+                                  feat_tbl]),
+        "route_rows_mxu": (route_rows, [bins, ivec, tbl, member, feat_tbl]),
+        "node_sums_mxu": (node_sums, [ivec, vec, vec, vec]),
+        "node_values_mxu": (node_values, [ivec, _sds((2 * s,),
+                                                     jnp.float32)]),
+        "find_best_splits_kernel": (
+            split_scan,
+            [_sds((s, f, bmax, 3), jnp.float32), svec, svec, svec, svec,
+             _sds((f,), jnp.int32), _sds((f,), jnp.bool_),
+             _sds((f,), jnp.bool_), fvec]),
+    }
+
+
+def _synthetic(rows: int, features: int, seed: int = 17):
+    rng = np.random.RandomState(seed)
+    X = rng.randn(rows, features).astype(np.float32)
+    y = (X[:, 0] - X[:, 1] + X[:, 2] * X[:, 3] > 0).astype(np.float32)
+    return X, y
+
+
+def _gbdt(params: dict, rows: int, features: int):
+    """The GBDT ``lgb.Booster(params)`` builds here over synthetic rows
+    (on this CPU host: portable grower, one device)."""
+    import lightgbm_tpu as lgb
+    X, y = _synthetic(rows, features)
+    ds = lgb.Dataset(X, label=y, params={"max_bin": params["max_bin"]})
+    return lgb.Booster(params=dict(params, verbosity=-1),
+                       train_set=ds).gbdt
+
+
+def training_programs(params: dict, *, rows: int, features: int,
+                      block: int) -> Dict[str, Tuple[Callable, List]]:
+    """The two growth programs of the serial MXU path at this shape —
+    the per-iteration grower (what a run that is not fused-eligible
+    dispatches) and one fused block of `block` trees (what
+    ``lgb.train(params)`` dispatches) — taken from a Booster built here,
+    with the kernel path a TPU backend selects forced on (this host is a
+    CPU, where GBDT._setup_train picks the portable grower)."""
+    from ..learner.grower_mxu import grow_tree_mxu
+    g = _gbdt(params, rows, features)
+    g._hist_impl = "mxu"
+    kw = g._mxu_grow_kwargs()
+
+    def grow(bins, grad, hess, cnt, fmask, key):
+        return grow_tree_mxu(bins, grad, hess, cnt, fmask, g.num_bins_d,
+                             g.missing_is_nan_d, g.is_cat_d, rng_key=key,
+                             **kw)
+
+    vec = _sds((rows,), jnp.float32)
+    run = g._build_fused()
+    return {
+        "grow_tree_mxu": (grow, [g.bins, vec, vec, vec,
+                                 _sds((features,), jnp.float32),
+                                 _sds((2,), jnp.uint32)]),
+        "fused_block_k%d" % block: (
+            run.program, [vec, _sds((), jnp.int32),
+                          _sds((block, 2), jnp.uint32), *run.operands]),
+    }
+
+
+def sharded_grower_program(params: dict, *, rows: int, features: int,
+                           devices: Sequence):
+    """(jitted grower, placed arg specs) of ``tree_learner=data`` with
+    the MXU grower inside shard_map over `devices` — what the smoke's
+    four-device leg dispatches per tree, rows split evenly."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from ..parallel import CommSpec
+    from ..parallel.learner import make_sharded_grower
+    g = _gbdt(params, rows, features)    # for its Config and its hp
+    cfg = g.config
+    mesh = Mesh(np.array(devices), ("data",))
+    comm = CommSpec(axis="data", mode="data", num_devices=len(devices),
+                    top_k=cfg.top_k, hist_agg="psum")
+    grower = make_sharded_grower(
+        mesh, comm, num_leaves=cfg.num_leaves, max_depth=cfg.max_depth,
+        hp=g.hp, leafwise=False, bmax=g.bmax, use_mxu=True,
+        with_rng=bool(cfg.use_quantized_grad),
+        mxu_kwargs=dict(
+            hist_double_prec=cfg.gpu_use_dp,
+            tail_split_cap=cfg.tail_split_cap,
+            hist_subtraction=cfg.hist_subtraction,
+            overshoot=cfg.growth_overshoot,
+            bridge_gate=cfg.growth_bridge_gate,
+            quantized_grad=cfg.use_quantized_grad, const_hessian=0.0))
+    rowwise, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    specs = [_sds((rows, features), jnp.uint8, rowwise)] + \
+        [_sds((rows,), jnp.float32, rowwise)] * 3 + \
+        [_sds((features,), jnp.float32, whole),
+         _sds((features,), jnp.int32, whole),
+         _sds((features,), jnp.bool_, whole),
+         _sds((features,), jnp.bool_, whole)]
+    if cfg.use_quantized_grad:
+        specs.append(_sds((2,), jnp.uint32, whole))
+    return grower, specs
+
+
+def serving_programs(*, features: int, leaves: int, max_bin: int,
+                     buckets: Sequence[int] = (16, 1024)
+                     ) -> Dict[str, Tuple[Callable, List]]:
+    """The serving predictor at the bucket sizes given, over a forest of
+    the trainer's width (two trees, trained here on the portable path)."""
+    import lightgbm_tpu as lgb
+    from ..learner.predict import predict_binned_forest
+    X, y = _synthetic(40 * leaves, features)
+    bst = lgb.train({"objective": "binary", "num_leaves": leaves,
+                     "max_bin": max_bin, "min_data_in_leaf": 2,
+                     "verbosity": -1}, lgb.Dataset(X, label=y), 2)
+    forest = bst.device_forest()
+    bin_dtype = forest.bin_rows(X[:1]).dtype
+
+    def predict(stacked, tree_class, bins, num_bins, minan, valid):
+        return predict_binned_forest(
+            stacked, tree_class, bins, num_bins, minan,
+            num_outputs=forest.num_outputs, row_valid=valid)
+
+    return {"serving_predict_b%d" % b: (predict, [
+        forest.stacked, forest.tree_class, _sds((b, features), bin_dtype),
+        forest.num_bins, forest.missing_is_nan, _sds((b,), jnp.bool_)])
+        for b in buckets}
+
+
+def main(argv=None) -> int:
+    import argparse
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", type=int, default=1_000_000)
+    ap.add_argument("--features", type=int, default=28)
+    ap.add_argument("--leaves", type=int, default=255)
+    ap.add_argument("--max-bin", type=int, default=255)
+    ap.add_argument("--block", type=int, default=10,
+                    help="fused block length (default: fused_block_size)")
+    ap.add_argument("--only", default="",
+                    help="comma-separated substrings of group names "
+                         "(kernel, defaults, bench/mxu, bench/pallas, "
+                         "serving, data_parallel)")
+    args = ap.parse_args(argv)
+    device = topology_devices()[0]
+    base = {"objective": "binary", "num_leaves": args.leaves,
+            "max_bin": args.max_bin, "min_data_in_leaf": 20}
+    bench = dict(base, use_quantized_grad=True, growth_overshoot=1.75,
+                 growth_bridge_gate=0.93)
+    shape = dict(rows=args.rows, features=args.features)
+    slots = int(np.ceil(args.leaves * 1.75)) + 1
+    groups = [
+        ("kernel", lambda: kernel_sites(bmax=args.max_bin, slots=slots,
+                                        **shape)),
+        ("defaults", lambda: training_programs(
+            dict(base, hist_backend="mxu"), block=args.block, **shape)),
+        ("bench/mxu", lambda: training_programs(
+            dict(bench, hist_backend="mxu"), block=args.block, **shape)),
+        ("bench/pallas", lambda: training_programs(
+            dict(bench, hist_backend="pallas"), block=args.block, **shape)),
+        ("serving", lambda: serving_programs(
+            features=args.features, leaves=args.leaves,
+            max_bin=args.max_bin)),
+    ]
+    def sharded():
+        devices = topology_devices()
+        grower, specs = sharded_grower_program(base, devices=devices,
+                                               **shape)
+        return {"grow_tree_mxu_x%d" % len(devices):
+                (grower, specs)}
+
+    groups.append(("data_parallel", sharded))
+    only = [t for t in args.only.split(",") if t]
+    failed = 0
+    for group, build in groups:
+        if only and not any(t in group for t in only):
+            continue
+        for name, (fn, fargs) in build().items():
+            label = "%s:%s" % (group, name)
+            try:
+                compiled, dt = compile_for(device, fn, *fargs)
+            except Exception as exc:   # report every program, then fail
+                failed += 1
+                print("FAIL %-40s %s: %s" % (
+                    label, type(exc).__name__, str(exc)[:800]), flush=True)
+                continue
+            mem = compiled.memory_analysis()
+            print("ok   %-40s compile %6.1fs  temp %5.0f MiB  args %5.0f "
+                  "MiB" % (label, dt, mem.temp_size_in_bytes / 2 ** 20,
+                           mem.argument_size_in_bytes / 2 ** 20),
+                  flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -7,7 +7,7 @@ This host timer brackets whole phases the same way the reference brackets
 CUDA phases (cuda_single_gpu_tree_learner.cpp:112-169). For the device
 side, `lightgbm_tpu/observability/profile.py` brackets real
 ``jax.profiler`` captures around named spans (``profile_spans=`` globs,
-e.g. ``pipeline_block,sharded_grow`` — the BENCH_r06 attribution
+e.g. ``pipeline_block,sharded_grow`` — the two-point attribution
 protocol in docs/Performance.md).
 """
 

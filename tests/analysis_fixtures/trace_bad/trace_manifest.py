@@ -34,7 +34,6 @@ def _probe_f64():
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     def f(x):
         return x.astype(jnp.float64) * 2.0
@@ -44,7 +43,7 @@ def _probe_f64():
         # the x64 trace below is the one the rule inspects
         warnings.simplefilter("ignore")
         out = {"jaxpr": jax.make_jaxpr(f)(_shaped((16,)))}
-    with enable_x64():
+    with jax.enable_x64(True):
         out["jaxpr_x64"] = jax.make_jaxpr(f)(_shaped((16,)))
     return out
 

@@ -20,7 +20,6 @@ def _shaped(shape, dtype="float32"):
 def _probe_clean():
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def f(acc, x, k):
@@ -34,7 +33,7 @@ def _probe_clean():
     out = {"jaxpr": traced.jaxpr,
            "lowered_text": traced.lower().as_text(),
            "stable": stable}
-    with enable_x64():
+    with jax.enable_x64(True):
         out["jaxpr_x64"] = f.trace(
             _shaped((16,)), _shaped((16,)), 2).jaxpr
     return out

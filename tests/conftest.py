@@ -18,6 +18,17 @@ def rng():
     return np.random.RandomState(42)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _drop_compiled_programs_between_modules():
+    """Tier-1 is one process. With every module's executables kept
+    alive, XLA:CPU died (SIGSEGV/abort in backend_compile_and_load) some
+    370 tests in; dropping them between modules lets the run finish,
+    and faster than it got to the crash."""
+    yield
+    import jax
+    jax.clear_caches()
+
+
 def make_binary(n=2000, f=10, seed=0):
     r = np.random.RandomState(seed)
     X = r.randn(n, f)

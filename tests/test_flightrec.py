@@ -230,7 +230,9 @@ def test_cli_failure_before_booster_flushes_bundle(tmp_path):
 def test_collective_brackets_and_clock_ride_the_ring():
     before = registry.clock_skew_snapshot()["samples"]
     out = guarded_allgather(np.arange(4, dtype=np.float64), "gather")
-    np.testing.assert_array_equal(out, np.arange(4, dtype=np.float64))
+    # one process stacks a leading rank axis of 1, as N processes stack N
+    np.testing.assert_array_equal(
+        out, np.arange(4, dtype=np.float64)[None])
     assert registry.clock_skew_snapshot()["samples"] == before + 1
     # single process: no guard bracket (collective_guard no-ops — the
     # bracket records are pinned by the watchdog tests above), but the

@@ -71,6 +71,49 @@ class TestFusedScan:
         assert a.model_to_string() == b.model_to_string()
 
     @pytest.mark.parametrize("extra_params", [
+        {},                                          # binary, biased
+        {"objective": "multiclass", "num_class": 3},
+        {"boosting": "goss", "top_rate": 0.3, "other_rate": 0.3},
+    ], ids=["binary", "multiclass", "goss"])
+    def test_block_from_iteration_zero_equals_per_iteration(
+            self, extra_params):
+        # a block that starts at iteration 0 runs whole in the fused
+        # program (one compiled growth program per run, not two): the
+        # boost_from_average constant must land on the scores, on the
+        # valid scores and in tree 0's leaves exactly as train_one_iter
+        # puts it there
+        rng = np.random.RandomState(8)
+        X = rng.randn(600, 5).astype(np.float32)
+        y = (X[:, 0] + 0.3 * rng.randn(600) > 0.4).astype(np.float32)
+        if "num_class" in extra_params:
+            y = y + (X[:, 1] > 0.5).astype(np.float32)
+        boosters = []
+        for _ in range(2):
+            ds = lgb.Dataset(X, label=y, params={"max_bin": 31})
+            bst = lgb.Booster(params={**PARAMS, **extra_params},
+                              train_set=ds)
+            bst.add_valid(lgb.Dataset(X[:200], label=y[:200],
+                                      reference=ds), "held_out")
+            g = bst.gbdt
+            g._hist_impl = "mxu"
+            g._mxu_interpret = True
+            boosters.append(bst)
+        a, b = boosters
+        assert a.gbdt._fused_eligible()
+        a.update_batch(3)
+        for _ in range(3):
+            b.update()
+        assert a.current_iteration() == b.current_iteration() == 3
+        np.testing.assert_array_equal(
+            np.asarray(a.gbdt.train_score), np.asarray(b.gbdt.train_score))
+        np.testing.assert_array_equal(
+            np.asarray(a.gbdt.valid_scores[0]),
+            np.asarray(b.gbdt.valid_scores[0]))
+        assert a.model_to_string() == b.model_to_string()
+        # the block left one trajectory point per iteration
+        assert a.gbdt._fused_valid_traj[0].shape[0] == 3
+
+    @pytest.mark.parametrize("extra_params", [
         {"bagging_fraction": 0.7, "bagging_freq": 2},          # bagging
         {"boosting": "goss", "top_rate": 0.3, "other_rate": 0.3},  # GOSS
     ])

@@ -319,23 +319,44 @@ class TestBackendResolution:
         # pinned: a second resolution returns the cache
         assert g._resolved_hist_backend() == "pallas"
 
-    def test_autotune_all_failures_fall_back_to_mxu(self):
-        # on CPU the non-interpret kernels cannot run: both timings come
-        # back inf and the choice must degrade to mxu, not raise
-        from lightgbm_tpu.learner.grower_mxu import autotune_hist_backend
+    def test_autotune_failure_raises_with_each_backends_text(self):
+        # on CPU the non-interpret kernels cannot build. A kernel that
+        # does not build is not a slow kernel: nothing is chosen, and
+        # the error carries each backend's exception text
+        from lightgbm_tpu.learner.grower_mxu import (HistAutotuneError,
+                                                     autotune_hist_backend)
         bins = jnp.asarray(np.random.RandomState(0).randint(
             0, 15, size=(256, 4)).astype(np.uint8))
-        choice, timings = autotune_hist_backend(bins, num_slots=4,
-                                                bmax=15)
-        assert choice == "mxu"
-        assert set(timings) == {"mxu", "pallas"}
-        assert all(t == float("inf") for t in timings.values())
+        with pytest.raises(HistAutotuneError) as info:
+            autotune_hist_backend(bins, num_slots=4, bmax=15)
+        assert set(info.value.errors) == {"mxu", "pallas"}
+        assert all("interpret" in t for t in info.value.errors.values())
+        assert info.value.timings_ms == {}
+
+    def test_autotune_failure_surfaces_and_is_recorded(self, monkeypatch):
+        # through the booster: the failure is not turned into the other
+        # backend, and hist_backend_snapshot() keeps the text
+        import jax
+        from lightgbm_tpu.observability import registry
+        registry.reset()
+        bst = self._booster(hist_backend="auto")
+        g = bst.gbdt
+        g._hist_impl = "mxu"
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(Exception, match="autotune"):
+            g._resolved_hist_backend()
+        snap = registry.hist_backend_snapshot()
+        assert snap["choice"] == "" and snap["autotuned"] is True
+        assert "interpret" in snap["mxu_error"]
+        assert "interpret" in snap["pallas_error"]
+        # strings stay out of the Prometheus families
+        assert "_error" not in registry.prometheus_text()
 
     def test_fused_rejects_unresolved_auto(self):
         from lightgbm_tpu.boosting.fused import build_fused_train
         with pytest.raises(ValueError, match="resolved hist_backend"):
             build_fused_train(
-                objective=None, bins=None, cnt_weight=None,
+                objective=None, bins=None,
                 feature_mask_fn=None, num_bins=None,
                 missing_is_nan=None, is_cat=None,
                 grower_kwargs={"hist_backend": "auto"}, shrinkage=0.1,

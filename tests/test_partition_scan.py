@@ -1,6 +1,6 @@
 """Scan-vs-argsort partition parity (`make kernels` / `make perf`).
 
-The round-6 partition contract (docs/PerfNotes.md): partition_rows'
+The round-6 partition contract (docs/Performance.md): partition_rows'
 "scan" implementation — stable rank via blocked prefix sums over the
 per-slot counts the router already emits — produces the IDENTICAL
 permutation the retained stable argsort oracle produces, hence

@@ -1,9 +1,10 @@
 """Bench regression sentinel (observability/regress.py + bench.py
---compare) — tier-1. Two halves: (1) the REAL trajectory in the repo
-root must schema-validate and carry no regressions (the contract that
-makes the sentinel a guard for every later round); (2) synthetic
-trajectories prove the detectors fire: a >10% drop, a broken latest
-record, a multichip flip, and the --strict exit code."""
+--compare) — tier-1. Two halves: (1) the records that remain in the
+repo root (the serve and multichip series; no training record has been
+taken on the current code) must schema-validate and carry no
+regressions; (2) synthetic trajectories prove the detectors fire: a
+>10% drop, a broken latest record, a multichip flip, and the --strict
+exit code."""
 
 import json
 import os
@@ -36,7 +37,7 @@ def _bench_rec(value, rc=0, metric="higgs1m_trees_per_sec", **extra):
 
 def test_real_trajectory_schema_validates():
     traj = regress.load_trajectory(REPO)
-    assert traj["bench"], "no BENCH_r*.json in the repo root"
+    assert traj["multichip"], "no MULTICHIP_r*.json in the repo root"
     assert traj["serve"], "no SERVE_r*.json in the repo root"
     problems = []
     for kind in ("bench", "multichip", "serve"):
@@ -49,8 +50,7 @@ def test_real_trajectory_has_no_regressions():
     result = regress.compare()
     assert result["root"] == REPO
     assert result["regressions"] == [], regress.render_compare(result)
-    # the headline metrics are tracked with best-so-far context
-    assert "higgs1m_trees_per_sec" in result["metrics"]
+    # the headline metric is tracked with best-so-far context
     assert "serve:serve_sustained_qps_p99lt10ms" in result["metrics"]
 
 
@@ -121,8 +121,8 @@ def test_broken_latest_record_is_a_regression(tmp_path):
 
 
 def test_skipped_latest_round_is_declared_not_broken(tmp_path):
-    # a record carrying skipped=true + a reason (hardware denial, r06
-    # protocol) is not a sample and does not trip the unusable-latest
+    # a record carrying skipped=true + a reason (hardware denial) is
+    # not a sample and does not trip the unusable-latest
     # rule — unlike an rc=0/value=0 record, which does
     _write(tmp_path, "BENCH_r01.json", _bench_rec(2.0))
     _write(tmp_path, "BENCH_r02.json",
@@ -138,7 +138,7 @@ def test_skipped_record_requires_a_reason(tmp_path):
     rec = {**_bench_rec(None), "skipped": True}
     problems = regress.validate_record("bench", "BENCH_r09.json", rec)
     assert any("skip_reason" in p for p in problems)
-    rec["skip_reason"] = "wedged accelerator tunnel"
+    rec["skip_reason"] = "no accelerator on this host"
     assert regress.validate_record("bench", "BENCH_r09.json", rec) == []
 
 

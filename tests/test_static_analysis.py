@@ -281,7 +281,7 @@ def test_trace_rules_fire():
     assert hits(findings) == {
         ("TRACE001", 94),   # sorting_entry: jnp.sort in the jaxpr
         ("TRACE002", 97),   # f64_entry: strong float64 under x64
-        ("TRACE003", 100),  # callback_entry: debug_callback primitive
+        ("TRACE003", 100),  # callback_entry: debug_print primitive
         ("TRACE004", 103),  # dead_donation_entry: donation unusable
         ("TRACE005", 107),  # baked_scalar_entry: static arg re-traces
         ("TRACE006", 1),    # manifest-level coverage findings
