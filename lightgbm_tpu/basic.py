@@ -22,6 +22,7 @@ from .config import Config, param_dict_to_config
 from .data import BinnedDataset, Metadata
 from .metrics import METRIC_ALIASES, create_metric
 from .objectives import create_objective
+from .observability import span
 from .utils.log import Log, LightGBMError
 from .utils.file_io import open_file
 
@@ -300,6 +301,15 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._binned is not None:
             return self
+        # ingest.construct: parsing, bin finding (dataset_sample,
+        # dataset_bounds) and quantizing (dataset_quantize) nest in it
+        with span("ingest.construct") as sp:
+            self._construct()
+            sp.attrs.update(rows=int(self._binned.num_data),
+                            features=int(self._binned.num_total_features))
+        return self
+
+    def _construct(self) -> "Dataset":
         cfg = param_dict_to_config(self.params)
         data = self.data
         if isinstance(data, str):

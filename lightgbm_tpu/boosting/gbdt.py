@@ -18,6 +18,7 @@ differences:
 from __future__ import annotations
 
 import os
+import contextlib
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -34,11 +35,11 @@ from ..learner.split import SplitHyperParams
 from ..metrics import Metric
 from ..objectives import ObjectiveFunction
 from ..observability import registry as _obs
+from ..observability import span
 from ..observability.profile import profiler as _profiler
 from ..reliability import (InjectedFault, counters, faults, guards,
                            retry_call)
 from ..utils.log import Log, LightGBMError
-from ..utils.timer import global_timer
 from ..utils.file_io import open_file
 
 __all__ = ["GBDT", "create_boosting"]
@@ -776,16 +777,24 @@ class GBDT:
             with guard.guard("sharded_grow"):
                 with _profiler.capture("sharded_grow"):
                     out = self._grow_impl(g, h, cnt, feature_mask)
-                    jax.block_until_ready(out)
+                    with span("entry.wait_device", iter=self.iter_,
+                              what="guarded_grow"):
+                        jax.block_until_ready(out)
             return out
 
-        out = retry_call(
-            _attempt, attempts=cfg.retry_max_attempts,
-            backoff_ms=cfg.retry_backoff_ms,
-            backoff_max_ms=cfg.retry_backoff_max_ms,
-            retry_on=self._transient_faults(
-                getattr(self, "_grow_warm", False)),
-            site="histogram_build")
+        # the grower's first call traces, lowers and compiles (or
+        # fetches) the growth program
+        warm = getattr(self, "_grow_warm", False)
+        with contextlib.nullcontext() if warm else span(
+                "boosting.build_program", iter=self.iter_, k=1,
+                program="grow_tree" if self._grower is None
+                else "sharded_grow"):
+            out = retry_call(
+                _attempt, attempts=cfg.retry_max_attempts,
+                backoff_ms=cfg.retry_backoff_ms,
+                backoff_max_ms=cfg.retry_backoff_max_ms,
+                retry_on=self._transient_faults(warm),
+                site="histogram_build")
         self._grow_warm = True
         return out
 
@@ -1009,20 +1018,19 @@ class GBDT:
         cfg = self.config
         k = self.num_tree_per_iteration
         init_scores = [0.0] * k
-        # observability: off path is this one branch; the guard
-        # skip-iteration early return below goes unrecorded (rare,
-        # and its counters surface in the next record's deltas)
-        _orec = _obs.enabled
-        if _orec:
-            _obs_iter = self.iter_
-            _obs_ph0 = global_timer.totals()
-            _obs_t0 = time.perf_counter()
+        # the iteration's phase spans, in order: observe=true reads
+        # the telemetry record off them at the end (the guard
+        # skip-iteration early return below goes unrecorded: rare, and
+        # its counters surface in the next record's deltas)
+        it = self.iter_
+        phases: List = []
 
-        with global_timer.timeit("boosting"):
+        with span("boosting.gradients", iter=it) as sp:
             if gradients is None or hessians is None:
                 init_scores = self._take_initial_bias()
                 gradients, hessians = self.objective.get_gradients(
                     self.train_score)
+        phases.append(sp)
 
         guard = cfg.guard_nonfinite
         prev_scores = None
@@ -1041,16 +1049,20 @@ class GBDT:
             prev_scores = (self.train_score,
                            list(getattr(self, "valid_scores", []) or []))
 
-        with global_timer.timeit("bagging"):
+        with span("boosting.bagging", iter=it) as sp:
             grad, hess, cnt = self._bagging(gradients, hessians)
+        phases.append(sp)
 
         should_continue = False
         for cls in range(k):
             g = grad if k == 1 else grad[:, cls]
             h = hess if k == 1 else hess[:, cls]
-            with global_timer.timeit("tree_train"):
+            # asynchronous: this is the host enqueueing the tree's
+            # growth program, not the device growing it
+            with span("entry.dispatch", iter=it) as sp:
                 feature_mask = self._feature_mask()
                 tree, row_node = self._grow(g, h, cnt, feature_mask)
+            phases.append(sp)
             # a host pull of num_leaves waits for the whole tree just
             # dispatched, so the device sits idle until the host asks
             # for the next one. Instead of syncing on the fresh tree,
@@ -1070,13 +1082,17 @@ class GBDT:
             # reference's immediate stop.
             if (self.iter_ == 0 and len(self.trees) < k) or \
                     self._exact_stop_poll:
-                nleaves = int(tree.num_leaves)
+                with span("entry.wait_device", iter=it, what="num_leaves"):
+                    nleaves = int(tree.num_leaves)
                 stop_hint = nleaves <= 1
             else:
                 prev = self._pending_nleaves
-                stop_hint = (prev is not None and
-                             self.iter_ % self._stop_poll_every == 0 and
-                             int(prev) <= 1)
+                stop_hint = False
+                if prev is not None and \
+                        self.iter_ % self._stop_poll_every == 0:
+                    with span("entry.wait_device", iter=it,
+                              what="stop_poll"):
+                        stop_hint = int(prev) <= 1
                 nleaves = 2
             pending = tree.num_leaves
             try:
@@ -1088,61 +1104,71 @@ class GBDT:
             if nleaves > 1:
                 if not stop_hint:
                     should_continue = True
-                if self.objective is not None and \
-                        self.objective.need_renew_tree_output:
-                    rw = cnt if self.objective.weight is None \
-                        else cnt * self.objective.weight
-                    tree = renew_tree_output(
-                        tree, row_node, self.train_score if k == 1
-                        else self.train_score[:, cls],
-                        jnp.asarray(self.objective.label), rw,
-                        self.objective.renew_percentile, cfg.num_leaves)
-                    if getattr(self, "_nproc", 1) > 1:
-                        tree = self._sync_renewed_leaves(tree, row_node,
-                                                         rw)
-                if self._linear:
-                    from ..learner.linear import fit_linear_leaves
-                    with global_timer.timeit("linear_fit"):
-                        lin = fit_linear_leaves(
-                            tree, row_node, self.raw, g, h, cnt,
-                            self.is_cat_d,
-                            jnp.float32(cfg.linear_lambda),
-                            dmax=self._lin_dmax)
-                # shrinkage (tree.cpp Shrinkage): scale leaf outputs and,
-                # for linear leaves, consts + coefficients. The `ok`
-                # factor zeroes trees that made no split (device-side
-                # stand-in for the reference's "no further splits" break)
-                ok = (tree.num_leaves > 1).astype(jnp.float32)
-                tree = tree._replace(
-                    leaf_value=tree.leaf_value * self.shrinkage_rate * ok)
-                if lin is not None:
-                    lin = lin._replace(
-                        const=lin.const * self.shrinkage_rate * ok,
-                        coeff=lin.coeff * self.shrinkage_rate * ok)
-                with global_timer.timeit("update_score"):
-                    self._update_score(tree, row_node, cls, lin)
-                if abs(init_scores[cls]) > 1e-35:
-                    # AddBias (gbdt.cpp:416-417): fold init into tree 0
+                with span("boosting.shrink", iter=it) as sp:
+                    if self.objective is not None and \
+                            self.objective.need_renew_tree_output:
+                        rw = cnt if self.objective.weight is None \
+                            else cnt * self.objective.weight
+                        tree = renew_tree_output(
+                            tree, row_node, self.train_score if k == 1
+                            else self.train_score[:, cls],
+                            jnp.asarray(self.objective.label), rw,
+                            self.objective.renew_percentile,
+                            cfg.num_leaves)
+                        if getattr(self, "_nproc", 1) > 1:
+                            tree = self._sync_renewed_leaves(
+                                tree, row_node, rw)
+                    if self._linear:
+                        from ..learner.linear import fit_linear_leaves
+                        with span("boosting.linear_fit", iter=it):
+                            lin = fit_linear_leaves(
+                                tree, row_node, self.raw, g, h, cnt,
+                                self.is_cat_d,
+                                jnp.float32(cfg.linear_lambda),
+                                dmax=self._lin_dmax)
+                    # shrinkage (tree.cpp Shrinkage): scale leaf outputs
+                    # and, for linear leaves, consts + coefficients. The
+                    # `ok` factor zeroes trees that made no split
+                    # (device-side stand-in for the reference's "no
+                    # further splits" break)
+                    ok = (tree.num_leaves > 1).astype(jnp.float32)
                     tree = tree._replace(
-                        leaf_value=jnp.where(
-                            tree.split_feature < 0,
-                            tree.leaf_value + init_scores[cls],
-                            tree.leaf_value))
+                        leaf_value=tree.leaf_value * self.shrinkage_rate
+                        * ok)
                     if lin is not None:
-                        lin = lin._replace(const=jnp.where(
-                            tree.split_feature < 0,
-                            lin.const + init_scores[cls], lin.const))
-            else:
-                if self.iter_ == 0 and len(self.trees) < k:
+                        lin = lin._replace(
+                            const=lin.const * self.shrinkage_rate * ok,
+                            coeff=lin.coeff * self.shrinkage_rate * ok)
+                phases.append(sp)
+                with span("boosting.update_score", iter=it) as sp:
+                    self._update_score(tree, row_node, cls, lin)
+                phases.append(sp)
+            with span("entry.append_tree", iter=it) as sp:
+                if nleaves > 1:
+                    if abs(init_scores[cls]) > 1e-35:
+                        # AddBias (gbdt.cpp:416-417): fold init into
+                        # tree 0
+                        tree = tree._replace(
+                            leaf_value=jnp.where(
+                                tree.split_feature < 0,
+                                tree.leaf_value + init_scores[cls],
+                                tree.leaf_value))
+                        if lin is not None:
+                            lin = lin._replace(const=jnp.where(
+                                tree.split_feature < 0,
+                                lin.const + init_scores[cls], lin.const))
+                elif self.iter_ == 0 and len(self.trees) < k:
                     if self.objective is not None and \
                             not cfg.boost_from_average and \
                             not self._has_init_score:
-                        init_scores[cls] = self.objective.boost_from_score(cls)
+                        init_scores[cls] = \
+                            self.objective.boost_from_score(cls)
                         self._add_const_score(init_scores[cls], cls)
                     tree = self._constant_tree(init_scores[cls])
-            self.trees.append(tree)
-            self.tree_class.append(cls)
-            self.linear_models.append(lin)
+                self.trees.append(tree)
+                self.tree_class.append(cls)
+                self.linear_models.append(lin)
+            phases.append(sp)
         self.iter_ += 1
         if guard != "off" and not guards.all_finite(
                 self.train_score,
@@ -1166,10 +1192,12 @@ class GBDT:
                         self.tree_class.append(cls)
                         self.linear_models.append(None)
                     self.iter_ += 1
-        if _orec:
+        if _obs.enabled:
+            walls: Dict[str, float] = {}
+            for sp in phases:
+                walls[sp.name] = walls.get(sp.name, 0.0) + sp.duration
             _obs.record_train_iteration(
-                self, _obs_iter, _obs_t0, time.perf_counter() - _obs_t0,
-                phases=_obs.phase_deltas(_obs_ph0),
+                self, it, phases[-1].end - phases[0].start, phases=walls,
                 gradients=gradients, hessians=hessians,
                 tree=self.trees[-1] if self.trees else None)
         return not should_continue
@@ -1409,22 +1437,56 @@ class GBDT:
 
     def finalize_block(self, handle: dict) -> bool:
         """Second half of train_many: unpack the dispatched block's
-        stacked trees into per-tree views on self.trees. Pure host work
-        (tree_map slicing; no device sync) whose only effect is the
-        tree list — scores, RNG, iter_, valid trajectories and the
-        stall poll were already advanced by train_many_dispatch, so the
-        pipelined executor defers this call into the window where the
-        NEXT block is running on device."""
+        stacked trees into per-tree views on self.trees. Its only
+        effect is the tree list — scores, RNG, iter_, valid
+        trajectories and the stall poll were already advanced by
+        train_many_dispatch, so the pipelined executor defers this call
+        until the NEXT block has been dispatched.
+
+        It is host code that makes no explicit sync, but it is not free
+        of the device: every slice is a small device program. On the
+        chip those queue behind the block in flight, so the first
+        `entry.unpack_tree` of a block waits that block out and the
+        rest run with the device idle, about 16 ms a tree (PERF.md
+        section 6). The wall of the `entry.unpack_block` span is left on
+        the handle as `unpack_s`."""
         if handle["mode"] == "fused":
             stacked, kcls = handle["stacked"], handle["kcls"]
-            for i in range(handle["k"]):
-                for c in range(kcls):
-                    self.trees.append(jax.tree_util.tree_map(
-                        (lambda a: a[i, c]) if kcls > 1
-                        else (lambda a: a[i]), stacked))
-                    self.tree_class.append(c if kcls > 1 else 0)
-                    self.linear_models.append(None)
+            it0, k = handle["iter"], handle["k"]
+            with span("entry.unpack_block", iter=it0, k=k) as unpack:
+                for i in range(k):
+                    for c in range(kcls):
+                        with span("entry.unpack_tree", iter=it0, k=k,
+                                  tree=(it0 + i) * kcls + c):
+                            self.trees.append(jax.tree_util.tree_map(
+                                (lambda a: a[i, c]) if kcls > 1
+                                else (lambda a: a[i]), stacked))
+                            self.tree_class.append(c if kcls > 1 else 0)
+                            self.linear_models.append(None)
+            handle["unpack_s"] = unpack.duration
+            self._obs_close_block(it0, unpack.end)
         return handle["stop"]
+
+    def _obs_close_block(self, iter0: Optional[int], now: float) -> None:
+        """observe=true: the telemetry record of the fused block still
+        open, made when the next block is dispatched or when the block
+        itself is unpacked, whichever comes first. Its wall runs from
+        its own dispatch to `now` (a span's clock reading): the device
+        works through blocks back to back, so dispatch to dispatch is
+        what a block costs, and no sync is made to measure it.
+        `iter0` names the block being unpacked (None: close whichever
+        is open)."""
+        open_ = getattr(self, "_obs_block", None)
+        if open_ is None or (iter0 is not None and open_[0] != iter0):
+            return
+        self._obs_block = None
+        _obs.record_fused_block(self, open_[0], open_[1], now - open_[2])
+
+    def _fused_warm_at(self, k: int) -> bool:
+        """Whether the fused closure has run a block of length k once:
+        k is a static argument, so each new length is a new program."""
+        return getattr(self, "_fused_run", None) is not None \
+            and k in self._fused_warm
 
     @staticmethod
     def _buffer_deleted(arr) -> bool:
@@ -1531,15 +1593,20 @@ class GBDT:
                     "dispatch and deleted by the runtime; cannot retry")
             try:
                 _maybe_inject_fused_fault()
-                if getattr(self, "_fused_run", None) is None:
-                    self._fused_run = self._build_fused()
-                    self._fused_warm = set()
-                keys = None
-                if getattr(self, "_fused_needs_keys", False):
-                    # the same _next_key sequence the per-iteration GOSS
-                    # path would draw, pre-drawn as scan inputs
-                    keys = jnp.stack([self._next_key() for _ in range(k)])
-                with global_timer.timeit("tree_train"):
+                # k is a static argument: a block length's first run
+                # traces, lowers and compiles (or fetches) its program
+                with contextlib.nullcontext() if self._fused_warm_at(k) \
+                        else span("boosting.build_program",
+                                  program="fused_train", iter=iter0, k=k):
+                    if getattr(self, "_fused_run", None) is None:
+                        self._fused_run = self._build_fused()
+                        self._fused_warm = set()
+                    keys = None
+                    if getattr(self, "_fused_needs_keys", False):
+                        # the same _next_key sequence the per-iteration
+                        # GOSS path would draw, pre-drawn as scan inputs
+                        keys = jnp.stack([self._next_key()
+                                          for _ in range(k)])
                     return self._fused_run(
                         self.train_score,
                         jnp.asarray(self.iter_, jnp.int32),
@@ -1548,24 +1615,20 @@ class GBDT:
                 self._fused_run = None  # closure may hold dead executables
                 raise
 
-        _orec = _obs.enabled
-        if _orec:
-            _obs_iter0 = self.iter_
-            _obs_was_built = getattr(self, "_fused_run", None) is None
-            _obs_t0 = time.perf_counter()
-        # block lengths this fused closure has run once: k is a static
-        # argument, so each new length is a new program
-        transient = self._transient_faults(
-            getattr(self, "_fused_run", None) is not None
-            and k in self._fused_warm)
+        iter0 = self.iter_
+        transient = self._transient_faults(self._fused_warm_at(k))
         try:
             # capped-exponential-backoff retries before degrading: a
-            # transient launch failure should not cost the fused path
-            score, stacked = retry_call(
-                _attempt, attempts=cfg.retry_max_attempts,
-                backoff_ms=cfg.retry_backoff_ms,
-                backoff_max_ms=cfg.retry_backoff_max_ms,
-                retry_on=transient, site="fused_dispatch")
+            # transient launch failure should not cost the fused path.
+            # The span times the host enqueueing the block (and, the
+            # first time, building its program): the scan is
+            # asynchronous and nothing here waits for it
+            with span("entry.dispatch", iter=iter0, k=k) as dispatch:
+                score, stacked = retry_call(
+                    _attempt, attempts=cfg.retry_max_attempts,
+                    backoff_ms=cfg.retry_backoff_ms,
+                    backoff_max_ms=cfg.retry_backoff_max_ms,
+                    retry_on=transient, site="fused_dispatch")
         except transient as exc:
             # rewind the RNG stream so the per-iteration fallback draws
             # the IDENTICAL key sequence the fused dispatch consumed —
@@ -1598,14 +1661,11 @@ class GBDT:
             return {"mode": "done", "stop": stop}
         self._fused_failures = 0
         self._fused_warm.add(k)
-        if _orec:
-            # the fused scan is lazy: force completion so the recorded
-            # wall covers device work, then record the whole block as
-            # one telemetry record (no host boundary inside it)
-            jax.block_until_ready(score)
-            _obs.record_fused_block(
-                self, _obs_iter0, k, _obs_t0,
-                time.perf_counter() - _obs_t0, _obs_was_built)
+        if _obs.enabled:
+            # one telemetry record per block (no host boundary inside
+            # it), closed at the next dispatch or at its own unpacking
+            self._obs_close_block(None, dispatch.start)
+            self._obs_block = (iter0, k, dispatch.start)
         self.train_score = score
         kcls = self.num_tree_per_iteration
         model_trees = stacked
@@ -1646,8 +1706,13 @@ class GBDT:
         prev = self._pending_nleaves
         crossed = (self.iter_ // self._stop_poll_every !=
                    (self.iter_ - k) // self._stop_poll_every)
-        stop_hint = (prev is not None and not self._exact_stop_poll and
-                     crossed and int(prev) <= 1)
+        stop_hint = False
+        if prev is not None and not self._exact_stop_poll and crossed:
+            # the one place this path waits for the device: the LAST
+            # block's final leaf count, normally long on the host
+            with span("entry.wait_device", iter=iter0, k=k,
+                      what="stop_poll"):
+                stop_hint = int(prev) <= 1
         pending = stacked.num_leaves[k - 1]
         if kcls > 1:
             pending = jnp.max(pending)  # stalled only if EVERY class is
@@ -1657,7 +1722,7 @@ class GBDT:
             pass
         self._pending_nleaves = pending
         return {"mode": "fused", "stacked": model_trees, "k": k,
-                "kcls": kcls, "stop": stop_hint}
+                "kcls": kcls, "stop": stop_hint, "iter": iter0}
 
     @staticmethod
     def _add_bias_to_first(stacked: TreeArrays, bias: List[float],

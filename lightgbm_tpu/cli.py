@@ -282,8 +282,13 @@ class Application:
         stats = getattr(getattr(booster, "gbdt", None),
                         "_pipeline_stats", None)
         if stats is not None and stats.blocks:
+            # not an overlap: on the chip the unpacking waits out the
+            # block in flight and then runs with the device idle
+            # (PERF.md section 6), so this share nears 100% whatever
+            # the device hid
             Log.info("pipelined executor: %d blocks / %d iterations, "
-                     "%.1f%% host/device overlap",
+                     "tree unpacking (entry.unpack_block) %.1f%% of the "
+                     "block walls, its waits for the device included",
                      stats.blocks, stats.iterations,
                      100.0 * stats.overlap_frac)
         booster.save_model(cfg.output_model)

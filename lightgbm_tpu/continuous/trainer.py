@@ -239,7 +239,7 @@ class ContinuousTrainer:
         window = WindowSource(self.source, self.next_chunk,
                               cfg.loop_window_chunks)
         ds = Dataset(window, params=params, free_raw_data=False)
-        with global_timer.timeit("loop_ingest"):
+        with global_timer.timeit("loop_ingest", fine=True):
             ds.construct()
         from ..engine import train
         found = latest_checkpoint(self.work_ckpt)

@@ -8,6 +8,7 @@ and :393 cv, including early stopping via EarlyStopException and
 from __future__ import annotations
 
 import collections
+import contextlib
 import copy
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -16,6 +17,7 @@ import numpy as np
 from . import callback as callback_mod
 from .basic import Booster, Dataset
 from .config import PARAM_ALIASES
+from .observability import span
 from .utils.log import Log
 
 __all__ = ["train", "cv", "CVBooster"]
@@ -40,6 +42,30 @@ def train(params: Dict[str, Any], train_set: Dataset,
           keep_training_booster: bool = False,
           callbacks: Optional[List] = None,
           resume_from: Optional[str] = None) -> Booster:
+    # boosting.init: from here to the first dispatch (Booster and GBDT
+    # construction, placing the bins, the objective's set-up, valid
+    # sets, a resume); _train ends it once the booster is ready, and an
+    # exception on the way ends it here
+    with contextlib.ExitStack() as init:
+        init_span = init.enter_context(span("boosting.init"))
+
+        def set_up_done(booster: Booster) -> None:
+            gb = booster.gbdt
+            init_span.attrs.update(
+                rows=int(getattr(gb, "num_data", 0) or 0),
+                devices=int(getattr(getattr(gb, "mesh", None), "size", 1)
+                            or 1))
+            init.close()
+
+        return _train(set_up_done, params, train_set, num_boost_round,
+                      valid_sets, valid_names, fobj, feval, init_model,
+                      feature_name, categorical_feature, callbacks,
+                      resume_from)
+
+
+def _train(set_up_done, params, train_set, num_boost_round, valid_sets,
+           valid_names, fobj, feval, init_model, feature_name,
+           categorical_feature, callbacks, resume_from) -> Booster:
     params = copy.deepcopy(params or {})
     num_boost_round = _resolve_num_boost_round(params, num_boost_round)
     from .streaming import ChunkSource
@@ -296,6 +322,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
                 evaluation_result_list=evaluation_result_list))
         return evaluation_result_list
 
+    set_up_done(booster)
     evaluation_result_list = []
     try:
         if use_pipeline and start_iter < num_boost_round:
@@ -376,13 +403,20 @@ def train(params: Dict[str, Any], train_set: Dataset,
                         evaluation_result_list = _eval_at(i + j)
                 i += b
                 continue
-            for cb in callbacks_before:
-                cb(callback_mod.CallbackEnv(
-                    model=booster, params=params, iteration=i,
-                    begin_iteration=start_iter, end_iteration=num_boost_round,
-                    evaluation_result_list=None))
-            booster.update(fobj=fobj)
-            evaluation_result_list = _eval_at(i)
+            # one tree (one per class): its phases open inside
+            # GBDT.train_one_iter as children of this span
+            with span("entry.tree", iter=i):
+                if callbacks_before:
+                    with span("entry.callbacks", iter=i, when="before"):
+                        for cb in callbacks_before:
+                            cb(callback_mod.CallbackEnv(
+                                model=booster, params=params, iteration=i,
+                                begin_iteration=start_iter,
+                                end_iteration=num_boost_round,
+                                evaluation_result_list=None))
+                booster.update(fobj=fobj)
+                with span("entry.callbacks", iter=i):
+                    evaluation_result_list = _eval_at(i)
             i += 1
     except callback_mod.EarlyStopException as es:
         # with continued training, iteration indexing covers the merged

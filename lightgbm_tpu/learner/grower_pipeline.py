@@ -139,27 +139,15 @@ class LevelPipelineStats:
     fallback: Optional[str] = None  # set when the monolith ran instead
     lookahead: int = 0
     wall_seconds: float = 0.0
-    entries: list = field(default_factory=list)  # compile-account names
+    entries: list = field(default_factory=list)  # stage names, in order
 
 
-def _cache_size() -> int:
-    try:
-        return _stage._cache_size()
-    except Exception:
-        return -1
-
-
-def _dispatch(entry: str, stats: LevelPipelineStats, compiles, kwargs):
-    """Run one stage, attributing its wall to the compile accounting
-    entry `entry` iff the jit cache grew (first sighting = trace +
-    compile + first dispatch, compiles.py bracketing semantics)."""
-    before = _cache_size()
-    t0 = time.perf_counter()
+def _dispatch(entry: str, stats: LevelPipelineStats, kwargs):
+    """Run one stage. What building each stage program cost is in the
+    compile ledger (observability/compiles.py) under `_stage`, from
+    JAX's own events: one trace, lowering and compile per static
+    stage."""
     out = _stage(**kwargs)
-    if compiles is not None:
-        grew = (before >= 0 and _cache_size() > before)
-        compiles.record(entry, time.perf_counter() - t0 if grew else 0.0,
-                        compiled=grew)
     stats.stages += 1
     stats.entries.append(entry)
     return out
@@ -192,7 +180,6 @@ def grow_tree_pipelined(bins, grad, hess, cnt_weight, feature_mask,
 
     st_acc = stats if stats is not None else LevelPipelineStats()
     st_acc.lookahead = lookahead = max(1, int(lookahead))
-    compiles = _obs.compiles
     plan = growth_plan(
         num_leaves=kw["num_leaves"],
         overshoot=kw.get("overshoot", 0.0),
@@ -207,15 +194,15 @@ def grow_tree_pipelined(bins, grad, hess, cnt_weight, feature_mask,
     t0 = time.time()
     w0 = time.perf_counter()
     state, quant_state = _dispatch(
-        "grow_stage_init", st_acc, compiles,
+        "grow_stage_init", st_acc,
         dict(common, stage="init"))
     common["quant_state"] = quant_state
     for p in range(len(plan.schedule)):
         state = _dispatch(
-            f"grow_stage_pass_{p}", st_acc, compiles,
+            f"grow_stage_pass_{p}", st_acc,
             dict(common, stage=("pass", p), state=state))
     state = _dispatch(
-        "grow_stage_bridge", st_acc, compiles,
+        "grow_stage_bridge", st_acc,
         dict(common, stage="bridge", state=state))
 
     # ---- speculative fixup: chunks of `lookahead`, lagged done poll ----
@@ -226,7 +213,7 @@ def grow_tree_pipelined(bins, grad, hess, cnt_weight, feature_mask,
         chunk = min(lookahead, max_fix - st_acc.fixup_dispatched)
         for _ in range(chunk):
             state = _dispatch(
-                "grow_stage_fixup", st_acc, compiles,
+                "grow_stage_fixup", st_acc,
                 dict(common, stage="fixup", state=state,
                      it=jnp.asarray(it, jnp.int32)))
             it += 1
@@ -245,7 +232,7 @@ def grow_tree_pipelined(bins, grad, hess, cnt_weight, feature_mask,
         prev_done = done_ref
 
     out = _dispatch(
-        "grow_stage_final", st_acc, compiles,
+        "grow_stage_final", st_acc,
         dict(common, stage="final", state=state,
              # only consumed under debug_info, which falls back above —
              # the monolith's value would be the executed (not

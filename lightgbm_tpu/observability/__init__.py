@@ -1,21 +1,25 @@
-"""Unified observability: spans, telemetry, MFU, compiles, exporters.
+"""Unified observability: one span primitive, a compile ledger fed by
+JAX's own events, telemetry, MFU, exporters.
 
-One process-global registry (`registry`) subsumes the fragments that
-grew separately — `utils.timer.global_timer` (phase totals),
-`reliability.counters` (degradation counters), `serving.metrics`
-(per-model request metrics) — and adds what they cannot express:
+One process-global registry (`registry`) over:
 
-- structured spans (`registry.trace.span("grow_tree", iter=i)`) with
-  thread-safe nesting, an in-memory ring, and JSONL / Chrome-Perfetto
-  `trace_event` export (`registry.dump_trace(path)`);
+- THE timed region, `span(name, **attrs)` (trace.py): a
+  ``jax.profiler.TraceAnnotation`` (so it lies on any capture's clock
+  beside the device's operations), a record in a bounded in-memory ring
+  with span and parent ids (JSONL / Chrome-Perfetto export,
+  `registry.dump_trace(path)`), and a per-name total.
+  `utils.timer.global_timer.timeit`, `registry.trace.span` and
+  `observability.span` are the same function. Names are
+  ``<layer>.<phase>`` with the layer names of PERF.md section 3;
+- the compile ledger (compiles.py): per jitted function, what JAX's
+  monitoring events say its tracing, lowering and backend compile (or
+  cache retrieval) cost, and inside which span it was built;
 - per-iteration training telemetry (iteration wall time, phase split,
   grad/hess norms, leaves grown, bagging fraction, reliability-counter
-  deltas) hooked into `boosting/gbdt.py`;
+  deltas) hooked into `boosting/gbdt.py`, read off the spans;
 - device-utilization accounting: achieved MACs from the MXU histogram
   kernel dimensions (nchan * S * N * F * B, learner/histogram_mxu.py)
   turned into achieved-TFLOP/s and model-flops-utilization (MFU);
-- compile accounting (compile count/seconds per jitted entry,
-  shape-bucket hits — the serving bucket-cache semantics);
 - exporters: `registry.snapshot()` JSON dict, Prometheus text format
   (served from `serving/server.py` at /metrics), `dump_trace(path)`;
 - a crash flight recorder (`recorder`, flightrec.py): bounded ring of
@@ -29,10 +33,14 @@ grew separately — `utils.timer.global_timer` (phase totals),
 - the bench regression sentinel (regress.py, ``bench.py --compare``)
   checking the BENCH_r*/MULTICHIP_r* trajectory for perf drops.
 
-The registry is disabled by default; every instrumentation site is a
-single `if registry.enabled:` branch, so the off path costs one
-attribute read (<2% of any phase). Enable with the `observe` parameter
-(config.py), `registry.enable()`, or per-surface flags.
+Always on: the annotation, the totals, the compile ledger, and the ring
+record of PHASE-level spans (a set-up step, a block, a tree and its
+phases), which is what gives the flight recorder its spans. The
+`observe` parameter (config.py, or `registry.enable()`) adds the FINE
+spans (per request, per batch, per chunk) to the ring, the per-
+iteration telemetry and MFU records. No span syncs the device, moves
+data or builds a program, and `observe` changes neither the model nor
+the number of device syncs (docs/Observability.md).
 
 Reference analog: Common::Timer / FunctionTimer RAII accumulators
 printed under USE_TIMETAG (include/LightGBM/utils/common.h:973) — here
@@ -49,7 +57,7 @@ from .merge import merge_traces
 from .profile import SpanProfiler, profiler
 from .registry import ObservabilityRegistry, registry
 from .telemetry import TrainingTelemetry
-from .trace import Span, Trace
+from .trace import Span, Trace, span
 
 __all__ = [
     "registry", "ObservabilityRegistry", "Trace", "Span",
@@ -61,7 +69,6 @@ __all__ = [
 ]
 
 # module-level conveniences bound to the process-global registry
-span = registry.trace.span
 snapshot = registry.snapshot
 dump_trace = registry.dump_trace
 prometheus_text = registry.prometheus_text

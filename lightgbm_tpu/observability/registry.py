@@ -30,13 +30,13 @@ from typing import Dict, List, Optional
 
 from ..reliability.counters import counters as _rel_counters
 from ..utils.timer import global_timer as _global_timer
-from .compiles import CompileAccounting
+from .compiles import ledger as _compile_ledger
 from .export import render_prometheus
 from .flightrec import current_rank, recorder as _flightrec
 from .mfu import DeviceUtilization, tree_macs
 from .profile import profiler as _profiler
-from .telemetry import PHASE_KEYS, TrainingTelemetry
-from .trace import Trace
+from .telemetry import TrainingTelemetry
+from .trace import tracer as _tracer
 
 __all__ = ["ObservabilityRegistry", "registry"]
 
@@ -49,9 +49,12 @@ class ObservabilityRegistry:
         self._lock = threading.Lock()
         self.enabled = False
         self.record_norms = False   # host-sync stats (norms, leaves)
-        self.trace = Trace()
+        # the process-global span trace (trace.py) and compile ledger
+        # (compiles.py): the same objects `observability.span`,
+        # `global_timer` and JAX's monitoring listeners feed
+        self.trace = _tracer
         self.training = TrainingTelemetry()
-        self.compiles = CompileAccounting()
+        self.compiles = _compile_ledger
         self.mfu = DeviceUtilization()
         # pipelined-executor aggregates (pipeline/executor.py): how much
         # of the block walls the overlapped host work covered
@@ -502,17 +505,14 @@ class ObservabilityRegistry:
         gbdt._obs_tree_macs = macs
         return macs
 
-    def phase_deltas(self, before: Dict[str, float]) -> Dict[str, float]:
-        """Per-iteration phase walls from two global_timer snapshots."""
-        now = self.timer.totals()
-        return {k: now.get(k, 0.0) - before.get(k, 0.0)
-                for k in PHASE_KEYS if now.get(k, 0.0) > before.get(k, 0.0)}
-
-    def record_train_iteration(self, gbdt, iteration: int, t0: float,
+    def record_train_iteration(self, gbdt, iteration: int,
                                wall_s: float,
                                phases: Optional[Dict[str, float]] = None,
                                gradients=None, hessians=None,
                                tree=None) -> None:
+        """One telemetry record for a per-iteration boosting step.
+        `wall_s` and `phases` are the durations of the iteration's own
+        spans (boosting/gbdt.py train_one_iter), not clocks read here."""
         if not self.enabled:
             return
         trees = int(getattr(gbdt, "num_tree_per_iteration", 1))
@@ -536,39 +536,36 @@ class ObservabilityRegistry:
             macs=macs or None, counters=self.counters.snapshot(), **extra)
         if macs:
             self.mfu.add(macs, wall_s, trees)
-        self.trace.add("train_iter", t0, wall_s, iteration=int(iteration))
 
-    def record_fused_block(self, gbdt, iteration: int, k: int, t0: float,
-                           wall_s: float, was_built: bool) -> None:
+    def record_fused_block(self, gbdt, iteration: int, k: int,
+                           wall_s: float) -> None:
         """One record for a k-iteration fused scan dispatch (no host
-        boundary inside the block). The first dispatch of a fused
-        program is its compilation — counted under entry
-        "fused_train" with the bracketing semantics of compiles.py."""
+        boundary inside the block). `wall_s` is the host clock from this
+        block's dispatch to the next one's (the first record of a run
+        has none and is not made): the device works through blocks back
+        to back, so that is what a block costs, and nothing syncs to
+        measure it. What building the program cost is in the compile
+        ledger, from JAX's own events."""
         if not self.enabled:
             return
         kcls = int(getattr(gbdt, "num_tree_per_iteration", 1))
         trees = int(k) * kcls
         macs = self.tree_macs_for(gbdt) * trees
-        self.compiles.record("fused_train",
-                             wall_s if was_built else 0.0,
-                             compiled=was_built)
         self.training.record_iteration(
             iteration, wall_s, trees=trees, iterations=int(k), fused=True,
             bagging_fraction=float(gbdt.config.bagging_fraction),
             macs=macs or None, counters=self.counters.snapshot())
         if macs:
             self.mfu.add(macs, wall_s, trees)
-        self.trace.add("fused_block", t0, wall_s, iterations=int(k),
-                       compiled=bool(was_built))
 
-    def record_pipeline_block(self, iteration: int, k: int, t0: float,
-                              wall_s: float, host_s: float,
-                              overlap_frac: float) -> None:
-        """One pipelined-executor block: wall_s spans dispatch to metric
-        sync, host_s is the overlapped host window inside it (previous
-        block's tree unpacking + scheduling). Training compute itself is
-        already recorded by record_fused_block — this layer only
-        accounts the overlap."""
+    def record_pipeline_block(self, k: int, wall_s: float,
+                              host_s: float) -> None:
+        """One pipelined-executor block, from its `entry.block` span:
+        wall_s runs from the dispatch to the end of the metric sync,
+        host_s is the previous block's tree unpacking inside it. On the
+        chip that unpacking does NOT overlap the device (PERF.md section
+        6): its slice programs queue behind the running block, so
+        host_s reads about the block's wall."""
         if not self.enabled:
             return
         with self._lock:
@@ -577,10 +574,6 @@ class ObservabilityRegistry:
             p["iterations"] += int(k)
             p["host_seconds"] += float(host_s)
             p["wall_seconds"] += float(wall_s)
-        self.trace.add("pipeline_block", t0, wall_s, iteration=int(iteration),
-                       iterations=int(k),
-                       host_ms=round(float(host_s) * 1e3, 3),
-                       overlap_frac=round(float(overlap_frac), 4))
 
     def record_level_pipeline(self, iteration: int, t0: float,
                               wall_s: float, stages: int,
@@ -603,10 +596,6 @@ class ObservabilityRegistry:
             p["fixup_speculative"] += int(fixup_speculative)
             p["early_stops"] += int(bool(stopped_early))
             p["wall_seconds"] += float(wall_s)
-        self.trace.add("level_pipeline", t0, wall_s,
-                       iteration=int(iteration), stages=int(stages),
-                       fixup=int(fixup_dispatched),
-                       speculative=int(fixup_speculative))
 
     def record_streaming_chunk(self, phase: str, chunk_index: int,
                                t0: float, wall_s: float, rows: int,
@@ -623,9 +612,6 @@ class ObservabilityRegistry:
                 s["rows"] += int(rows)
             s["bytes"] += int(nbytes)
             s["wall_seconds"] += float(wall_s)
-        self.trace.add("streaming_chunk", t0, wall_s, phase=str(phase),
-                       chunk=int(chunk_index), rows=int(rows),
-                       bytes=int(nbytes))
 
     def record_streaming_sketch(self, sample_rows: int,
                                 exact: bool) -> None:

@@ -21,13 +21,14 @@ from typing import Dict, List, Optional
 
 __all__ = ["TrainingTelemetry", "PHASE_KEYS"]
 
-#: phase-timer keys recorded per iteration (utils/timer.py names).
-#: `tree_train` is ONE fused device dispatch covering histogram build,
-#: split search and routing — the on-device phases are not separable
-#: host-side without a device profiler; `update_score` is the apply
-#: (score-update) phase.
-PHASE_KEYS = ("boosting", "bagging", "tree_train", "update_score",
-              "linear_fit")
+#: span names recorded per iteration as its phases (boosting/gbdt.py
+#: train_one_iter). All are HOST walls: `entry.dispatch` is the time to
+#: enqueue one tree's growth program (asynchronous: microseconds to
+#: milliseconds, not the tree's device time, which only a device trace
+#: shows); `boosting.update_score` enqueues the score update.
+PHASE_KEYS = ("boosting.gradients", "boosting.bagging", "entry.dispatch",
+              "boosting.shrink", "boosting.update_score",
+              "boosting.linear_fit", "entry.append_tree")
 
 
 class TrainingTelemetry:

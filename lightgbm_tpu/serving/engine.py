@@ -22,12 +22,10 @@ hit is a cached dispatch. Both counts surface in the metrics snapshot.
 from __future__ import annotations
 
 import threading
-import time
 from typing import Dict, Tuple
 
 import numpy as np
 
-from ..observability import registry as _obs
 from ..utils.timer import global_timer
 from .forest import DeviceForest
 
@@ -117,22 +115,16 @@ class BucketedPredictor:
             hit = self._record(forest, bucket)
             if metrics is not None:
                 metrics.record_batch(bucket_hit=hit, compiled=not hit)
-            _t0 = time.perf_counter()
-            with global_timer.timeit("serve_device_predict"):
+            # a bucket-cache miss makes JAX build the serving predictor
+            # for this shape: the compile ledger (observability/
+            # compiles.py) books that under this span's name
+            with global_timer.timeit("serve_device_predict", fine=True,
+                                     bucket=bucket, rows=rows):
                 raw = predict_binned_forest(
                     forest.stacked, forest.tree_class, jnp.asarray(chunk),
                     forest.num_bins, forest.missing_is_nan,
                     num_outputs=forest.num_outputs, row_valid=valid)
                 raw = np.asarray(raw)    # device -> host sync
-            if _obs.enabled:
-                # a bucket-cache miss IS a compilation of the serving
-                # predictor for this shape (module docstring); fold it
-                # into the unified compile accounting + span trace
-                _dt = time.perf_counter() - _t0
-                _obs.compiles.record(f"serving_predict_b{bucket}", _dt,
-                                     compiled=not hit)
-                _obs.trace.add("serve_device_predict", _t0, _dt,
-                               bucket=bucket, rows=rows)
             outs.append(raw[:rows])
             lo = hi
         return np.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]

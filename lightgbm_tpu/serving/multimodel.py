@@ -46,7 +46,6 @@ scheduler) serves the whole pack.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -315,7 +314,6 @@ def dispatch_pack(engine, pack: ForestPack,
     """
     import jax.numpy as jnp
 
-    from ..observability import registry as _obs
     from ..reliability import faults
 
     # registered fault site: the fused multi-model dispatch boundary
@@ -324,7 +322,7 @@ def dispatch_pack(engine, pack: ForestPack,
 
     if not requests:
         return np.zeros((0, pack.num_outputs), np.float32)
-    with global_timer.timeit("serve_pack_predict"):
+    with global_timer.timeit("serve_pack_predict", fine=True):
         by_slot: Dict[int, List[np.ndarray]] = {}
         spans: List[Tuple[int, int, int]] = []   # (slot, start, rows)
         for slot, bins in requests:
@@ -357,23 +355,19 @@ def dispatch_pack(engine, pack: ForestPack,
                     m = metrics_by_slot.get(s)
                     if m is not None:
                         m.record_batch(bucket_hit=hit, compiled=not hit)
-            _t0 = time.perf_counter()
-            raw = predict_packed_forest(
-                pack.stacked, pack.tree_model, pack.tree_class,
-                jnp.int32(pack.num_trees), jnp.asarray(packed),
-                pack.num_bins, pack.missing_is_nan,
-                num_outputs=pack.num_outputs, row_block=block,
-                row_valid=jnp.asarray(valid))
-            raw = np.asarray(raw)        # device -> host sync
-            _dt = time.perf_counter() - _t0
-            if _obs.enabled:
-                # a pack bucket-cache miss IS an XLA compilation of the
-                # fused predictor for this block shape
-                _obs.compiles.record(f"serving_pack_b{block}", _dt,
-                                     compiled=not hit)
-                _obs.trace.add("serve_pack_predict", _t0, _dt,
-                               block=block, slots=len(this_round),
-                               rows=sum(this_round.values()))
+            # a pack bucket-cache miss makes JAX build the fused
+            # predictor for this block shape (the compile ledger books
+            # it under this span's name)
+            with global_timer.timeit("serve_pack_dispatch", fine=True,
+                                     block=block, slots=len(this_round),
+                                     rows=sum(this_round.values())):
+                raw = predict_packed_forest(
+                    pack.stacked, pack.tree_model, pack.tree_class,
+                    jnp.int32(pack.num_trees), jnp.asarray(packed),
+                    pack.num_bins, pack.missing_is_nan,
+                    num_outputs=pack.num_outputs, row_block=block,
+                    row_valid=jnp.asarray(valid))
+                raw = np.asarray(raw)        # device -> host sync
             if pack_metrics is not None:
                 pack_metrics.record_dispatch(
                     rows=sum(this_round.values()),

@@ -212,7 +212,8 @@ class ReplicaSet:
                     return engine.predict_raw(_rep.forest, bins,
                                               metrics=metrics)
 
-                with global_timer.timeit("serve_replica_dispatch"):
+                with global_timer.timeit("serve_replica_dispatch",
+                                         fine=True):
                     raw = retry_call(
                         _one_attempt,
                         attempts=retry_attempts,

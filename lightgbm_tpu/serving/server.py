@@ -194,7 +194,7 @@ class Server:
         with self._lock:
             if self._closed:
                 raise RuntimeError("server is closed")
-        with global_timer.timeit("serve_model_load"):
+        with global_timer.timeit("serve_model_load", fine=True):
             entry = self.registry.load(name, booster=booster,
                                        model_file=model_file,
                                        model_str=model_str)
@@ -216,7 +216,7 @@ class Server:
                 raise RuntimeError("server is closed")
         members = list(members)
         entries = []
-        with global_timer.timeit("serve_model_load"):
+        with global_timer.timeit("serve_model_load", fine=True):
             for i in range(0, len(members), self.pack_size):
                 chunk = members[i:i + self.pack_size]
                 nm = pack_name if i == 0 else \
@@ -243,7 +243,7 @@ class Server:
                 raise RuntimeError("server is closed")
         if name not in self.registry:
             raise LightGBMError(f"model '{name}' is not loaded")
-        with global_timer.timeit("serve_hot_swap"):
+        with global_timer.timeit("serve_hot_swap", fine=True):
             # registered fault site: a swap that dies mid-way must
             # leave the old entry serving (docs/Reliability.md)
             faults.inject("serving_hot_swap")
@@ -331,7 +331,7 @@ class Server:
             # cooldowns pending: the bottom rung answers directly
             self._host_resolve(entry, X, raw_score, t0, out)
             return out
-        with global_timer.timeit("serve_bin_rows"):
+        with global_timer.timeit("serve_bin_rows", fine=True):
             bins = entry.forest.bin_rows(X)
         # pack members share the PACK's slot-aware queue; solo models
         # keep their own
@@ -416,7 +416,7 @@ class Server:
                       raw_score: bool, t0: float, out: Future) -> None:
         """Serve via Booster/HostModel predict (CPU fallback path)."""
         try:
-            with global_timer.timeit("serve_host_fallback"):
+            with global_timer.timeit("serve_host_fallback", fine=True):
                 res = entry.booster.predict(X, raw_score=raw_score)
         except Exception as exc:
             entry.metrics.record_error()

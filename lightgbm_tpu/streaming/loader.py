@@ -39,6 +39,7 @@ import numpy as np
 from ..binning import BinMapper, bin_columns, find_bin_mappers
 from ..data import BinnedDataset, Metadata, _select_used_features
 from ..observability import registry as _obs
+from ..observability import span
 from ..reliability.counters import counters
 from ..reliability.faults import faults
 from ..utils.log import Log, LightGBMError
@@ -420,7 +421,10 @@ def build_streamed_dataset(
         return c, time.perf_counter() - t
 
     row0, ci = 0, 0
-    with ThreadPoolExecutor(max_workers=1) as pool:
+    # the quantizing pass under the name from_raw gives it: one span a
+    # dataset (the chunks inside are counted, not spanned)
+    with span("dataset_quantize"), \
+            ThreadPoolExecutor(max_workers=1) as pool:
         fut = pool.submit(_pull)
         while True:
             chunk, parse_s = fut.result()

@@ -16,7 +16,7 @@ Three contracts are pinned here:
 - **compile bound**: distinct stage programs per (shape, config) ==
   ``growth_plan(...).n_stage_programs``, each compiling EXACTLY once —
   a shape leak that recompiled per level or per tree would show up as
-  compiles > 1 in the ``grow_stage_*`` compile-accounting entries;
+  more than that many builds of ``_stage`` in the compile ledger;
 - **overlap accounting**: LevelPipelineStats counts (stages, fixup
   dispatch, speculative lower bound, early stop) obey the dispatch
   algebra — count-based, no wall-clock thresholds.
@@ -79,18 +79,20 @@ def test_compile_count_bounded_and_no_shape_leak():
     plan = growth_plan(num_leaves=kw["num_leaves"])
     _obs.compiles.reset()
 
-    grow_tree_pipelined(*args_a, lookahead=2, **kw)
-    snap = {k: v for k, v in _obs.compiles.snapshot().items()
-            if k.startswith("grow_stage_")}
-    assert len(snap) == plan.n_stage_programs
-    assert set(snap) == ({"grow_stage_init", "grow_stage_bridge",
-                          "grow_stage_fixup", "grow_stage_final"} |
-                         {f"grow_stage_pass_{p}"
-                          for p in range(len(plan.schedule))})
-    # one compiled program per entry — the fixup program is compiled
-    # once and re-dispatched with a traced iteration index
-    for entry, rec in snap.items():
-        assert rec["compiles"] == 1, (entry, rec)
+    stats = LevelPipelineStats()
+    grow_tree_pipelined(*args_a, lookahead=2, stats=stats, **kw)
+    assert set(stats.entries) == ({"grow_stage_init", "grow_stage_bridge",
+                                   "grow_stage_fixup", "grow_stage_final"} |
+                                  {f"grow_stage_pass_{p}"
+                                   for p in range(len(plan.schedule))})
+    # one compiled program per stage, as JAX's own events count them
+    # (the compile ledger keys them by the jitted function, `_stage`):
+    # the fixup program is compiled once and re-dispatched with a
+    # traced iteration index
+    assert len(set(stats.entries)) == plan.n_stage_programs
+    built = _obs.compiles.snapshot()["_stage"]
+    assert built["built"] == built["lowered"] == plan.n_stage_programs
+    assert built["backend_seconds"] > 0
 
 
 def test_fixup_program_retrace_stable():
