@@ -42,7 +42,7 @@ pipeline:
 
 # the histogram-kernel tier: scatter/mxu/oracle parity (incl.
 # adversarial bin distributions and the quantized bit-exactness
-# contract), hist_backend resolution + autotune (tests/
+# contract), hist_backend resolution + the per-pass rule (tests/
 # test_hist_backends.py, docs/Performance.md) — the fast subset is
 # tier-1; `-m "kernels and slow"` adds tree/model byte-parity
 kernels:

@@ -72,16 +72,15 @@ PARAMS = {"objective": "binary", "num_leaves": NUM_LEAVES,
           "growth_overshoot": float(os.environ.get("BENCH_OVERSHOOT",
                                                    1.75)),
           "growth_bridge_gate": 0.93,
-          # histogram kernel: "auto" autotunes mxu vs the Pallas
-          # scatter kernel on device and pins the winner (byte-neutral
-          # in the quantized posture). Pin explicitly to measure one
-          # backend, e.g. LGBM_TPU_HIST_BACKEND=mxu.
+          # histogram formulation: "auto" chooses one-hot or
+          # slot-grouped per pass from static shapes (byte-neutral in
+          # the quantized posture). Pin explicitly to measure one
+          # formulation on every pass, e.g. LGBM_TPU_HIST_BACKEND=mxu.
           "hist_backend": os.environ.get("LGBM_TPU_HIST_BACKEND",
                                          "auto"),
-          # row partition for the slot-grouped scatter kernels: "auto"
-          # resolves to the blocked-prefix-sum scan (byte-identical to
-          # the argsort oracle); pin LGBM_TPU_PARTITION_IMPL=argsort
-          # to measure the other one.
+          # row partition of the slot-grouped build: "auto" resolves
+          # to the rank sweep (byte-identical to the argsort oracle);
+          # pin LGBM_TPU_PARTITION_IMPL=argsort to measure the other.
           "partition_impl": os.environ.get("LGBM_TPU_PARTITION_IMPL",
                                            "auto")}
 if int(os.environ.get("BENCH_LEVEL_PIPELINE", "0")):
@@ -534,11 +533,12 @@ def main(argv):
     result["vs_baseline"] = round(median_rate / BASELINE_TREES_PER_SEC, 3)
     result["vs_single_core"] = round(
         median_rate / SINGLE_CORE_TREES_PER_SEC, 3)
-    # which histogram backend actually ran (+ autotune timings) —
-    # pinned once per process by GBDT._resolved_hist_backend and
-    # recorded regardless of the observability enable flag
+    # which histogram backend ran and its per-pass plan: pinned once
+    # per process by GBDT._resolved_hist_backend and recorded
+    # regardless of the observability enable flag
     result["hist_backend"] = _obs.hist_backend_snapshot()
-    if result["hist_backend"]["choice"] == "mxu":
+    plan = result["hist_backend"]["plan"]
+    if plan and all(p["formulation"] == "onehot" for p in plan):
         # device utilization: analytic MACs of one tree at the bench
         # posture (quantized grads -> 3 histogram channels; binary
         # log-loss has non-constant hessians, so the const-hessian
@@ -610,10 +610,8 @@ def _report(result, block_times, block_trees, bench):
               f"(observability/mfu.py, slight lower bound), {mfu_s}",
               file=sys.stderr)
     hb = result["hist_backend"]
-    tim = ", ".join(f"{k[:-3]} {v:.2f}ms" for k, v in sorted(hb.items())
-                    if k.endswith("_ms"))
-    print(f"# histogram backend: {hb['choice']} "
-          f"({'autotuned: ' + tim if hb['autotuned'] else 'pinned'})",
+    print(f"# histogram backend: {hb['choice']} (" + ", ".join(
+          f"{p['sk']}:{p['formulation']}" for p in hb["plan"]) + ")",
           file=sys.stderr)
     for row in result["tasks"]:
         print(f"# task {row['task']}: {row['value']:.2f} trees/sec "

@@ -5,8 +5,9 @@ One process, through the entry points a user calls (lgb.Dataset,
 lgb.train, serving.Server), at the full width of the Higgs-shaped
 configuration: 1,000,000 x 28, 255 bins, 255 leaves. Depth is cut to a
 few fused blocks per leg. It trains under the library defaults, under
-the bench posture (whose autotune builds both histogram kernels on the
-real bin matrix) and once more with the kernel that lost, serves the
+the bench posture (hist_backend=auto: a formulation per pass, from
+static shapes) and once more with every pass on the formulation auto
+used least, serves the
 second booster from every local device, and, where four devices are
 visible, trains data-parallel over them. After every leg it checks that
 the run took the device path it was meant to take: no fallback, no
@@ -121,16 +122,17 @@ def train_leg(name, params, rounds, dtrain, dvalid, clock, *, on_tpu,
     gb = bst.gbdt
     auc = [float(v) for v in evals["held_out"]["auc"]]
     leaves = [int(t.num_leaves) for t in gb.trees]
-    tune = dict(getattr(gb, "_hist_autotune", None) or {})
+    plan = gb._hist_plan_attrs()
     stats = getattr(gb, "_pipeline_stats", None)
     rec = {
         "trees": len(gb.trees), "min_leaves": min(leaves),
         "hist_impl": gb._hist_impl,
         "sharded_mxu": bool(getattr(gb, "_sharded_mxu", False)),
-        "hist_backend": tune.get("choice", ""),
-        "autotuned": bool(tune.get("autotuned", False)),
-        "autotune_ms": {k: round(float(v), 3) for k, v in
-                        (tune.get("timings_ms") or {}).items()},
+        "hist_backend": getattr(gb, "_hist_backend", None) or "",
+        # the growth program's histogram passes, kernel slots and
+        # formulation each (static: grower_mxu.hist_pass_plan)
+        "hist_plan": plan.get("hist_plan", ""),
+        "grouped_passes_per_tree": plan.get("grouped_passes_per_tree", 0),
         "fused_blocks": stats.block_sizes if stats else [],
         # dispatch-to-results wall of each block: the first one carries
         # the compile, a later one of the same length does not
@@ -157,9 +159,6 @@ def train_leg(name, params, rounds, dtrain, dvalid, clock, *, on_tpu,
     snap = counters.snapshot()
     check(snap["fallbacks"] == 0 and snap["device_retries"] == 0,
           "leg %s: reliability counters %s" % (name, snap))
-    check(all(math.isfinite(v) for v in rec["autotune_ms"].values()),
-          "leg %s: autotune timing not finite: %s"
-          % (name, rec["autotune_ms"]))
     if on_tpu:
         check(gb._hist_impl == "mxu" or getattr(gb, "_sharded_mxu", False),
               "leg %s ran the %s grower, not the MXU one"
@@ -338,17 +337,13 @@ def main(argv=None) -> int:
     bst_b, legs["b_bench_auto"] = train_leg(
         "b_bench_auto", dict(bench_posture, hist_backend="auto"), rounds,
         dtrain, dvalid, clock, on_tpu=on_tpu)
-    if on_tpu:
-        check(legs["b_bench_auto"]["autotuned"]
-              and set(legs["b_bench_auto"]["autotune_ms"]) ==
-              {"mxu", "pallas"},
-              "the bench posture's autotune did not time both kernels: "
-              "%s" % legs["b_bench_auto"])
-    loser = "pallas" if legs["b_bench_auto"]["hist_backend"] != "pallas" \
-        else "mxu"
-    _, legs["c_bench_" + loser] = train_leg(
-        "c_bench_" + loser, dict(bench_posture, hist_backend=loser),
-        block, dtrain, dvalid, clock, on_tpu=on_tpu, expect_backend=loser)
+    # the formulation `auto` uses least in this posture runs every pass
+    # of a third leg, so both kernels are proven to build and train
+    other = "mxu" if legs["b_bench_auto"]["grouped_passes_per_tree"] \
+        else "pallas"
+    _, legs["c_bench_" + other] = train_leg(
+        "c_bench_" + other, dict(bench_posture, hist_backend=other),
+        block, dtrain, dvalid, clock, on_tpu=on_tpu, expect_backend=other)
 
     serving = serve_leg(bst_b, Xva, on_tpu=on_tpu)
 

@@ -1,248 +1,269 @@
-"""Per-pass floor microbench at the MAIN bench shape (1M x 28 x 255).
+"""One growth pass per histogram formulation, at the benchmark cell's
+shape (2,625,000 x 28 x 256): the derivation of
+histogram_pallas.GROUPED_MIN_WIDTH.
 
-A tree's time splits into the histogram dots, a fixed floor per growth
-pass, the sibling reconstruction and glue.  This times the fused
-route+hist sweep (the whole per-pass kernel cost) across kernel-slot
-counts and row blocks to separate:
-  - MXU row-padding waste (C*sk < 128 on early passes),
-  - per-grid-step overhead (489 steps at row_block=2048),
-  - the dot's true slot-proportional cost,
-and times the sibling-reconstruction dot at f32-HIGHEST vs an exact
-split-bf16 2-pass formulation.
+For each kernel width sk in {24, 40, 72, 136, 263} and for five (exact)
+and three (quantized) channels it times, on the chip:
 
-All timings are CHAINED IN-JIT (k dependency-chained iterations per
-dispatch, long-minus-short differencing), so the host's dispatch and
-sync cost cancels out of a kernel's number.
+  onehot   fused_route_hist_mxu, at the row block grower_mxu.sweep picks
+  route    route_rows_mxu(emit_counts=True), the grouped pass's first step
+  part     partition_rows from the routed slots: the rank sweep plus the
+           scatter that inverts it (part_argsort: the retained oracle)
+  gather   the row table's build and the gather of the blocks in use
+  kernel   the grouped kernel over the gathered table
+  grouped  route + build_histograms_scatter as sweep() runs them
 
-Usage: python helpers/microbench_pass.py [sweep|recon|tree|all]
+Half the rows are live (the smaller sibling; the other child is parked),
+as in a wide pass under sibling subtraction.
+
+All timings are CHAINED IN-JIT: k dependency-chained repetitions in one
+dispatch, long minus short, so the host's dispatch and sync cost cancels
+out of a kernel's number. Every repetition starts from the same state.
+
+With --check each pass is first built once by both formulations and the
+row carries their largest difference over the largest entry, per output
+channel (`agree_rel`: 0.0 three times in the quantized posture, f32
+summation noise in exact mode): a time for a kernel that computes
+something else is worth nothing, and interpret mode on a CPU cannot see
+what the chip's compiler does to a kernel.
+
+Usage: python helpers/microbench_pass.py [--rows N] [--sk 24,40,...]
+       [--nchan 5,3] [--stages onehot,grouped,...] [--reps K] [--check]
+       [--interpret]
+Writes chiprun_out/microbench_pass.json (after every pass, so a call cut
+short keeps what it measured) beside the table it prints.
 """
 
+import argparse
+import json
+import os
 import sys
 import time
 
 import numpy as np
 
-import os
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-N = 1_000_000
+from lightgbm_tpu.learner import histogram_pallas as hp  # noqa: E402
+from lightgbm_tpu.learner.histogram_mxu import (  # noqa: E402
+    _hist_channels, _round_up, fits_v2, fused_route_hist_mxu,
+    hist_num_channels, pack_route_tables, route_rows_mxu)
+
 F = 28
 BMAX = 256
-M_PAD = 896          # round_up(2*447-1+1, 128) at overshoot 1.75
 
 
-def timeit_chained(body, carry0, reps=16):
-    """Per-iteration seconds of `body` (carry -> carry), timed as one
-    jitted fori_loop dispatch of 2+reps iterations minus one of 2."""
+def timeit_chained(step, reps):
+    """Seconds per call of `step` (() -> a scalar that depends on all of
+    its work), timed as one jitted fori_loop of 1+reps repetitions minus
+    one of 1. The carry threads a value XLA cannot fold, so repetitions
+    neither fuse nor vanish."""
 
     @jax.jit
-    def chain(c0, k):
-        return jax.lax.fori_loop(0, k, lambda i, c: body(c), c0)
+    def chain(k):
+        def body(_, c):
+            # c is always 0, which XLA cannot know
+            return jnp.minimum(c + step(c), 0)
+        return jax.lax.fori_loop(0, k, body, jnp.int32(0))
 
     def run(k):
-        out = chain(carry0, jnp.asarray(k, jnp.int32))
-        jax.tree_util.tree_map(lambda a: np.asarray(a).ravel()[:1], out)
+        t0 = time.perf_counter()
+        int(chain(jnp.int32(k)))
+        return time.perf_counter() - t0
 
-    run(2)  # compile + warm
-    best = np.inf
-    for _ in range(2):
-        t0 = time.time()
-        run(2 + reps)
-        dt_long = time.time() - t0
-        t0 = time.time()
-        run(2)
-        dt_short = time.time() - t0
-        best = min(best, (dt_long - dt_short) / reps)
-    return best
+    run(1)  # compile + warm
+    return min((run(1 + reps) - run(1)) / reps for _ in range(2))
 
 
-def make_pass_state(sk, rng):
-    """Ping-pong tables: sk parents split into children that split
-    straight back, so EVERY chained iteration routes through a split
-    node (full decision math + slot pickup) and builds sk slots —
-    the steady-pass cost, not the settled-rows shortcut."""
-    from lightgbm_tpu.learner.histogram_mxu import pack_route_tables
-    m1 = M_PAD
-    ids = np.arange(m1)
-    is_parent = ids < sk
-    is_child = (ids >= sk) & (ids < 3 * sk)
-    split = is_parent | is_child
-    feat = ids % F
-    thr = np.full(m1, 128)
-    child_l = np.where(is_parent, sk + 2 * ids,
-                       np.where(is_child, (ids - sk) // 2, -1))
-    child_r = np.where(is_parent, sk + 2 * ids + 1,
-                       np.where(is_child, (ids - sk) // 2, -1))
-    slot = np.where(split, ids % sk, -1)
+def opaque(x):
+    """An int32 zero that depends on every element of x's first row."""
+    return (jnp.sum(jnp.ravel(x)[:8].astype(jnp.float32)) < -1e30) \
+        .astype(jnp.int32)
+
+
+def make_state(sk, n, rng):
+    """Route tables in which each of sk parents splits on the median bin
+    into a left child that owns kernel slot = parent and a parked right
+    child, and a row_node vector spread over the parents: a wide pass
+    under sibling subtraction, half the rows live."""
+    m_pad = _round_up(4 * sk, 128)
+    ids = np.arange(m_pad)
+    split = ids < sk
+    slot = np.full(m_pad, -1)
+    slot[sk + 2 * np.arange(sk)] = np.arange(sk)         # left children
     tbl, member = pack_route_tables(
-        jnp.asarray(split), jnp.asarray(feat, jnp.int32),
-        jnp.asarray(thr, jnp.int32), jnp.zeros(m1, bool),
-        jnp.zeros(m1, bool), jnp.asarray(child_l, jnp.int32),
-        jnp.asarray(child_r, jnp.int32), jnp.asarray(slot, jnp.int32),
-        jnp.zeros((m1, (BMAX + 31) // 32), jnp.uint32), M_PAD, BMAX)
-    row_node = jnp.asarray(rng.randint(0, max(sk, 1), N), jnp.int32)
+        jnp.asarray(split), jnp.asarray(ids % F, jnp.int32),
+        jnp.full(m_pad, BMAX // 2 - 1, jnp.int32), jnp.zeros(m_pad, bool),
+        jnp.zeros(m_pad, bool),
+        jnp.asarray(np.where(split, sk + 2 * ids, 0), jnp.int32),
+        jnp.asarray(np.where(split, sk + 2 * ids + 1, 0), jnp.int32),
+        jnp.asarray(slot, jnp.int32),
+        jnp.zeros((m_pad, (BMAX + 31) // 32), jnp.uint32), m_pad, BMAX)
+    row_node = jnp.asarray(rng.randint(0, sk, n), jnp.int32)
     return tbl, member, row_node
 
 
-def bench_sweep():
-    from lightgbm_tpu.learner.histogram_mxu import fused_route_hist_mxu
+def onehot_row_block(sk, quant):
+    """The row block grower_mxu.sweep picks for a one-hot pass."""
+    if sk <= 64:
+        return 2048
+    for rb in (8192, 4096, 2048):
+        if fits_v2(sk, F, BMAX, True, quant, row_block=rb):
+            return rb
+    return 2048
+
+
+def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
+               only=None):
+    quant = nchan == 3
+    bins = jnp.asarray(rng.randint(0, BMAX, (n, F)), jnp.uint8)
+    if quant:
+        g = jnp.asarray(rng.randint(-127, 128, n), jnp.float32)
+        h = jnp.asarray(rng.randint(0, 128, n), jnp.float32)
+    else:
+        g = jnp.asarray(rng.randn(n), jnp.float32)
+        h = jnp.asarray(rng.rand(n), jnp.float32)
+    cnt = jnp.ones(n, jnp.float32)
+    feat_tbl = jnp.stack([jnp.full(F, float(BMAX)), jnp.zeros(F)], axis=1)
+    tbl, member, row_node = make_state(sk, n, rng)
+    assert hist_num_channels(True, quant) == nchan
+    kw = dict(quantized=quant, double_prec=True)
+    nb = hp.GROUPED_ROW_BLOCK
+    sg = min(hp.group_width(nchan), sk)
+    ng = -(-sk // sg)
+    rb = onehot_row_block(sk, quant)
+
+    def route(c):
+        return route_rows_mxu(bins, row_node + c, tbl, member, feat_tbl,
+                              emit_counts=True, num_slots=sk,
+                              interpret=interpret)
+
+    # the stages' inputs, computed once outside the clock
+    _, rs, cts = jax.jit(route)(jnp.int32(0))
+    block_group, used, src = jax.jit(lambda: hp.partition_rows(
+        rs, num_slots=sk, row_block=nb, group=sg, counts=cts,
+        interpret=interpret))()
+    data, _ = _hist_channels(g, h, cnt, True, quant)
+    live_share = float(jnp.mean((rs >= 0).astype(jnp.float32)))
+
+    def table_of(c):
+        return hp._row_table(bins, data, nchan,
+                             jnp.where(rs >= 0, rs % sg, 255) + c)
+
+    def gather(c):
+        return hp._gather_used(table_of(c), src, used * nb,
+                               min(hp._GATHER_CHUNK_BLOCKS,
+                                   block_group.shape[0]) * nb)
+
+    tab_g = jax.jit(gather)(jnp.int32(0))
+
+    def part(impl):
+        return lambda c: opaque(hp.partition_rows(
+            rs + c, num_slots=sk, row_block=nb, group=sg, counts=cts,
+            impl=impl, interpret=interpret)[2])
+
+    def grouped(c):
+        rn, rs_, cts_ = route(c)
+        return opaque(hp.build_histograms_scatter(
+            bins, g, h, cnt, rs_, num_slots=sk, bmax=BMAX, slot_counts=cts_,
+            interpret=interpret, **kw)) + opaque(rn)
+
+    def onehot(c):
+        hist, rn = fused_route_hist_mxu(
+            bins, g, h, cnt, row_node + c, tbl, member, feat_tbl,
+            num_slots=sk, bmax=BMAX, has_cat=False, row_block=rb,
+            interpret=interpret, **kw)
+        return opaque(hist) + opaque(rn)
+
+    stages = {
+        "onehot": onehot,
+        "route": lambda c: opaque(route(c)[1]),
+        "part": part("rank"),
+        "gather": lambda c: opaque(gather(c)),
+        "kernel": lambda c: opaque(hp._grouped_call(
+            block_group, used, tab_g + c.astype(tab_g.dtype), nb=nb, f=F,
+            b=BMAX, sg=sg, ng=ng, nchan=nchan, fcols=F,
+            interpret=interpret)),
+        "grouped": grouped,
+    }
+    if "argsort" in extras:
+        stages["part_argsort"] = part("argsort")
+    if "table" in extras:
+        stages["table"] = lambda c: opaque(table_of(c))
+    if only:
+        stages = {k: v for k, v in stages.items() if k in only}
+    row = {"sk": sk, "nchan": nchan, "rows": n, "onehot_row_block": rb,
+           "groups": ng, "live_share": round(live_share, 4),
+           "blocks_used": int(used), "blocks_static": int(
+               block_group.shape[0])}
+    if check:
+        h_one = np.asarray(jax.jit(lambda: fused_route_hist_mxu(
+            bins, g, h, cnt, row_node, tbl, member, feat_tbl, num_slots=sk,
+            bmax=BMAX, has_cat=False, row_block=rb, interpret=interpret,
+            **kw)[0])())
+        h_grp = np.asarray(jax.jit(lambda: hp.build_histograms_scatter(
+            bins, g, h, cnt, rs, num_slots=sk, bmax=BMAX, slot_counts=cts,
+            interpret=interpret, **kw))())
+        scale = np.abs(h_one).max(axis=(0, 1, 2)) + 1e-30
+        row["agree_rel"] = [float(x) for x in
+                            np.abs(h_grp - h_one).max(axis=(0, 1, 2)) / scale]
+        print("  agree_rel %s" % row["agree_rel"], flush=True)
+    for name, fn in stages.items():
+        try:
+            row[name + "_ms"] = round(timeit_chained(fn, reps) * 1e3, 3)
+        except Exception as e:      # a kernel that does not build: say so
+            row[name + "_ms"] = None
+            row[name + "_error"] = "%s: %s" % (type(e).__name__,
+                                               str(e)[:300])
+        print("  %s %s" % (name, row[name + "_ms"]), flush=True)
+    return row
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--rows", type=int, default=2_625_000)
+    ap.add_argument("--sk", default="24,40,72,136,263")
+    ap.add_argument("--nchan", default="5,3")
+    ap.add_argument("--stages", default="",
+                    help="time these stages only (default: all)")
+    ap.add_argument("--reps", type=int, default=6)
+    ap.add_argument("--check", action="store_true",
+                    help="first compare the two formulations' histograms")
+    ap.add_argument("--interpret", action="store_true",
+                    help="Pallas interpret mode: a CPU rehearsal of the "
+                         "control flow; its times mean nothing")
+    args = ap.parse_args()
+    dev = jax.devices()[0]
+    print("# device: %s %s" % (dev.platform, dev.device_kind), flush=True)
     rng = np.random.RandomState(0)
-    bins = jnp.asarray(rng.randint(0, BMAX, (N, F)), jnp.uint8)
-    g = jnp.asarray(rng.randint(-127, 128, N), jnp.float32)
-    h = jnp.asarray(rng.randint(0, 128, N), jnp.float32)
-    cnt = jnp.ones(N, jnp.float32)
-    feat_tbl = jnp.stack([jnp.full(F, 255.0), jnp.zeros(F)], axis=1)
-
-    def _r128(x):
-        return min(M_PAD, ((x + 127) // 128) * 128)
-
-    print("# fused_route_hist_mxu per pass, quantized (3ch), chained")
-    print("sk\trb\tm_cap\tms")
-    # m_cap mirrors the grower's per-pass slice (round_up to lanes of
-    # the live node-id range); the sk=72 full-width row quantifies the
-    # table-width cost at mid frontier
-    for sk in (16, 72, 136, 232):
-        tbl, member, row_node = make_pass_state(sk, rng)
-        for rb in (2048, 4096, 8192):
-            for m_cap in ({_r128(3 * sk), M_PAD} if sk == 72 and
-                          rb == 2048 else {_r128(3 * sk)}):
-                t = tbl[:m_cap]
-                mem = member[:m_cap]
-
-                def body(rn):
-                    _h, rn2 = fused_route_hist_mxu(
-                        bins, g, h, cnt, rn, t, mem, feat_tbl,
-                        num_slots=sk, bmax=BMAX, has_cat=False,
-                        double_prec=True, quantized=True, row_block=rb)
-                    return rn2
-
-                try:
-                    dt = timeit_chained(body, row_node)
-                except Exception as e:
-                    print(f"{sk}\t{rb}\t{m_cap}\tFAIL {type(e).__name__}")
-                    continue
-                print(f"{sk}\t{rb}\t{m_cap}\t{dt * 1e3:.2f}", flush=True)
-
-
-def bench_recon():
-    s, sk, p_all = 448, 232, 226
-    fb3 = F * BMAX * 3
-    rng = np.random.RandomState(1)
-    kern2 = jnp.asarray(rng.rand(sk, fb3), jnp.float32)
-    parent = jnp.asarray(rng.rand(p_all, fb3), jnp.float32)
-    mk = jnp.asarray(rng.randint(-1, 2, (s, sk)), jnp.float32)
-    mp = jnp.asarray((rng.rand(s, p_all) < 0.01), jnp.float32)
-
-    def recon_highest(kern2):
-        return jax.lax.dot_general(
-            jnp.concatenate([mk, mp], axis=1),
-            jnp.concatenate([kern2, parent], axis=0),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-
-    def recon_split(kern2):
-        lhs = jnp.concatenate([mk, mp], axis=1).astype(jnp.bfloat16)
-        rhs = jnp.concatenate([kern2, parent], axis=0)
-        hi = jax.lax.reduce_precision(rhs, exponent_bits=8,
-                                      mantissa_bits=7)
-        lo = rhs - hi
-        d = lambda r: jax.lax.dot_general(
-            lhs, r.astype(jnp.bfloat16),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return d(hi) + d(lo)
-
-    a = timeit_chained(lambda k2: recon_highest(k2)[:sk], kern2,
-                       reps=300)
-    b = timeit_chained(lambda k2: recon_split(k2)[:sk], kern2,
-                       reps=300)
-    ra = np.asarray(recon_highest(kern2))
-    rb = np.asarray(recon_split(kern2))
-    rel = np.abs(ra - rb).max() / max(np.abs(ra).max(), 1e-30)
-    print(f"# recon dot [s={s}, {sk}+{p_all}] x [{fb3}], chained")
-    print(f"highest\t{a * 1e3:.2f} ms")
-    print(f"split2\t{b * 1e3:.2f} ms\tmax rel diff {rel:.2e}")
-
-    # the parent-carry dot (sel_p): [P, s] x [s, F*B*3]
-    selp = jnp.asarray((rng.rand(p_all, s) < 0.004), jnp.float32)
-    hist = jnp.asarray(rng.rand(s, fb3), jnp.float32)
-
-    def carry_highest(hist):
-        return jax.lax.dot_general(
-            selp, hist, dimension_numbers=(((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-
-    def carry_split(hist):
-        hi = jax.lax.reduce_precision(hist, exponent_bits=8,
-                                      mantissa_bits=7)
-        sl = selp.astype(jnp.bfloat16)
-        d = lambda r: jax.lax.dot_general(
-            sl, r.astype(jnp.bfloat16),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return d(hi) + d(hist - hi)
-
-    pad = jnp.zeros((s - p_all, fb3), jnp.float32)
-    a = timeit_chained(
-        lambda h_: jnp.concatenate([carry_highest(h_), pad]), hist,
-        reps=300)
-    b = timeit_chained(
-        lambda h_: jnp.concatenate([carry_split(h_), pad]), hist,
-        reps=300)
-    print(f"carry_highest\t{a * 1e3:.2f} ms")
-    print(f"carry_split\t{b * 1e3:.2f} ms")
+    rows = []
+    only = set(filter(None, args.stages.split(",")))
+    out = os.path.join(REPO, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    for nchan in [int(x) for x in args.nchan.split(",")]:
+        for sk in [int(x) for x in args.sk.split(",")]:
+            print("sk=%d nchan=%d" % (sk, nchan), flush=True)
+            # the partition's A/B does not depend on the channels
+            extras = {"argsort", "table"} if nchan == 5 else set()
+            rows.append(bench_pass(sk, nchan, args.rows, args.reps,
+                                   args.interpret, rng, extras,
+                                   check=args.check, only=only))
+            with open(os.path.join(out, "microbench_pass.json"), "w") as fh:
+                json.dump({"platform": dev.platform,
+                           "device_kind": dev.device_kind, "rows": rows},
+                          fh, indent=1)
+    cols = ["sk", "nchan", "onehot_ms", "grouped_ms", "route_ms",
+            "part_ms", "gather_ms", "kernel_ms", "part_argsort_ms",
+            "table_ms", "live_share", "blocks_used",
+            "agree_rel"]
+    print("\t".join(cols))
+    for r in rows:
+        print("\t".join(str(r.get(c, "")) for c in cols))
 
 
 if __name__ == "__main__":
-    which = sys.argv[1] if len(sys.argv) > 1 else "all"
-    if which in ("sweep", "all"):
-        bench_sweep()
-    if which in ("recon", "all"):
-        bench_recon()
-
-
-def bench_tree():
-    """Chained whole-tree growth on the REAL bench data/config —
-    separates the grower's cost from the boosting ring's (grad/quantize/
-    score/stacking glue): ring = fused-block per-tree minus this."""
-    sys.path.insert(0, REPO)
-    from bench import make_higgs_like, PARAMS, MAX_BIN, N_FEATURES
-    import lightgbm_tpu as lgb
-    from lightgbm_tpu.learner.grower_mxu import grow_tree_mxu
-
-    X, y = make_higgs_like(N, N_FEATURES)
-    ds = lgb.Dataset(X, label=y, params={"max_bin": MAX_BIN})
-    bst = lgb.Booster(params=dict(PARAMS), train_set=ds)
-    g = bst.gbdt
-    kw = g._mxu_grow_kwargs()
-    print("# grower kwargs:", {k: v for k, v in kw.items()
-                               if not hasattr(v, "shape")})
-    yd = jnp.asarray(y)
-    p = jnp.float32(0.5)
-    grad0 = p - yd
-    hess0 = jnp.full(N, 0.25, jnp.float32)
-    cnt = jnp.ones(N, jnp.float32)
-    fmask = jnp.ones(N_FEATURES, jnp.float32)
-    key = jax.random.PRNGKey(3)
-
-    def body(rn):
-        # dependency chain without changing the data: 0*rn is not
-        # foldable for floats per IEEE (rn is int -> cast first)
-        g_in = grad0 + 0.0 * rn.astype(jnp.float32)
-        tree, rn2 = grow_tree_mxu(
-            g.bins, g_in, hess0, cnt, fmask, g.num_bins_d,
-            g.missing_is_nan_d, g.is_cat_d, rng_key=key, **kw)
-        return rn2
-
-    dt = timeit_chained(body, jnp.zeros(N, jnp.int32), reps=10)
-    print(f"whole-tree growth (chained): {dt * 1e3:.1f} ms/tree")
-
-
-if __name__ == "__main__" and "tree" in sys.argv[1:]:
-    bench_tree()
+    main()

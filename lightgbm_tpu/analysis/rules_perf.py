@@ -48,7 +48,7 @@ __all__ = ["PerfHotPathSortRule", "HOT_PATH_MANIFEST"]
 #: learner/" — so host-side preprocessing keeps its freedom.
 HOT_PATH_MANIFEST = {
     ("histogram_pallas.py", "partition_rows"),
-    ("histogram_pallas.py", "_stable_order_scan"),
+    ("histogram_pallas.py", "_stable_positions"),
     ("histogram_pallas.py", "build_histograms_scatter"),
     ("histogram_pallas.py", "build_histograms_pallas"),
     ("histogram_mxu.py", "route_rows_mxu"),
@@ -72,9 +72,9 @@ class PerfHotPathSortRule(Rule):
     id = "PERF001"
     severity = "error"
     doc = ("O(N log N) `argsort` inside a registered device hot-path "
-           "function (HOT_PATH_MANIFEST, rules_perf.py) — the scan "
+           "function (HOT_PATH_MANIFEST, rules_perf.py) — the rank "
            "partition made these paths row-linear; route the ordering "
-           "through partition_rows(impl='scan') or, for a retained "
+           "through partition_rows(impl='rank') or, for a retained "
            "parity oracle, suppress the exact line (lexical fallback; "
            "TRACE001 checks the traced program)")
 

@@ -281,7 +281,7 @@ def _probe_partition_rows() -> Dict:
     import jax.numpy as jnp
     from ..learner.histogram_pallas import partition_rows
     fn = functools.partial(partition_rows, num_slots=8, row_block=64,
-                           impl="scan")
+                           impl="rank")
     jaxpr = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((512,), jnp.int32))
     return {"jaxpr": jaxpr}
 
@@ -414,7 +414,7 @@ _GROW_DEPS = ("learner/grower_mxu.py", "learner/histogram_mxu.py",
 
 TRACE_MANIFEST: Tuple[TraceEntry, ...] = (
     TraceEntry(
-        name="partition_rows_scan",
+        name="partition_rows_rank",
         target_file="learner/histogram_pallas.py",
         target_fn="partition_rows",
         build=_probe_partition_rows,

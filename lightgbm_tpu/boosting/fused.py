@@ -118,12 +118,6 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     from ..learner.grower_mxu import grow_tree_mxu
     from ..learner.histogram_mxu import node_values_mxu
 
-    # the histogram backend is a static grow arg and must reach the
-    # scan already resolved — "auto" here would mean the caller skipped
-    # GBDT._resolved_hist_backend and each recompile could re-decide
-    if grower_kwargs.get("hist_backend", "mxu") == "auto":
-        raise ValueError("build_fused_train requires a resolved "
-                         "hist_backend (mxu|pallas|scatter), not 'auto'")
     num_data = bins.shape[0]
     row_names, row_arrays = objective_row_state(objective, num_data)
     shrink = jnp.float32(shrinkage)

@@ -212,6 +212,11 @@ class GBDT:
             cfg.monotone_constraints_method != "basic")
         self._mono_method = (cfg.monotone_constraints_method
                              if self._mono_nonbasic else "basic")
+        # histogram backend of the MXU growth path (config.hist_backend):
+        # pinned by _resolved_hist_backend() at first use, which for the
+        # serial learner is after objective binding (the const-hessian
+        # gate decides the channel count the per-pass plan is made of)
+        self._hist_backend = None
         self._setup_parallel(cfg)
         # TPU kernel choice (serial learner; the data-parallel sharded
         # path picks mxu in _setup_parallel, other modes keep the
@@ -244,12 +249,6 @@ class GBDT:
             self._hist_impl = "scatter"
         Log.debug("Tree kernel path: %s (backend=%s)", self._hist_impl,
                   backend)
-        # histogram backend for the MXU growth path (config.hist_backend)
-        # — resolved lazily in _resolved_hist_backend() because "auto"
-        # autotunes on the device bin matrix, which must happen after
-        # objective binding and 4-bit packing are final
-        self._hist_backend = None
-        self._hist_autotune = None
         if cfg.use_quantized_grad and self._hist_impl != "mxu" and \
                 not getattr(self, "_sharded_mxu", False):
             Log.warning("use_quantized_grad only accelerates the MXU "
@@ -534,7 +533,10 @@ class GBDT:
                 # be evaluated safely yet (a weighted dataset would get
                 # the fast path wrongly enabled and train silently
                 # wrong hessians)
-                const_hessian=0.0))
+                const_hessian=0.0,
+                **({"hist_backend": self._resolved_hist_backend(),
+                    "partition_impl": cfg.partition_impl}
+                   if use_mxu else {})))
         Log.info("Distributed learner: %s-parallel over %d devices%s "
                  "(hist_agg=%s)", self.comm.mode, ndev,
                  " (mxu)" if use_mxu else "", self.comm.hist_agg)
@@ -641,69 +643,61 @@ class GBDT:
             self._custom_objective = True
             self._fused_run = None
             self._obs_tree_macs = None
+            self._hist_backend = None   # the plan counts channels
 
     def _resolved_hist_backend(self) -> str:
-        """Resolve config.hist_backend to a concrete kernel for
-        grow_tree_mxu. The backend is a static (jit) argument, so
-        resolution happens host-side before the first dispatch and the
-        answer is pinned for the run.
-
-        "auto" considers the Pallas scatter kernel only in the
-        quantized posture — there integer histogram sums make the two
-        backends bit-identical (byte-equal model.txt either way), so
-        the autotuned choice is purely a speed knob. Exact mode differs
-        in last-ulp summation order, so auto pins mxu and switching
-        requires an explicit hist_backend. EFB growth has no scatter
-        wiring (bundle-space routing stays on the mxu sweep), and on
-        CPU hosts there is nothing real to time — both pin mxu."""
+        """config.hist_backend as grow_tree_mxu takes it, pinned for the
+        run. "auto" stays "auto": the grower then chooses a formulation
+        per pass from static shapes (grower_mxu.pass_formulation), the
+        same on every platform. EFB growth has bundle-space wiring in
+        the one-hot sweep only, so there everything resolves to mxu.
+        Records the run's per-pass plan (hist_pass_plan: static, no
+        device sync) in the registry."""
         if self._hist_backend is not None:
             return self._hist_backend
-        cfg = self.config
-        hb = cfg.hist_backend
-        timings: dict = {}
-        autotuned = False
-        if self._efb is not None and hb not in ("auto", "mxu"):
-            Log.warning("hist_backend=%s has no EFB bundle-space "
-                        "wiring; using mxu", hb)
+        hb = self.config.hist_backend
+        if self._efb is not None and hb != "mxu":
+            if hb != "auto":
+                Log.warning("hist_backend=%s has no EFB bundle-space "
+                            "wiring; using mxu", hb)
             hb = "mxu"
-        elif hb == "auto":
-            if (self._efb is not None or
-                    jax.default_backend() == "cpu" or
-                    not cfg.hist_autotune or
-                    not cfg.use_quantized_grad):
-                hb = "mxu"
-            else:
-                import math as _math
-                from ..learner.grower_mxu import (HistAutotuneError,
-                                                  _kernel_cap,
-                                                  autotune_hist_backend)
-                over = cfg.growth_overshoot \
-                    if cfg.growth_overshoot >= 1.0 else 1.0
-                s_max = int(_math.ceil(cfg.num_leaves * over)) + 1
-                s_rep = max(2, _kernel_cap(s_max)
-                            if cfg.hist_subtraction else s_max)
-                try:
-                    hb, timings = autotune_hist_backend(
-                        self.bins, num_slots=s_rep, bmax=self.bmax,
-                        num_features=(int(self.num_bins_d.shape[0])
-                                      if self._packed4 else 0),
-                        double_prec=cfg.gpu_use_dp, quantized=True,
-                        const_hess=self._const_hessian())
-                except HistAutotuneError as exc:
-                    # a kernel that does not build stops training; the
-                    # snapshot keeps why for whoever reads the record
-                    _obs.record_hist_autotune("", exc.timings_ms, True,
-                                              errors=exc.errors)
-                    raise
-                autotuned = True
-                Log.info("hist_backend=auto picked %s (%s)", hb,
-                         ", ".join("%s=%.2fms" % kv
-                                   for kv in sorted(timings.items())))
         self._hist_backend = hb
-        self._hist_autotune = {"choice": hb, "autotuned": autotuned,
-                               "timings_ms": dict(timings)}
-        _obs.record_hist_autotune(hb, timings, autotuned)
+        self._hist_plan = self._hist_pass_plan(hb)
+        _obs.record_hist_plan(hb, self._hist_plan)
         return hb
+
+    def _hist_plan_attrs(self) -> dict:
+        """The per-pass plan as attributes of a boosting.build_program
+        span: which formulation each pass of the program being built
+        uses. Empty off the MXU growth path."""
+        if self._hist_impl != "mxu" and \
+                not getattr(self, "_sharded_mxu", False):
+            return {}
+        self._resolved_hist_backend()
+        return {"hist_plan": ",".join("%d:%s" % (sk, form)
+                                      for _, sk, form in self._hist_plan),
+                "grouped_passes_per_tree": sum(
+                    form == "grouped" and stage != "fixup"
+                    for stage, _, form in self._hist_plan)}
+
+    def _hist_pass_plan(self, hist_backend: str) -> list:
+        """[(stage, kernel slots, formulation)] of this booster's growth
+        program (grower_mxu.hist_pass_plan); rows are ONE device's."""
+        from ..learner.grower_mxu import hist_pass_plan
+        cfg = self.config
+        sharded = getattr(self, "_sharded_mxu", False)
+        ndev = int(self.mesh.devices.size) if sharded else 1
+        return hist_pass_plan(
+            rows=int(self.bins.shape[0]) // max(1, ndev),
+            num_leaves=cfg.num_leaves, overshoot=cfg.growth_overshoot,
+            tail_split_cap=cfg.tail_split_cap,
+            hist_subtraction=cfg.hist_subtraction,
+            bridge_gate=cfg.growth_bridge_gate, hist_backend=hist_backend,
+            hist_double_prec=cfg.gpu_use_dp,
+            quantized_grad=cfg.use_quantized_grad,
+            # the sharded learner keeps const-hessian off (its kwargs)
+            const_hessian=0.0 if sharded else self._const_hessian(),
+            has_efb=self._efb is not None)
 
     def _mxu_grow_kwargs(self):
         """Static grow_tree_mxu settings — single source shared by the
@@ -788,7 +782,7 @@ class GBDT:
         with contextlib.nullcontext() if warm else span(
                 "boosting.build_program", iter=self.iter_, k=1,
                 program="grow_tree" if self._grower is None
-                else "sharded_grow"):
+                else "sharded_grow", **self._hist_plan_attrs()):
             out = retry_call(
                 _attempt, attempts=cfg.retry_max_attempts,
                 backoff_ms=cfg.retry_backoff_ms,
@@ -1597,7 +1591,8 @@ class GBDT:
                 # traces, lowers and compiles (or fetches) its program
                 with contextlib.nullcontext() if self._fused_warm_at(k) \
                         else span("boosting.build_program",
-                                  program="fused_train", iter=iter0, k=k):
+                                  program="fused_train", iter=iter0, k=k,
+                                  **self._hist_plan_attrs()):
                     if getattr(self, "_fused_run", None) is None:
                         self._fused_run = self._build_fused()
                         self._fused_warm = set()

@@ -462,29 +462,26 @@ _PARAMS: List[ParamSpec] = [
             "quantization only perturbs the split search"),
     _p("hist_backend", str, "auto", (),
        lambda v: v in ("auto", "mxu", "pallas", "scatter"),
-       "histogram kernel for the serial MXU growth path: 'mxu' = "
-       "one-hot x MXU matmul (histogram_mxu.py), 'pallas' = "
-       "slot-grouped scatter-accumulate kernel (histogram_pallas.py; "
-       "per-row cost independent of frontier width), 'scatter' = "
-       "pure-XLA segment sums (the parity oracle). 'auto' runs a "
-       "one-shot on-device autotune of mxu vs pallas and pins the "
-       "winner for the run (quantized posture only — there the "
-       "backends are bit-identical, so the choice is byte-neutral on "
-       "model.txt; exact mode pins mxu). The decision and per-backend "
-       "timings land in observability and the bench JSON"),
-    _p("hist_autotune", bool, True, (),
-       desc="allow hist_backend='auto' to time both kernels on device "
-            "before pinning one; false pins mxu without measuring "
-            "(deterministic startup, e.g. for profiling runs)"),
+       "histogram formulation of the MXU growth path: 'mxu' = one-hot "
+       "x MXU matmul (histogram_mxu.py; cost grows with the frontier "
+       "width), 'pallas' = slot-grouped build (histogram_pallas.py; "
+       "live rows partitioned by slot, cost independent of the width), "
+       "'scatter' = pure-XLA segment sums (the parity oracle). 'auto' "
+       "chooses PER PASS from static shapes (grower_mxu."
+       "pass_formulation): narrow passes stay one-hot, wide ones are "
+       "built slot-grouped; the same rule on every platform. Bit-"
+       "identical histograms in the quantized posture, last-ulp "
+       "summation-order differences in exact mode. The per-pass plan "
+       "lands in observability and the bench JSON"),
     _p("partition_impl", str, "auto", (),
-       lambda v: v in ("auto", "argsort", "scan"),
-       "row-partitioning algorithm behind the slot-grouped scatter "
-       "kernels (histogram_pallas.py partition_rows): 'scan' = stable "
-       "rank via blocked prefix sums over the per-slot counts the "
-       "router already emits (O(N), one sweep), 'argsort' = the "
-       "original O(N log N) sort, retained as the bit-parity oracle. "
-       "'auto' = scan. Both produce the identical slot-contiguous "
-       "block layout, so the choice is byte-neutral on model.txt"),
+       lambda v: v in ("auto", "argsort", "rank"),
+       "row-partitioning algorithm behind the slot-grouped build "
+       "(histogram_pallas.py partition_rows): 'rank' = stable rank "
+       "from one sweep of triangular matmuls whose cost does not grow "
+       "with the frontier width, 'argsort' = the stable sort, retained "
+       "as the bit-parity oracle. 'auto' = rank. Both produce the "
+       "identical group-contiguous block layout, so the choice is "
+       "byte-neutral on model.txt"),
     _p("level_pipeline", bool, False, (),
        desc="stage-dispatched tree growth (learner/grower_pipeline.py): "
             "each doubling-schedule pass, the bridge and speculative "

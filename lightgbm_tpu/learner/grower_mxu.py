@@ -31,27 +31,25 @@ from __future__ import annotations
 import functools
 import math
 import os
-import time
 import types
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..utils.log import LightGBMError
 from .grower import _init_tree, TreeArrays
 from .histogram import build_histograms
 from .histogram_mxu import (_round_up, build_histograms_mxu_auto, fits_v2,
-                            fused_route_hist_mxu, node_sums_mxu,
-                            node_values_mxu, pack_route_tables,
-                            quantize_gradients, route_rows_mxu,
-                            unpack_bins_4bit)
-from .histogram_pallas import build_histograms_scatter
+                            fused_route_hist_mxu, hist_num_channels,
+                            node_sums_mxu, node_values_mxu,
+                            pack_route_tables, quantize_gradients,
+                            route_rows_mxu, unpack_bins_4bit)
+from .histogram_pallas import build_histograms_scatter, use_grouped
 from .split import (BestSplits, SplitHyperParams, find_best_splits,
                     leaf_gain, leaf_output, _split_gain)
 from .split_kernel import find_best_splits_kernel, kernel_supports
 
-__all__ = ["grow_tree_mxu", "HistAutotuneError"]
+__all__ = ["grow_tree_mxu", "hist_pass_plan"]
 
 
 def _prune_to_best_first(tree: TreeArrays, row_node: jax.Array, *,
@@ -179,70 +177,6 @@ def _kernel_cap(s: int) -> int:
     return min(s, s // 2 + 8)
 
 
-class HistAutotuneError(LightGBMError):
-    """hist_backend=auto met a histogram kernel that does not build."""
-
-    def __init__(self, errors: dict, timings_ms: dict):
-        self.errors = dict(errors)
-        self.timings_ms = dict(timings_ms)
-        super().__init__(
-            "hist_backend autotune: " + "; ".join(
-                "%s backend failed (%s)" % kv
-                for kv in sorted(self.errors.items())))
-
-
-def autotune_hist_backend(bins, *, num_slots: int, bmax: int,
-                          num_features: int = 0, double_prec: bool = True,
-                          quantized: bool = True, const_hess: float = 0.0,
-                          row_block_scatter: int = 1024):
-    """One-shot on-device histogram-backend measurement (hist_backend=
-    auto): build one frontier histogram at the dominant frontier width
-    with the MXU one-hot kernel and the Pallas scatter kernel on the
-    REAL bin matrix, time the post-compile call of each, and return
-    (choice, timings_ms). Synthetic gradients/slots are used — kernel
-    runtime is data-independent (dense dots, static shapes), so the
-    measurement transfers to training. Runs host-side BEFORE the first
-    grow_tree_mxu dispatch because the backend is a static (jit) arg;
-    the result is pinned for the whole run and recorded in
-    observability (boosting/gbdt.py). A backend that fails to compile
-    or run is a broken kernel, not a slow one: both are still tried so
-    the report is complete, then HistAutotuneError carries each
-    exception's text and the timings taken, and nothing is chosen."""
-    n = bins.shape[0]
-    g = jnp.linspace(-127.0, 127.0, n, dtype=jnp.float32)
-    g = jnp.round(g) if quantized else g * 1e-2
-    h = jnp.ones(n, jnp.float32)
-    cnt = jnp.ones(n, jnp.float32)
-    slot = (jnp.arange(n, dtype=jnp.int32) % num_slots)
-
-    def _mxu():
-        return build_histograms_mxu_auto(
-            bins, g, h, cnt, slot, num_slots=num_slots, bmax=bmax,
-            double_prec=double_prec, quantized=quantized,
-            num_features=num_features, const_hess=const_hess)
-
-    def _pallas():
-        return build_histograms_scatter(
-            bins, g, h, cnt, slot, num_slots=num_slots, bmax=bmax,
-            double_prec=double_prec, quantized=quantized,
-            num_features=num_features, const_hess=const_hess,
-            row_block=row_block_scatter)
-
-    timings, errors = {}, {}
-    for name, fn in (("mxu", _mxu), ("pallas", _pallas)):
-        try:
-            jax.block_until_ready(fn())       # compile + warm
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            timings[name] = (time.perf_counter() - t0) * 1e3
-        except Exception as exc:
-            errors[name] = "%s: %s" % (type(exc).__name__, exc)
-            last = exc
-    if errors:
-        raise HistAutotuneError(errors, timings) from last
-    return min(timings, key=timings.get), timings
-
-
 #: index of the done flag in the growth state tuple (shared with the
 #: level-pipelined driver, grower_pipeline.py)
 _DONE = 9
@@ -306,6 +240,57 @@ def growth_plan(*, num_leaves: int, overshoot: float = 0.0,
         # iteration arg) + final epilogue
         n_stage_programs=len(schedule) + 4,
         max_fixup_dispatch=max(0, L_g - len(schedule) - 1))
+
+
+def pass_formulation(nslots: int, *, hist_backend: str, nchan: int,
+                     rows: int, has_efb: bool = False) -> str:
+    """Which histogram formulation a pass with `nslots` kernel slots
+    uses: "onehot" (histogram_mxu: every row against every slot),
+    "grouped" (histogram_pallas: live rows partitioned by slot group,
+    cost independent of the width) or "scatter" (the XLA segment-sum
+    oracle). hist_backend=mxu|pallas|scatter name one for the whole
+    run; "auto" asks histogram_pallas.use_grouped per pass, from static
+    shapes alone. EFB growth has bundle-space wiring in the one-hot
+    sweep only."""
+    if has_efb or hist_backend == "mxu":
+        return "onehot"
+    if hist_backend == "scatter":
+        return "scatter"
+    if hist_backend == "pallas" or use_grouped(nchan * nslots, rows):
+        return "grouped"
+    return "onehot"
+
+
+def hist_pass_plan(*, rows: int, num_leaves: int, overshoot: float = 0.0,
+                   tail_split_cap: int = 0, hist_subtraction: bool = True,
+                   bridge_gate: float = 0.0, hist_backend: str = "auto",
+                   hist_double_prec: bool = True,
+                   quantized_grad: bool = False,
+                   const_hessian: float = 0.0, has_efb: bool = False):
+    """The growth program's histogram passes as [(stage, kernel slots,
+    formulation)], from static configuration alone: what sweep() will
+    decide at trace time, computed by the same function, so a caller can
+    record it without a device sync. `rows` is the row count ONE device
+    holds. Stages: "pass" (the doubling schedule), "bridge", "fixup"
+    (the while_loop's body, run as often as the tree needs)."""
+    plan = growth_plan(num_leaves=num_leaves, overshoot=overshoot,
+                       tail_split_cap=tail_split_cap,
+                       hist_subtraction=hist_subtraction,
+                       bridge_gate=bridge_gate)
+    nchan = hist_num_channels(hist_double_prec, quantized_grad,
+                              const_hessian)
+
+    def sk_of(s):
+        return _kernel_cap(s) if hist_subtraction else s
+
+    stages = [("pass", sk_of(s)) for s in plan.schedule]
+    if plan.schedule:
+        stages.append(("bridge", sk_of(plan.s_max)))
+    stages.append(("fixup", plan.sk_fix if hist_subtraction
+                   else plan.s_fix))
+    return [(stage, sk, pass_formulation(
+        sk, hist_backend=hist_backend, nchan=nchan, rows=rows,
+        has_efb=has_efb)) for stage, sk in stages]
 
 
 def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
@@ -379,19 +364,19 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     unpack nibbles in VMEM, so HBM holds half the bin bytes. Exact —
     identical trees to unpacked storage.
 
-    hist_backend selects the per-pass histogram kernel: "mxu" keeps the
-    one-hot matmul kernels (fused route+hist when it fits VMEM),
-    "pallas" routes with route_rows_mxu(emit_counts=True) and builds
-    via the slot-grouped scatter kernel (histogram_pallas — per-row
-    cost independent of the frontier width), "scatter" routes the same
-    way and builds with the XLA segment-sum oracle. Must be a RESOLVED
-    backend, never "auto" — the one-shot autotune
-    (autotune_hist_backend, driven from boosting/gbdt.py) happens
-    before jit dispatch because the choice is a static argument. In the
-    quantized posture all three backends produce bit-identical
-    histograms (integer sums, order-independent below 2^24), hence
-    byte-identical trees. EFB data ignores the selector (bundle-space
-    histograms are an MXU-kernel-only formulation).
+    hist_backend selects the histogram formulation (pass_formulation):
+    "auto" decides PER PASS from static shapes (the six narrow passes of
+    a 255-leaf tree stay on the one-hot kernels, which sit on the MXU's
+    floor there; the wide ones route with route_rows_mxu(emit_counts=
+    True) and build slot-grouped, histogram_pallas, at a cost
+    independent of the width); "mxu", "pallas" and "scatter" (the XLA
+    segment-sum oracle) name one formulation for every pass and serve
+    as each other's test oracles. The choice is made at trace time, so
+    a program holds exactly the kernels its passes use. In the
+    quantized posture all formulations produce bit-identical histograms
+    (integer sums, order-independent below 2^24), hence byte-identical
+    trees. EFB data ignores the selector (bundle-space histograms are a
+    one-hot-kernel-only formulation).
 
     efb (EfbDev, efb.py) marks `bins` as the BUNDLED matrix [N, Fb]:
     histograms build in bundle space ([S, Fb, Bb, 3] — the flop and
@@ -544,6 +529,8 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     forced_ok0 = jnp.zeros(m1 if use_forced else 1, bool)
     was_forced0 = jnp.zeros(m1 if use_forced else 1, bool)
 
+    nchan = hist_num_channels(hist_double_prec, quant, ch)
+
     def hist_cfg(s):
         # empirically tuned on v5e: wider feature chunks while the output
         # block fits comfortably in VMEM, narrower for big frontiers
@@ -563,15 +550,17 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         if m_cap is not None and m_cap < m_pad:
             tbl_c = tbl_c[:m_cap]
             member_c = member_c[:m_cap]
-        if hist_backend != "mxu" and efb is None:
-            # non-MXU histogram backends: route + per-slot counts in one
-            # sweep (the on-device partition), then build via the
-            # scatter kernel or the XLA oracle
+        form = pass_formulation(nslots, hist_backend=hist_backend,
+                                nchan=nchan, rows=n,
+                                has_efb=efb is not None)
+        if form != "onehot":
+            # route + per-slot counts in one sweep, then build from the
+            # partitioned live rows (grouped) or by the XLA oracle
             rn, rs, cts = route_rows_mxu(
                 bins, row_node, tbl_c, member_c, feat_tbl,
                 num_features=nf_packed, emit_counts=True,
                 num_slots=nslots, interpret=interpret)
-            if hist_backend == "pallas":
+            if form == "grouped":
                 h = build_histograms_scatter(
                     bins, h_grad, h_hess, cnt_weight, rs,
                     num_slots=nslots, bmax=bk, num_features=nf_packed,

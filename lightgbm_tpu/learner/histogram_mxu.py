@@ -43,7 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ..utils.log import Log
 
 __all__ = ["build_histograms_mxu", "build_histograms_mxu_v2",
-           "build_histograms_mxu_auto", "route_rows_mxu",
+           "build_histograms_mxu_auto", "hist_num_channels",
+           "route_rows_mxu",
            "pack_route_tables", "node_values_mxu", "node_sums_mxu",
            "quantize_gradients", "pack_bins_4bit", "unpack_bins_4bit"]
 
@@ -171,6 +172,16 @@ def _hist_kernel(nb: int, fc: int, b: int, s: int, flane: int,
                 out_ref[0, c * s:(c + 1) * s, :] += part
 
     return kernel
+
+
+def hist_num_channels(double_prec: bool = True, quantized: bool = False,
+                      const_hess: float = 0.0) -> int:
+    """Channels _hist_channels builds for this posture (static)."""
+    if const_hess:
+        # [g, cnt] quantized, [g_hi, g_lo, cnt] exact (regardless of
+        # double_prec: the dropped channel is the hessian)
+        return 2 if quantized else 3
+    return 3 if quantized else (5 if double_prec else 4)
 
 
 def _hist_channels(grad, hess, cnt, double_prec: bool,
@@ -579,12 +590,7 @@ def fits_v2(num_slots: int, num_features: int, bmax: int,
     one-hots + the loc_table decode); row_block: the block the caller
     will actually use."""
     b = ((bmax + 127) // 128) * 128
-    if const_hess:
-        # _hist_channels: [g, cnt] quantized, [g_hi, g_lo, cnt] exact
-        # (regardless of double_prec — the dropped channel is hessian)
-        nchan = 2 if quantized else 3
-    else:
-        nchan = 3 if quantized else (5 if double_prec else 4)
+    nchan = hist_num_channels(double_prec, quantized, const_hess)
     out = nchan * num_slots * num_features * b * 4
     plane = ((num_features + 127) // 128) * 128
     flane_r = ((max(route_width, num_features) + 127) // 128) * 128
@@ -1081,11 +1087,8 @@ def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
     excluded), the exact metadata the scatter histogram's
     partition_rows needs, so routing stops being a count-only second
     pass. Returns (row_node, row_slot, counts) instead of 2-tuple.
-    Both partition implementations consume these counts: 'scan'
-    derives its exclusive prefix-sum slot bases from them directly
-    (routing + counting + partitioning = one sweep, no O(N log N)
-    sort), 'argsort' uses them only for the slot-base offsets while
-    re-deriving order via the stable sort (the bit-parity oracle).
+    Both partition implementations consume these counts for the
+    groups' block starts; neither counts again.
     """
     n, fcols = bins.shape
     has_efb = loc_table is not None and not efb_range

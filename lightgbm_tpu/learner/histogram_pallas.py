@@ -1,42 +1,43 @@
-"""Pallas TPU histogram kernel: slot-grouped scatter-accumulate in VMEM.
+"""Slot-grouped histogram build: rows partitioned by frontier slot, so a
+pass costs the same whatever the frontier's width.
 
-The TPU answer to the reference's CUDA shared-memory histogram kernels
-(cuda_histogram_constructor.cu:18-307): per-row scatter-adds serialize
-on the TPU vector units, and the one-hot x MXU kernels in
-histogram_mxu.py pay a per-row cost proportional to the frontier width
-S — their slot-masked channel operand is [row_block, nchan*S], so every
-row is multiplied against every live slot. This kernel removes the S
-factor:
+The one-hot kernels of histogram_mxu.py contract a slot-masked channel
+operand [rows, nchan*S] with the bin one-hot [rows, F*B]: nchan*S*N*F*B
+MACs per pass, in which a row belongs to ONE slot. Up to nchan*S = 128
+the MXU's rows are there anyway and the pass sits on a floor; beyond it
+the cost grows with S. This module removes the S factor for the wide
+passes:
 
-1. rows are partitioned by frontier slot ON DEVICE (partition_rows:
-   a blocked-prefix-sum stable rank of the row->slot vector — or the
-   retained argsort oracle, partition_impl= — padded so every
-   `row_block` consecutive positions belong to ONE slot; the per-slot
-   counts can come straight from route_rows_mxu(emit_counts=True),
-   making routing + counting + partition one sweep with no O(N log N)
-   sort);
-2. each grid step builds the block's (feature, bin) one-hots in VMEM
-   and computes `data8 @ onehot` on the MXU — [8, row_block] x
-   [row_block, G*B] per feature group, all channels in one dot. Cost is
-   8 x F x B MACs per row REGARDLESS of S, vs nchan x S x F x B for the
-   one-hot kernels; the scatter path wins once the frontier outgrows
-   ~8/nchan slots, a crossover hist_backend=auto (boosting/gbdt.py)
-   measures on device rather than models;
-3. consecutive same-slot blocks accumulate into the same output block,
-   which Pallas keeps resident in VMEM (flash-attention-style
-   revisiting) — a slot's [8, F*B] accumulator touches HBM once, after
-   its last block, and the f32 final reduce to [S, F, bmax, 3] happens
-   outside the kernel.
+1. live rows are partitioned ON DEVICE by slot GROUP: `group_width`
+   consecutive slots (as many as fill the MXU's 128 rows: 25 at five
+   channels, 42 at three) share a group, and the padded layout gives
+   every `row_block` consecutive positions to ONE group
+   (partition_rows). Parked rows (slot -1: the larger sibling rebuilt by
+   subtraction, finished leaves) are not in the layout at all: they are
+   neither gathered nor multiplied;
+2. the stable rank behind the layout does not grow with S: a Pallas
+   sweep ranks each 256-row tile against a triangular matrix on the MXU
+   and carries the per-group running counts in VMEM (_stable_positions);
+   the stable `argsort` is retained as its oracle (impl="argsort");
+3. only the blocks in use are gathered (a dynamic trip count over fixed
+   chunks) and multiplied (the kernel skips the layout's tail);
+4. each grid step runs the one-hot accumulation of histogram_mxu
+   (_hist_accumulate) on its group's `group_width` slots:
+   [rows, nchan*group_width] x [rows, G*B], the floor cost per row.
+   Consecutive blocks of a group accumulate into the same VMEM-resident
+   output block.
 
-Accumulation precision: operands ride bf16 like the MXU kernels — in
-quantized mode (use_quantized_grad) the integer gradient channels are
-bf16-exact and the f32 accumulation of integer sums is EXACT while
-every partial stays below 2^24, so histograms (and therefore models)
-are bit-identical across hist_backend settings in the quantized
-posture; exact mode rides the same hi/lo bf16 channel pairs as
-histogram_mxu (~f32-accurate, equal to the MXU path up to last-ulp
-summation-order noise). Bin ids stream as uint8 — or 4-bit packed
-pairs (pack_bins_4bit), unpacked nibble-wise in VMEM.
+Which passes of a tree use this build is `use_grouped`'s to say, from
+static shapes alone (grower_mxu.sweep asks it once per pass at trace
+time); hist_backend=pallas forces it for every pass.
+
+Accumulation precision: the channels of histogram_mxu._hist_channels,
+bf16 operands, f32 accumulation, _combine_hist: only the summation
+order differs from the one-hot kernels. In quantized mode the integer
+sums are exact below 2^24, so histograms and models are bit-identical
+across formulations; exact mode agrees to last-ulp summation-order
+noise. A row reaches the kernel as one bf16 row (bins, channels, slot:
+_row_table); 4-bit packed bin pairs are unpacked in VMEM.
 """
 
 from __future__ import annotations
@@ -48,159 +49,304 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .histogram_mxu import (_COMPILER_PARAMS, _FGROUP, _combine_hist,
-                            _hist_channels, _packed_cols)
+from .histogram_mxu import (_COMPILER_PARAMS, _combine_hist,
+                            _hist_accumulate, _hist_channels,
+                            hist_num_channels)
 
 __all__ = ["build_histograms_pallas", "build_histograms_scatter",
-           "partition_rows"]
+           "partition_rows", "group_width", "use_grouped"]
 
 
-#: rows per step of the scan partition's blocked cumsum (static; the
-#: per-step one-hot working set is _SCAN_CB x (num_slots+1) i32)
-_SCAN_CB = 4096
+#: rows per tile of the rank sweep (the triangular operand is
+#: [_RANK_TILE, _RANK_TILE] bf16) and tiles per grid step
+_RANK_TILE = 256
+_RANK_TILES_PER_STEP = 8
+
+#: blocks gathered per trip of the gather loop: its trip count is
+#: dynamic, so a pass moves the blocks in use and no others
+_GATHER_CHUNK_BLOCKS = 32
+
+#: row block of the grouped kernel (see use_grouped for its derivation)
+GROUPED_ROW_BLOCK = 2048
+
+#: a pass is built slot-grouped from this operand width (nchan * slots)
+#: on. DERIVATION: helpers/microbench_pass.py on the v5e at 2,625,000 x
+#: 28 x 256, half the rows live (PERF.md section 5 has the table). The
+#: one-hot pass sits on its floor up to a width of 128 and grows
+#: linearly beyond (five channels: 59.2 ms at width 200, 97.8 at 360,
+#: 296.1 at 1315; three: 68.7 at 216, 108.2 at 408, 188.9 at 789); the
+#: grouped pass costs the route, the rank, the gather of the live rows
+#: and the floor on them, 107-119 ms whatever the width. With HALF the
+#: rows live the lines cross at a width of 431 (five channels) and 417
+#: (three), but the grouped pass shrinks with the live share and the
+#: one-hot pass does not, and a wide pass of a real tree holds the
+#: smaller siblings less the parked leaves: in the Higgs tree the pass
+#: at width 360 costs 66 ms grouped against 94 one-hot (the same run
+#: with this constant at 400 is 27.7 ms a tree slower, PERF.md section
+#: 6), while at width 200 the one-hot pass (59 ms) is still the cheaper.
+GROUPED_MIN_WIDTH = 320
+
+#: ...and only where the rows outweigh the layout's padding (one block
+#: per group at most) this many times: a padded row costs the gather
+#: and the kernel what a live row does, so at the crossover width the
+#: grouped pass wins only while the padding stays under about a fifth
+#: of the rows (same table); tiny data sets stay one-hot
+GROUPED_MIN_ROWS_PER_PAD = 8
+
+#: positions are carried in f32 through the rank sweep: exact below
+_MAX_POSITIONS = 1 << 24
 
 
-def _stable_order_scan(slot_full: jax.Array, sort_start: jax.Array,
-                       num_slots: int) -> jax.Array:
-    """The stable argsort permutation WITHOUT sorting: O(N*S) blocked
-    prefix sums instead of the O(N log N) sort network.
+def group_width(nchan: int) -> int:
+    """Slots that share one group: as many as fill the MXU's 128 rows
+    with `nchan` channels each."""
+    return max(1, 128 // nchan)
 
-    A stable sort by slot places row i at
-        position[i] = sort_start[slot[i]] + rank[i]
-    where rank[i] = #{j < i : slot[j] == slot[i]} — the running
-    occurrence count of its slot. The rank comes from a blocked
-    exclusive cumsum: rows stream in _SCAN_CB-row blocks; each step
-    one-hots its block against the slot axis, takes the within-block
-    exclusive cumsum, and adds the carried per-slot totals of all
-    earlier blocks. Scattering arange(N) through `position` (a
-    permutation of [0, N), so the scatter is collision-free) inverts
-    it back into the order vector argsort would have produced —
-    bit-identical, which is what keeps the scan and argsort partitions
-    byte-equal downstream.
-    """
-    n = slot_full.shape[0]
-    s1 = num_slots + 1
-    cb = min(_SCAN_CB, max(n, 1))
-    npad = (-n) % cb
+
+def use_grouped(width: int, rows: int, row_block: int = GROUPED_ROW_BLOCK
+                ) -> bool:
+    """Whether a pass whose one-hot operand would be `width` = nchan *
+    slots wide, over `rows` rows, is built slot-grouped. A pure function
+    of static shapes: the same answer on every platform and in every
+    trace of a shape."""
+    groups = -(-width // 128)
+    pad_rows = groups * row_block
+    return (width >= GROUPED_MIN_WIDTH and
+            rows >= GROUPED_MIN_ROWS_PER_PAD * pad_rows and
+            rows + pad_rows < _MAX_POSITIONS)
+
+
+# ---------------------------------------------------------------------------
+# partition
+# ---------------------------------------------------------------------------
+
+def _rank_kernel(gpad: int, t: int, tiles: int, dump: int):
+    def kernel(base_ref, grp_ref, dst_ref, run_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            run_ref[:] = base_ref[:]
+
+        iota_g = jax.lax.broadcasted_iota(jnp.int32, (gpad, t), 0)
+        # tri[j, i] = j < i: (one-hot @ tri)[g, i] counts the rows of
+        # group g before row i in this tile
+        tri = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) <
+               jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)) \
+            .astype(jnp.bfloat16)
+        for k in range(tiles):
+            grp = grp_ref[k:k + 1, :]                        # [1, T] i32
+            oh = grp == iota_g                               # [G, T] bool
+            ohf = jnp.where(oh, jnp.float32(1.0), jnp.float32(0.0))
+            before = jax.lax.dot_general(
+                ohf.astype(jnp.bfloat16), tri,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [G, T]
+            run = run_ref[:]
+            pos = jnp.sum(jnp.where(oh, before + run, jnp.float32(0.0)),
+                          axis=0, keepdims=True)             # [1, T]
+            dst_ref[k:k + 1, :] = jnp.where(
+                grp >= 0, pos, jnp.float32(dump)).astype(jnp.int32)
+            run_ref[:] = run + jnp.sum(ohf, axis=1, keepdims=True)
+
+    return kernel
+
+
+def _stable_positions(grp: jax.Array, base: jax.Array, *, num_groups: int,
+                      dump: int, interpret: bool = False) -> jax.Array:
+    """Layout position of every row under a stable partition by group:
+    position[i] = base[grp[i]] + #{j < i : grp[j] == grp[i]}, and `dump`
+    for rows with grp < 0. One sweep whose cost does not grow with the
+    number of slots: each tile's rank comes from a triangular matmul,
+    the per-group running count stays in VMEM across the (sequential)
+    grid. Positions ride f32: exact below 2^24."""
+    n = grp.shape[0]
+    t, tiles = _RANK_TILE, _RANK_TILES_PER_STEP
+    gpad = ((num_groups + 15) // 16) * 16    # bf16 sublane tile
+    step = t * tiles
+    npad = (-n) % step
     if npad:
-        # padded rows ride the trash slot AFTER every real row, so no
-        # real row's rank can count them
-        slot_full = jnp.pad(slot_full, (0, npad),
-                            constant_values=num_slots)
-    blocks = slot_full.reshape(-1, cb)
-    iota_s = jnp.arange(s1, dtype=jnp.int32)[None, :]
-
-    def step(base, slot_blk):
-        oh = (slot_blk[:, None] == iota_s).astype(jnp.int32)  # [cb, S+1]
-        excl = jnp.cumsum(oh, axis=0) - oh
-        rank_blk = base[slot_blk] + \
-            jnp.take_along_axis(excl, slot_blk[:, None], axis=1)[:, 0]
-        return base + jnp.sum(oh, axis=0), rank_blk
-
-    _, ranks = jax.lax.scan(step, jnp.zeros(s1, jnp.int32), blocks)
-    position = sort_start[slot_full] + ranks.reshape(-1)
-    return jnp.zeros(n, jnp.int32).at[position[:n]].set(
-        jnp.arange(n, dtype=jnp.int32))
+        grp = jnp.pad(grp, (0, npad), constant_values=-1)
+    grp2 = grp.reshape(-1, t)
+    base_b = jnp.broadcast_to(
+        jnp.pad(base.astype(jnp.float32), (0, gpad - num_groups))[:, None],
+        (gpad, t))
+    dst = pl.pallas_call(
+        _rank_kernel(gpad, t, tiles, dump),
+        grid=(grp2.shape[0] // tiles,),
+        in_specs=[pl.BlockSpec((gpad, t), lambda i: (0, 0)),
+                  pl.BlockSpec((tiles, t), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tiles, t), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(grp2.shape, jnp.int32),
+        scratch_shapes=[pltpu.VMEM((gpad, t), jnp.float32)],
+        name="partition_rank", interpret=interpret,
+        **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
+    )(base_b, grp2)
+    return dst.reshape(-1)[:n]
 
 
 def partition_rows(row_slot: jax.Array, *, num_slots: int, row_block: int,
-                   counts: jax.Array = None, impl: str = "auto"):
-    """Device-side padded partition of rows by frontier slot.
+                   group: int = 1, counts: jax.Array = None,
+                   impl: str = "auto", interpret: bool = False):
+    """Device-side padded partition of the LIVE rows by slot group.
 
-    Every `row_block` consecutive positions of the returned layout hold
-    rows of ONE slot; the trash slot `num_slots` collects parked rows
-    (slot < 0 / out of range) and layout padding.
+    Group g holds slots [g*group, (g+1)*group). Every `row_block`
+    consecutive positions of the layout hold rows of ONE group, in row
+    order (a stable partition); each group owns at least one block, so
+    its output block is always initialised. Rows with a slot outside
+    [0, num_slots) are parked: they are not in the layout.
 
     counts: optional per-slot row counts ([num_slots] or longer, e.g.
-    the route_rows_mxu(emit_counts=True) output) — skips the
-    segment_sum here, so routing + partition metadata is a single
-    sweep over the rows.
+    the route_rows_mxu(emit_counts=True) output): skips the counting
+    pass here.
 
-    impl selects how the slot-stable row permutation is produced:
-    "scan" (the "auto" resolution) computes the stable rank by blocked
-    prefix sums (_stable_order_scan — no O(N log N) sort), "argsort"
-    keeps the original stable sort as the bit-parity oracle. Both
-    yield the identical permutation, hence identical block layouts.
+    impl: "rank" (what "auto" means) takes the positions from
+    _stable_positions and inverts them with one collision-free scatter;
+    "argsort" is the stable sort, retained as the oracle. Both give the
+    identical layout.
 
-    Returns (block_slot [TB] i32, src [TB*row_block] i32): src indexes
-    the original rows (n = dummy/padding position) and TB is the static
-    block-count bound ceil(n/row_block) + num_slots + 1.
+    Returns (block_group [TB] i32, blocks_used [] i32, src [TB*row_block]
+    i32): src indexes the original rows, n marks padding; blocks at and
+    after blocks_used hold padding only and repeat the last group. TB is
+    static: ceil(n / row_block) + groups, rounded up to whole gather
+    chunks.
     """
-    if impl not in ("auto", "argsort", "scan"):
+    if impl not in ("auto", "argsort", "rank"):
         raise ValueError(f"unknown partition impl {impl!r}")
     n = row_slot.shape[0]
-    s = num_slots
-    nb = row_block
-    slot_full = jnp.where((row_slot < 0) | (row_slot >= s), s,
-                          row_slot).astype(jnp.int32)
+    s, nb = num_slots, row_block
+    ng = -(-s // group)
+    live = (row_slot >= 0) & (row_slot < s)
+    grp = jnp.where(live, row_slot // group, -1).astype(jnp.int32)
     if counts is None:
-        counts = jax.ops.segment_sum(jnp.ones(n, jnp.int32), slot_full,
-                                     num_segments=s + 1)  # [S+1]
+        gcounts = jax.ops.segment_sum(
+            live.astype(jnp.int32), jnp.where(live, grp, 0),
+            num_segments=ng)
     else:
-        live = counts[:s].astype(jnp.int32)
-        counts = jnp.concatenate(
-            [live, (jnp.int32(n) - jnp.sum(live))[None]])
-    sort_start = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32),
-         jnp.cumsum(counts)[:-1].astype(jnp.int32)])
-    if impl == "argsort":
-        # the retained O(N log N) bit-parity oracle — the ONLY
-        # sanctioned sort on the partition path (PERF001)
-        order = jnp.argsort(slot_full)  # tpulint: disable=PERF001
-    else:
-        order = _stable_order_scan(slot_full, sort_start, s)
+        c = counts[:s].astype(jnp.int32)
+        gcounts = jnp.pad(c, (0, ng * group - s)).reshape(ng, group) \
+            .sum(axis=1)
 
-    # padded block layout: ceil(count/nb) blocks per slot, min 1
-    caps = jnp.maximum(1, -(-counts // nb))
-    tb_max = (n + nb - 1) // nb + s + 1                   # static bound
+    tb = -(-n // nb) + ng
+    chunk = min(_GATHER_CHUNK_BLOCKS, tb)
+    tb = -(-tb // chunk) * chunk
+    caps = jnp.maximum(1, -(-gcounts // nb))
     blk_start = jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(caps).astype(jnp.int32)])
-    # block j belongs to slot searchsorted(blk_start, j, 'right')-1;
-    # tail blocks beyond blk_start[-1] go to the trash slot
-    j = jnp.arange(tb_max, dtype=jnp.int32)
-    block_slot = jnp.clip(
-        jnp.searchsorted(blk_start, j, side="right") - 1, 0, s) \
+    blocks_used = blk_start[-1]
+    j = jnp.arange(tb, dtype=jnp.int32)
+    block_group = jnp.clip(
+        jnp.searchsorted(blk_start, j, side="right") - 1, 0, ng - 1) \
         .astype(jnp.int32)
-    block_slot = jnp.where(j >= blk_start[-1], s, block_slot)
 
-    # padded source row per position (n -> dummy row)
-    p = jnp.arange(tb_max * nb, dtype=jnp.int32)
-    pslot = block_slot[p // nb]
-    r = p - blk_start[pslot] * nb                         # offset in slot
-    take = (r >= 0) & (r < counts[pslot])
-    src_sorted = jnp.clip(sort_start[pslot] + r, 0, n - 1)
-    src = jnp.where(take, order[src_sorted], n)
-    return block_slot, src
+    if impl == "rank" and tb * nb >= _MAX_POSITIONS:
+        raise ValueError("partition impl 'rank' carries positions in "
+                         "f32: %d rows are too many" % n)
+    if impl == "argsort" or tb * nb >= _MAX_POSITIONS:
+        # the retained O(N log N) oracle: the ONLY sanctioned sort on
+        # the partition path (PERF001)
+        order = jnp.argsort(  # tpulint: disable=PERF001
+            jnp.where(live, grp, ng))
+        sort_start = jnp.concatenate(
+            [jnp.zeros(1, jnp.int32),
+             jnp.cumsum(gcounts)[:-1].astype(jnp.int32)])
+        p = jnp.arange(tb * nb, dtype=jnp.int32)
+        pg = block_group[p // nb]
+        r = p - blk_start[pg] * nb
+        take = r < gcounts[pg]     # a tail block's r is past its group
+        src = jnp.where(
+            take, order[jnp.clip(sort_start[pg] + r, 0, n - 1)], n)
+    else:
+        dst = _stable_positions(grp, blk_start[:ng] * nb, num_groups=ng,
+                                dump=tb * nb, interpret=interpret)
+        src = jnp.full(tb * nb, n, jnp.int32).at[dst].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop",
+            unique_indices=True)
+    return block_group, blocks_used, src
 
 
-def _scatter_kernel(nb: int, f: int, b: int, fh: int = 0,
-                    mm_dtype=jnp.bfloat16):
-    def kernel(slot_ref, bins_ref, data_ref, out_ref):
+def _gather_used(table: jax.Array, src: jax.Array, rows_used: jax.Array,
+                 chunk_rows: int) -> jax.Array:
+    """table[src] for the first `rows_used` positions of `src`, in fixed
+    chunks under a dynamic trip count; the rest stays zero (the kernel
+    never multiplies it)."""
+    nchunks = (rows_used + chunk_rows - 1) // chunk_rows
+
+    def body(c, buf):
+        at = c * chunk_rows
+        idx = jax.lax.dynamic_slice(src, (at,), (chunk_rows,))
+        return jax.lax.dynamic_update_slice(buf, table[idx], (at, 0))
+
+    return jax.lax.fori_loop(
+        0, nchunks, body,
+        jnp.zeros((src.shape[0], table.shape[1]), table.dtype))
+
+
+def _row_table(bins: jax.Array, data: jax.Array, nchan: int,
+               slot_local: jax.Array) -> jax.Array:
+    """Everything the kernel reads of a row as ONE bf16 row, so a pass
+    gathers once: the bin columns (byte values, exact in bf16), then
+    the channels as the very bf16 operand the one-hot kernels build
+    from `data` (the MXU is fed bf16 either way, so nothing is lost),
+    then the row's slot within its group (255: none). One extra
+    all-zero, slot-less row at the end stands for padding."""
+    tab = jnp.concatenate(
+        [bins.astype(jnp.bfloat16), data[:, :nchan].astype(jnp.bfloat16),
+         slot_local.astype(jnp.bfloat16)[:, None]], axis=1)
+    pad = jnp.zeros((1, tab.shape[1]), jnp.bfloat16).at[0, -1].set(255)
+    return jnp.concatenate([tab, pad])
+
+
+# ---------------------------------------------------------------------------
+# kernel
+# ---------------------------------------------------------------------------
+
+def _grouped_kernel(nb: int, f: int, b: int, sg: int, nchan: int,
+                    fcols: int, fh: int = 0, mm_dtype=jnp.bfloat16):
+    def kernel(grp_ref, used_ref, tab_ref, out_ref):
         i = pl.program_id(0)
-        slot = slot_ref[i]
-        prev = slot_ref[jnp.maximum(i - 1, 0)]
-        first = (i == 0) | (slot != prev)
+        g = grp_ref[i]
+        prev = grp_ref[jnp.maximum(i - 1, 0)]
 
-        @pl.when(first)
+        @pl.when((i == 0) | (g != prev))
         def _():
             out_ref[0] = jnp.zeros_like(out_ref[0])
 
-        bins_i = bins_ref[:].astype(jnp.int32)           # [Nb, Fcols]
-        data = data_ref[:].astype(mm_dtype)              # [8, Nb]
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (nb, b), 1)
-        for gj in range(0, f, _FGROUP):
-            js = range(gj, min(gj + _FGROUP, f))
-            cols = _packed_cols(bins_i, js, fh) if fh else \
-                [bins_i[:, j:j + 1] for j in js]
-            oh = jnp.concatenate(
-                [(c == iota_b) for c in cols],
-                axis=1).astype(mm_dtype)                 # [Nb, G*B]
-            part = jax.lax.dot_general(
-                data, oh, dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [8, G*B]
-            out_ref[0, :, gj * b:(gj + len(js)) * b] += part
+        @pl.when(i < used_ref[0])
+        def _():
+            tab = tab_ref[:].astype(jnp.float32)             # [Nb, W]
+            _hist_accumulate(
+                out_ref,
+                tab[:, fcols + nchan:fcols + nchan + 1].astype(jnp.int32),
+                tab[:, :fcols].astype(jnp.int32),
+                tab[:, fcols:fcols + nchan],
+                nb=nb, f=f, b=b, s=sg, nchan=nchan, mm_dtype=mm_dtype,
+                fh=fh)
 
     return kernel
+
+
+def _grouped_call(block_group: jax.Array, blocks_used: jax.Array,
+                  tab_g: jax.Array, *, nb: int, f: int, b: int, sg: int,
+                  ng: int, nchan: int, fcols: int, fh: int = 0,
+                  interpret: bool = False) -> jax.Array:
+    """The kernel over a gathered row table: [G, nchan*sg, F*B] f32.
+    Blocks at and after blocks_used are neither fetched (their index
+    repeats the last block in use) nor multiplied."""
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(block_group.shape[0],),
+        in_specs=[pl.BlockSpec(
+            (nb, tab_g.shape[1]),
+            lambda i, grp, used: (jnp.minimum(i, used[0] - 1), 0))],
+        out_specs=pl.BlockSpec((1, nchan * sg, f * b),
+                               lambda i, grp, used: (grp[i], 0, 0)))
+    return pl.pallas_call(
+        _grouped_kernel(nb, f, b, sg, nchan, fcols, fh=fh),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((ng, nchan * sg, f * b),
+                                       jnp.float32),
+        name="grouped_hist", interpret=interpret,
+        **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
+    )(block_group, blocks_used[None], tab_g)
 
 
 @functools.partial(
@@ -211,7 +357,8 @@ def _scatter_kernel(nb: int, f: int, b: int, fh: int = 0,
 def build_histograms_scatter(bins: jax.Array, grad: jax.Array,
                              hess: jax.Array, cnt: jax.Array,
                              row_slot: jax.Array, *, num_slots: int,
-                             bmax: int, row_block: int = 1024,
+                             bmax: int,
+                             row_block: int = GROUPED_ROW_BLOCK,
                              num_features: int = 0,
                              double_prec: bool = True,
                              quantized: bool = False,
@@ -219,14 +366,14 @@ def build_histograms_scatter(bins: jax.Array, grad: jax.Array,
                              slot_counts: jax.Array = None,
                              partition_impl: str = "auto",
                              interpret: bool = False) -> jax.Array:
-    """Per-slot histograms via the slot-grouped scatter kernel.
+    """Per-slot histograms via the slot-grouped build.
 
     Args mirror build_histograms_mxu_v2; row_slot < 0 routes to no
     slot. num_features > 0 marks `bins` as 4-bit packed
     (pack_bins_4bit) with that many logical features. slot_counts:
     optional per-slot row counts (route_rows_mxu emit_counts) so the
     partition skips its own counting pass. partition_impl selects the
-    row-permutation scheme (partition_rows: auto|argsort|scan).
+    row-permutation scheme (partition_rows: auto|rank|argsort).
 
     Returns [num_slots, F, bmax, 3] f32 (grad, hess, count).
     """
@@ -237,37 +384,28 @@ def build_histograms_scatter(bins: jax.Array, grad: jax.Array,
     s = num_slots
     b = ((bmax + 127) // 128) * 128      # lane-aligned bin axis
     fb = f * b
+    nchan = hist_num_channels(double_prec, quantized, const_hess)
+    sg = min(group_width(nchan), s)
+    ng = -(-s // sg)
 
-    block_slot, src = partition_rows(row_slot, num_slots=s,
-                                     row_block=nb, counts=slot_counts,
-                                     impl=partition_impl)
-    tb_max = block_slot.shape[0]
+    block_group, blocks_used, src = partition_rows(
+        row_slot, num_slots=s, row_block=nb, group=sg,
+        counts=slot_counts, impl=partition_impl, interpret=interpret)
+    data, _ = _hist_channels(grad, hess, cnt, double_prec, quantized,
+                             const_hess)                     # [N, 8]
+    live = (row_slot >= 0) & (row_slot < s)
+    table = _row_table(bins, data, nchan,
+                       jnp.where(live, row_slot % sg, 255))
+    tab_g = _gather_used(
+        table, src, blocks_used * nb,
+        min(_GATHER_CHUNK_BLOCKS, block_group.shape[0]) * nb)
+    out = _grouped_call(block_group, blocks_used, tab_g, nb=nb, f=f, b=b,
+                        sg=sg, ng=ng, nchan=nchan, fcols=fcols, fh=fh,
+                        interpret=interpret)
 
-    bins_ext = jnp.concatenate(
-        [bins, jnp.zeros((1, fcols), bins.dtype)], axis=0)
-    bins_pad = bins_ext[src]                              # [TB*Nb, Fc]
-    data, nchan = _hist_channels(grad, hess, cnt, double_prec,
-                                 quantized, const_hess)   # [N, 8]
-    data8 = jnp.concatenate(
-        [data, jnp.zeros((1, 8), jnp.float32)], axis=0)[src].T
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(tb_max,),
-        in_specs=[pl.BlockSpec((nb, fcols), lambda i, sl: (i, 0)),
-                  pl.BlockSpec((8, nb), lambda i, sl: (0, i))],
-        out_specs=pl.BlockSpec((1, 8, fb), lambda i, sl: (sl[i], 0, 0)))
-    out = pl.pallas_call(
-        _scatter_kernel(nb, f, b, fh=fh),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s + 1, 8, fb), jnp.float32),
-        interpret=interpret,
-        **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(block_slot, bins_pad, data8)
-
-    # [S+1, 8, F*B] -> the shared postlude layout [1, C*S, F*B]
-    out = jnp.transpose(out[:s, :nchan], (1, 0, 2)).reshape(
-        1, nchan * s, fb)
+    # [G, C*sg, F*B] -> the shared postlude layout [1, C*S, F*B]
+    out = jnp.transpose(out.reshape(ng, nchan, sg, fb), (1, 0, 2, 3)) \
+        .reshape(nchan, ng * sg, fb)[:, :s].reshape(1, nchan * s, fb)
     return _combine_hist(out, nchan=nchan, s=s, f=f, b=b, bmax=bmax,
                          double_prec=double_prec, const_hess=const_hess)
 
@@ -275,14 +413,15 @@ def build_histograms_scatter(bins: jax.Array, grad: jax.Array,
 def build_histograms_pallas(bins: jax.Array, grad: jax.Array,
                             hess: jax.Array, cnt: jax.Array,
                             row_slot: jax.Array, *, num_slots: int,
-                            bmax: int, row_block: int = 1024,
+                            bmax: int,
+                            row_block: int = GROUPED_ROW_BLOCK,
                             fchunk: int = 0,
                             partition_impl: str = "auto",
                             interpret: bool = False) -> jax.Array:
     """Compat contract of the original one-hot kernel for the portable
     grower (grower.py hist_impl="pallas"): exact full-precision
-    channels on the scatter kernel. fchunk is accepted and ignored (the
-    scatter kernel groups features by _FGROUP)."""
+    channels on the slot-grouped build. fchunk is accepted and ignored
+    (the kernel groups features by _FGROUP)."""
     del fchunk
     return build_histograms_scatter(
         bins, grad, hess, cnt, row_slot, num_slots=num_slots, bmax=bmax,

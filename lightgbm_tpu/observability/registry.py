@@ -74,9 +74,9 @@ class ObservabilityRegistry:
                            "wall_seconds": 0.0, "sample_rows": 0,
                            "exact": 0}
         # histogram-backend resolution (boosting/gbdt.py
-        # _resolved_hist_backend): the pinned choice + autotune timings
-        self._hist_backend = {"choice": "", "autotuned": False,
-                              "timings_ms": {}}
+        # _resolved_hist_backend): the pinned choice + the growth
+        # program's static per-pass plan
+        self._hist_backend = {"choice": "", "plan": []}
         # collective-watchdog aggregates (reliability/watchdog.py):
         # guarded brackets, deadline overruns, aborts and the worst
         # peer heartbeat age observed while diagnosing
@@ -171,8 +171,7 @@ class ObservabilityRegistry:
             self._streaming = {"chunks": 0, "rows": 0, "bytes": 0,
                                "wall_seconds": 0.0, "sample_rows": 0,
                                "exact": 0}
-            self._hist_backend = {"choice": "", "autotuned": False,
-                                  "timings_ms": {}}
+            self._hist_backend = {"choice": "", "plan": []}
             self._collective = {"guarded": 0, "wall_seconds": 0.0,
                                 "timeouts": 0, "aborts": 0,
                                 "heartbeat_age_max_s": 0.0, "world": 0}
@@ -228,20 +227,26 @@ class ObservabilityRegistry:
                 "rows_per_sec": round(rps, 1)}
 
     def hist_backend_snapshot(self) -> Dict:
-        """The pinned histogram backend as a flat exportable mapping.
-        The string `choice` rides the JSON snapshot/bench tail; the
-        Prometheus exporter skips strings, so the choice is ALSO
-        one-hot encoded (is_mxu/is_pallas/is_scatter) for scrapers."""
+        """The pinned histogram backend and the growth program's
+        per-pass plan as an exportable mapping. `plan` lists every
+        histogram pass of one tree as {stage, sk, formulation}
+        (grower_mxu.hist_pass_plan: static, read without a device
+        sync); `grouped_passes_per_tree` counts the scheduled passes
+        and the bridge built slot-grouped (the fixup body runs as often
+        as a tree needs, so it is listed and not counted). The strings
+        ride the JSON snapshot/bench tail; the Prometheus exporter
+        skips them, so the choice is ALSO one-hot encoded (is_auto/
+        is_mxu/is_pallas/is_scatter) for scrapers."""
         with self._lock:
             hb = dict(self._hist_backend)
-        out: Dict = {"choice": hb["choice"],
-                     "autotuned": bool(hb["autotuned"])}
-        for name in ("mxu", "pallas", "scatter"):
+        plan = [dict(p) for p in hb["plan"]]
+        out: Dict = {"choice": hb["choice"], "plan": plan}
+        for name in ("auto", "mxu", "pallas", "scatter"):
             out["is_" + name] = int(hb["choice"] == name)
-        for name, ms in sorted((hb.get("timings_ms") or {}).items()):
-            out[str(name) + "_ms"] = round(float(ms), 3)
-        for name, text in sorted((hb.get("errors") or {}).items()):
-            out[str(name) + "_error"] = text
+        for form in ("onehot", "grouped", "scatter"):
+            out[form + "_passes_per_tree"] = sum(
+                p["formulation"] == form and p["stage"] != "fixup"
+                for p in plan)
         return out
 
     def collective_snapshot(self) -> Dict:
@@ -339,25 +344,21 @@ class ObservabilityRegistry:
                                clock_samples=self.clock_samples())
 
     # -- training hooks (called from boosting/gbdt.py) ------------------
-    def record_hist_autotune(self, choice: str, timings_ms: Dict,
-                             autotuned: bool,
-                             errors: Optional[Dict] = None) -> None:
-        """Pin the resolved histogram backend (+ per-backend autotune
-        timings, ms). Recorded even when disabled — this is one-shot
-        startup configuration, not per-iteration telemetry, and the
-        bench JSON tail reads it regardless of the enable flag.
-        `errors` maps a backend that failed to build to its exception
-        text (the autotune then chose nothing and training stopped)."""
+    def record_hist_plan(self, choice: str, plan) -> None:
+        """Pin the resolved histogram backend and the growth program's
+        per-pass plan, [(stage, kernel slots, formulation)]. Recorded
+        even when disabled: this is one-shot startup configuration, not
+        per-iteration telemetry, and the bench JSON tail reads it
+        regardless of the enable flag."""
         with self._lock:
             self._hist_backend = {
-                "choice": str(choice), "autotuned": bool(autotuned),
-                "timings_ms": {str(k): float(v)
-                               for k, v in (timings_ms or {}).items()},
-                "errors": {str(k): str(v)[:2000]
-                           for k, v in (errors or {}).items()}}
+                "choice": str(choice),
+                "plan": [{"stage": str(st), "sk": int(sk),
+                          "formulation": str(form)}
+                         for st, sk, form in plan]}
 
     # -- collective-watchdog hooks (reliability/watchdog.py) ------------
-    # recorded even when disabled, like record_hist_autotune: watchdog
+    # recorded even when disabled, like record_hist_plan: watchdog
     # events are rare, high-value incident forensics — the last thing
     # the run prints before aborting must not depend on an enable flag
     def record_collective_guard(self, wall_seconds: float) -> None:
@@ -482,16 +483,18 @@ class ObservabilityRegistry:
 
     def tree_macs_for(self, gbdt) -> int:
         """Analytic per-tree MAC estimate for this booster's config;
-        cached on the booster. 0 off the MXU path (no MAC model) —
-        including when hist_backend resolves to the scatter kernels,
-        whose cost is partition- not matmul-shaped: MFU then reads as
-        unavailable rather than invented (docs/Observability.md)."""
+        cached on the booster. 0 off the MXU path (no MAC model) and
+        wherever a pass of the growth program is built slot-grouped or
+        by the XLA oracle: the model counts the one-hot kernel's MACs,
+        which such a pass does not do, so MFU then reads as unavailable
+        rather than invented (docs/Observability.md)."""
         cached = getattr(gbdt, "_obs_tree_macs", None)
         if cached is not None:
             return cached
         macs = 0
-        if (getattr(gbdt, "_hist_impl", None) == "mxu" and
-                getattr(gbdt, "_hist_backend", None) in (None, "mxu")):
+        all_onehot = all(form == "onehot" for _, _, form in
+                         getattr(gbdt, "_hist_plan", None) or ())
+        if getattr(gbdt, "_hist_impl", None) == "mxu" and all_onehot:
             cfg = gbdt.config
             macs = tree_macs(
                 num_leaves=cfg.num_leaves, num_rows=gbdt.num_data,

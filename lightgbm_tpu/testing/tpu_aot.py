@@ -210,7 +210,9 @@ def sharded_grower_program(params: dict, *, rows: int, features: int,
             hist_subtraction=cfg.hist_subtraction,
             overshoot=cfg.growth_overshoot,
             bridge_gate=cfg.growth_bridge_gate,
-            quantized_grad=cfg.use_quantized_grad, const_hessian=0.0))
+            quantized_grad=cfg.use_quantized_grad, const_hessian=0.0,
+            hist_backend=cfg.hist_backend,
+            partition_impl=cfg.partition_impl))
     rowwise, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     specs = [_sds((rows, features), jnp.uint8, rowwise)] + \
         [_sds((rows,), jnp.float32, rowwise)] * 3 + \
@@ -272,8 +274,10 @@ def main(argv=None) -> int:
     groups = [
         ("kernel", lambda: kernel_sites(bmax=args.max_bin, slots=slots,
                                         **shape)),
+        # the library's defaults: hist_backend=auto, a formulation per
+        # pass (one-hot and slot-grouped kernels in one program)
         ("defaults", lambda: training_programs(
-            dict(base, hist_backend="mxu"), block=args.block, **shape)),
+            base, block=args.block, **shape)),
         ("bench/mxu", lambda: training_programs(
             dict(bench, hist_backend="mxu"), block=args.block, **shape)),
         ("bench/pallas", lambda: training_programs(

@@ -29,6 +29,22 @@ def _drop_compiled_programs_between_modules():
     jax.clear_caches()
 
 
+@pytest.fixture
+def low_crossover(monkeypatch):
+    """hist_backend=auto's per-pass rule (histogram_pallas.use_grouped)
+    with its constants lowered, so that it engages at sizes interpret
+    mode can run: the one-hot/grouped choice then differs between the
+    passes of ONE small tree. The constants are read at trace time, so
+    compiled programs are dropped around the patch."""
+    import jax
+    from lightgbm_tpu.learner import histogram_pallas as hp
+    jax.clear_caches()
+    monkeypatch.setattr(hp, "GROUPED_MIN_WIDTH", 20)
+    monkeypatch.setattr(hp, "GROUPED_MIN_ROWS_PER_PAD", 0)
+    yield
+    jax.clear_caches()
+
+
 def make_binary(n=2000, f=10, seed=0):
     r = np.random.RandomState(seed)
     X = r.randn(n, f)

@@ -1,14 +1,14 @@
 """Histogram-backend parity suite (`make kernels`).
 
 The hist_backend contract (config.py, docs/Performance.md): in the
-quantized posture the mxu one-hot kernel, the Pallas scatter kernel
+quantized posture the mxu one-hot kernel, the slot-grouped build
 (histogram_pallas.py), and the XLA segment-sum oracle produce
 BIT-IDENTICAL histograms — integer gradient channels are bf16-exact and
 f32 accumulation of integer sums is exact below 2^24 — so trees and
-model.txt are byte-equal across backends and `hist_backend=auto` is
-purely a speed knob. Exact (non-quantized) mode rides hi/lo bf16
-channel pairs and is only ~f32-accurate; its error bound is pinned
-here too.
+model.txt are byte-equal across backends and `hist_backend=auto`, which
+chooses a formulation per pass from static shapes, is purely a speed
+knob. Exact (non-quantized) mode rides hi/lo bf16 channel pairs and is
+only ~f32-accurate; its error bound is pinned here too.
 
 The fast subset (not slow) is tier-1; the slow subset adds tree- and
 model-level byte parity through the boosters.
@@ -197,20 +197,22 @@ class TestPartitionRows:
         rng = np.random.RandomState(1)
         n, nb = 997, 128
         slot = jnp.asarray(rng.randint(-1, S, size=n).astype(np.int32))
-        block_slot, src = partition_rows(slot, num_slots=S, row_block=nb)
-        bs, sr = np.asarray(block_slot), np.asarray(src)
+        block_group, used, src = partition_rows(
+            slot, num_slots=S, row_block=nb, interpret=True)
+        bs, sr = np.asarray(block_group), np.asarray(src)
         sl = np.asarray(slot)
         assert sr.shape[0] == bs.shape[0] * nb
-        # every real row appears exactly once
+        # every LIVE row appears exactly once; parked rows (slot -1) are
+        # not in the layout at all
         real = sr[sr < n]
-        assert sorted(real.tolist()) == list(range(n))
-        # every REAL row sits in a block of its own slot (parked rows in
-        # the trash slot S); padding positions carry the dummy row n and
-        # may sit anywhere — they contribute zeros
+        assert sorted(real.tolist()) == np.flatnonzero(sl >= 0).tolist()
+        # every real row sits in a block of its own slot; padding
+        # positions carry the dummy row n and contribute zeros
         pos_slot = bs[np.arange(sr.shape[0]) // nb]
         live = sr < n
-        expect = np.where(sl[sr[live]] < 0, S, sl[sr[live]])
-        np.testing.assert_array_equal(pos_slot[live], expect)
+        np.testing.assert_array_equal(pos_slot[live], sl[sr[live]])
+        # nothing real past the blocks in use, which the kernel skips
+        assert not live[int(used) * nb:].any()
 
 
 class TestRouteEmitCounts:
@@ -297,19 +299,31 @@ class TestBackendResolution:
         with pytest.raises(Exception):
             self._booster(hist_backend="vliw")
 
-    def test_auto_pins_mxu_on_cpu(self):
+    def test_auto_stays_auto_and_records_the_plan(self):
+        # `auto` is the static per-pass rule on every platform: nothing
+        # is measured, nothing pinned to one kernel; the registry and
+        # the booster hold the growth program's plan
         from lightgbm_tpu.observability import registry
         registry.reset()
         bst = self._booster(hist_backend="auto")
         g = bst.gbdt
         g._hist_impl = "mxu"
-        assert g._resolved_hist_backend() == "mxu"
-        assert g._hist_autotune == {"choice": "mxu", "autotuned": False,
-                                    "timings_ms": {}}
+        assert g._resolved_hist_backend() == "auto"
+        assert g._mxu_grow_kwargs()["hist_backend"] == "auto"
         snap = registry.hist_backend_snapshot()
-        assert snap["choice"] == "mxu" and snap["is_mxu"] == 1
-        assert "lightgbm_tpu_hist_backend_is_mxu 1" in \
-            registry.prometheus_text()
+        assert snap["choice"] == "auto" and snap["is_auto"] == 1
+        # 7 leaves x overshoot 2: kernel widths 2, 4, 8, 15, the bridge
+        # at 15 and the fixup body; 300 rows keep every pass one-hot
+        assert [(p["stage"], p["sk"]) for p in snap["plan"]] == [
+            ("pass", 2), ("pass", 4), ("pass", 8), ("pass", 15),
+            ("bridge", 15), ("fixup", 15)]
+        assert {p["formulation"] for p in snap["plan"]} == {"onehot"}
+        assert snap["grouped_passes_per_tree"] == 0
+        assert snap["onehot_passes_per_tree"] == 5
+        text = registry.prometheus_text()
+        assert "lightgbm_tpu_hist_backend_is_auto 1" in text
+        assert "lightgbm_tpu_hist_backend_grouped_passes_per_tree 0" \
+            in text
 
     def test_forced_backend_reaches_grow_kwargs(self):
         bst = self._booster(hist_backend="pallas")
@@ -318,49 +332,165 @@ class TestBackendResolution:
         assert g._mxu_grow_kwargs()["hist_backend"] == "pallas"
         # pinned: a second resolution returns the cache
         assert g._resolved_hist_backend() == "pallas"
-
-    def test_autotune_failure_raises_with_each_backends_text(self):
-        # on CPU the non-interpret kernels cannot build. A kernel that
-        # does not build is not a slow kernel: nothing is chosen, and
-        # the error carries each backend's exception text
-        from lightgbm_tpu.learner.grower_mxu import (HistAutotuneError,
-                                                     autotune_hist_backend)
-        bins = jnp.asarray(np.random.RandomState(0).randint(
-            0, 15, size=(256, 4)).astype(np.uint8))
-        with pytest.raises(HistAutotuneError) as info:
-            autotune_hist_backend(bins, num_slots=4, bmax=15)
-        assert set(info.value.errors) == {"mxu", "pallas"}
-        assert all("interpret" in t for t in info.value.errors.values())
-        assert info.value.timings_ms == {}
-
-    def test_autotune_failure_surfaces_and_is_recorded(self, monkeypatch):
-        # through the booster: the failure is not turned into the other
-        # backend, and hist_backend_snapshot() keeps the text
-        import jax
         from lightgbm_tpu.observability import registry
-        registry.reset()
+        snap = registry.hist_backend_snapshot()
+        assert {p["formulation"] for p in snap["plan"]} == {"grouped"}
+        assert snap["grouped_passes_per_tree"] == 5
+
+    def test_plan_rides_the_build_program_span(self):
+        # readable without a device sync: attributes of the span that
+        # brackets the program's build
         bst = self._booster(hist_backend="auto")
         g = bst.gbdt
+        assert g._hist_plan_attrs() == {}      # off the MXU growth path
         g._hist_impl = "mxu"
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        with pytest.raises(Exception, match="autotune"):
-            g._resolved_hist_backend()
-        snap = registry.hist_backend_snapshot()
-        assert snap["choice"] == "" and snap["autotuned"] is True
-        assert "interpret" in snap["mxu_error"]
-        assert "interpret" in snap["pallas_error"]
-        # strings stay out of the Prometheus families
-        assert "_error" not in registry.prometheus_text()
+        assert g._hist_plan_attrs() == {
+            "hist_plan": "2:onehot,4:onehot,8:onehot,15:onehot,"
+                         "15:onehot,15:onehot",
+            "grouped_passes_per_tree": 0}
 
-    def test_fused_rejects_unresolved_auto(self):
-        from lightgbm_tpu.boosting.fused import build_fused_train
-        with pytest.raises(ValueError, match="resolved hist_backend"):
-            build_fused_train(
-                objective=None, bins=None,
-                feature_mask_fn=None, num_bins=None,
-                missing_is_nan=None, is_cat=None,
-                grower_kwargs={"hist_backend": "auto"}, shrinkage=0.1,
-                extra_seed=0, needs_rng=False)
+    def test_plan_without_subtraction_counts_every_child(self):
+        # no sibling subtraction: a pass builds BOTH children from rows,
+        # so its kernel width is the scan capacity itself (not the
+        # smaller-sibling cap) and the rule sees the wider operand
+        from lightgbm_tpu.learner.grower_mxu import (growth_plan,
+                                                     hist_pass_plan)
+        kw = dict(num_leaves=255, overshoot=2.0, hist_subtraction=False)
+        gp = growth_plan(**kw)
+        plan = hist_pass_plan(rows=2_625_000, **kw)
+        assert [sk for st, sk, _ in plan if st == "pass"] == \
+            list(gp.schedule)
+        assert plan[-2][:2] == ("bridge", gp.s_max)
+        assert plan[-1][:2] == ("fixup", gp.s_fix)
+        from lightgbm_tpu.learner.histogram_pallas import GROUPED_MIN_WIDTH
+        for _, sk, form in plan:
+            assert form == ("grouped" if 5 * sk >= GROUPED_MIN_WIDTH
+                            else "onehot"), (sk, form)
+
+
+class TestPassRule:
+    """histogram_pallas.use_grouped / grower_mxu.pass_formulation: which
+    formulation a pass uses, a pure function of static shapes."""
+
+    ROWS = 2_625_000
+
+    @pytest.mark.parametrize("nchan", [2, 3, 4, 5])
+    def test_boundary_widths(self, nchan):
+        from lightgbm_tpu.learner import histogram_pallas as hp
+        w = hp.GROUPED_MIN_WIDTH
+        assert not hp.use_grouped(w - 1, self.ROWS)
+        assert hp.use_grouped(w, self.ROWS)
+        # the same boundary through the grower's own question, in slots
+        from lightgbm_tpu.learner.grower_mxu import pass_formulation
+        kw = dict(hist_backend="auto", nchan=nchan, rows=self.ROWS)
+        sk = -(-w // nchan)
+        assert pass_formulation(sk, **kw) == "grouped"
+        assert pass_formulation(sk - 1, **kw) == "onehot"
+
+    def test_tiny_data_stays_onehot(self):
+        # the layout pads one row block per group at least: where that
+        # outweighs the rows, the one-hot kernel keeps the pass
+        from lightgbm_tpu.learner import histogram_pallas as hp
+        width = 5 * 263
+        groups = -(-width // 128)
+        edge = hp.GROUPED_MIN_ROWS_PER_PAD * groups * hp.GROUPED_ROW_BLOCK
+        assert hp.use_grouped(width, edge)
+        assert not hp.use_grouped(width, edge - 1)
+        assert not hp.use_grouped(width, 1000)
+        # and where positions would not fit the rank sweep's f32
+        assert not hp.use_grouped(width, 1 << 24)
+
+    @pytest.mark.parametrize("hb,efb,want", [
+        ("mxu", False, "onehot"), ("pallas", False, "grouped"),
+        ("scatter", False, "scatter"), ("pallas", True, "onehot"),
+        ("auto", True, "onehot")])
+    def test_named_backends_keep_their_whole_run_meaning(self, hb, efb,
+                                                         want):
+        from lightgbm_tpu.learner.grower_mxu import pass_formulation
+        for sk in (2, 24, 263):
+            assert pass_formulation(sk, hist_backend=hb, nchan=5,
+                                    rows=self.ROWS, has_efb=efb) == want
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_the_benchmark_cells_plan(self, quant):
+        # higgs_train / higgs_dp4_train: 255 leaves, overshoot 2.0,
+        # subtraction on, 2,625,000 rows a chip. The six narrow passes
+        # keep the one-hot kernel whatever the crossover; the quantized
+        # posture's crossover comes out of the same formula, higher
+        from lightgbm_tpu.learner import histogram_pallas as hp
+        from lightgbm_tpu.learner.grower_mxu import hist_pass_plan
+        plan = hist_pass_plan(rows=self.ROWS, num_leaves=255,
+                              overshoot=2.0, quantized_grad=quant)
+        assert [(st, sk) for st, sk, _ in plan] == [
+            ("pass", 2), ("pass", 4), ("pass", 8), ("pass", 16),
+            ("pass", 24), ("pass", 40), ("pass", 72), ("pass", 136),
+            ("pass", 263), ("bridge", 263), ("fixup", 511)]
+        nchan = 3 if quant else 5
+        for _, sk, form in plan:
+            want = "grouped" if nchan * sk >= hp.GROUPED_MIN_WIDTH \
+                else "onehot"
+            assert form == want, (sk, form)
+        assert [form for _, _, form in plan[:5]] == ["onehot"] * 5
+        assert [form for _, _, form in plan[-3:]] == ["grouped"] * 3
+
+
+def _grow_args(n=1500, f=4, seed=0):
+    from lightgbm_tpu.learner.split import SplitHyperParams
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, f).astype(np.float32)
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
+    ds = BinnedDataset.from_raw(X, Metadata(n, label=y), max_bin=31)
+    p = np.full(n, 0.5, np.float32)
+    args = (jnp.asarray(ds.bins), jnp.asarray(p - y),
+            jnp.asarray(p * (1 - p)), jnp.ones(n, jnp.float32),
+            jnp.ones(f, jnp.float32), jnp.asarray(ds.num_bins),
+            jnp.asarray(ds.missing_types == 2),
+            jnp.asarray(ds.is_categorical))
+    kw = dict(num_leaves=15, max_depth=0,
+              hp=SplitHyperParams(min_data_in_leaf=5),
+              bmax=int(ds.num_bins.max()), interpret=True)
+    return args, kw
+
+
+class TestPerPassRuleGrowsTheSameTree:
+    """A tree grown with the per-pass rule against the all-one-hot
+    oracle (hist_backend=mxu), at the kernel level (interpret mode)."""
+
+    def test_rule_mixes_formulations_in_one_tree(self, low_crossover):
+        from lightgbm_tpu.learner.grower_mxu import hist_pass_plan
+        plan = hist_pass_plan(rows=1500, num_leaves=15)
+        forms = [form for _, _, form in plan]
+        assert "onehot" in forms and "grouped" in forms
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_matches_all_onehot_oracle(self, low_crossover, quant):
+        from lightgbm_tpu.learner.grower_mxu import grow_tree_mxu
+        args, kw = _grow_args()
+        key = jax.random.PRNGKey(3)
+        t_ref, r_ref = grow_tree_mxu(*args, hist_backend="mxu",
+                                     quantized_grad=quant, rng_key=key,
+                                     **kw)
+        t_got, r_got = grow_tree_mxu(*args, hist_backend="auto",
+                                     quantized_grad=quant, rng_key=key,
+                                     **kw)
+        nn = int(t_ref.num_nodes)
+        assert int(t_got.num_nodes) == nn
+        for fld in ("split_feature", "threshold_bin", "left", "right",
+                    "default_left"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(t_ref, fld))[:nn],
+                np.asarray(getattr(t_got, fld))[:nn], err_msg=fld)
+        np.testing.assert_array_equal(np.asarray(r_ref),
+                                      np.asarray(r_got))
+        lv_ref = np.asarray(t_ref.leaf_value)[:nn]
+        lv_got = np.asarray(t_got.leaf_value)[:nn]
+        if quant:
+            # integer sums: bit-identical across formulations
+            assert lv_ref.tobytes() == lv_got.tobytes()
+        else:
+            # the f32 bound of test_exact_mode_f32_error_bound
+            np.testing.assert_allclose(lv_got, lv_ref, rtol=1e-4,
+                                       atol=1e-5)
 
 
 # ----------------------------------------------------------------------
@@ -404,8 +534,11 @@ class TestModelByteParity:
 
     @pytest.mark.parametrize("objective,num_class", [
         ("regression", 1), ("binary", 1), ("multiclass", 3)])
-    def test_byte_identical_across_backends(self, objective, num_class):
+    def test_byte_identical_across_backends(self, objective, num_class,
+                                            low_crossover):
+        # `auto` runs the per-pass rule (its constants lowered so that
+        # it mixes formulations at this size)
         ref = self._train(objective, "mxu", num_class)
-        for hb in ("pallas", "scatter"):
+        for hb in ("pallas", "scatter", "auto"):
             got = self._train(objective, hb, num_class)
             assert got == ref, f"{objective}: {hb} differs from mxu"

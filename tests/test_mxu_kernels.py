@@ -398,3 +398,107 @@ class TestScanKernel:
                                    np.asarray(t1.leaf_value)[:nn],
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_array_equal(np.asarray(r0), np.asarray(r1))
+
+
+class TestPerPassRule:
+    """hist_backend=auto (a formulation per pass, from static shapes)
+    through the three drivers of the shared growth core. The rule's
+    constants are lowered (conftest.low_crossover) so that one small
+    tree mixes one-hot and slot-grouped passes."""
+
+    @staticmethod
+    def _bytes_equal(out_a, out_b):
+        for fld, x, y in zip(out_a[0]._fields, out_a[0], out_b[0]):
+            assert np.asarray(x).tobytes() == np.asarray(y).tobytes(), fld
+        assert np.asarray(out_a[1]).tobytes() == \
+            np.asarray(out_b[1]).tobytes()
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_pipelined_stays_byte_equal_to_monolith(self, low_crossover,
+                                                    quant):
+        # same traced core, same choice per pass: byte-equal whatever
+        # the formulation, exact or quantized
+        from lightgbm_tpu.learner.grower_pipeline import grow_tree_pipelined
+        ds, g, h = _data(n=1500, f=5, seed=5)
+        kw = dict(num_leaves=15, max_depth=0,
+                  hp=SplitHyperParams(min_data_in_leaf=20),
+                  bmax=int(ds.num_bins.max()), interpret=True,
+                  hist_backend="auto", quantized_grad=quant,
+                  rng_key=jax.random.PRNGKey(1))
+        args = _mxu_args(ds, g, h)
+        self._bytes_equal(grow_tree_pipelined(*args, lookahead=2, **kw),
+                          grow_tree_mxu(*args, **kw))
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_sharded_rule_matches_sharded_onehot(self, low_crossover,
+                                                 quant):
+        # the data-parallel grower (CPU mesh, histogram psum): under the
+        # rule each shard partitions its own rows and the psum is
+        # unchanged. Quantized sums are integers: byte-equal to the
+        # all-one-hot sharded grower; exact mode: the same structure,
+        # leaf values within the f32 bound
+        from lightgbm_tpu.parallel.comm import CommSpec
+        from lightgbm_tpu.parallel.learner import make_sharded_grower
+        from lightgbm_tpu.parallel.mesh import make_mesh
+        ds, g, h = _data(n=2000, f=5, seed=6)
+        args = _mxu_args(ds, g, h)
+        mesh = make_mesh(4)
+        comm = CommSpec(axis="data", mode="data", num_devices=4)
+        outs = {}
+        for hb in ("mxu", "auto"):
+            grower = make_sharded_grower(
+                mesh, comm, num_leaves=15, max_depth=-1,
+                hp=SplitHyperParams(min_data_in_leaf=20), leafwise=False,
+                bmax=int(ds.num_bins.max()), use_mxu=True, interpret=True,
+                with_rng=quant,
+                mxu_kwargs=dict(overshoot=2.0, hist_backend=hb,
+                                quantized_grad=quant))
+            with mesh:
+                outs[hb] = grower(*args, *(
+                    (jax.random.PRNGKey(2),) if quant else ()))
+        if quant:
+            self._bytes_equal(outs["auto"], outs["mxu"])
+            return
+        t_ref, r_ref = outs["mxu"]
+        t_got, r_got = outs["auto"]
+        nn = int(t_ref.num_nodes)
+        assert int(t_got.num_nodes) == nn
+        for fld in ("split_feature", "threshold_bin", "left", "right"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(t_ref, fld))[:nn],
+                np.asarray(getattr(t_got, fld))[:nn], err_msg=fld)
+        np.testing.assert_allclose(np.asarray(t_got.leaf_value)[:nn],
+                                   np.asarray(t_ref.leaf_value)[:nn],
+                                   rtol=1e-4, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(r_ref), np.asarray(r_got))
+
+    @pytest.mark.parametrize("posture", ["const_hessian", "packed4",
+                                         "no_subtraction"])
+    def test_rule_in_other_postures(self, low_crossover, posture):
+        # three channels exact (const-hessian regression), 4-bit packed
+        # bins, and no sibling subtraction (every child built from rows,
+        # so the live rows are ALL rows): structure equal to all-one-hot
+        ds, g, h = _data(n=1500, f=6, seed=7)
+        args = list(_mxu_args(ds, g, h))
+        extra = {}
+        if posture == "const_hessian":
+            args[2] = jnp.ones_like(h)
+            extra["const_hessian"] = 1.0
+        elif posture == "packed4":
+            from lightgbm_tpu.learner.histogram_mxu import pack_bins_4bit
+            rng = np.random.RandomState(8)
+            X = rng.randn(1500, 6).astype(np.float32)
+            y = (X[:, 0] > 0).astype(np.float32)
+            ds = BinnedDataset.from_raw(X, Metadata(1500, label=y),
+                                        max_bin=14)
+            args = list(_mxu_args(ds, g, h))
+            args[0] = jnp.asarray(pack_bins_4bit(np.asarray(ds.bins)))
+            extra["packed4"] = True
+        else:
+            extra["hist_subtraction"] = False
+        kw = dict(num_leaves=15, max_depth=0,
+                  hp=SplitHyperParams(min_data_in_leaf=20),
+                  bmax=int(ds.num_bins.max()), interpret=True, **extra)
+        t_ref, r_ref = grow_tree_mxu(*args, hist_backend="mxu", **kw)
+        t_got, r_got = grow_tree_mxu(*args, hist_backend="auto", **kw)
+        _assert_same_tree(t_ref, r_ref, t_got, r_got)

@@ -1,21 +1,21 @@
-"""Scan-vs-argsort partition parity (`make kernels` / `make perf`).
+"""Rank-vs-argsort partition parity (`make kernels` / `make perf`).
 
-The round-6 partition contract (docs/Performance.md): partition_rows'
-"scan" implementation — stable rank via blocked prefix sums over the
-per-slot counts the router already emits — produces the IDENTICAL
-permutation the retained stable argsort oracle produces, hence
-bit-identical (block_slot, src) layouts and byte-equal model.txt
-through every downstream consumer. The adversarial shapes here are the
-ones that break naive rank constructions: empty slots (zero-count
-prefix entries), all rows in one slot (single giant run), a single
-row, N not a multiple of row_block (padded tail rows must rank AFTER
-every real row), and duplicate-heavy slot vectors (long equal runs
-where only a STABLE rank preserves source order).
+The partition contract (docs/Performance.md): partition_rows' "rank"
+implementation (one sweep of triangular matmuls that carries per-group
+running counts, then one collision-free scatter) produces the IDENTICAL
+layout the retained stable argsort oracle produces, hence byte-equal
+model.txt through every downstream consumer. The adversarial shapes
+here are the ones that break naive rank constructions: empty slots
+(zero-count groups still own a block), all rows in one slot (single
+giant run), a single row, N not a multiple of row_block or of the rank
+sweep's tile (padded tail rows must not be counted), all rows parked,
+and duplicate-heavy slot vectors (long equal runs where only a STABLE
+rank preserves source order).
 
-The perf-marked subset asserts the structural claims behind the win —
-counts reuse (routing + counting + partitioning is one sweep) and the
-absence of any sort primitive in the scan path's jaxpr — with no
-wall-clock thresholds (tier-1 stays timing-independent).
+The perf-marked subset asserts the structural claims behind the win
+(counts reuse, no sort primitive in the rank path's jaxpr, parked rows
+out of the layout) with no wall-clock thresholds (tier-1 stays
+timing-independent).
 """
 
 import numpy as np
@@ -27,29 +27,44 @@ import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.analysis.tracecheck import has_sort_primitive
-from lightgbm_tpu.learner.histogram_pallas import (_stable_order_scan,
+from lightgbm_tpu.learner.histogram_pallas import (_stable_positions,
                                                    partition_rows)
 
 
-def _parity(row_slot, *, num_slots, row_block, counts=None):
-    """Assert scan and argsort return byte-identical layouts. (auto is
-    asserted to BE scan once, in test_auto_resolves_to_scan — running
-    it per-case would just re-dispatch the scan path a third time.)"""
+def _parity(row_slot, *, num_slots, row_block, group=1, counts=None):
+    """Assert rank and argsort return byte-identical layouts (auto is
+    asserted to BE rank once, in test_auto_resolves_to_rank), and the
+    layout's own invariants: every live row exactly once, in a block of
+    its group, in row order; parked rows nowhere."""
+    row_slot = np.asarray(row_slot)
     outs = {}
-    for impl in ("argsort", "scan"):
-        bs, src = partition_rows(jnp.asarray(row_slot, jnp.int32),
-                                 num_slots=num_slots, row_block=row_block,
-                                 counts=counts, impl=impl)
-        outs[impl] = (np.asarray(bs), np.asarray(src))
-    for a, b in zip(outs["argsort"], outs["scan"]):
+    for impl in ("argsort", "rank"):
+        out = partition_rows(jnp.asarray(row_slot, jnp.int32),
+                             num_slots=num_slots, row_block=row_block,
+                             group=group, counts=counts, impl=impl,
+                             interpret=True)
+        outs[impl] = tuple(np.asarray(o) for o in out)
+    for a, b in zip(outs["argsort"], outs["rank"]):
         assert a.tobytes() == b.tobytes()
+    bg, used, src = outs["argsort"]
+    n = row_slot.shape[0]
+    live = (row_slot >= 0) & (row_slot < num_slots)
+    real = src < n
+    assert sorted(src[real].tolist()) == np.flatnonzero(live).tolist()
+    pos_grp = np.repeat(bg, row_block)
+    np.testing.assert_array_equal(pos_grp[real],
+                                  row_slot[src[real]] // group)
+    assert not real[int(used) * row_block:].any()
+    for g in range(-(-num_slots // group)):
+        rows_g = src[real & (pos_grp == g)]
+        assert (np.diff(rows_g) > 0).all()      # stable: row order kept
+        assert (bg[:int(used)] == g).any()      # even an empty group
     return outs["argsort"]
 
 
 class TestAdversarialParity:
     def test_empty_slots(self):
-        # slots 1, 3, 5 get zero rows: their prefix-sum bases collapse
-        # onto the next live slot's base
+        # slots 1, 3, 5 get zero rows: their groups still own a block
         rng = np.random.RandomState(0)
         slot = rng.choice([0, 2, 4, 6], size=777)
         _parity(slot, num_slots=8, row_block=64)
@@ -62,7 +77,7 @@ class TestAdversarialParity:
 
     def test_n_not_multiple_of_row_block(self):
         rng = np.random.RandomState(1)
-        # also not a multiple of the scan's internal block size
+        # also not a multiple of the rank sweep's 2048-row step
         _parity(rng.randint(0, 6, size=5001), num_slots=6, row_block=128)
 
     def test_duplicate_heavy(self):
@@ -72,34 +87,52 @@ class TestAdversarialParity:
         slot = np.repeat(rng.randint(0, 4, size=40), 100)
         _parity(slot, num_slots=4, row_block=32)
 
-    def test_parked_rows_go_to_trash_slot(self):
+    def test_parked_rows_are_not_in_the_layout(self):
         rng = np.random.RandomState(3)
         slot = rng.randint(-1, 5, size=900)   # -1 = parked
-        bs, src = _parity(slot, num_slots=5, row_block=64)
-        # parked rows appear only in trash-slot blocks
-        trash_positions = np.repeat(bs == 5, 64)
-        real = src[~trash_positions]
-        real = real[real < 900]
-        assert np.all(np.asarray(slot)[real] >= 0)
+        bg, used, src = _parity(slot, num_slots=5, row_block=64)
+        assert (src < 900).sum() == (slot >= 0).sum()
+        # ... so the blocks in use cover the live rows, not all rows
+        assert int(used) <= -(-int((slot >= 0).sum()) // 64) + 5
 
     def test_unknown_impl_raises(self):
         with pytest.raises(ValueError, match="unknown partition impl"):
             partition_rows(jnp.zeros(8, jnp.int32), num_slots=2,
                            row_block=8, impl="radix")
 
-    def test_auto_resolves_to_scan(self):
+    def test_auto_resolves_to_rank(self):
         rng = np.random.RandomState(9)
         slot = jnp.asarray(rng.randint(0, 5, 300), jnp.int32)
-        a = partition_rows(slot, num_slots=5, row_block=32, impl="auto")
-        s = partition_rows(slot, num_slots=5, row_block=32, impl="scan")
+        a = partition_rows(slot, num_slots=5, row_block=32, impl="auto",
+                           interpret=True)
+        s = partition_rows(slot, num_slots=5, row_block=32, impl="rank",
+                           interpret=True)
         for x, y in zip(a, s):
             assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
 
+    @pytest.mark.parametrize("case", ["all_parked", "grouped_slots",
+                                      "one_group_holds_all"])
+    def test_groups_and_parked(self, case):
+        # slot GROUPS (what the grouped kernel partitions by): several
+        # slots share a block; all rows parked leaves one empty block
+        # per group
+        rng = np.random.RandomState(11)
+        if case == "all_parked":
+            bg, used, src = _parity(np.full(700, -1), num_slots=60,
+                                    row_block=64, group=25)
+            assert int(used) == 3 and (src == 700).all()
+        elif case == "grouped_slots":
+            _parity(rng.randint(-1, 60, size=3000), num_slots=60,
+                    row_block=64, group=25)
+        else:
+            _parity(rng.randint(25, 50, size=1000), num_slots=60,
+                    row_block=64, group=25)
+
 
 @pytest.mark.perf
-class TestScanStructure:
+class TestRankStructure:
     """Microbench-shaped assertions: the structural facts behind the
-    round-6 numbers, with no wall-clock thresholds."""
+    chip numbers, with no wall-clock thresholds."""
 
     def test_counts_reuse_is_bit_identical(self):
         # the route_rows_mxu(emit_counts=True) counts replace the
@@ -107,13 +140,13 @@ class TestScanStructure:
         rng = np.random.RandomState(4)
         slot = rng.randint(-1, 7, size=3000)
         live = np.bincount(slot[slot >= 0], minlength=7).astype(np.int32)
-        a = _parity(slot, num_slots=7, row_block=128)
-        b = _parity(slot, num_slots=7, row_block=128,
+        a = _parity(slot, num_slots=7, row_block=128, group=3)
+        b = _parity(slot, num_slots=7, row_block=128, group=3,
                     counts=jnp.asarray(live))
         for x, y in zip(a, b):
             assert x.tobytes() == y.tobytes()
 
-    def test_scan_path_has_no_sort_primitive(self):
+    def test_rank_path_has_no_sort_primitive(self):
         # shared predicate with TRACE001 (analysis.tracecheck): the
         # same walk the lint-time contract checker runs over the
         # manifest entry; the argsort oracle doubles as its positive
@@ -121,31 +154,32 @@ class TestScanStructure:
         slot = jnp.asarray(np.random.RandomState(5).randint(0, 6, 2048),
                            jnp.int32)
 
-        def scan_part(s):
+        def rank_part(s):
             return partition_rows(s, num_slots=6, row_block=128,
-                                  impl="scan")
+                                  impl="rank")
 
         def argsort_part(s):
             return partition_rows(s, num_slots=6, row_block=128,
                                   impl="argsort")
 
-        assert not has_sort_primitive(jax.make_jaxpr(scan_part)(slot))
+        assert not has_sort_primitive(jax.make_jaxpr(rank_part)(slot))
         assert has_sort_primitive(jax.make_jaxpr(argsort_part)(slot))
 
-    def test_stable_rank_matches_argsort_rank(self):
-        # _stable_order_scan directly vs the stable sort, with tail
-        # padding crossing the internal scan block boundary
+    def test_stable_positions_match_argsort_rank(self):
+        # _stable_positions directly vs the stable sort, with tail
+        # padding crossing the sweep's tile and step boundaries
         rng = np.random.RandomState(6)
-        for n in (1, 17, 4096, 4097, 9000):
-            slot = jnp.asarray(rng.randint(0, 5, n), jnp.int32)
-            counts = jax.ops.segment_sum(jnp.ones(n, jnp.int32), slot,
-                                         num_segments=6)
-            start = jnp.concatenate(
-                [jnp.zeros(1, jnp.int32),
-                 jnp.cumsum(counts)[:-1].astype(jnp.int32)])
-            got = np.asarray(_stable_order_scan(slot, start, 5))
-            want = np.asarray(jnp.argsort(slot))
-            assert got.tobytes() == want.tobytes(), n
+        for n in (1, 17, 2048, 2049, 9000):
+            grp = rng.randint(-1, 5, n)
+            base = np.array([0, 100000, 200000, 300000, 400000])
+            got = np.asarray(_stable_positions(
+                jnp.asarray(grp, jnp.int32), jnp.asarray(base, jnp.int32),
+                num_groups=5, dump=1 << 20, interpret=True))
+            want = np.full(n, 1 << 20)
+            for g in range(5):
+                idx = np.flatnonzero(grp == g)
+                want[idx] = base[g] + np.arange(idx.size)
+            np.testing.assert_array_equal(got, want, err_msg=str(n))
 
 
 @pytest.mark.slow
@@ -175,5 +209,5 @@ class TestFusedModelParity:
             ln for ln in bst.model_to_string().splitlines()
             if not ln.startswith("[partition_impl:"))
 
-    def test_byte_identical_scan_vs_argsort(self):
-        assert self._train("scan") == self._train("argsort")
+    def test_byte_identical_rank_vs_argsort(self):
+        assert self._train("rank") == self._train("argsort")
