@@ -9,9 +9,21 @@ and three (quantized) channels it times, on the chip:
   route    route_rows_mxu(emit_counts=True), the grouped pass's first step
   part     partition_rows from the routed slots: the rank sweep plus the
            scatter that inverts it (part_argsort: the retained oracle)
-  gather   the row table's build and the gather of the blocks in use
+  scatter  that scatter alone, from positions ranked outside the clock
+  table    the row table's build (bins and channels as one bf16 row):
+           what a tree now does once, and a pass used to do
+  gather   the gather of the blocks in use from a table built outside
+           the clock, with the pass's slot column written on the way
   kernel   the grouped kernel over the gathered table
   grouped  route + build_histograms_scatter as sweep() runs them
+
+onehot and grouped call the wrappers' self-preparing form on arrays
+that are not loop-invariant (every repetition pads the bins, stacks the
+channels, builds the table: a pass of a tree before ISSUE 29, whose
+passes sit in cond branches that share nothing); onehot_prepared and
+grouped_prepared take
+the operands of prepare_hist_operands, built ONCE outside the chained
+repetitions, as a pass of a tree does now.
 
 Half the rows are live (the smaller sibling; the other child is parked),
 as in a wide pass under sibling subtraction.
@@ -29,9 +41,9 @@ what the chip's compiler does to a kernel.
 
 Usage: python helpers/microbench_pass.py [--rows N] [--sk 24,40,...]
        [--nchan 5,3] [--stages onehot,grouped,...] [--reps K] [--check]
-       [--interpret]
-Writes chiprun_out/microbench_pass.json (after every pass, so a call cut
-short keeps what it measured) beside the table it prints.
+       [--interpret] [--out NAME.json]
+Writes chiprun_out/microbench_pass.json (or --out; after every pass, so
+a call cut short keeps what it measured) beside the table it prints.
 """
 
 import argparse
@@ -50,8 +62,9 @@ import jax.numpy as jnp  # noqa: E402
 
 from lightgbm_tpu.learner import histogram_pallas as hp  # noqa: E402
 from lightgbm_tpu.learner.histogram_mxu import (  # noqa: E402
-    _hist_channels, _round_up, fits_v2, fused_route_hist_mxu,
-    hist_num_channels, pack_route_tables, route_rows_mxu)
+    _hist_channels, _round_up, _row_table, fits_v2, fused_route_hist_mxu,
+    hist_num_channels, pack_route_tables, prepare_hist_operands,
+    route_rows_mxu)
 
 F = 28
 BMAX = 256
@@ -83,6 +96,14 @@ def opaque(x):
     """An int32 zero that depends on every element of x's first row."""
     return (jnp.sum(jnp.ravel(x)[:8].astype(jnp.float32)) < -1e30) \
         .astype(jnp.int32)
+
+
+def opaque_all(x):
+    """An int32 zero that depends on EVERY element of x: a stage whose
+    result nothing else reads would otherwise be computed for the eight
+    elements `opaque` looks at (XLA narrows an elementwise producer to
+    the slice that is used)."""
+    return (jnp.sum(x, dtype=jnp.float32) < -1e30).astype(jnp.int32)
 
 
 def make_state(sk, n, rng):
@@ -144,56 +165,87 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
 
     # the stages' inputs, computed once outside the clock
     _, rs, cts = jax.jit(route)(jnp.int32(0))
-    block_group, used, src = jax.jit(lambda: hp.partition_rows(
+    block_group, used, src, src_slot = jax.jit(lambda: hp.partition_rows(
         rs, num_slots=sk, row_block=nb, group=sg, counts=cts,
         interpret=interpret))()
     data, _ = _hist_channels(g, h, cnt, True, quant)
     live_share = float(jnp.mean((rs >= 0).astype(jnp.float32)))
+    ops = jax.jit(lambda: prepare_hist_operands(
+        bins, g, h, cnt, lanes=True, table=True, **kw))()
+    chunk = min(hp._GATHER_CHUNK_BLOCKS, block_group.shape[0]) * nb
 
     def table_of(c):
-        return hp._row_table(bins, data, nchan,
-                             jnp.where(rs >= 0, rs % sg, 255) + c)
+        return _row_table(bins, data + c.astype(data.dtype), nchan)
 
     def gather(c):
-        return hp._gather_used(table_of(c), src, used * nb,
-                               min(hp._GATHER_CHUNK_BLOCKS,
-                                   block_group.shape[0]) * nb)
+        return hp._gather_used(ops.table, src, src_slot + c, used * nb,
+                               chunk)
 
     tab_g = jax.jit(gather)(jnp.int32(0))
+
+    # the scatter alone: the positions of the live rows, ranked once
+    live = rs >= 0
+    grp = jnp.where(live, rs // sg, -1).astype(jnp.int32)
+    gcounts = jnp.pad(cts[:sk], (0, ng * sg - sk)).reshape(ng, sg).sum(1)
+    blk_start = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(
+        jnp.maximum(1, -(-gcounts // nb))).astype(jnp.int32)])
+    dst = jax.jit(lambda: hp._stable_positions(
+        grp, blk_start[:ng] * nb, num_groups=ng, dump=src.shape[0],
+        interpret=interpret))()
+
+    def scatter(c):
+        return opaque(jnp.full(src.shape[0], n, jnp.int32).at[dst].set(
+            jnp.arange(n, dtype=jnp.int32) + c, mode="drop",
+            unique_indices=True))
 
     def part(impl):
         return lambda c: opaque(hp.partition_rows(
             rs + c, num_slots=sk, row_block=nb, group=sg, counts=cts,
             impl=impl, interpret=interpret)[2])
 
-    def grouped(c):
-        rn, rs_, cts_ = route(c)
-        return opaque(hp.build_histograms_scatter(
-            bins, g, h, cnt, rs_, num_slots=sk, bmax=BMAX, slot_counts=cts_,
-            interpret=interpret, **kw)) + opaque(rn)
+    def plain(c):
+        """The plain arrays as a pass of a tree meets them when nothing
+        is prepared: not loop-invariant (c is a zero XLA cannot see), or
+        the chained repetitions would share one preparation, which is
+        what the passes of a tree, each in its own cond branch, cannot
+        do."""
+        return (bins + c.astype(bins.dtype), g + c.astype(g.dtype), h, cnt)
 
-    def onehot(c):
+    def grouped(c, operands=None):
+        raw = plain(c) if operands is None else (None,) * 4
+        rn, rs_, cts_ = route_rows_mxu(
+            raw[0] if operands is None else operands.bins, row_node + c,
+            tbl, member, feat_tbl, emit_counts=True, num_slots=sk,
+            interpret=interpret)
+        return opaque(hp.build_histograms_scatter(
+            *raw, rs_, num_slots=sk, bmax=BMAX, slot_counts=cts_,
+            operands=operands, interpret=interpret, **kw)) + opaque(rn)
+
+    def onehot(c, operands=None):
+        raw = plain(c) if operands is None else (None,) * 4
         hist, rn = fused_route_hist_mxu(
-            bins, g, h, cnt, row_node + c, tbl, member, feat_tbl,
+            *raw, row_node + c, tbl, member, feat_tbl,
             num_slots=sk, bmax=BMAX, has_cat=False, row_block=rb,
-            interpret=interpret, **kw)
+            operands=operands, interpret=interpret, **kw)
         return opaque(hist) + opaque(rn)
 
     stages = {
         "onehot": onehot,
+        "onehot_prepared": lambda c: onehot(c, ops),
         "route": lambda c: opaque(route(c)[1]),
         "part": part("rank"),
+        "scatter": scatter,
+        "table": lambda c: opaque_all(table_of(c)),
         "gather": lambda c: opaque(gather(c)),
         "kernel": lambda c: opaque(hp._grouped_call(
             block_group, used, tab_g + c.astype(tab_g.dtype), nb=nb, f=F,
             b=BMAX, sg=sg, ng=ng, nchan=nchan, fcols=F,
             interpret=interpret)),
         "grouped": grouped,
+        "grouped_prepared": lambda c: grouped(c, ops),
     }
     if "argsort" in extras:
         stages["part_argsort"] = part("argsort")
-    if "table" in extras:
-        stages["table"] = lambda c: opaque(table_of(c))
     if only:
         stages = {k: v for k, v in stages.items() if k in only}
     row = {"sk": sk, "nchan": nchan, "rows": n, "onehot_row_block": rb,
@@ -211,7 +263,19 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
         scale = np.abs(h_one).max(axis=(0, 1, 2)) + 1e-30
         row["agree_rel"] = [float(x) for x in
                             np.abs(h_grp - h_one).max(axis=(0, 1, 2)) / scale]
-        print("  agree_rel %s" % row["agree_rel"], flush=True)
+        # ... and each by the prepared operands: the same bits
+        p_one, _ = jax.jit(lambda: fused_route_hist_mxu(
+            None, None, None, None, row_node, tbl, member, feat_tbl,
+            num_slots=sk, bmax=BMAX, has_cat=False, row_block=rb,
+            operands=ops, interpret=interpret, **kw))()
+        p_grp = jax.jit(lambda: hp.build_histograms_scatter(
+            None, None, None, None, rs, num_slots=sk, bmax=BMAX,
+            slot_counts=cts, operands=ops, interpret=interpret, **kw))()
+        row["prepared_identical"] = bool(
+            np.asarray(p_one).tobytes() == h_one.tobytes() and
+            np.asarray(p_grp).tobytes() == h_grp.tobytes())
+        print("  agree_rel %s prepared_identical %s" % (
+            row["agree_rel"], row["prepared_identical"]), flush=True)
     for name, fn in stages.items():
         try:
             row[name + "_ms"] = round(timeit_chained(fn, reps) * 1e3, 3)
@@ -231,6 +295,8 @@ def main():
     ap.add_argument("--stages", default="",
                     help="time these stages only (default: all)")
     ap.add_argument("--reps", type=int, default=6)
+    ap.add_argument("--out", default="microbench_pass.json",
+                    help="file name under chiprun_out/")
     ap.add_argument("--check", action="store_true",
                     help="first compare the two formulations' histograms")
     ap.add_argument("--interpret", action="store_true",
@@ -248,18 +314,19 @@ def main():
         for sk in [int(x) for x in args.sk.split(",")]:
             print("sk=%d nchan=%d" % (sk, nchan), flush=True)
             # the partition's A/B does not depend on the channels
-            extras = {"argsort", "table"} if nchan == 5 else set()
+            extras = {"argsort"} if nchan == 5 else set()
             rows.append(bench_pass(sk, nchan, args.rows, args.reps,
                                    args.interpret, rng, extras,
                                    check=args.check, only=only))
-            with open(os.path.join(out, "microbench_pass.json"), "w") as fh:
+            with open(os.path.join(out, args.out), "w") as fh:
                 json.dump({"platform": dev.platform,
                            "device_kind": dev.device_kind, "rows": rows},
                           fh, indent=1)
-    cols = ["sk", "nchan", "onehot_ms", "grouped_ms", "route_ms",
-            "part_ms", "gather_ms", "kernel_ms", "part_argsort_ms",
-            "table_ms", "live_share", "blocks_used",
-            "agree_rel"]
+    cols = ["sk", "nchan", "onehot_ms", "onehot_prepared_ms",
+            "grouped_ms", "grouped_prepared_ms", "route_ms", "part_ms",
+            "scatter_ms", "table_ms", "gather_ms", "kernel_ms",
+            "part_argsort_ms", "live_share", "blocks_used", "agree_rel",
+            "prepared_identical"]
     print("\t".join(cols))
     for r in rows:
         print("\t".join(str(r.get(c, "")) for c in cols))

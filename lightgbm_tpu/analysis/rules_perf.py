@@ -52,6 +52,7 @@ HOT_PATH_MANIFEST = {
     ("histogram_pallas.py", "build_histograms_scatter"),
     ("histogram_pallas.py", "build_histograms_pallas"),
     ("histogram_mxu.py", "route_rows_mxu"),
+    ("histogram_mxu.py", "prepare_hist_operands"),
     ("histogram_mxu.py", "build_histograms_mxu"),
     ("histogram_mxu.py", "build_histograms_mxu_v2"),
     ("histogram_mxu.py", "fused_route_hist_mxu"),

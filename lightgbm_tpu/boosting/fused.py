@@ -94,7 +94,9 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     persistent-cache key would change with every dataset of the same
     shape. `run.program` is the jitted function and `run.operands` the
     arrays it is called with after (score, it0, sample_keys) — what an
-    ahead-of-time compile needs (testing/tpu_aot.py).
+    ahead-of-time compile needs (testing/tpu_aot.py);
+    `run.arguments(score, it0, k=, sample_keys=)` is the whole argument
+    tuple of a call, for `run.program.trace`.
 
     `objective.get_gradients` must be pure jnp (all built-in objectives
     are); `grower_kwargs` are the static grow_tree_mxu settings
@@ -186,11 +188,16 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
 
     operands = (bins, tuple(row_arrays))
 
-    def run(score, it0, *, k: int, sample_keys=None):
+    def arguments(score, it0, *, k: int, sample_keys=None):
         if sample_keys is None:
             sample_keys = jnp.zeros((k, 2), jnp.uint32)
-        return program(score, it0, sample_keys, *operands)
+        return (score, it0, sample_keys) + operands
+
+    def run(score, it0, *, k: int, sample_keys=None):
+        return program(*arguments(score, it0, k=k,
+                                  sample_keys=sample_keys))
 
     run.program = program
     run.operands = operands
+    run.arguments = arguments
     return run

@@ -663,6 +663,7 @@ class GBDT:
             hb = "mxu"
         self._hist_backend = hb
         self._hist_plan = self._hist_pass_plan(hb)
+        self._operand_builds = None     # read from the next trace
         _obs.record_hist_plan(hb, self._hist_plan)
         return hb
 
@@ -679,6 +680,34 @@ class GBDT:
                 "grouped_passes_per_tree": sum(
                     form == "grouped" and stage != "fixup"
                     for stage, _, form in self._hist_plan)}
+
+    def _trace_operand_builds(self, program, *args, **kwargs) -> None:
+        """Before a jitted growth program's first run: trace it and
+        count where it builds its row-sized kernel operands
+        (grower_mxu.operand_builds: once per tree, or in every pass).
+        The run that follows finds this trace in jit's cache, so the
+        program is traced once all the same. The counts go to the
+        registry, beside the plan (_operand_build_attrs puts them on
+        the boosting.build_program span)."""
+        if getattr(self, "_operand_builds", None) is None and \
+                self._hist_plan_attrs():
+            from ..learner.grower_mxu import operand_builds
+            built = operand_builds(program.trace(*args, **kwargs).jaxpr)
+            self._operand_builds = built
+            _obs.record_operand_builds(built["per_tree"],
+                                       built["per_pass"])
+
+    def _operand_build_attrs(self) -> dict:
+        """The counts of _trace_operand_builds as span attributes; empty
+        until a growth program has been traced (and for the
+        level-pipelined driver, whose stages are programs of their
+        own)."""
+        built = getattr(self, "_operand_builds", None)
+        if built is None:
+            return {}
+        return {"operand_builds_per_tree": ",".join(
+                    "%s:%d" % kv for kv in built["per_tree"].items()),
+                "operand_builds_per_pass": built["per_pass"]}
 
     def _hist_pass_plan(self, hist_backend: str) -> list:
         """[(stage, kernel slots, formulation)] of this booster's growth
@@ -782,13 +811,15 @@ class GBDT:
         with contextlib.nullcontext() if warm else span(
                 "boosting.build_program", iter=self.iter_, k=1,
                 program="grow_tree" if self._grower is None
-                else "sharded_grow", **self._hist_plan_attrs()):
+                else "sharded_grow", **self._hist_plan_attrs()) as build:
             out = retry_call(
                 _attempt, attempts=cfg.retry_max_attempts,
                 backoff_ms=cfg.retry_backoff_ms,
                 backoff_max_ms=cfg.retry_backoff_max_ms,
                 retry_on=self._transient_faults(warm),
                 site="histogram_build")
+            if build is not None:
+                build.attrs.update(self._operand_build_attrs())
         self._grow_warm = True
         return out
 
@@ -816,11 +847,14 @@ class GBDT:
                     **self._mxu_grow_kwargs())
             else:
                 from ..learner.grower_mxu import grow_tree_mxu
-                out = grow_tree_mxu(
-                    self.bins, g, h, cnt, feature_mask, self.num_bins_d,
-                    self.missing_is_nan_d, self.is_cat_d,
-                    rng_key=rng_key, cegb_state=self._cegb_state,
-                    **self._mxu_grow_kwargs())
+                args = (self.bins, g, h, cnt, feature_mask,
+                        self.num_bins_d, self.missing_is_nan_d,
+                        self.is_cat_d)
+                kwargs = dict(rng_key=rng_key,
+                              cegb_state=self._cegb_state,
+                              **self._mxu_grow_kwargs())
+                self._trace_operand_builds(grow_tree_mxu, *args, **kwargs)
+                out = grow_tree_mxu(*args, **kwargs)
             if self._cegb_cfg is not None:
                 tree, row_node, (fu, rfu) = out
                 self._cegb_state = (self._cegb_state[0],
@@ -865,10 +899,11 @@ class GBDT:
             extra = extra + (self._cegb_state,)
         if getattr(self, "_bins_ft", None) is not None:
             extra = extra + (self._bins_ft,)
+        args = (self.bins, g, h, cnt, feature_mask, self.num_bins_d,
+                self.missing_is_nan_d, self.is_cat_d) + extra
         with self.mesh:
-            out = self._grower(
-                self.bins, g, h, cnt, feature_mask, self.num_bins_d,
-                self.missing_is_nan_d, self.is_cat_d, *extra)
+            self._trace_operand_builds(self._grower, *args)
+            out = self._grower(*args)
         if self._cegb_cfg is not None:
             tree, row_node, (fu, rfu) = out
             self._cegb_state = (self._cegb_state[0], self._cegb_state[1],
@@ -1592,7 +1627,7 @@ class GBDT:
                 with contextlib.nullcontext() if self._fused_warm_at(k) \
                         else span("boosting.build_program",
                                   program="fused_train", iter=iter0, k=k,
-                                  **self._hist_plan_attrs()):
+                                  **self._hist_plan_attrs()) as build:
                     if getattr(self, "_fused_run", None) is None:
                         self._fused_run = self._build_fused()
                         self._fused_warm = set()
@@ -1602,10 +1637,18 @@ class GBDT:
                         # GOSS path would draw, pre-drawn as scan inputs
                         keys = jnp.stack([self._next_key()
                                           for _ in range(k)])
-                    return self._fused_run(
-                        self.train_score,
-                        jnp.asarray(self.iter_, jnp.int32),
-                        k=k, sample_keys=keys)
+                    run, it0 = self._fused_run, \
+                        jnp.asarray(self.iter_, jnp.int32)
+                    if build is not None and hasattr(run, "arguments"):
+                        # (the sharded scan grows with the portable
+                        # grower and has no such operands)
+                        self._trace_operand_builds(
+                            run.program, *run.arguments(
+                                self.train_score, it0, k=k,
+                                sample_keys=keys))
+                        build.attrs.update(self._operand_build_attrs())
+                    return run(self.train_score, it0, k=k,
+                               sample_keys=keys)
             except Exception:
                 self._fused_run = None  # closure may hold dead executables
                 raise

@@ -42,14 +42,15 @@ from .histogram import build_histograms
 from .histogram_mxu import (_round_up, build_histograms_mxu_auto, fits_v2,
                             fused_route_hist_mxu, hist_num_channels,
                             node_sums_mxu, node_values_mxu,
-                            pack_route_tables, quantize_gradients,
-                            route_rows_mxu, unpack_bins_4bit)
+                            pack_route_tables, prepare_hist_operands,
+                            quantize_gradients, route_rows_mxu,
+                            unpack_bins_4bit)
 from .histogram_pallas import build_histograms_scatter, use_grouped
 from .split import (BestSplits, SplitHyperParams, find_best_splits,
                     leaf_gain, leaf_output, _split_gain)
 from .split_kernel import find_best_splits_kernel, kernel_supports
 
-__all__ = ["grow_tree_mxu", "hist_pass_plan"]
+__all__ = ["grow_tree_mxu", "hist_pass_plan", "operand_builds"]
 
 
 def _prune_to_best_first(tree: TreeArrays, row_node: jax.Array, *,
@@ -293,6 +294,127 @@ def hist_pass_plan(*, rows: int, num_leaves: int, overshoot: float = 0.0,
         has_efb=has_efb)) for stage, sk in stages]
 
 
+# ---------------------------------------------------------------------------
+# per-tree operands, counted in the traced program
+# ---------------------------------------------------------------------------
+
+#: the equations that build a kernel operand from what is fixed for a
+#: tree (bins, gradients, count weights): what belongs outside every
+#: pass. "rank_scatter" is the one row-sized equation a grouped pass
+#: keeps by nature, listed so that its count can be held to one a pass.
+OPERAND_EQUATIONS = ("bins_row_pad", "bins_lane_pad", "channels_stack",
+                     "channels_split", "channels_pad", "table_bins",
+                     "table_concat")
+
+
+def _operand_equation(eqn, rows: int) -> Optional[str]:
+    """Which of OPERAND_EQUATIONS (or "rank_scatter") `eqn` is, by its
+    primitive and its output's shape and dtype; None for the rest."""
+    out = eqn.outvars[0].aval
+    shape = getattr(out, "shape", ())
+    if not shape or len(shape) > 2 or shape[0] < rows:
+        return None
+    name, dt = eqn.primitive.name, out.dtype
+    if len(shape) == 1:
+        if name == "reduce_precision":
+            return "channels_split"        # _hist_channels' hi/lo
+        if name == "scatter" and dt == jnp.int32:
+            return "rank_scatter"          # partition_rows' inversion
+        return None
+    if name not in ("pad", "concatenate", "convert_element_type"):
+        return None
+    src = eqn.invars[0].aval
+    if name == "pad" and jnp.issubdtype(dt, jnp.integer):
+        return "bins_row_pad" if shape[1] == src.shape[1] \
+            else "bins_lane_pad"
+    if name == "pad" and dt == jnp.float32:
+        return "channels_pad"
+    if name == "concatenate" and dt == jnp.float32:
+        return "channels_stack"            # _hist_channels' [N, 8]
+    if name == "concatenate" and dt == jnp.bfloat16:
+        return "table_concat"              # _row_table, its padding row
+    if name == "convert_element_type" and dt == jnp.bfloat16 and \
+            jnp.issubdtype(src.dtype, jnp.integer):
+        return "table_bins"                # _row_table's bin columns
+    return None
+
+
+def _sub_jaxprs(eqn):
+    for val in eqn.params.values():
+        for sub in (val if isinstance(val, (list, tuple)) else (val,)):
+            while not hasattr(sub, "eqns") and hasattr(sub, "jaxpr"):
+                sub = sub.jaxpr
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _find_jit(jaxpr, name: str):
+    """The body of the first `jit` equation called `name` in `jaxpr` or
+    its sub-jaxprs (a scan's body, a shard_map's), else None."""
+    for eqn in jaxpr.eqns:
+        for sub in _sub_jaxprs(eqn):
+            if eqn.primitive.name in ("jit", "pjit") and \
+                    eqn.params.get("name") == name:
+                return sub
+            found = _find_jit(sub, name)
+            if found is not None:
+                return found
+    return None
+
+
+def operand_builds(jaxpr, rows: Optional[int] = None) -> dict:
+    """Where a traced program builds the row-sized kernel operands:
+    once per tree, or again in every pass.
+
+    `jaxpr` is any traced program that grows trees with grow_tree_mxu
+    (its own jaxpr, the fused scan's, the sharded grower's): the walk
+    goes to grow_tree_mxu's body and counts the equations of
+    OPERAND_EQUATIONS whose output has at least `rows` rows (default:
+    the rows of the body's first argument, the bins), apart for every
+    pass body (each branch of a top-level lax.cond, the fixup
+    while_loop's body: where XLA shares nothing with the other passes)
+    and for what lies outside them. Kernels are not entered.
+
+    Returns {"per_tree": {"bins_pad", "channels", "row_table"}: builds
+    outside every pass (the channel operand counts 2 in the quantized
+    posture: the exact leaf refit stacks its own), "per_pass": the most
+    OPERAND_EQUATIONS any one pass body holds (0 when every operand is
+    prepared per tree), "tree": the raw counts outside, "passes": the
+    raw counts of each pass body that has any}. Static: read from the
+    trace, no device involved."""
+    while not hasattr(jaxpr, "eqns"):
+        jaxpr = jaxpr.jaxpr
+    body = _find_jit(jaxpr, "grow_tree_mxu") or jaxpr
+    if rows is None:
+        rows = body.invars[0].aval.shape[0]
+    tree: dict = {}
+    passes = []
+
+    def walk(jx, into):
+        for eqn in jx.eqns:
+            kind = _operand_equation(eqn, rows)
+            if kind:
+                into[kind] = into.get(kind, 0) + 1
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                continue
+            for sub in _sub_jaxprs(eqn):
+                if into is tree and name in ("cond", "while"):
+                    passes.append({})
+                    walk(sub, passes[-1])
+                else:
+                    walk(sub, into)
+
+    walk(body, tree)
+    return {
+        "per_tree": {"bins_pad": tree.get("bins_row_pad", 0),
+                     "channels": tree.get("channels_stack", 0),
+                     "row_table": tree.get("table_bins", 0)},
+        "per_pass": max([sum(c.get(k, 0) for k in OPERAND_EQUATIONS)
+                         for c in passes] or [0]),
+        "tree": tree, "passes": [c for c in passes if c]}
+
+
 def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     cnt_weight: jax.Array, feature_mask: jax.Array,
                     num_bins: jax.Array, missing_is_nan: jax.Array,
@@ -531,6 +653,23 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     nchan = hist_num_channels(hist_double_prec, quant, ch)
 
+    # what the kernels read of the rows and that no pass changes (the
+    # padded bins, the channel operand, the grouped build's row table)
+    # is built HERE, once per tree: every pass below runs in its own
+    # lax.cond branch or in the fixup while_loop's body, where XLA
+    # shares nothing across passes. Only what the static plan uses is
+    # built; the passes close over it.
+    forms = {form for _, _, form in hist_pass_plan(
+        rows=n, num_leaves=num_leaves, overshoot=overshoot,
+        tail_split_cap=tail_split_cap, hist_subtraction=hist_subtraction,
+        bridge_gate=bridge_gate, hist_backend=hist_backend,
+        hist_double_prec=hist_double_prec, quantized_grad=quant,
+        const_hessian=ch, has_efb=efb is not None)}
+    ops = prepare_hist_operands(
+        bins, h_grad, h_hess, cnt_weight, double_prec=hist_double_prec,
+        quantized=quant, const_hess=ch, lanes="onehot" in forms,
+        channels="onehot" in forms, table="grouped" in forms)
+
     def hist_cfg(s):
         # empirically tuned on v5e: wider feature chunks while the output
         # block fits comfortably in VMEM, narrower for big frontiers
@@ -557,16 +696,17 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # route + per-slot counts in one sweep, then build from the
             # partitioned live rows (grouped) or by the XLA oracle
             rn, rs, cts = route_rows_mxu(
-                bins, row_node, tbl_c, member_c, feat_tbl,
+                ops.bins, row_node, tbl_c, member_c, feat_tbl,
                 num_features=nf_packed, emit_counts=True,
                 num_slots=nslots, interpret=interpret)
             if form == "grouped":
                 h = build_histograms_scatter(
-                    bins, h_grad, h_hess, cnt_weight, rs,
+                    None, None, None, None, rs,
                     num_slots=nslots, bmax=bk, num_features=nf_packed,
                     quantized=quant, double_prec=hist_double_prec,
                     const_hess=ch, slot_counts=cts,
-                    partition_impl=partition_impl, interpret=interpret)
+                    partition_impl=partition_impl, operands=ops,
+                    interpret=interpret)
             else:  # "scatter": the pure-XLA segment-sum oracle
                 ub = unpack_bins_4bit(bins, f) if packed4 else bins
                 h = build_histograms(ub, h_grad, h_hess, rs, cnt_weight,
@@ -605,24 +745,26 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         if fits_v2(nslots, fk, bk, hist_double_prec, quant,
                    route_width=rw, row_block=rb, const_hess=ch):
             h, rn = fused_route_hist_mxu(
-                bins, h_grad, h_hess, cnt_weight, row_node, tbl_c,
+                None, None, None, None, row_node, tbl_c,
                 member_c, feat_tbl, num_slots=nslots, bmax=bk,
                 has_cat=hp.has_categorical, quantized=quant,
                 double_prec=hist_double_prec, num_features=nf_packed,
                 loc_table=None if efb_seg else loc_tbl,
                 efb_range=efb_seg, row_block=rb, const_hess=ch,
-                interpret=interpret)
+                operands=ops, interpret=interpret)
         else:
-            rn, rs = route_rows_mxu(bins, row_node, tbl_c, member_c,
+            rn, rs = route_rows_mxu(ops.bins, row_node, tbl_c, member_c,
                                     feat_tbl, num_features=nf_packed,
                                     loc_table=None if efb_seg
                                     else loc_tbl, efb_range=efb_seg,
                                     interpret=interpret)
+            # (the chunked v1 fallback pads to its own selector layout
+            # from the plain arguments)
             h = build_histograms_mxu_auto(
                 bins, h_grad, h_hess, cnt_weight, rs, num_slots=nslots,
                 bmax=bk, interpret=interpret, quantized=quant,
                 double_prec=hist_double_prec, num_features=nf_packed,
-                const_hess=ch,
+                const_hess=ch, operands=ops,
                 **hist_cfg(nslots))
         if quant:
             h = h * hist_scale  # integer sums -> gradient units
@@ -1118,8 +1260,9 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # flush the routing of the last pass's splits (sweeps route at
         # the START of a pass, so the final commits have not moved rows
         # yet)
-        row_node, _ = route_rows_mxu(bins, state[1], state[2], state[3],
-                                     feat_tbl, num_features=nf_packed,
+        row_node, _ = route_rows_mxu(ops.bins, state[1], state[2],
+                                     state[3], feat_tbl,
+                                     num_features=nf_packed,
                                      loc_table=None if efb_seg
                                      else loc_tbl,
                                      efb_range=efb_seg,

@@ -33,6 +33,7 @@ routing.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +45,7 @@ from ..utils.log import Log
 
 __all__ = ["build_histograms_mxu", "build_histograms_mxu_v2",
            "build_histograms_mxu_auto", "hist_num_channels",
-           "route_rows_mxu",
+           "route_rows_mxu", "HistOperands", "prepare_hist_operands",
            "pack_route_tables", "node_values_mxu", "node_sums_mxu",
            "quantize_gradients", "pack_bins_4bit", "unpack_bins_4bit"]
 
@@ -234,6 +235,97 @@ def _hist_channels(grad, hess, cnt, double_prec: bool,
     data = jnp.stack(chans + [jnp.zeros_like(g)] * (8 - nchan),
                      axis=1)                                 # [N, 8]
     return data, nchan
+
+
+class HistOperands(NamedTuple):
+    """What the kernels read of the rows that does not change within a
+    tree, in the form their BlockSpecs take it. Built once per tree by
+    the grower (prepare_hist_operands, outside every pass) and passed
+    to the wrappers as `operands=`; a wrapper called without it builds
+    the same thing for itself, at its own row block. The true row count
+    is the length of the per-pass vector (row_node / row_slot) that
+    comes with it; rows past it are padding (bins 0, channels 0)."""
+    bins: jax.Array                        # [R, fcols], rows padded
+    lanes: Optional[jax.Array]             # [R, plane]: + 128-lane pad
+    data: Optional[jax.Array]              # [R, 8] f32 (_hist_channels)
+    table: Optional[jax.Array] = None      # [n + 1, W] bf16 (_row_table)
+
+
+#: rows of per-tree operands are padded to a multiple of this: every
+#: row block the wrappers use (1024 ... 8192) divides it
+OPERAND_ROW_MULTIPLE = 8192
+
+
+def _pad_rows(x, rows: int, **kw):
+    """x padded along axis 0 to `rows` (no-op when already there)."""
+    extra = rows - x.shape[0]
+    if not extra:
+        return x
+    return jnp.pad(x, ((0, extra),) + ((0, 0),) * (x.ndim - 1), **kw)
+
+
+def _row_table(bins: jax.Array, data: jax.Array, nchan: int) -> jax.Array:
+    """Everything the slot-grouped kernel (histogram_pallas) reads of a
+    row as ONE bf16 row, so a pass gathers once: the bin columns (byte
+    values, exact in bf16), then the channels as the very bf16 operand
+    the one-hot kernels build from `data` (the MXU is fed bf16 either
+    way, so nothing is lost), then a column for the row's slot within
+    its group, which is the only part a pass writes (after the gather;
+    255: none). One extra all-zero, slot-less row at the end stands for
+    padding."""
+    n = bins.shape[0]
+    tab = jnp.concatenate(
+        [bins.astype(jnp.bfloat16), data[:, :nchan].astype(jnp.bfloat16),
+         jnp.full((n, 1), 255, jnp.bfloat16)], axis=1)
+    pad = jnp.zeros((1, tab.shape[1]), jnp.bfloat16).at[0, -1].set(255)
+    return jnp.concatenate([tab, pad])
+
+
+def prepare_hist_operands(bins, grad, hess, cnt, *, double_prec=True,
+                          quantized=False, const_hess=0.0,
+                          row_multiple: int = OPERAND_ROW_MULTIPLE,
+                          lanes: bool = False, channels: bool = True,
+                          table: bool = False) -> HistOperands:
+    """The per-tree operands of the histogram and routing kernels from
+    the binned matrix and one tree's gradients: bins padded in rows to
+    `row_multiple` (and, lanes=True, to 128 lanes: the one-hot kernels'
+    block), the channel operand of _hist_channels padded likewise, and
+    (table=True) the slot-grouped build's row table. The grower calls
+    this once per tree and asks for what its static plan uses; a
+    wrapper without `operands=` calls it with its own row block, so the
+    arrays a kernel sees are the same either way."""
+    rows = _round_up(bins.shape[0], row_multiple)
+    bins_p = _pad_rows(bins, rows)
+    plane = _round_up(bins.shape[1], 128)
+    lanes_p = None
+    if lanes:
+        # padded lanes are never sliced by the kernels (j < f); the
+        # value only needs to be in range for the int cast
+        lanes_p = bins_p if plane == bins.shape[1] else \
+            jnp.pad(bins_p, ((0, 0), (0, plane - bins.shape[1])))
+    data = tab = None
+    if channels or table:
+        data, nchan = _hist_channels(grad, hess, cnt, double_prec,
+                                     quantized, const_hess)  # [N, 8]
+        if table:
+            tab = _row_table(bins, data, nchan)
+        data = _pad_rows(data, rows) if channels else None
+    return HistOperands(bins_p, lanes_p, data, tab)
+
+
+def _kernel_operands(operands: Optional[HistOperands], nb: int, bins,
+                     grad, hess, cnt, **posture) -> HistOperands:
+    """A one-hot kernel's operands at its row block `nb`: the tree's
+    (whose rows `nb` divides already, for every block that divides
+    OPERAND_ROW_MULTIPLE), else prepared here from the plain arrays."""
+    if operands is None:
+        return prepare_hist_operands(bins, grad, hess, cnt,
+                                     row_multiple=nb, lanes=True,
+                                     **posture)
+    rows = _round_up(operands.bins.shape[0], nb)
+    return operands._replace(bins=_pad_rows(operands.bins, rows),
+                             lanes=_pad_rows(operands.lanes, rows),
+                             data=_pad_rows(operands.data, rows))
 
 
 def quantize_gradients(grad, hess, key, *, pmax_axis=None):
@@ -617,6 +709,7 @@ def build_histograms_mxu_v2(bins: jax.Array, grad: jax.Array,
                             quantized: bool = False,
                             num_features: int = 0,
                             const_hess: float = 0.0,
+                            operands: Optional[HistOperands] = None,
                             interpret: bool = False) -> jax.Array:
     """Extraction-free variant of build_histograms_mxu (same contract):
     one grid pass over rows, per-feature static lane slices instead of
@@ -624,32 +717,28 @@ def build_histograms_mxu_v2(bins: jax.Array, grad: jax.Array,
 
     num_features > 0 marks `bins` as 4-bit packed storage
     (pack_bins_4bit) with that many logical features; the kernel unpacks
-    nibbles in VMEM, halving the bin matrix's HBM traffic."""
-    n, fcols = bins.shape
+    nibbles in VMEM, halving the bin matrix's HBM traffic.
+
+    operands: the tree's prepared bins and channel operand
+    (prepare_hist_operands(lanes=True)), read instead of bins, grad,
+    hess and cnt."""
+    nb = row_block
+    ops = _kernel_operands(operands, nb, bins, grad, hess, cnt,
+                           double_prec=double_prec, quantized=quantized,
+                           const_hess=const_hess)
+    rows, fcols = ops.bins.shape
     f = num_features if num_features else fcols
     fh = fcols if num_features else 0
-    nb = row_block
     s = num_slots
     b = ((bmax + 127) // 128) * 128
-    flane = ((fcols + 127) // 128) * 128
+    flane = ops.lanes.shape[1]
+    nchan = hist_num_channels(double_prec, quantized, const_hess)
 
-    npad = (-n) % nb
-    if npad:
-        bins = jnp.pad(bins, ((0, npad), (0, 0)))
-    if flane != fcols:
-        # padded lanes are never sliced by the kernel (j < f); the value
-        # only needs to be in-range for the int cast
-        bins = jnp.pad(bins, ((0, 0), (0, flane - fcols)))
     slot = jnp.where((row_slot < 0) | (row_slot >= s), -1, row_slot) \
         .astype(jnp.int32)
-    if npad:
-        slot = jnp.pad(slot, (0, npad), constant_values=-1)
-    data, nchan = _hist_channels(grad, hess, cnt, double_prec, quantized,
-                                 const_hess)
-    if npad:
-        data = jnp.pad(data, ((0, npad), (0, 0)))
+    slot = _pad_rows(slot, rows, constant_values=-1)
 
-    nblocks = (n + npad) // nb
+    nblocks = rows // nb
     block_any = jnp.max(
         (slot >= 0).astype(jnp.int32).reshape(nblocks, nb), axis=1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -670,7 +759,7 @@ def build_histograms_mxu_v2(bins: jax.Array, grad: jax.Array,
         out_shape=jax.ShapeDtypeStruct((1, nchan * s, f * b), jnp.float32),
         interpret=interpret,
         **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(block_any, slot[:, None], bins, data)
+    )(block_any, slot[:, None], ops.lanes, ops.data)
 
     return _combine_hist(out, nchan=nchan, s=s, f=f, b=b, bmax=bmax,
                          double_prec=double_prec, const_hess=const_hess)
@@ -679,12 +768,14 @@ def build_histograms_mxu_v2(bins: jax.Array, grad: jax.Array,
 def build_histograms_mxu_auto(bins, grad, hess, cnt, row_slot, *,
                               num_slots, bmax, double_prec=True,
                               quantized=False, num_features=0,
-                              const_hess=0.0,
+                              const_hess=0.0, operands=None,
                               interpret=False, **v1_cfg):
     """v2 kernel when its per-feature output block fits VMEM, else the
     chunked v1 kernel (wide-feature datasets). num_features > 0 marks
     `bins` as 4-bit packed (the v1 fallback unpacks on device — packed
-    storage targets small-bmax shapes, which always fit v2)."""
+    storage targets small-bmax shapes, which always fit v2). operands:
+    the tree's prepared operands, for the v2 kernel; the v1 fallback
+    pads to its own selector layout from the plain arguments."""
     f = num_features if num_features else bins.shape[1]
     if fits_v2(num_slots, f, bmax, double_prec, quantized,
                const_hess=const_hess):
@@ -692,7 +783,7 @@ def build_histograms_mxu_auto(bins, grad, hess, cnt, row_slot, *,
             bins, grad, hess, cnt, row_slot, num_slots=num_slots,
             bmax=bmax, double_prec=double_prec, quantized=quantized,
             num_features=num_features, const_hess=const_hess,
-            interpret=interpret)
+            operands=operands, interpret=interpret)
     if num_features:
         bins = unpack_bins_4bit(bins, num_features)
     return build_histograms_mxu(
@@ -794,6 +885,7 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                          num_features: int = 0, loc_table=None,
                          efb_range: bool = False,
                          const_hess: float = 0.0,
+                         operands: Optional[HistOperands] = None,
                          interpret: bool = False):
     """One sweep: route rows through the previous pass's packed split
     tables (pack_route_tables) AND build the per-slot histograms of the
@@ -813,27 +905,32 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     routing decodes the original local bin through loc_table (efb.py);
     feat_tbl stays original-feature-indexed. efb_range=True routes by
     the bundle-RANGE table columns instead — no loc table, no
-    original-feature-width work (pack_route_tables efb=)."""
-    n, fcols = bins.shape
+    original-feature-width work (pack_route_tables efb=).
+
+    operands: the tree's prepared bins and channel operand
+    (prepare_hist_operands(lanes=True)), read instead of bins, grad,
+    hess and cnt; row_node keeps the true row count, and padding rows
+    ride along at node 0 with all-zero channels."""
+    nb = row_block
+    ops = _kernel_operands(operands, nb, bins, grad, hess, cnt,
+                           double_prec=double_prec, quantized=quantized,
+                           const_hess=const_hess)
+    n = row_node.shape[0]
+    rows, fcols = ops.bins.shape
     has_efb = loc_table is not None and not efb_range
     f = num_features if num_features else fcols
     fh = fcols if num_features else 0
-    nb = row_block
     s = num_slots
     b = ((bmax + 127) // 128) * 128
-    plane = ((fcols + 127) // 128) * 128     # bins block width (packed)
+    plane = ops.lanes.shape[1]               # bins block width (packed)
     # route tables are original-feature-indexed under decode-mode EFB
     f_route = loc_table.shape[0] if has_efb else f
     flane = ((f_route + 127) // 128) * 128
     m, kcols = tbl.shape
     bpad = member.shape[1]
+    nchan = hist_num_channels(double_prec, quantized, const_hess)
 
-    npad = (-n) % nb
-    if npad:
-        bins = jnp.pad(bins, ((0, npad), (0, 0)))
-        row_node = jnp.pad(row_node, (0, npad))
-    if plane != fcols:
-        bins = jnp.pad(bins, ((0, 0), (0, plane - fcols)))
+    row_node = _pad_rows(row_node, rows)
     if feat_tbl.shape[0] > flane:
         feat_tbl = feat_tbl[:flane]   # range mode: ftbl is unused
     elif feat_tbl.shape[0] < flane:
@@ -846,12 +943,8 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                        (0, bb_lane - loc_table.shape[1])))
     else:
         loc = jnp.zeros((8, 128), jnp.float32)  # unused placeholder
-    data, nchan = _hist_channels(grad, hess, cnt, double_prec, quantized,
-                                 const_hess)
-    if npad:
-        data = jnp.pad(data, ((0, npad), (0, 0)))
 
-    nblocks = (n + npad) // nb
+    nblocks = rows // nb
     hist, node_out = pl.pallas_call(
         _fused_kernel(nb, f, flane, b, s, m, bpad, nchan=nchan,
                       has_cat=has_cat, fh=fh, has_efb=has_efb,
@@ -872,12 +965,12 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, nchan * s, f * b), jnp.float32),
-            jax.ShapeDtypeStruct((n + npad, 2), jnp.int32),
+            jax.ShapeDtypeStruct((rows, 2), jnp.int32),
         ],
         interpret=interpret,
         **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(row_node.astype(jnp.int32)[:, None], bins, data, tbl, member,
-      feat_tbl, loc)
+    )(row_node.astype(jnp.int32)[:, None], ops.lanes, ops.data, tbl,
+      member, feat_tbl, loc)
 
     h3 = _combine_hist(hist, nchan=nchan, s=s, f=f, b=b, bmax=bmax,
                        double_prec=double_prec, const_hess=const_hess)
@@ -1073,6 +1166,9 @@ def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
                    interpret: bool = False):
     """Advance rows one level and emit (new row_node, new row_slot).
 
+    bins may come padded in rows (HistOperands.bins: once per tree
+    instead of once per call): the true row count is row_node's, and
+    rows past it route nowhere and are not counted.
     tbl/member: from pack_route_tables (M_pad lane-friendly).
     feat_tbl: [F, 2] f32: (num_bins, missing_is_nan).
     num_features > 0 marks `bins` as 4-bit packed (pack_bins_4bit).
@@ -1090,7 +1186,8 @@ def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
     Both partition implementations consume these counts for the
     groups' block starts; neither counts again.
     """
-    n, fcols = bins.shape
+    n = row_node.shape[0]
+    fcols = bins.shape[1]
     has_efb = loc_table is not None and not efb_range
     f = num_features if num_features else fcols
     f_route = loc_table.shape[0] if has_efb else f
@@ -1114,10 +1211,9 @@ def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
     else:
         nb = 1024
     bpad = member.shape[1]
-    npad = (-n) % nb
-    if npad:
-        bins = jnp.pad(bins, ((0, npad), (0, 0)))
-        row_node = jnp.pad(row_node, (0, npad))
+    rows = _round_up(bins.shape[0], nb)
+    bins = _pad_rows(bins, rows)
+    row_node = _pad_rows(row_node, rows)
     if feat_tbl.shape[0] > f_route:
         feat_tbl = feat_tbl[:f_route]  # range mode: ftbl is unused
     elif feat_tbl.shape[0] < f_route:
@@ -1125,10 +1221,10 @@ def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
                            ((0, f_route - feat_tbl.shape[0]), (0, 0)))
     loc = loc_table.astype(jnp.float32) if has_efb else \
         jnp.zeros((8, 128), jnp.float32)
-    nblocks = (n + npad) // nb
+    nblocks = rows // nb
     spad = ((max(num_slots, 1) + 127) // 128) * 128 if emit_counts else 0
     out_specs = pl.BlockSpec((nb, 2), lambda ri: (ri, 0))
-    out_shape = jax.ShapeDtypeStruct((n + npad, 2), jnp.int32)
+    out_shape = jax.ShapeDtypeStruct((rows, 2), jnp.int32)
     if emit_counts:
         out_specs = [out_specs,
                      pl.BlockSpec((1, 8, spad), lambda ri: (0, 0, 0))]

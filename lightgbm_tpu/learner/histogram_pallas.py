@@ -37,7 +37,9 @@ order differs from the one-hot kernels. In quantized mode the integer
 sums are exact below 2^24, so histograms and models are bit-identical
 across formulations; exact mode agrees to last-ulp summation-order
 noise. A row reaches the kernel as one bf16 row (bins, channels, slot:
-_row_table); 4-bit packed bin pairs are unpacked in VMEM.
+histogram_mxu._row_table, built once per tree; the slot column is
+written per pass, after the gather); 4-bit packed bin pairs are
+unpacked in VMEM.
 """
 
 from __future__ import annotations
@@ -49,9 +51,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .histogram_mxu import (_COMPILER_PARAMS, _combine_hist,
-                            _hist_accumulate, _hist_channels,
-                            hist_num_channels)
+from .histogram_mxu import (_COMPILER_PARAMS, HistOperands, _combine_hist,
+                            _hist_accumulate, hist_num_channels,
+                            prepare_hist_operands)
 
 __all__ = ["build_histograms_pallas", "build_histograms_scatter",
            "partition_rows", "group_width", "use_grouped"]
@@ -95,6 +97,14 @@ GROUPED_MIN_ROWS_PER_PAD = 8
 
 #: positions are carried in f32 through the rank sweep: exact below
 _MAX_POSITIONS = 1 << 24
+
+#: a position of the layout carries its row id in the low bits of ONE
+#: int32 and the row's slot within its group above them, so the single
+#: scatter that inverts the rank delivers both (row ids and the padding
+#: marker n stay under _MAX_POSITIONS wherever that scatter runs)
+_SLOT_SHIFT = 24
+#: "no slot" (padding), as the row table's slot column spells it
+_NO_SLOT = 255
 
 
 def group_width(nchan: int) -> int:
@@ -204,18 +214,29 @@ def partition_rows(row_slot: jax.Array, *, num_slots: int, row_block: int,
     identical layout.
 
     Returns (block_group [TB] i32, blocks_used [] i32, src [TB*row_block]
-    i32): src indexes the original rows, n marks padding; blocks at and
-    after blocks_used hold padding only and repeat the last group. TB is
-    static: ceil(n / row_block) + groups, rounded up to whole gather
-    chunks.
+    i32, src_slot [TB*row_block] i32): src indexes the original rows, n
+    marks padding; src_slot is the row's slot within its group
+    (row_slot % group), 255 on padding: it rides the scatter that
+    inverts the rank, packed above the row id, so delivering it costs
+    no second pass over the rows. Blocks at and after blocks_used hold
+    padding only and repeat the last group. TB is static: ceil(n /
+    row_block) + groups, rounded up to whole gather chunks.
     """
     if impl not in ("auto", "argsort", "rank"):
         raise ValueError(f"unknown partition impl {impl!r}")
+    if group >= _NO_SLOT:
+        raise ValueError("a group holds at most %d slots" % (_NO_SLOT - 1))
     n = row_slot.shape[0]
     s, nb = num_slots, row_block
     ng = -(-s // group)
+    # one dense copy for the readers below: row_slot arrives as a column
+    # sliced out of the route kernel's lane-padded [rows, 2] output, and
+    # XLA would fuse that slice into every one of them (the mask, the
+    # group, the slot within it), each re-reading 512 bytes a row
+    row_slot = jax.lax.optimization_barrier(row_slot)
     live = (row_slot >= 0) & (row_slot < s)
     grp = jnp.where(live, row_slot // group, -1).astype(jnp.int32)
+    slot_local = row_slot % group
     if counts is None:
         gcounts = jax.ops.segment_sum(
             live.astype(jnp.int32), jnp.where(live, grp, 0),
@@ -254,45 +275,49 @@ def partition_rows(row_slot: jax.Array, *, num_slots: int, row_block: int,
         take = r < gcounts[pg]     # a tail block's r is past its group
         src = jnp.where(
             take, order[jnp.clip(sort_start[pg] + r, 0, n - 1)], n)
+        # the oracle pays a second gather for the slots
+        src_slot = jnp.where(
+            take, slot_local[jnp.clip(src, 0, n - 1)], _NO_SLOT)
     else:
         dst = _stable_positions(grp, blk_start[:ng] * nb, num_groups=ng,
                                 dump=tb * nb, interpret=interpret)
-        src = jnp.full(tb * nb, n, jnp.int32).at[dst].set(
-            jnp.arange(n, dtype=jnp.int32), mode="drop",
-            unique_indices=True)
-    return block_group, blocks_used, src
+        # parked rows are dropped by the scatter, so their slot bits
+        # never land; bitcast, not convert: 255 << 24 is past int32
+        packed = jax.lax.bitcast_convert_type(
+            (slot_local.astype(jnp.uint32) << _SLOT_SHIFT) |
+            jnp.arange(n, dtype=jnp.uint32), jnp.int32)
+        fill = jax.lax.bitcast_convert_type(
+            jnp.uint32((_NO_SLOT << _SLOT_SHIFT) | n), jnp.int32)
+        packed = jax.lax.bitcast_convert_type(
+            jnp.full(tb * nb, fill, jnp.int32).at[dst].set(
+                packed, mode="drop", unique_indices=True), jnp.uint32)
+        src = (packed & jnp.uint32((1 << _SLOT_SHIFT) - 1)) \
+            .astype(jnp.int32)
+        src_slot = (packed >> _SLOT_SHIFT).astype(jnp.int32)
+    return block_group, blocks_used, src, src_slot
 
 
-def _gather_used(table: jax.Array, src: jax.Array, rows_used: jax.Array,
-                 chunk_rows: int) -> jax.Array:
+def _gather_used(table: jax.Array, src: jax.Array, src_slot: jax.Array,
+                 rows_used: jax.Array, chunk_rows: int) -> jax.Array:
     """table[src] for the first `rows_used` positions of `src`, in fixed
-    chunks under a dynamic trip count; the rest stays zero (the kernel
-    never multiplies it)."""
+    chunks under a dynamic trip count, with the pass's src_slot written
+    into the table's last (slot) column on the way; the rest stays zero
+    (the kernel never multiplies it)."""
     nchunks = (rows_used + chunk_rows - 1) // chunk_rows
+    width = table.shape[1]
+    is_slot_col = jax.lax.broadcasted_iota(
+        jnp.int32, (chunk_rows, width), 1) == width - 1
 
     def body(c, buf):
         at = c * chunk_rows
         idx = jax.lax.dynamic_slice(src, (at,), (chunk_rows,))
-        return jax.lax.dynamic_update_slice(buf, table[idx], (at, 0))
+        slot = jax.lax.dynamic_slice(src_slot, (at,), (chunk_rows,))
+        blk = jnp.where(is_slot_col,
+                        slot.astype(table.dtype)[:, None], table[idx])
+        return jax.lax.dynamic_update_slice(buf, blk, (at, 0))
 
     return jax.lax.fori_loop(
-        0, nchunks, body,
-        jnp.zeros((src.shape[0], table.shape[1]), table.dtype))
-
-
-def _row_table(bins: jax.Array, data: jax.Array, nchan: int,
-               slot_local: jax.Array) -> jax.Array:
-    """Everything the kernel reads of a row as ONE bf16 row, so a pass
-    gathers once: the bin columns (byte values, exact in bf16), then
-    the channels as the very bf16 operand the one-hot kernels build
-    from `data` (the MXU is fed bf16 either way, so nothing is lost),
-    then the row's slot within its group (255: none). One extra
-    all-zero, slot-less row at the end stands for padding."""
-    tab = jnp.concatenate(
-        [bins.astype(jnp.bfloat16), data[:, :nchan].astype(jnp.bfloat16),
-         slot_local.astype(jnp.bfloat16)[:, None]], axis=1)
-    pad = jnp.zeros((1, tab.shape[1]), jnp.bfloat16).at[0, -1].set(255)
-    return jnp.concatenate([tab, pad])
+        0, nchunks, body, jnp.zeros((src.shape[0], width), table.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -365,6 +390,7 @@ def build_histograms_scatter(bins: jax.Array, grad: jax.Array,
                              const_hess: float = 0.0,
                              slot_counts: jax.Array = None,
                              partition_impl: str = "auto",
+                             operands: HistOperands = None,
                              interpret: bool = False) -> jax.Array:
     """Per-slot histograms via the slot-grouped build.
 
@@ -374,30 +400,34 @@ def build_histograms_scatter(bins: jax.Array, grad: jax.Array,
     optional per-slot row counts (route_rows_mxu emit_counts) so the
     partition skips its own counting pass. partition_impl selects the
     row-permutation scheme (partition_rows: auto|rank|argsort).
+    operands: the tree's prepared row table
+    (prepare_hist_operands(table=True)), read instead of bins, grad,
+    hess and cnt.
 
     Returns [num_slots, F, bmax, 3] f32 (grad, hess, count).
     """
-    n, fcols = bins.shape
+    if operands is None:
+        operands = prepare_hist_operands(
+            bins, grad, hess, cnt, double_prec=double_prec,
+            quantized=quantized, const_hess=const_hess,
+            row_multiple=1, channels=False, table=True)
+    table = operands.table
+    nchan = hist_num_channels(double_prec, quantized, const_hess)
+    fcols = table.shape[1] - nchan - 1
     f = num_features if num_features else fcols
     fh = fcols if num_features else 0
     nb = row_block
     s = num_slots
     b = ((bmax + 127) // 128) * 128      # lane-aligned bin axis
     fb = f * b
-    nchan = hist_num_channels(double_prec, quantized, const_hess)
     sg = min(group_width(nchan), s)
     ng = -(-s // sg)
 
-    block_group, blocks_used, src = partition_rows(
+    block_group, blocks_used, src, src_slot = partition_rows(
         row_slot, num_slots=s, row_block=nb, group=sg,
         counts=slot_counts, impl=partition_impl, interpret=interpret)
-    data, _ = _hist_channels(grad, hess, cnt, double_prec, quantized,
-                             const_hess)                     # [N, 8]
-    live = (row_slot >= 0) & (row_slot < s)
-    table = _row_table(bins, data, nchan,
-                       jnp.where(live, row_slot % sg, 255))
     tab_g = _gather_used(
-        table, src, blocks_used * nb,
+        table, src, src_slot, blocks_used * nb,
         min(_GATHER_CHUNK_BLOCKS, block_group.shape[0]) * nb)
     out = _grouped_call(block_group, blocks_used, tab_g, nb=nb, f=f, b=b,
                         sg=sg, ng=ng, nchan=nchan, fcols=fcols, fh=fh,

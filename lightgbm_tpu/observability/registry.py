@@ -233,7 +233,11 @@ class ObservabilityRegistry:
         (grower_mxu.hist_pass_plan: static, read without a device
         sync); `grouped_passes_per_tree` counts the scheduled passes
         and the bridge built slot-grouped (the fixup body runs as often
-        as a tree needs, so it is listed and not counted). The strings
+        as a tree needs, so it is listed and not counted). Once the
+        growth program has been traced, `operand_builds_per_tree`
+        ({bins_pad, channels, row_table}) and `operand_builds_per_pass`
+        say where that program builds its row-sized kernel operands
+        (grower_mxu.operand_builds: counted in the trace). The strings
         ride the JSON snapshot/bench tail; the Prometheus exporter
         skips them, so the choice is ALSO one-hot encoded (is_auto/
         is_mxu/is_pallas/is_scatter) for scrapers."""
@@ -241,6 +245,10 @@ class ObservabilityRegistry:
             hb = dict(self._hist_backend)
         plan = [dict(p) for p in hb["plan"]]
         out: Dict = {"choice": hb["choice"], "plan": plan}
+        if "operand_builds_per_pass" in hb:
+            out["operand_builds_per_tree"] = \
+                dict(hb["operand_builds_per_tree"])
+            out["operand_builds_per_pass"] = hb["operand_builds_per_pass"]
         for name in ("auto", "mxu", "pallas", "scatter"):
             out["is_" + name] = int(hb["choice"] == name)
         for form in ("onehot", "grouped", "scatter"):
@@ -356,6 +364,15 @@ class ObservabilityRegistry:
                 "plan": [{"stage": str(st), "sk": int(sk),
                           "formulation": str(form)}
                          for st, sk, form in plan]}
+
+    def record_operand_builds(self, per_tree: Dict, per_pass: int) -> None:
+        """What the traced growth program builds of its row-sized
+        kernel operands once per tree and (still) in every pass; beside
+        the plan it belongs to, and recorded like it."""
+        with self._lock:
+            self._hist_backend["operand_builds_per_tree"] = {
+                str(k): int(v) for k, v in per_tree.items()}
+            self._hist_backend["operand_builds_per_pass"] = int(per_pass)
 
     # -- collective-watchdog hooks (reliability/watchdog.py) ------------
     # recorded even when disabled, like record_hist_plan: watchdog

@@ -197,7 +197,7 @@ class TestPartitionRows:
         rng = np.random.RandomState(1)
         n, nb = 997, 128
         slot = jnp.asarray(rng.randint(-1, S, size=n).astype(np.int32))
-        block_group, used, src = partition_rows(
+        block_group, used, src, _ = partition_rows(
             slot, num_slots=S, row_block=nb, interpret=True)
         bs, sr = np.asarray(block_group), np.asarray(src)
         sl = np.asarray(slot)
@@ -434,6 +434,126 @@ class TestPassRule:
         assert [form for _, _, form in plan[-3:]] == ["grouped"] * 3
 
 
+def _abstract_grow_args(n, f, packed4=False):
+    sds = jax.ShapeDtypeStruct
+    cols = (f + 1) // 2 if packed4 else f
+    return (sds((n, cols), jnp.uint8), sds((n,), jnp.float32),
+            sds((n,), jnp.float32), sds((n,), jnp.float32),
+            sds((f,), jnp.float32), sds((f,), jnp.int32),
+            sds((f,), jnp.bool_), sds((f,), jnp.bool_))
+
+
+class TestOperandsPreparedOncePerTree:
+    """What is fixed for a tree (the padded bins, the channel operand,
+    the grouped build's row table) is built outside every pass: counted
+    in the jaxpr of grow_tree_mxu on abstract arguments (no kernel
+    runs), by the walk that feeds the registry's counter."""
+
+    def _check(self, n, f, hb, posture, **over):
+        from lightgbm_tpu.learner import grower_mxu as gm
+        from lightgbm_tpu.learner.split import SplitHyperParams
+        quant = posture == "quantized"
+        ch = 0.25 if posture == "const_hessian" else 0.0
+        packed4 = posture == "packed4"
+        kw = dict(num_leaves=15, max_depth=0, hp=SplitHyperParams(),
+                  bmax=16 if packed4 else 32, overshoot=2.0,
+                  hist_backend=hb, quantized_grad=quant,
+                  const_hessian=ch, packed4=packed4,
+                  rng_key=jax.random.PRNGKey(0) if quant else None)
+        kw.update(over)
+        key = kw.pop("rng_key")
+        jaxpr = jax.make_jaxpr(
+            lambda *a: gm.grow_tree_mxu(*a, rng_key=key, **kw))(
+                *_abstract_grow_args(n, f, packed4))
+        built = gm.operand_builds(jaxpr)
+        plan = gm.hist_pass_plan(
+            rows=n, num_leaves=kw["num_leaves"], overshoot=kw["overshoot"],
+            hist_backend=hb, quantized_grad=quant, const_hessian=ch)
+        forms = [form for _, _, form in plan]
+        # rows one to seven of ISSUE 29's table: nothing inside a pass
+        assert built["per_pass"] == 0
+        for body in built["passes"]:
+            assert set(body) <= {"rank_scatter"}, body
+        # ... and at most one build of each outside (hi/lo splits of
+        # gradient and hessian; the table and its padding row; the
+        # quantized posture's exact leaf refit stacks its own channels)
+        tree = built["tree"]
+        assert tree.get("bins_row_pad", 0) == 1
+        assert tree.get("bins_lane_pad", 0) == int("onehot" in forms)
+        assert tree.get("channels_stack", 0) == 1 + quant
+        assert tree.get("channels_pad", 0) == \
+            int("onehot" in forms) + quant
+        assert tree.get("channels_split", 0) <= 2 + 2 * quant
+        assert tree.get("table_bins", 0) == int("grouped" in forms)
+        assert tree.get("table_concat", 0) == 2 * int("grouped" in forms)
+        assert built["per_tree"] == {
+            "bins_pad": 1, "channels": 1 + quant,
+            "row_table": int("grouped" in forms)}
+        # the scatter that inverts the rank stays: one a grouped pass
+        scatters = [b["rank_scatter"] for b in built["passes"]]
+        assert scatters == [1] * forms.count("grouped")
+        return built
+
+    @pytest.mark.parametrize("posture", ["exact", "const_hessian",
+                                         "quantized", "packed4"])
+    @pytest.mark.parametrize("hb", ["mxu", "auto", "pallas"])
+    def test_nothing_row_sized_is_built_inside_a_pass(
+            self, low_crossover, hb, posture):
+        built = self._check(3001, 6, hb, posture)
+        if hb == "auto":   # the lowered rule mixes both formulations
+            assert built["per_tree"]["row_table"] == 1
+            assert built["tree"]["bins_lane_pad"] == 1
+
+    def test_the_benchmark_cells_program(self):
+        # higgs_train's own shape and plan: six one-hot passes, then
+        # three grouped, the bridge and the fixup body
+        built = self._check(2_625_000, 28, "auto", "exact",
+                            num_leaves=255, bmax=256)
+        assert len(built["passes"]) == 5
+
+    def test_the_counter_is_this_walk(self, monkeypatch):
+        # the registry and the boosting.build_program span carry what
+        # the walk finds in the program the booster actually traces,
+        # and reading it costs no second trace of the grow core
+        import lightgbm_tpu as lgb
+        from lightgbm_tpu.learner import grower_mxu as gm
+        from lightgbm_tpu.observability import registry
+        traced, core = [], gm._make_grow_core
+        monkeypatch.setattr(
+            gm, "_make_grow_core",
+            lambda *a, **k: traced.append(1) or core(*a, **k))
+        jax.clear_caches()
+        rng = np.random.RandomState(3)
+        X = rng.randn(300, 4).astype(np.float32)
+        y = (X[:, 0] > 0).astype(np.float32)
+        ds = lgb.Dataset(X, label=y, params={"max_bin": 31})
+        bst = lgb.Booster(params={
+            "objective": "binary", "num_leaves": 7, "max_bin": 31,
+            "verbosity": -1, "min_data_in_leaf": 5}, train_set=ds)
+        g = bst.gbdt
+        g._hist_impl, g._mxu_interpret = "mxu", True
+        g._hist_backend = None
+        registry.reset()
+        assert "operand_builds_per_pass" not in \
+            registry.hist_backend_snapshot()       # nothing traced yet
+        g.train_one_iter()
+        g.train_one_iter()
+        assert len(traced) == 1
+        snap = registry.hist_backend_snapshot()
+        assert snap["operand_builds_per_pass"] == 0
+        assert snap["operand_builds_per_tree"] == {
+            "bins_pad": 1, "channels": 1, "row_table": 0}
+        assert snap["operand_builds_per_tree"] == \
+            g._operand_builds["per_tree"]
+        attrs = [sp["attrs"] for sp in registry.trace.spans()
+                 if sp["name"] == "boosting.build_program"][-1]
+        assert attrs["operand_builds_per_pass"] == 0
+        assert attrs["operand_builds_per_tree"] == \
+            "bins_pad:1,channels:1,row_table:0"
+        assert "lightgbm_tpu_hist_backend_operand_builds_per_pass 0" \
+            in registry.prometheus_text()
+
+
 def _grow_args(n=1500, f=4, seed=0):
     from lightgbm_tpu.learner.split import SplitHyperParams
     rng = np.random.RandomState(seed)
@@ -450,6 +570,35 @@ def _grow_args(n=1500, f=4, seed=0):
               hp=SplitHyperParams(min_data_in_leaf=5),
               bmax=int(ds.num_bins.max()), interpret=True)
     return args, kw
+
+
+def _per_pass_form(monkeypatch):
+    """grow_tree_mxu as it was before a tree prepared its operands: the
+    passes call the wrappers' self-preparing form (no `operands=`), so
+    every pass pads the bins, stacks the channels and builds the row
+    table for itself from the plain arrays."""
+    from lightgbm_tpu.learner import grower_mxu as gm
+    plain = {}
+    real_prepare = gm.prepare_hist_operands
+
+    def prepare(bins, grad, hess, cnt, **kw):
+        plain["args"] = (bins, grad, hess, cnt)
+        return real_prepare(bins, grad, hess, cnt, **kw)
+
+    def unprepared(fn):
+        def call(_b, _g, _h, _c, *a, operands=None, **kw):
+            assert operands is not None
+            return fn(*plain["args"], *a, **kw)
+        return call
+
+    real_route = gm.route_rows_mxu
+    monkeypatch.setattr(gm, "prepare_hist_operands", prepare)
+    monkeypatch.setattr(gm, "route_rows_mxu",
+                        lambda _b, *a, **kw: real_route(
+                            plain["args"][0], *a, **kw))
+    for name in ("fused_route_hist_mxu", "build_histograms_scatter",
+                 "build_histograms_mxu_auto"):
+        monkeypatch.setattr(gm, name, unprepared(getattr(gm, name)))
 
 
 class TestPerPassRuleGrowsTheSameTree:
@@ -491,6 +640,50 @@ class TestPerPassRuleGrowsTheSameTree:
             # the f32 bound of test_exact_mode_f32_error_bound
             np.testing.assert_allclose(lv_got, lv_ref, rtol=1e-4,
                                        atol=1e-5)
+
+    @pytest.mark.parametrize("posture", ["exact", "quantized",
+                                         "const_hessian"])
+    @pytest.mark.parametrize("hb", ["auto", "pallas"])
+    def test_prepared_operands_grow_the_per_pass_tree(
+            self, low_crossover, monkeypatch, hb, posture):
+        # operands prepared once per tree against the wrappers'
+        # self-preparing form in every pass (what the grower did before
+        # it prepared anything): the kernels see the same arrays, so
+        # the trees agree to the byte, in exact mode too. 1500 rows are
+        # a multiple of no row block.
+        from lightgbm_tpu.learner import grower_mxu as gm
+        args, kw = _grow_args()
+        kw.update(hist_backend=hb, rng_key=jax.random.PRNGKey(3),
+                  quantized_grad=posture == "quantized", overshoot=2.0,
+                  const_hessian=0.25 if posture == "const_hessian"
+                  else 0.0)
+        t_new, r_new = gm.grow_tree_mxu(*args, **kw)
+        _per_pass_form(monkeypatch)
+        jax.clear_caches()
+        t_old, r_old = gm.grow_tree_mxu(*args, **kw)
+        assert int(t_new.num_leaves) > 8
+        for fld in t_new._fields:
+            a, b = np.asarray(getattr(t_new, fld)), \
+                np.asarray(getattr(t_old, fld))
+            assert a.tobytes() == b.tobytes(), fld
+        assert np.asarray(r_new).tobytes() == np.asarray(r_old).tobytes()
+
+    def test_the_per_pass_form_is_what_it_says(self, low_crossover,
+                                               monkeypatch):
+        # the oracle of the test above really builds per pass: the
+        # walk that feeds the counter finds the operands inside the
+        # pass bodies again (the tree's own, unused there, are dead
+        # code to XLA)
+        from lightgbm_tpu.learner import grower_mxu as gm
+        _per_pass_form(monkeypatch)
+        jax.clear_caches()
+        args, kw = _grow_args()
+        kw.update(hist_backend="auto", interpret=False)
+        built = gm.operand_builds(jax.make_jaxpr(
+            lambda *a: gm.grow_tree_mxu(*a, **kw))(*args))
+        assert built["per_pass"] >= 4
+        grouped = [b for b in built["passes"] if "rank_scatter" in b]
+        assert grouped and all(b["table_bins"] == 1 for b in grouped)
 
 
 # ----------------------------------------------------------------------

@@ -46,11 +46,18 @@ def _parity(row_slot, *, num_slots, row_block, group=1, counts=None):
         outs[impl] = tuple(np.asarray(o) for o in out)
     for a, b in zip(outs["argsort"], outs["rank"]):
         assert a.tobytes() == b.tobytes()
-    bg, used, src = outs["argsort"]
+    bg, used, src, src_slot = outs["argsort"]
     n = row_slot.shape[0]
     live = (row_slot >= 0) & (row_slot < num_slots)
     real = src < n
     assert sorted(src[real].tolist()) == np.flatnonzero(live).tolist()
+    # the slot within the group rides beside the row id: the grouped
+    # build writes it into the gathered table, whose static part is
+    # built once per tree
+    np.testing.assert_array_equal(src_slot[real],
+                                  row_slot[src[real]] % group)
+    assert (src_slot[~real] == 255).all()
+    assert (src[~real] == n).all()
     pos_grp = np.repeat(bg, row_block)
     np.testing.assert_array_equal(pos_grp[real],
                                   row_slot[src[real]] // group)
@@ -90,10 +97,42 @@ class TestAdversarialParity:
     def test_parked_rows_are_not_in_the_layout(self):
         rng = np.random.RandomState(3)
         slot = rng.randint(-1, 5, size=900)   # -1 = parked
-        bg, used, src = _parity(slot, num_slots=5, row_block=64)
+        bg, used, src, _ = _parity(slot, num_slots=5, row_block=64)
         assert (src < 900).sum() == (slot >= 0).sum()
         # ... so the blocks in use cover the live rows, not all rows
         assert int(used) <= -(-int((slot >= 0).sum()) // 64) + 5
+
+    def test_all_rows_parked(self):
+        # a pass whose every row sits in a finished leaf: each group
+        # still owns its (all-padding) block, and no slot is delivered
+        bg, used, src, src_slot = _parity(np.full(700, -1), num_slots=6,
+                                          row_block=64, group=3)
+        assert int(used) == 2 and (src == 700).all()
+        assert (src_slot == 255).all()
+
+    def test_one_group_holds_all_rows(self):
+        # group >= num_slots: one group, and the slot within it is the
+        # slot itself
+        rng = np.random.RandomState(4)
+        slot = rng.randint(-1, 7, size=1111)
+        bg, used, src, src_slot = _parity(slot, num_slots=7,
+                                          row_block=128, group=25)
+        assert (bg == 0).all()
+        real = src < 1111
+        np.testing.assert_array_equal(src_slot[real], slot[src[real]])
+
+    @pytest.mark.parametrize("n", [2049, 4097, 8193, 12345])
+    def test_slot_delivery_off_every_row_block(self, n):
+        # N a multiple of none of the row blocks in use (1024 ... 8192)
+        # nor of the rank sweep's step
+        rng = np.random.RandomState(n)
+        slot = rng.randint(-1, 50, size=n)
+        _parity(slot, num_slots=50, row_block=256, group=25)
+
+    def test_group_too_wide_for_the_slot_bits(self):
+        with pytest.raises(ValueError, match="at most 254 slots"):
+            partition_rows(jnp.zeros(8, jnp.int32), num_slots=300,
+                           row_block=8, group=255)
 
     def test_unknown_impl_raises(self):
         with pytest.raises(ValueError, match="unknown partition impl"):
@@ -118,8 +157,8 @@ class TestAdversarialParity:
         # per group
         rng = np.random.RandomState(11)
         if case == "all_parked":
-            bg, used, src = _parity(np.full(700, -1), num_slots=60,
-                                    row_block=64, group=25)
+            bg, used, src, _ = _parity(np.full(700, -1), num_slots=60,
+                                       row_block=64, group=25)
             assert int(used) == 3 and (src == 700).all()
         elif case == "grouped_slots":
             _parity(rng.randint(-1, 60, size=3000), num_slots=60,
