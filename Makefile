@@ -50,12 +50,9 @@ kernels:
 	$(PY) -m pytest tests/ -x -q -m "kernels and slow"
 
 # the round-6 perf tier: microbench-shaped structural assertions for
-# the scan partition and the level-pipelined grower — stage/fixup
-# dispatch counts, speculative-overlap accounting, counts reuse,
-# sort-free jaxprs (tests/test_partition_scan.py,
-# tests/test_level_pipeline.py, docs/Performance.md "Level
-# pipelining"). Count-based, never wall-clock: green means the
-# structure the partition and the staged grower rely on is intact
+# the row partition — counts reuse, sort-free jaxprs
+# (tests/test_partition_scan.py). Count-based, never wall-clock: green
+# means the structure the partition relies on is intact
 perf:
 	$(PY) -m pytest tests/ -x -q -m "perf and not slow"
 

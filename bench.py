@@ -83,11 +83,6 @@ PARAMS = {"objective": "binary", "num_leaves": NUM_LEAVES,
           # pin LGBM_TPU_PARTITION_IMPL=argsort to measure the other.
           "partition_impl": os.environ.get("LGBM_TPU_PARTITION_IMPL",
                                            "auto")}
-if int(os.environ.get("BENCH_LEVEL_PIPELINE", "0")):
-    # staged level-pipelined grower (serial MXU path only; the fused
-    # multi-tree scan — the headline dispatch shape — ignores it).
-    # Opt-in so the default posture's parameter echo is unchanged.
-    PARAMS["level_pipeline"] = True
 # Bench posture vs library defaults (ROADMAP D3): quantized gradients
 # with exact leaf refit, growth_overshoot 1.75 (default 2.0) and
 # growth_bridge_gate 0.93 (default 0 = full chase). Each trades a few
@@ -487,11 +482,9 @@ def main(argv):
         "achieved_tflops": 0.0, "mfu_per_tree": 0.0,
         "device_peak_tflops": 0.0,
         # attribution side channels (never sentinel metrics): which
-        # partition impl ran, the staged-grower dispatch accounting,
-        # and — under BENCH_PROFILE_SPANS=1 — per-span wall totals
-        # from the observability trace
-        "partition_impl": "", "level_pipeline": {},
-        "profile_spans": {},
+        # partition impl ran and — under BENCH_PROFILE_SPANS=1 —
+        # per-span wall totals from the observability trace
+        "partition_impl": "", "profile_spans": {},
         # per-task rows (regression/multiclass/lambdarank) from
         # helpers/bench_tasks.py, filled by _task_bench
         "tasks": [],
@@ -558,7 +551,6 @@ def main(argv):
         if peak:
             result["mfu_per_tree"] = round(tflops / peak, 6)
     result["partition_impl"] = str(PARAMS.get("partition_impl", "auto"))
-    result["level_pipeline"] = _obs.level_pipeline_snapshot()
     if profile_spans:
         agg = {}
         for sp in _obs.trace.spans():

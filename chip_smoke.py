@@ -124,10 +124,11 @@ def train_leg(name, params, rounds, dtrain, dvalid, clock, *, on_tpu,
     leaves = [int(t.num_leaves) for t in gb.trees]
     plan = gb._hist_plan_attrs()
     stats = getattr(gb, "_pipeline_stats", None)
+    learner = gb._learner       # the resolved crossbar cell
     rec = {
         "trees": len(gb.trees), "min_leaves": min(leaves),
         "hist_impl": gb._hist_impl,
-        "sharded_mxu": bool(getattr(gb, "_sharded_mxu", False)),
+        "sharded_mxu": learner.is_parallel and learner.device == "mxu",
         "hist_backend": getattr(gb, "_hist_backend", None) or "",
         # the growth program's histogram passes, kernel slots and
         # formulation each (static: grower_mxu.hist_pass_plan)
@@ -160,10 +161,10 @@ def train_leg(name, params, rounds, dtrain, dvalid, clock, *, on_tpu,
     check(snap["fallbacks"] == 0 and snap["device_retries"] == 0,
           "leg %s: reliability counters %s" % (name, snap))
     if on_tpu:
-        check(gb._hist_impl == "mxu" or getattr(gb, "_sharded_mxu", False),
+        check(learner.device == "mxu",
               "leg %s ran the %s grower, not the MXU one"
-              % (name, gb._hist_impl))
-        if getattr(gb, "_grower", None) is None:
+              % (name, learner.device))
+        if not learner.is_parallel:
             check(stats is not None and stats.blocks > 0,
                   "leg %s did not go through the fused, pipelined "
                   "executor" % name)
@@ -245,7 +246,7 @@ def multichip_leg(params, dtrain, dvalid, clock, auc_a, *, on_tpu,
           "rows are not spread over %d devices: %s"
           % (ndev, rec["row_blocks"]))
     if on_tpu:
-        check(getattr(gb, "_sharded_mxu", False),
+        check(rec["sharded_mxu"],
               "the sharded learner did not take the MXU grower")
     diff = abs(rec["auc"] - auc_a[rounds - 1])
     rec["auc_vs_leg_a"] = round(diff, 6)

@@ -62,10 +62,6 @@ BRACKET_PREFIX = "record_"
 DELEGATED_SITES = {
     ("grower.py", "grow_tree"): ("gbdt.py", "boosting", "_grow"),
     ("grower_mxu.py", "grow_tree_mxu"): ("gbdt.py", "boosting", "_grow"),
-    # the shared growth core traced by both grower drivers (monolithic
-    # grow_tree_mxu and the level-pipelined stage programs) — same
-    # host-side bracket
-    ("grower_mxu.py", "_make_grow_core"): ("gbdt.py", "boosting", "_grow"),
     ("histogram_mxu.py", "quantize_gradients"):
         ("gbdt.py", "boosting", "_grow"),
     ("loader.py", "_ingest_chunk_step"):

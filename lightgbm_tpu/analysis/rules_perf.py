@@ -57,10 +57,7 @@ HOT_PATH_MANIFEST = {
     ("histogram_mxu.py", "build_histograms_mxu_v2"),
     ("histogram_mxu.py", "fused_route_hist_mxu"),
     ("grower.py", "grow_tree"),
-    ("grower_mxu.py", "_make_grow_core"),
     ("grower_mxu.py", "grow_tree_mxu"),
-    ("grower_pipeline.py", "_stage"),
-    ("grower_pipeline.py", "grow_tree_pipelined"),
 }
 
 _SORT_TAILS = ("argsort",)

@@ -339,11 +339,6 @@ COLLECTIVE_MANIFEST = (
      "dispatch", ("test_distributed.py",)),
     ("grower_mxu.py", "learner", "grow_tree_mxu", "collective_psum",
      "dispatch", ("test_distributed.py",)),
-    # the shared growth core both grower drivers trace (the psum sites
-    # moved here from grow_tree_mxu's body in the level-pipeline
-    # refactor; same fault site, same multihost coverage)
-    ("grower_mxu.py", "learner", "_make_grow_core", "collective_psum",
-     "dispatch", ("test_distributed.py", "test_level_pipeline.py")),
     ("histogram_mxu.py", "learner", "quantize_gradients",
      "collective_psum", "dispatch",
      ("test_distributed.py", "test_hist_backends.py")),

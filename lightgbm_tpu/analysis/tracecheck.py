@@ -41,8 +41,8 @@ collective, delegation to a covered entry).
 Everything here imports jax lazily and forces
 ``jax.default_device(cpu)`` around input construction, so the linter
 never takes the accelerator away from the process that holds it.
-tests/test_partition_scan.py and tests/test_level_pipeline.py import
-the jaxpr helpers from here so lint and tests assert one predicate.
+tests/test_partition_scan.py imports the jaxpr helpers from here so
+lint and tests assert one predicate.
 """
 
 from __future__ import annotations

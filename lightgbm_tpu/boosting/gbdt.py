@@ -17,8 +17,9 @@ differences:
 
 from __future__ import annotations
 
-import os
 import contextlib
+import dataclasses
+import os
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -217,48 +218,16 @@ class GBDT:
         # serial learner is after objective binding (the const-hessian
         # gate decides the channel count the per-pass plan is made of)
         self._hist_backend = None
-        self._setup_parallel(cfg)
-        # TPU kernel choice (serial learner; the data-parallel sharded
-        # path picks mxu in _setup_parallel, other modes keep the
-        # portable scatter grower): "mxu" = sort/gather-free
-        # one-hot-matmul growth (grower_mxu.py), "pallas" = grouped-rows
-        # histogram kernel, "scatter" = pure-XLA segment adds
-        backend = jax.default_backend()
-        if cfg.use_pallas and self._grower is None and backend != "cpu":
-            # the mxu kernels carry bin values through bf16 matmul
-            # operands, exact only for max_bin <= 256. EFB rides the mxu
-            # path too (bundle-space histograms + per-pass expansion)
-            # when the bundle bins fit bf16 exactness and the expanded
-            # scan tensor fits a device-memory budget.
-            excl = self._mxu_exclusions(cfg)
-            if not excl:
-                self._hist_impl = "mxu"
-            else:
-                self._hist_impl = "pallas" if self._efb is None \
-                    else "scatter"
-                # the EFB exclusion is the default by design (config
-                # efb_use_mxu) — only the genuine perf cliffs warn
-                hard = [r for r in excl if r != "efb config"]
-                if hard:
-                    Log.warning(
-                        "training runs on the portable %s grower (MXU "
-                        "path excluded by: %s) — expect ~10x lower "
-                        "throughput on TPU", self._hist_impl,
-                        ", ".join(hard))
-        else:
-            self._hist_impl = "scatter"
-        Log.debug("Tree kernel path: %s (backend=%s)", self._hist_impl,
-                  backend)
-        if cfg.use_quantized_grad and self._hist_impl != "mxu" and \
-                not getattr(self, "_sharded_mxu", False):
+        self._setup_parallel(cfg)   # resolves self._learner
+        if cfg.use_quantized_grad and self._learner.device != "mxu":
             Log.warning("use_quantized_grad only accelerates the MXU "
                         "growth path (active: %s); training runs "
-                        "full-precision", self._hist_impl)
+                        "full-precision", self._learner.device)
         # 4-bit packed bin storage (reference dense_bin.hpp:42): when
         # every feature fits a nibble, re-upload the bin matrix packed
         # two-features-per-byte; the MXU kernels unpack in VMEM. Exact.
         self._packed4 = False
-        if (self._hist_impl == "mxu" and cfg.bin_pack_4bit and
+        if (self._learner.serial_mxu and cfg.bin_pack_4bit and
                 self.bmax <= 16 and not cfg.linear_tree and
                 self._efb is None):
             from ..learner.histogram_mxu import (fits_v2, pack_bins_4bit)
@@ -390,42 +359,74 @@ class GBDT:
         return (jnp.asarray(feat, jnp.int32), jnp.asarray(tbin, jnp.int32),
                 jnp.asarray(left, jnp.int32), jnp.asarray(right, jnp.int32))
 
+    @property
+    def _hist_impl(self) -> str:
+        """NAME of the serial learner's kernel path, read from
+        self._learner: "mxu" (grower_mxu.py, sort/gather-free
+        one-hot-matmul growth) or the portable grower's "pallas"
+        (grouped-rows histogram kernel) / "scatter" (pure-XLA segment
+        adds), which is also what it reads under a sharded learner.
+        Tests and tpu_aot SET it to force a path this host would not
+        pick."""
+        spec = self._learner
+        return "scatter" if spec.is_parallel else spec.device
+
+    @_hist_impl.setter
+    def _hist_impl(self, device: str) -> None:
+        self._learner = dataclasses.replace(self._learner, device=device)
+
     def _setup_parallel(self, cfg) -> None:
-        """Distributed learner setup (reference CreateTreeLearner crossbar,
-        tree_learner.cpp:16-64, + Network::Init)."""
+        """Learner resolution and, for a parallel one, its setup
+        (reference CreateTreeLearner crossbar, tree_learner.cpp:16-64,
+        + Network::Init)."""
         self.comm = None
         self.mesh = None
         self._grower = None
         self._row_pad = 0
         self._bins_ft = None
-        if cfg.tree_learner == "serial":
-            return
-        if cfg.num_machines > 1:
-            # reference Network::Init from the machine list
-            # (application.cpp:165); here a jax.distributed rendezvous —
-            # afterwards jax.devices() spans all hosts and the mesh
-            # collectives ride DCN between them
-            from ..parallel.mesh import setup_multihost
-            setup_multihost(cfg.num_machines, cfg.machines,
-                            cfg.machine_list_filename,
-                            cfg.local_listen_port)
+        ndev = 1
+        if cfg.tree_learner != "serial":
+            if cfg.num_machines > 1:
+                # reference Network::Init from the machine list
+                # (application.cpp:165); here a jax.distributed
+                # rendezvous — afterwards jax.devices() spans all hosts
+                # and the mesh collectives ride DCN between them
+                from ..parallel.mesh import setup_multihost
+                setup_multihost(cfg.num_machines, cfg.machines,
+                                cfg.machine_list_filename,
+                                cfg.local_listen_port)
+            visible = len(jax.devices())
+            if cfg.num_devices > visible:
+                # a chip that did not come up must not turn into a
+                # smaller (or serial) run with a warning nobody reads
+                raise LightGBMError(
+                    "num_devices=%d requested but JAX sees %d device(s) "
+                    "(%s)" % (cfg.num_devices, visible,
+                              jax.devices()[0].platform))
+            ndev = cfg.num_devices if cfg.num_devices > 0 else visible
         _setup_t0 = time.time()
-        visible = len(jax.devices())
-        if cfg.num_devices > visible:
-            # a chip that did not come up must not turn into a smaller
-            # (or serial) run with a warning nobody reads
-            raise LightGBMError(
-                "num_devices=%d requested but JAX sees %d device(s) (%s)"
-                % (cfg.num_devices, visible, jax.devices()[0].platform))
-        ndev = cfg.num_devices if cfg.num_devices > 0 else visible
-        if ndev <= 1:
-            Log.warning("tree_learner=%s requested but only one device "
-                        "visible; falling back to serial", cfg.tree_learner)
-            return
-        from ..parallel import CommSpec, make_mesh
+        # crossbar resolution (distributed/crossbar.py, the reference
+        # CreateTreeLearner factory), asked by every run: the MXU gate
+        # picks the device row, cfg.distributed_hist_agg the
+        # histogram-merge column, the downgrades applied in ONE place
         from ..distributed.crossbar import (create_tree_learner,
                                             resolve_learner)
+        platform = jax.default_backend()
+        spec = self._learner = resolve_learner(
+            cfg.tree_learner, platform=platform,
+            use_pallas=cfg.use_pallas,
+            mxu_exclusions=self._mxu_exclusions(cfg), num_devices=ndev,
+            hist_agg=cfg.distributed_hist_agg,
+            num_features=int(self.bins.shape[1]), top_k=cfg.top_k,
+            nproc=jax.process_count(), has_efb=self._efb is not None,
+            mono_rescan=self._mono_nonbasic)
+        Log.debug("Tree learner: %s, kernel path %s (backend=%s)",
+                  spec.mode, spec.device, platform)
+        if not spec.is_parallel:
+            return
+        from ..parallel import CommSpec, make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
+        use_mxu = spec.device == "mxu"
         self._nproc = jax.process_count()
         if self._nproc > 1:
             from ..reliability.watchdog import maybe_start_watchdog
@@ -436,19 +437,6 @@ class GBDT:
                 "(rows pre-partitioned per machine, reference "
                 "dataset_loader.cpp:560-592); got %r" % cfg.tree_learner)
         self.mesh = make_mesh(ndev)
-        # crossbar resolution (distributed/crossbar.py, the reference
-        # CreateTreeLearner factory): the MXU gate picks the device row,
-        # cfg.distributed_hist_agg the histogram-merge column — with the
-        # safety downgrades to psum applied in ONE place
-        excl = self._mxu_exclusions(cfg)
-        use_mxu = (cfg.use_pallas and jax.default_backend() != "cpu" and
-                   cfg.tree_learner == "data" and not excl)
-        spec = resolve_learner(
-            cfg.tree_learner, device="mxu" if use_mxu else "scatter",
-            hist_agg=cfg.distributed_hist_agg,
-            num_features=int(self.bins.shape[1]), top_k=cfg.top_k,
-            nproc=self._nproc, has_efb=self._efb is not None,
-            mono_rescan=self._mono_nonbasic)
         self.comm = CommSpec(axis="data", mode=spec.mode,
                              num_devices=ndev, top_k=cfg.top_k,
                              hist_agg=spec.hist_agg)
@@ -484,14 +472,6 @@ class GBDT:
         else:  # feature-parallel replicates rows (docs/Features.rst:109)
             self.bins = jax.device_put(
                 self.bins, NamedSharding(self.mesh, P()))
-        hard = [r for r in excl if r != "efb config"]
-        if hard and cfg.use_pallas and jax.default_backend() != "cpu" \
-                and self.comm.mode == "data":
-            Log.warning(
-                "data-parallel training runs on the portable grower "
-                "inside shard_map (MXU path excluded by: %s) — expect "
-                "~10x lower throughput on TPU", ", ".join(hard))
-        self._sharded_mxu = use_mxu
         # per-node sampling / extra_trees / quantized rounding need a
         # per-iteration key; it rides into shard_map replicated so every
         # shard samples identically (the reference's cross-machine seed
@@ -570,9 +550,8 @@ class GBDT:
             [np.asarray(s.data) for s in shards]))
 
     def _mxu_exclusions(self, cfg) -> List[str]:
-        """Why the MXU growth path cannot be used (empty = usable).
-        Single source for the serial kernel choice and the sharded
-        use_mxu gate so the two growers can never drift apart. Forced
+        """Why the MXU growth path cannot be used (empty = usable): what
+        crossbar.resolve_learner's gate reads. Forced
         splits and coupled/split CEGB ride the MXU path (round 4); only
         the lazy per-row CEGB penalty, non-basic monotone methods, wide
         bins, and unsuited EFB configs stay portable."""
@@ -583,6 +562,8 @@ class GBDT:
             (self._efb.scan is not None or
              self._mxu_expand_bytes(cfg) <= 1 << 30))
         return [r for r, hit in [
+            # the mxu kernels carry bin values through bf16 matmul
+            # operands, exact only for max_bin <= 256
             ("max_bin > 256", self.bmax > 256),
             ("monotone_constraints_method", self._mono_nonbasic),
             ("cegb_penalty_feature_lazy",
@@ -671,8 +652,7 @@ class GBDT:
         """The per-pass plan as attributes of a boosting.build_program
         span: which formulation each pass of the program being built
         uses. Empty off the MXU growth path."""
-        if self._hist_impl != "mxu" and \
-                not getattr(self, "_sharded_mxu", False):
+        if self._learner.device != "mxu":
             return {}
         self._resolved_hist_backend()
         return {"hist_plan": ",".join("%d:%s" % (sk, form)
@@ -699,9 +679,7 @@ class GBDT:
 
     def _operand_build_attrs(self) -> dict:
         """The counts of _trace_operand_builds as span attributes; empty
-        until a growth program has been traced (and for the
-        level-pipelined driver, whose stages are programs of their
-        own)."""
+        until a growth program has been traced."""
         built = getattr(self, "_operand_builds", None)
         if built is None:
             return {}
@@ -714,7 +692,7 @@ class GBDT:
         program (grower_mxu.hist_pass_plan); rows are ONE device's."""
         from ..learner.grower_mxu import hist_pass_plan
         cfg = self.config
-        sharded = getattr(self, "_sharded_mxu", False)
+        sharded = self._learner.is_parallel
         ndev = int(self.mesh.devices.size) if sharded else 1
         return hist_pass_plan(
             rows=int(self.bins.shape[0]) // max(1, ndev),
@@ -771,24 +749,23 @@ class GBDT:
         dispatch returns."""
         cfg = self.config
 
+        sharded = self._learner.is_parallel
+
         def _attempt():
             faults.inject("histogram_build")
-            if self._grower is None:
+            guard = None
+            if sharded:
+                from ..parallel.comm import check_collective_fault
+                from ..reliability.watchdog import active_guard
+                check_collective_fault()
+                guard = active_guard()
+            if guard is None:
                 # device-profiler bracket (profile_spans=grow_tree): a
                 # live capture forces a block_until_ready so the trace
                 # window covers the async device work; otherwise the
                 # dispatch stays fully async
-                with _profiler.capture("grow_tree") as capturing:
-                    out = self._grow_impl(g, h, cnt, feature_mask)
-                    if capturing:
-                        jax.block_until_ready(out)
-                return out
-            from ..parallel.comm import check_collective_fault
-            from ..reliability.watchdog import active_guard
-            check_collective_fault()
-            guard = active_guard()
-            if guard is None:
-                with _profiler.capture("sharded_grow") as capturing:
+                with _profiler.capture("sharded_grow" if sharded
+                                       else "grow_tree") as capturing:
                     out = self._grow_impl(g, h, cnt, feature_mask)
                     if capturing:
                         jax.block_until_ready(out)
@@ -810,8 +787,8 @@ class GBDT:
         warm = getattr(self, "_grow_warm", False)
         with contextlib.nullcontext() if warm else span(
                 "boosting.build_program", iter=self.iter_, k=1,
-                program="grow_tree" if self._grower is None
-                else "sharded_grow", **self._hist_plan_attrs()) as build:
+                program="sharded_grow" if sharded else "grow_tree",
+                **self._hist_plan_attrs()) as build:
             out = retry_call(
                 _attempt, attempts=cfg.retry_max_attempts,
                 backoff_ms=cfg.retry_backoff_ms,
@@ -824,93 +801,66 @@ class GBDT:
         return out
 
     def _grow_impl(self, g, h, cnt, feature_mask):
-        """Dispatch serial vs sharded growth; returns (tree, row_node[:N])."""
+        """Dispatch the grower self._learner names; returns
+        (tree, row_node[:N])."""
         cfg = self.config
+        spec = self._learner
         needs_rng = (self.hp.extra_trees or
                      cfg.feature_fraction_bynode < 1.0 or
                      cfg.use_quantized_grad)
         rng_key = jax.random.fold_in(
             jax.random.PRNGKey(cfg.extra_seed), self.iter_) \
             if needs_rng else None
-        if self._grower is None and self._hist_impl == "mxu":
-            if cfg.level_pipeline:
-                # staged per-level dispatch (byte-identical to the
-                # monolith; grower_pipeline.py falls back on its own
-                # ineligible configs)
-                from ..learner.grower_pipeline import grow_tree_pipelined
-                out = grow_tree_pipelined(
-                    self.bins, g, h, cnt, feature_mask, self.num_bins_d,
-                    self.missing_is_nan_d, self.is_cat_d,
-                    lookahead=cfg.level_pipeline_lookahead,
-                    iteration=self.iter_,
-                    rng_key=rng_key, cegb_state=self._cegb_state,
-                    **self._mxu_grow_kwargs())
-            else:
-                from ..learner.grower_mxu import grow_tree_mxu
-                args = (self.bins, g, h, cnt, feature_mask,
-                        self.num_bins_d, self.missing_is_nan_d,
-                        self.is_cat_d)
-                kwargs = dict(rng_key=rng_key,
-                              cegb_state=self._cegb_state,
-                              **self._mxu_grow_kwargs())
-                self._trace_operand_builds(grow_tree_mxu, *args, **kwargs)
-                out = grow_tree_mxu(*args, **kwargs)
-            if self._cegb_cfg is not None:
-                tree, row_node, (fu, rfu) = out
-                self._cegb_state = (self._cegb_state[0],
-                                    self._cegb_state[1], fu, rfu)
-                return tree, row_node
-            return out
-        if self._grower is None:
+        args = (self.bins, g, h, cnt, feature_mask, self.num_bins_d,
+                self.missing_is_nan_d, self.is_cat_d)
+        if spec.serial_mxu:
+            from ..learner.grower_mxu import grow_tree_mxu
+            kwargs = dict(rng_key=rng_key, cegb_state=self._cegb_state,
+                          **self._mxu_grow_kwargs())
+            self._trace_operand_builds(grow_tree_mxu, *args, **kwargs)
+            out = grow_tree_mxu(*args, **kwargs)
+        elif not spec.is_parallel:
             out = grow_tree(
-                self.bins, g, h, cnt, feature_mask, self.num_bins_d,
-                self.missing_is_nan_d, self.is_cat_d,
-                num_leaves=cfg.num_leaves,
+                *args, num_leaves=cfg.num_leaves,
                 max_depth=cfg.max_depth, hp=self.hp,
                 leafwise=self._mono_nonbasic, bmax=self.bmax,
                 monotone=self._monotone,
                 interaction_groups=self._interaction_groups,
                 feature_fraction_bynode=cfg.feature_fraction_bynode,
-                rng_key=rng_key, hist_impl=self._hist_impl,
+                rng_key=rng_key, hist_impl=spec.device,
                 partition_impl=cfg.partition_impl,
                 forced=self._forced, cegb_cfg=self._cegb_cfg,
                 cegb_state=self._cegb_state,
                 monotone_method=self._mono_method, efb=self._efb)
+        else:
+            if self._row_pad:
+                g = jnp.pad(g, (0, self._row_pad))
+                h = jnp.pad(h, (0, self._row_pad))
+                cnt = jnp.pad(cnt, (0, self._row_pad))
+            if self.comm.mode in ("data", "voting") and self._nproc > 1:
+                g, h, cnt = (self._shard_rows(a) for a in (g, h, cnt))
+            extra = ()
+            if self._sharded_rng:
+                extra = (jax.random.fold_in(
+                    jax.random.PRNGKey(cfg.extra_seed), self.iter_),)
             if self._cegb_cfg is not None:
-                tree, row_node, (fu, rfu) = out
-                # feature-used flags persist across the whole model
-                # (is_feature_used_in_split_ / is_feature_used_)
-                self._cegb_state = (self._cegb_state[0],
-                                    self._cegb_state[1], fu, rfu)
-                return tree, row_node
-            return out
-        if self._row_pad:
-            g = jnp.pad(g, (0, self._row_pad))
-            h = jnp.pad(h, (0, self._row_pad))
-            cnt = jnp.pad(cnt, (0, self._row_pad))
-        if self.comm.mode in ("data", "voting") and \
-                getattr(self, "_nproc", 1) > 1:
-            g, h, cnt = (self._shard_rows(a) for a in (g, h, cnt))
-        extra = ()
-        if getattr(self, "_sharded_rng", False):
-            extra = (jax.random.fold_in(
-                jax.random.PRNGKey(cfg.extra_seed), self.iter_),)
+                extra = extra + (self._cegb_state,)
+            if self._bins_ft is not None:
+                extra = extra + (self._bins_ft,)
+            args = (self.bins, g, h, cnt) + args[4:] + extra
+            with self.mesh:
+                self._trace_operand_builds(self._grower, *args)
+                out = self._grower(*args)
+        tree, row_node = out[:2]
         if self._cegb_cfg is not None:
-            extra = extra + (self._cegb_state,)
-        if getattr(self, "_bins_ft", None) is not None:
-            extra = extra + (self._bins_ft,)
-        args = (self.bins, g, h, cnt, feature_mask, self.num_bins_d,
-                self.missing_is_nan_d, self.is_cat_d) + extra
-        with self.mesh:
-            self._trace_operand_builds(self._grower, *args)
-            out = self._grower(*args)
-        if self._cegb_cfg is not None:
-            tree, row_node, (fu, rfu) = out
+            # feature-used flags persist across the whole model
+            # (is_feature_used_in_split_ / is_feature_used_)
+            fu, rfu = out[2]
             self._cegb_state = (self._cegb_state[0], self._cegb_state[1],
                                 fu, rfu)
-        else:
-            tree, row_node = out
-        return tree, self._local_rows(row_node)[:self.num_data]
+        if spec.is_parallel:
+            row_node = self._local_rows(row_node)[:self.num_data]
+        return tree, row_node
 
     def _sync_renewed_leaves(self, tree: TreeArrays, row_node, rw
                              ) -> TreeArrays:
@@ -1304,7 +1254,7 @@ class GBDT:
         cfg = self.config
         # guard rails need per-iteration host checks; the fused scan has
         # no host boundary to interpose on (docs/Reliability.md)
-        serial_ok = self._grower is None and self._hist_impl == "mxu"
+        serial_ok = self._learner.serial_mxu
         return (type(self) is GBDT and cfg.boosting in ("gbdt", "goss")
                 and cfg.guard_nonfinite == "off"
                 and (serial_ok or self._sharded_fused_ok())
@@ -1328,8 +1278,8 @@ class GBDT:
         grower — GOSS (global top-k over all rows) and EFB/CEGB/rescan
         monotone (per-iteration host state) stay per-iteration."""
         cfg = self.config
-        return (self._grower is not None
-                and not getattr(self, "_sharded_mxu", False)
+        return (self._learner.is_parallel
+                and self._learner.device != "mxu"
                 and getattr(self, "_nproc", 1) <= 1
                 and self.comm.mode == "data"
                 and cfg.boosting == "gbdt"
@@ -1863,7 +1813,7 @@ class GBDT:
         """Learner-side score update: leaf value via row->node gather
         (score_updater.hpp:21-110 AddScore(tree_learner) equivalent)."""
         if lin is None:
-            if self._hist_impl == "mxu":
+            if self._learner.serial_mxu:
                 # per-row gathers are the slow op on a TPU; the one-hot
                 # matmul lookup kernel keeps the lookup on the MXU
                 from ..learner.histogram_mxu import node_values_mxu

@@ -482,23 +482,6 @@ _PARAMS: List[ParamSpec] = [
        "as the bit-parity oracle. 'auto' = rank. Both produce the "
        "identical group-contiguous block layout, so the choice is "
        "byte-neutral on model.txt"),
-    _p("level_pipeline", bool, False, (),
-       desc="stage-dispatched tree growth (learner/grower_pipeline.py): "
-            "each doubling-schedule pass, the bridge and speculative "
-            "fixup chunks run as separate async dispatches so level "
-            "k+1's histogram build is enqueued before level k's "
-            "bookkeeping is host-visible, and the host regains a "
-            "per-level observation point (the level_pipeline trace "
-            "span). Byte-identical models to the default monolithic "
-            "one-dispatch-per-tree grower, which stays the parity "
-            "oracle. Serial MXU growth only: the sharded grower "
-            "and the fused multi-tree scan ignore it"),
-    _p("level_pipeline_lookahead", int, 4, (), lambda v: v >= 1,
-       "speculative fixup stages enqueued per chunk before the "
-       "level-pipelined grower consults the previous chunk's "
-       "(already in flight) done flag. Larger values keep the device "
-       "busier past the done boundary at the cost of more identity "
-       "no-op dispatches on early-finishing trees"),
     _p("fused_block_size", int, 10, (), lambda v: v >= 1,
        "iterations per fused on-device dispatch in engine.train when "
        "the config is fused-eligible (boosting/fused.py). Metrics, "

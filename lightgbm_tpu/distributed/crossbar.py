@@ -5,19 +5,22 @@ The reference resolves its tree learner through one factory,
 of device type {cpu, gpu, cuda} x learner type {serial, feature, data,
 voting}. Our device column collapses to XLA (the same jitted growth
 body runs on CPU/TPU), but the crossbar survives as the single registry
-`boosting/gbdt.py` and the pipelined executor resolve a grower through
-— with two device rows of our own: the portable scatter grower and the
-MXU growth path, each crossed with the parallelism mode.
+EVERY run of `boosting/gbdt.py`, serial included, resolves its grower
+through — with device rows of our own: the MXU growth path and the
+portable grower (pallas or scatter histograms), each crossed with the
+parallelism mode.
 
-``resolve_learner`` picks the row (validating mode/device/hist_agg
-combinations in ONE place instead of scattered gates);
+``resolve_learner`` picks the row: the kernel-path gate, the one-device
+fallback and the mode/device/hist_agg validation live HERE only;
 ``create_tree_learner`` builds the actual shard_map'ped grower for it.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Sequence
+
+from ..utils.log import Log
 
 __all__ = ["LearnerSpec", "CROSSBAR", "resolve_learner",
            "create_tree_learner"]
@@ -32,7 +35,7 @@ class LearnerSpec:
     the parallelism column, `device` the kernel row, `hist_agg` the
     histogram merge algorithm for the row-sharded modes."""
     mode: str                 # "serial" | "data" | "feature" | "voting"
-    device: str               # "scatter" (portable) | "mxu"
+    device: str               # "mxu" | portable: "pallas" | "scatter"
     hist_agg: str = "psum"    # "psum" | "reduce_scatter" (data/voting)
     rows_sharded: bool = False    # bins/grad/hess/cnt sharded over mesh
     supports_multihost: bool = False
@@ -40,6 +43,12 @@ class LearnerSpec:
     @property
     def is_parallel(self) -> bool:
         return self.mode != "serial"
+
+    @property
+    def serial_mxu(self) -> bool:
+        """The un-sharded MXU grower: what the packed 4-bit bins and the
+        one-hot score update are wired for."""
+        return self.mode == "serial" and self.device == "mxu"
 
 
 #: the factory table (reference tree_learner.cpp:16-64). Keys are
@@ -49,6 +58,7 @@ class LearnerSpec:
 #: kernel), and feature-parallel has no histogram merge at all.
 CROSSBAR = {
     ("scatter", "serial"): LearnerSpec("serial", "scatter"),
+    ("pallas", "serial"): LearnerSpec("serial", "pallas"),
     ("mxu", "serial"): LearnerSpec("serial", "mxu"),
     ("scatter", "data"): LearnerSpec(
         "data", "scatter", hist_agg="reduce_scatter", rows_sharded=True,
@@ -63,12 +73,24 @@ CROSSBAR = {
 }
 
 
-def resolve_learner(tree_learner: str, *, device: str = "scatter",
+def resolve_learner(tree_learner: str, *, platform: str = "cpu",
+                    use_pallas: bool = True,
+                    mxu_exclusions: Sequence[str] = (),
+                    num_devices: int = 1,
                     hist_agg: str = "auto", num_features: int = 0,
                     top_k: int = 20, nproc: int = 1,
                     has_efb: bool = False,
                     mono_rescan: bool = False) -> LearnerSpec:
-    """Resolve one crossbar cell, downgrading `hist_agg` where the
+    """Resolve one crossbar cell from what the choice depends on.
+
+    A parallel learner over one device is the serial one. On the chip
+    (`use_pallas`, a `platform` other than "cpu") the MXU grower runs
+    the serial and the data-parallel learner unless `mxu_exclusions`
+    (GBDT._mxu_exclusions) names a reason; then the serial learner keeps
+    the portable grower's Pallas histograms (scatter under EFB, which
+    that kernel has no bundle-space form of) and the data-parallel one
+    the portable body inside shard_map. Feature- and voting-parallel
+    have a portable body only. `hist_agg` is downgraded where the
     reduce-scatter path cannot hold its contract:
 
     - multihost (nproc > 1): the chaos/resume guarantees are proven on
@@ -85,6 +107,27 @@ def resolve_learner(tree_learner: str, *, device: str = "scatter",
     `hist_agg="auto"` means "reduce_scatter wherever exact", explicit
     "psum"/"reduce_scatter" are honored (with the same safety
     downgrades)."""
+    if tree_learner != "serial" and num_devices <= 1:
+        Log.warning("tree_learner=%s requested but only one device "
+                    "visible; falling back to serial", tree_learner)
+        tree_learner = "serial"
+    device = "scatter"
+    if use_pallas and platform != "cpu" and \
+            tree_learner in ("serial", "data"):
+        if not mxu_exclusions:
+            device = "mxu"
+        else:
+            if tree_learner == "serial" and not has_efb:
+                device = "pallas"
+            # the EFB exclusion is the default by design (config
+            # efb_use_mxu) — only the genuine perf cliffs warn
+            hard = [r for r in mxu_exclusions if r != "efb config"]
+            if hard:
+                Log.warning(
+                    "%s training runs on the portable %s grower (MXU "
+                    "path excluded by: %s) — expect ~10x lower "
+                    "throughput on TPU", tree_learner, device,
+                    ", ".join(hard))
     key = (device, tree_learner)
     if key not in CROSSBAR:
         raise ValueError(

@@ -48,7 +48,6 @@ from .histogram_mxu import (_round_up, build_histograms_mxu_auto, fits_v2,
 from .histogram_pallas import build_histograms_scatter, use_grouped
 from .split import (BestSplits, SplitHyperParams, find_best_splits,
                     leaf_gain, leaf_output, _split_gain)
-from .split_kernel import find_best_splits_kernel, kernel_supports
 
 __all__ = ["grow_tree_mxu", "hist_pass_plan", "operand_builds"]
 
@@ -178,27 +177,23 @@ def _kernel_cap(s: int) -> int:
     return min(s, s // 2 + 8)
 
 
-#: index of the done flag in the growth state tuple (shared with the
-#: level-pipelined driver, grower_pipeline.py)
+#: index of the done flag in the growth state tuple
 _DONE = 9
 
 
 def growth_plan(*, num_leaves: int, overshoot: float = 0.0,
                 tail_split_cap: int = 0, hist_subtraction: bool = True,
                 bridge_gate: float = 0.0):
-    """Static growth schedule shared by the monolithic grower and the
-    level-pipelined driver (grower_pipeline.py).
+    """Static growth schedule of grow_tree_mxu.
 
     Everything here derives from static config only — no array in
-    sight — so the pipelined driver can size its stage-program
-    sequence (init + len(schedule) passes + bridge + fixups + final)
-    on the host without tracing anything. _make_grow_core consumes the
-    same plan, so the two drivers cannot disagree on the schedule.
+    sight — so hist_pass_plan (and through it the booster, on the
+    host) reads the same schedule the traced program runs.
 
     With overshoot the fixup frontier runs FULL-width (s_fix =
-    min(LGBM_TPU_SFIX, s_max), default 512): narrow fixup frontiers
-    chasing 65-200 leftover splits made late trees slower than early
-    ones; the bridge gate (growth_bridge_gate) skips the s_max-wide
+    min(512, s_max)): narrow fixup frontiers chasing 65-200 leftover
+    splits made late trees slower than early ones; the bridge gate
+    (growth_bridge_gate) skips the s_max-wide
     bridge sweep once num_leaves >= gate * L_g, never gating below the
     actual leaf budget so the prune keeps its num_leaves target."""
     over = overshoot if overshoot and overshoot >= 1.0 else 0.0
@@ -213,7 +208,8 @@ def growth_plan(*, num_leaves: int, overshoot: float = 0.0,
         schedule.append(min(max(2 * s_p, 2), s_max))
         s_p *= 2
     if over:
-        s_fix = min(int(os.environ.get("LGBM_TPU_SFIX", 512)), s_max)
+        # 512: the whole frontier of a 255-leaf tree at overshoot 2
+        s_fix = min(512, s_max)
         sk_fix = s_fix if hist_subtraction else None
     elif tail_split_cap <= 0:
         s_fix = min(64, s_max)
@@ -235,12 +231,7 @@ def growth_plan(*, num_leaves: int, overshoot: float = 0.0,
     return types.SimpleNamespace(
         over=over, L_g=L_g, m_pad=m_pad, s_max=s_max, schedule=schedule,
         s_fix=s_fix, sk_fix=sk_fix, k_fix=k_fix, gate_leaves=gate_leaves,
-        m_cap_of=m_cap_of, tail_split_cap=tail_split_cap,
-        # stage-program count for the pipelined driver: init + one per
-        # scheduled pass + bridge + ONE shared fixup program (traced
-        # iteration arg) + final epilogue
-        n_stage_programs=len(schedule) + 4,
-        max_fixup_dispatch=max(0, L_g - len(schedule) - 1))
+        m_cap_of=m_cap_of, tail_split_cap=tail_split_cap)
 
 
 def pass_formulation(nslots: int, *, hist_backend: str, nchan: int,
@@ -415,53 +406,51 @@ def operand_builds(jaxpr, rows: Optional[int] = None) -> dict:
         "tree": tree, "passes": [c for c in passes if c]}
 
 
-def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
-                    cnt_weight: jax.Array, feature_mask: jax.Array,
-                    num_bins: jax.Array, missing_is_nan: jax.Array,
-                    is_cat_feat: jax.Array, *, num_leaves: int,
-                    max_depth: int,
-                    hp: SplitHyperParams, bmax: int,
-                    monotone: Optional[jax.Array] = None,
-                    interaction_groups: Optional[tuple] = None,
-                    feature_fraction_bynode: float = 1.0,
-                    rng_key: Optional[jax.Array] = None,
-                    interpret: bool = False,
-                    hist_double_prec: bool = True,
-                    tail_split_cap: int = 0,
-                    hist_subtraction: bool = True,
-                    overshoot: float = 0.0,
-                    bridge_gate: float = 0.0,
-                    psum_axis: Optional[str] = None,
-                    quantized_grad: bool = False,
-                    use_scan_kernel: bool = False,
-                    packed4: bool = False,
-                    const_hessian: float = 0.0,
-                    hist_backend: str = "mxu",
-                    partition_impl: str = "auto",
-                    efb=None,
-                    forced=None,
-                    cegb_cfg=None,
-                    cegb_state=None,
-                    debug_info: bool = False,
-                    quant_state=None):
-    """Trace the shared growth-program pieces for one tree and return
-    them as a namespace: the initial state tuple (`state0`), the
-    per-pass transition closures (`one_pass`/`cond_pass`/`fixup_pass`),
-    the unrolled doubling `schedule`, the fixup-capacity constants
-    (`s_fix`/`sk_fix`/`k_fix`), the bridge gate (`apply_gate`) and the
-    `epilogue` (flush + prune + exact refit).
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_leaves", "max_depth", "hp", "bmax",
+                     "interaction_groups", "feature_fraction_bynode",
+                     "interpret", "hist_double_prec", "tail_split_cap",
+                     "hist_subtraction", "overshoot", "bridge_gate",
+                     "psum_axis",
+                     "quantized_grad", "packed4",
+                     "const_hessian", "hist_backend", "partition_impl",
+                     "cegb_cfg", "debug_info"))
+def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
+                  cnt_weight: jax.Array, feature_mask: jax.Array,
+                  num_bins: jax.Array, missing_is_nan: jax.Array,
+                  is_cat_feat: jax.Array, *, num_leaves: int,
+                  max_depth: int,
+                  hp: SplitHyperParams, bmax: int,
+                  monotone: Optional[jax.Array] = None,
+                  interaction_groups: Optional[tuple] = None,
+                  feature_fraction_bynode: float = 1.0,
+                  rng_key: Optional[jax.Array] = None,
+                  interpret: bool = False,
+                  hist_double_prec: bool = True,
+                  tail_split_cap: int = 0,
+                  hist_subtraction: bool = True,
+                  overshoot: float = 0.0,
+                  bridge_gate: float = 0.0,
+                  psum_axis: Optional[str] = None,
+                  quantized_grad: bool = False,
+                  packed4: bool = False,
+                  const_hessian: float = 0.0,
+                  hist_backend: str = "mxu",
+                  partition_impl: str = "auto",
+                  efb=None,
+                  forced=None,
+                  cegb_cfg=None,
+                  cegb_state=None,
+                  debug_info: bool = False
+                  ) -> Tuple[TreeArrays, jax.Array]:
+    """Grow one tree; same contract as grower.grow_tree (serial mode).
 
-    Both grow_tree_mxu (ONE monolithic jit program per tree) and the
-    level-pipelined driver (grower_pipeline.py — one jit program per
-    stage, dispatched asynchronously from the host) trace THIS code,
-    so the two paths run the same math on the same state layout and
-    stay byte-identical — the pipeline's parity oracle is structural,
-    not re-implemented.
-
-    quant_state: optional (h_grad, h_hess, hist_scale) triple from an
-    earlier stage's `quant_state_out` — skips the (deterministic)
-    gradient-quantization prologue so per-stage programs reuse the
-    init stage's quantized gradients instead of recomputing them.
+    One jit program: the doubling schedule, the bridge pass, the
+    data-dependent fixup while_loop and the epilogue (flush + prune +
+    exact refit) all run in ONE device dispatch (zero host syncs per
+    tree). The serial learner, the fused scan and the sharded grower
+    (inside shard_map, psum_axis set) all trace THIS function.
 
     tail_split_cap > 0 enables hybrid growth: while the leaf budget is
     loose (remaining leaves >= splittable leaves) passes split every
@@ -555,29 +544,20 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     ch = const_hessian
     root_c = _allred(jnp.sum(cnt_weight))
     if quant:
-        if quant_state is not None:
-            # stage programs reuse the init stage's quantized gradients
-            # (deterministic, so recomputing yields the same bits — this
-            # only saves the per-stage O(N) quantization work)
-            h_grad, h_hess, hist_scale = quant_state
-            gscale, hscale = hist_scale[0], hist_scale[1]
-        else:
-            qkey = rng_key if rng_key is not None \
-                else jax.random.PRNGKey(0)
-            qkey = jax.random.fold_in(qkey, 6271)
-            # decorrelate rounding noise across trees even when no
-            # per-tree key is plumbed (the sharded grower path): fold in
-            # gradient bits so each iteration's noise differs — reusing
-            # one u per row every tree would make its rounding error
-            # systematic in the ensemble
-            qkey = jax.random.fold_in(
-                qkey,
-                jax.lax.bitcast_convert_type(jnp.sum(grad), jnp.int32))
-            h_grad, h_hess, gscale, hscale = quantize_gradients(
-                grad, None if ch else hess, qkey, pmax_axis=psum_axis)
-            if h_hess is None:
-                h_hess = hess  # never read: the channel builder drops it
-            hist_scale = jnp.stack([gscale, hscale, jnp.float32(1.0)])
+        qkey = rng_key if rng_key is not None else jax.random.PRNGKey(0)
+        qkey = jax.random.fold_in(qkey, 6271)
+        # decorrelate rounding noise across trees even when no per-tree
+        # key is plumbed (the sharded grower path): fold in gradient
+        # bits so each iteration's noise differs — reusing one u per
+        # row every tree would make its rounding error systematic in
+        # the ensemble
+        qkey = jax.random.fold_in(
+            qkey, jax.lax.bitcast_convert_type(jnp.sum(grad), jnp.int32))
+        h_grad, h_hess, gscale, hscale = quantize_gradients(
+            grad, None if ch else hess, qkey, pmax_axis=psum_axis)
+        if h_hess is None:
+            h_hess = hess  # never read: the channel builder drops it
+        hist_scale = jnp.stack([gscale, hscale, jnp.float32(1.0)])
         # hist-consistent root sums (exact integer sums x scale), so
         # right-child = parent - left stays internally consistent
         root_g = _allred(jnp.sum(h_grad)) * gscale
@@ -878,11 +858,6 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         else:
             gp = None
 
-        # fused single-launch scan kernel (split_kernel.py, the
-        # CUDABestSplitFinder analog). Measured ~4% SLOWER than the XLA
-        # scan in-context on v5e (the scan is NOT this backend's
-        # bottleneck; XLA fuses it well) — kept opt-in for backends
-        # where launch overhead dominates.
         if efb is not None and efb.scan is not None:
             # segmented bundle-space scan: [S, Fb, Bb] in, original-
             # feature BestSplits out (split_bundled.py)
@@ -894,15 +869,6 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 monotone=monotone, cons_min=cons_min[sn],
                 cons_max=cons_max[sn], depth=tree.depth[sn],
                 rand_bins=rand_bins, gain_penalty=gp)
-        elif use_scan_kernel and kernel_supports(hp) and \
-                rand_bins is None and gp is None:
-            bs = find_best_splits_kernel(
-                hist_scan, tree.sum_grad[sn], tree.sum_hess[sn],
-                tree.count[sn],
-                tree.leaf_value[sn], num_bins, missing_is_nan, is_cat_feat,
-                slot_fmask, hp, monotone=monotone, cons_min=cons_min[sn],
-                cons_max=cons_max[sn], depth=tree.depth[sn],
-                interpret=interpret)
         else:
             bs = find_best_splits(
                 hist_scan, tree.sum_grad[sn], tree.sum_hess[sn],
@@ -1209,10 +1175,11 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             lambda st_: one_pass(s, st_, pass_idx, k_cap, sk_next,
                                  m_cap), st)
 
-    # ---- unrolled doubling schedule (growth_plan: shared with the
-    # level-pipelined driver, which needs the stage count host-side) ----
+    # ---- unrolled doubling schedule (growth_plan) ----
     schedule = plan.schedule
-    m_cap_of = plan.m_cap_of
+    for p, s_p in enumerate(schedule):
+        state = cond_pass(s_p, state, jnp.asarray(p, jnp.int32),
+                          m_cap=plan.m_cap_of(s_p))
 
     # ---- fixup loop for off-schedule leftovers ----
     # the best-first tail often splits only a couple of leaves per pass
@@ -1235,185 +1202,84 @@ def _make_grow_core(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     #   costs only ~2.4e-4 AUC@115 for +6% — the r4 bench posture.
     # fixup capacities and the bridge gate are part of the static
     # growth_plan (see its docstring for the round-3/round-4 tuning
-    # history: full-frontier s_fix, LGBM_TPU_SFIX, growth_bridge_gate)
+    # history: full-frontier s_fix, growth_bridge_gate)
     s_fix, sk_fix, k_fix = plan.s_fix, plan.sk_fix, plan.k_fix
-    gate_leaves = plan.gate_leaves
+    if plan.gate_leaves is not None:
+        gated = state[_DONE] | (state[0].num_leaves >= plan.gate_leaves)
+        state = state[:_DONE] + (gated,) + state[_DONE + 1:]
+    if schedule:
+        state = cond_pass(s_max, state, len(schedule), k_cap=k_fix,
+                          sk_next=sk_fix)
 
-    def apply_gate(st):
-        if gate_leaves is None:
-            return st
-        st_l = list(st)
-        st_l[_DONE] = st_l[_DONE] | (st[0].num_leaves >= gate_leaves)
-        return tuple(st_l)
-
-    def fixup_pass(st, it):
-        """One fixup pass at the tail frontier capacity; `it` is the
-        (traced) fixup iteration counter starting at len(schedule)+1."""
-        return one_pass(s_fix, st, it + 1000, k_cap=k_fix,
-                        sk_next=sk_fix, sk_self=sk_fix)
-
-    def epilogue(state, fixup_iters):
-        """Flush routing, prune to best-first, exact leaf refit; the
-        grow_tree_mxu return value from a finished state tuple."""
-        pre_prune_leaves = state[0].num_leaves
-
-        # flush the routing of the last pass's splits (sweeps route at
-        # the START of a pass, so the final commits have not moved rows
-        # yet)
-        row_node, _ = route_rows_mxu(ops.bins, state[1], state[2],
-                                     state[3], feat_tbl,
-                                     num_features=nf_packed,
-                                     loc_table=None if efb_seg
-                                     else loc_tbl,
-                                     efb_range=efb_seg,
-                                     interpret=interpret)
-        tree_out = state[0]
-        cmin, cmax = state[6], state[7]
-        if over:
-            # forced splits outrank every gain-chosen split in the
-            # replay order (their recorded gains stay true)
-            rank = (state[0].gain + jnp.where(state[17], 1e30, 0.0)) \
-                if use_forced else None
-            if quant and hp.has_monotone:
-                tree_out, row_node, (cmin, cmax) = _prune_to_best_first(
-                    tree_out, row_node, num_leaves=num_leaves, m_grow=m,
-                    interpret=interpret, rank_gain=rank,
-                    aux=((cmin, -jnp.inf), (cmax, jnp.inf)))
-            else:
-                tree_out, row_node = _prune_to_best_first(
-                    tree_out, row_node, num_leaves=num_leaves, m_grow=m,
-                    interpret=interpret, rank_gain=rank)
-        if quant:
-            # exact leaf refit: per-leaf double-bf16 sums over the final
-            # row->leaf vector, psum'd under data-parallel; quantization
-            # then never reaches the fitted outputs (reference closed
-            # form, feature_histogram.hpp:737
-            # CalculateSplittedLeafOutput). One caveat: with
-            # path_smooth > 0 the parent reference values are the
-            # growth-time (quantized) ones — mirroring the reference,
-            # which also smooths toward the parent's output as it stood
-            # at split time, but those carry rounding noise here.
-            nn = tree_out.leaf_value.shape[0]
-            sums = _allred(node_sums_mxu(row_node, grad, hess,
-                                         cnt_weight, num_nodes=nn,
-                                         interpret=interpret))
-            pout = tree_out.leaf_value[
-                jnp.clip(tree_out.parent, 0, nn - 1)]
-            ex_val = leaf_output(sums[:, 0], sums[:, 1], hp.lambda_l1,
-                                 hp.lambda_l2, hp.max_delta_step,
-                                 hp.path_smooth, sums[:, 2], pout)
-            if hp.has_monotone:
-                ex_val = jnp.clip(ex_val, cmin, cmax)
-            lf = tree_out.is_leaf
-            tree_out = tree_out._replace(
-                leaf_value=jnp.where(lf, ex_val, tree_out.leaf_value),
-                sum_grad=jnp.where(lf, sums[:, 0], tree_out.sum_grad),
-                sum_hess=jnp.where(lf, sums[:, 1], tree_out.sum_hess),
-                count=jnp.where(lf, sums[:, 2], tree_out.count))
-        if debug_info:
-            return tree_out, row_node, (fixup_iters, pre_prune_leaves)
-        if use_cegb:
-            # feature-used flags persist across trees (portable
-            # contract, grower.py:674); no lazy state here, flags pass
-            # through
-            return tree_out, row_node, (state[16], row_feat_used0)
-        return tree_out, row_node
-
-    return types.SimpleNamespace(
-        state0=state, schedule=schedule, s_max=s_max, m_pad=m_pad,
-        L_g=L_g, s_fix=s_fix, sk_fix=sk_fix, k_fix=k_fix,
-        gate_leaves=gate_leaves, m_cap_of=m_cap_of,
-        one_pass=one_pass, cond_pass=cond_pass, apply_gate=apply_gate,
-        fixup_pass=fixup_pass, epilogue=epilogue,
-        quant_state_out=(h_grad, h_hess, hist_scale))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_leaves", "max_depth", "hp", "bmax",
-                     "interaction_groups", "feature_fraction_bynode",
-                     "interpret", "hist_double_prec", "tail_split_cap",
-                     "hist_subtraction", "overshoot", "bridge_gate",
-                     "psum_axis",
-                     "quantized_grad", "use_scan_kernel", "packed4",
-                     "const_hessian", "hist_backend", "partition_impl",
-                     "cegb_cfg", "debug_info"))
-def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
-                  cnt_weight: jax.Array, feature_mask: jax.Array,
-                  num_bins: jax.Array, missing_is_nan: jax.Array,
-                  is_cat_feat: jax.Array, *, num_leaves: int,
-                  max_depth: int,
-                  hp: SplitHyperParams, bmax: int,
-                  monotone: Optional[jax.Array] = None,
-                  interaction_groups: Optional[tuple] = None,
-                  feature_fraction_bynode: float = 1.0,
-                  rng_key: Optional[jax.Array] = None,
-                  interpret: bool = False,
-                  hist_double_prec: bool = True,
-                  tail_split_cap: int = 0,
-                  hist_subtraction: bool = True,
-                  overshoot: float = 0.0,
-                  bridge_gate: float = 0.0,
-                  psum_axis: Optional[str] = None,
-                  quantized_grad: bool = False,
-                  use_scan_kernel: bool = False,
-                  packed4: bool = False,
-                  const_hessian: float = 0.0,
-                  hist_backend: str = "mxu",
-                  partition_impl: str = "auto",
-                  efb=None,
-                  forced=None,
-                  cegb_cfg=None,
-                  cegb_state=None,
-                  debug_info: bool = False
-                  ) -> Tuple[TreeArrays, jax.Array]:
-    """Grow one tree; same contract as grower.grow_tree (serial mode).
-
-    One monolithic jit program: the doubling schedule, the bridge pass
-    and the data-dependent fixup while_loop all run in ONE device
-    dispatch (zero host syncs per tree). The level-pipelined
-    driver (grower_pipeline.py, config level_pipeline=true) dispatches
-    the SAME passes as separate stage programs with speculative
-    host-side fixup dispatch; this function is its byte-parity oracle.
-    See _make_grow_core for the full parameter semantics
-    (tail_split_cap, hist_subtraction, packed4, hist_backend,
-    partition_impl, efb)."""
-    core = _make_grow_core(
-        bins, grad, hess, cnt_weight, feature_mask, num_bins,
-        missing_is_nan, is_cat_feat, num_leaves=num_leaves,
-        max_depth=max_depth, hp=hp, bmax=bmax, monotone=monotone,
-        interaction_groups=interaction_groups,
-        feature_fraction_bynode=feature_fraction_bynode,
-        rng_key=rng_key, interpret=interpret,
-        hist_double_prec=hist_double_prec,
-        tail_split_cap=tail_split_cap,
-        hist_subtraction=hist_subtraction, overshoot=overshoot,
-        bridge_gate=bridge_gate, psum_axis=psum_axis,
-        quantized_grad=quantized_grad, use_scan_kernel=use_scan_kernel,
-        packed4=packed4, const_hessian=const_hessian,
-        hist_backend=hist_backend, partition_impl=partition_impl,
-        efb=efb, forced=forced, cegb_cfg=cegb_cfg,
-        cegb_state=cegb_state, debug_info=debug_info)
-
-    state = core.state0
-    for p, s_p in enumerate(core.schedule):
-        state = core.cond_pass(s_p, state, jnp.asarray(p, jnp.int32),
-                               m_cap=core.m_cap_of(s_p))
-
-    state = core.apply_gate(state)
-    if core.schedule:
-        state = core.cond_pass(core.s_max, state, len(core.schedule),
-                               k_cap=core.k_fix, sk_next=core.sk_fix)
+    first_fix = len(schedule) + 1
 
     def cond(c):
         st, it = c
-        return (~st[_DONE]) & (it < core.L_g)
+        return (~st[_DONE]) & (it < L_g)
 
     def body(c):
+        # one fixup pass at the tail frontier capacity; `it` is the
+        # (traced) fixup iteration counter
         st, it = c
-        return core.fixup_pass(st, it), it + 1
+        return one_pass(s_fix, st, it + 1000, k_cap=k_fix,
+                        sk_next=sk_fix, sk_self=sk_fix), it + 1
 
     state, it_final = jax.lax.while_loop(
-        cond, body,
-        (state, jnp.asarray(len(core.schedule) + 1, jnp.int32)))
-    return core.epilogue(state, it_final - (len(core.schedule) + 1))
+        cond, body, (state, jnp.asarray(first_fix, jnp.int32)))
+    fixup_iters = it_final - first_fix
+    pre_prune_leaves = state[0].num_leaves
+
+    # ---- epilogue: flush routing, prune to best-first, exact refit ----
+    # flush the routing of the last pass's splits (sweeps route at the
+    # START of a pass, so the final commits have not moved rows yet)
+    row_node, _ = route_rows_mxu(ops.bins, state[1], state[2], state[3],
+                                 feat_tbl, num_features=nf_packed,
+                                 loc_table=None if efb_seg else loc_tbl,
+                                 efb_range=efb_seg, interpret=interpret)
+    tree_out = state[0]
+    cmin, cmax = state[6], state[7]
+    if over:
+        # forced splits outrank every gain-chosen split in the replay
+        # order (their recorded gains stay true)
+        rank = (state[0].gain + jnp.where(state[17], 1e30, 0.0)) \
+            if use_forced else None
+        if quant and hp.has_monotone:
+            tree_out, row_node, (cmin, cmax) = _prune_to_best_first(
+                tree_out, row_node, num_leaves=num_leaves, m_grow=m,
+                interpret=interpret, rank_gain=rank,
+                aux=((cmin, -jnp.inf), (cmax, jnp.inf)))
+        else:
+            tree_out, row_node = _prune_to_best_first(
+                tree_out, row_node, num_leaves=num_leaves, m_grow=m,
+                interpret=interpret, rank_gain=rank)
+    if quant:
+        # exact leaf refit: per-leaf double-bf16 sums over the final
+        # row->leaf vector, psum'd under data-parallel; quantization
+        # then never reaches the fitted outputs (reference closed form,
+        # feature_histogram.hpp:737 CalculateSplittedLeafOutput). One
+        # caveat: with path_smooth > 0 the parent reference values are
+        # the growth-time (quantized) ones — mirroring the reference,
+        # which also smooths toward the parent's output as it stood at
+        # split time, but those carry rounding noise here.
+        nn = tree_out.leaf_value.shape[0]
+        sums = _allred(node_sums_mxu(row_node, grad, hess, cnt_weight,
+                                     num_nodes=nn, interpret=interpret))
+        pout = tree_out.leaf_value[jnp.clip(tree_out.parent, 0, nn - 1)]
+        ex_val = leaf_output(sums[:, 0], sums[:, 1], hp.lambda_l1,
+                             hp.lambda_l2, hp.max_delta_step,
+                             hp.path_smooth, sums[:, 2], pout)
+        if hp.has_monotone:
+            ex_val = jnp.clip(ex_val, cmin, cmax)
+        lf = tree_out.is_leaf
+        tree_out = tree_out._replace(
+            leaf_value=jnp.where(lf, ex_val, tree_out.leaf_value),
+            sum_grad=jnp.where(lf, sums[:, 0], tree_out.sum_grad),
+            sum_hess=jnp.where(lf, sums[:, 1], tree_out.sum_hess),
+            count=jnp.where(lf, sums[:, 2], tree_out.count))
+    if debug_info:
+        return tree_out, row_node, (fixup_iters, pre_prune_leaves)
+    if use_cegb:
+        # feature-used flags persist across trees (portable contract,
+        # grower.py:674); no lazy state here, flags pass through
+        return tree_out, row_node, (state[16], row_feat_used0)
+    return tree_out, row_node

@@ -60,14 +60,6 @@ class ObservabilityRegistry:
         # of the block walls the overlapped host work covered
         self._pipeline = {"blocks": 0, "iterations": 0,
                           "host_seconds": 0.0, "wall_seconds": 0.0}
-        # level-pipelined grower aggregates (learner/grower_pipeline.py):
-        # staged per-level dispatch counts, the speculative fixup
-        # dispatches that turned out to be no-ops, and early stops from
-        # the lagged done poll
-        self._level_pipeline = {"trees": 0, "stage_dispatches": 0,
-                                "fixup_dispatched": 0,
-                                "fixup_speculative": 0, "early_stops": 0,
-                                "wall_seconds": 0.0}
         # streamed-ingestion aggregates (streaming/loader.py): chunk and
         # byte volume per pass plus the frozen sketch sample size
         self._streaming = {"chunks": 0, "rows": 0, "bytes": 0,
@@ -164,10 +156,6 @@ class ObservabilityRegistry:
         with self._lock:
             self._pipeline = {"blocks": 0, "iterations": 0,
                               "host_seconds": 0.0, "wall_seconds": 0.0}
-            self._level_pipeline = {"trees": 0, "stage_dispatches": 0,
-                                    "fixup_dispatched": 0,
-                                    "fixup_speculative": 0,
-                                    "early_stops": 0, "wall_seconds": 0.0}
             self._streaming = {"chunks": 0, "rows": 0, "bytes": 0,
                                "wall_seconds": 0.0, "sample_rows": 0,
                                "exact": 0}
@@ -193,19 +181,6 @@ class ObservabilityRegistry:
                                 "resharded_loads": 0}
 
     # -- exporters ------------------------------------------------------
-    def level_pipeline_snapshot(self) -> Dict:
-        with self._lock:
-            p = dict(self._level_pipeline)
-        disp = p["fixup_dispatched"]
-        frac = p["fixup_speculative"] / disp if disp > 0 else 0.0
-        return {"trees": p["trees"],
-                "stage_dispatches": p["stage_dispatches"],
-                "fixup_dispatched": disp,
-                "fixup_speculative": p["fixup_speculative"],
-                "speculative_frac": round(frac, 4),
-                "early_stops": p["early_stops"],
-                "wall_seconds": round(p["wall_seconds"], 6)}
-
     def pipeline_snapshot(self) -> Dict:
         with self._lock:
             p = dict(self._pipeline)
@@ -309,7 +284,6 @@ class ObservabilityRegistry:
             "profiler": _profiler.snapshot(),
             "hist_backend": self.hist_backend_snapshot(),
             "pipeline": self.pipeline_snapshot(),
-            "level_pipeline": self.level_pipeline_snapshot(),
             "streaming": self.streaming_snapshot(),
             "training": self.training.snapshot(),
             "compiles": {"entries": self.compiles.snapshot(),
@@ -341,7 +315,6 @@ class ObservabilityRegistry:
             (snap["flightrec"], "lightgbm_tpu_flightrec", None),
             (snap["hist_backend"], "lightgbm_tpu_hist_backend", None),
             (snap["pipeline"], "lightgbm_tpu_pipeline", None),
-            (snap["level_pipeline"], "lightgbm_tpu_level_pipeline", None),
             (snap["streaming"], "lightgbm_tpu_streaming", None),
             (snap["timers"], "lightgbm_tpu_timer_seconds", None),
             (snap["trace"], "lightgbm_tpu_trace", None),
@@ -593,28 +566,6 @@ class ObservabilityRegistry:
             p["blocks"] += 1
             p["iterations"] += int(k)
             p["host_seconds"] += float(host_s)
-            p["wall_seconds"] += float(wall_s)
-
-    def record_level_pipeline(self, iteration: int, t0: float,
-                              wall_s: float, stages: int,
-                              fixup_dispatched: int,
-                              fixup_speculative: int,
-                              stopped_early: bool) -> None:
-        """One level-pipelined tree (learner/grower_pipeline.py):
-        `stages` staged programs dispatched, of the fixups
-        `fixup_speculative` were in flight past the (lagged) done flag
-        and executed as identity no-ops. Training compute is recorded
-        elsewhere — this layer accounts the dispatch overlap so a
-        merged trace shows where speculation paid or wasted."""
-        if not self.enabled:
-            return
-        with self._lock:
-            p = self._level_pipeline
-            p["trees"] += 1
-            p["stage_dispatches"] += int(stages)
-            p["fixup_dispatched"] += int(fixup_dispatched)
-            p["fixup_speculative"] += int(fixup_speculative)
-            p["early_stops"] += int(bool(stopped_early))
             p["wall_seconds"] += float(wall_s)
 
     def record_streaming_chunk(self, phase: str, chunk_index: int,

@@ -76,8 +76,6 @@ def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
     slots. The route tables are sized for 2*slots nodes."""
     from ..learner import histogram_mxu as hm
     from ..learner.histogram_pallas import build_histograms_scatter
-    from ..learner.split import SplitHyperParams
-    from ..learner.split_kernel import find_best_splits_kernel
 
     n, f, s = rows, features, slots
     m_pad = hm._round_up(2 * s, 128)
@@ -89,9 +87,6 @@ def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
     member = _sds((m_pad, bpad), jnp.float32)
     feat_tbl = _sds((f, 2), jnp.float32)
     hist_kw = dict(num_slots=s, bmax=bmax, quantized=quantized)
-    hp = SplitHyperParams()
-    svec = _sds((s,), jnp.float32)
-    fvec = _sds((f,), jnp.float32)
 
     def hist_v1(b, g, h, c, sl):
         return hm.build_histograms_mxu(b, g, h, c, sl, **hist_kw)
@@ -116,10 +111,6 @@ def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
     def node_values(node, vals):
         return hm.node_values_mxu(node, vals)
 
-    def split_scan(hist, pg, ph, pc, po, nb, minan, isc, fm):
-        return find_best_splits_kernel(hist, pg, ph, pc, po, nb, minan,
-                                       isc, fm, hp)
-
     return {
         "build_histograms_mxu": (hist_v1, [bins, vec, vec, vec, ivec]),
         "build_histograms_mxu_v2": (hist_v2, [bins, vec, vec, vec, ivec]),
@@ -132,11 +123,6 @@ def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
         "node_sums_mxu": (node_sums, [ivec, vec, vec, vec]),
         "node_values_mxu": (node_values, [ivec, _sds((2 * s,),
                                                      jnp.float32)]),
-        "find_best_splits_kernel": (
-            split_scan,
-            [_sds((s, f, bmax, 3), jnp.float32), svec, svec, svec, svec,
-             _sds((f,), jnp.int32), _sds((f,), jnp.bool_),
-             _sds((f,), jnp.bool_), fvec]),
     }
 
 

@@ -18,6 +18,7 @@ Row counts divide the 8-device mesh (row_pad=0) — parity with padding
 is exercised at small scale by the 1-device fallback test.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -70,6 +71,110 @@ def _train(task, extra, rounds=8):
     bst = lgb.train(params, lgb.Dataset(X, label=y),
                     num_boost_round=rounds)
     return bst
+
+
+# ---------------------------------------------------------------------------
+# the decision itself: crossbar.resolve_learner, asked by every run
+
+_TPU4 = dict(platform="tpu", num_devices=4)
+_CPU8 = dict(platform="cpu", num_devices=8)
+
+# (tree_learner, what the choice depends on) -> (device, mode, hist_agg,
+# warns of the throughput cliff). One case per row of the decision; the
+# expectations are what each run got BEFORE the gate moved here, read
+# off GBDT's attributes on the parent commit.
+_DECISIONS = {
+    "cpu_platform": ("serial", dict(platform="cpu"),
+                     ("scatter", "serial", "psum", False)),
+    "use_pallas_false": ("serial", dict(platform="tpu", use_pallas=False),
+                         ("scatter", "serial", "psum", False)),
+    "serial_mxu": ("serial", dict(platform="tpu"),
+                   ("mxu", "serial", "psum", False)),
+    "excluded_max_bin": (
+        "serial", dict(platform="tpu", mxu_exclusions=["max_bin > 256"]),
+        ("pallas", "serial", "psum", True)),
+    "excluded_monotone_method": (
+        "serial", dict(platform="tpu", mono_rescan=True,
+                       mxu_exclusions=["monotone_constraints_method"]),
+        ("pallas", "serial", "psum", True)),
+    "excluded_cegb_lazy": (
+        "serial", dict(platform="tpu",
+                       mxu_exclusions=["cegb_penalty_feature_lazy"]),
+        ("pallas", "serial", "psum", True)),
+    "efb_without_efb_use_mxu": (
+        "serial", dict(platform="tpu", has_efb=True,
+                       mxu_exclusions=["efb config"]),
+        ("scatter", "serial", "psum", False)),
+    "efb_with_efb_use_mxu": ("serial", dict(platform="tpu", has_efb=True),
+                             ("mxu", "serial", "psum", False)),
+    "data_mxu": ("data", _TPU4, ("mxu", "data", "psum", False)),
+    "data_excluded": (
+        "data", dict(_TPU4, mxu_exclusions=["max_bin > 256"]),
+        ("scatter", "data", "reduce_scatter", True)),
+    "data_portable": ("data", _CPU8,
+                      ("scatter", "data", "reduce_scatter", False)),
+    "data_portable_psum_asked": ("data", dict(_CPU8, hist_agg="psum"),
+                                 ("scatter", "data", "psum", False)),
+    "data_portable_efb": ("data", dict(_CPU8, has_efb=True),
+                          ("scatter", "data", "psum", False)),
+    "data_multihost": ("data", dict(_CPU8, nproc=2),
+                       ("scatter", "data", "psum", False)),
+    "feature": ("feature", _TPU4, ("scatter", "feature", "psum", False)),
+    "voting": ("voting", dict(_TPU4, num_features=12, top_k=20),
+               ("scatter", "voting", "reduce_scatter", False)),
+    "voting_votes_miss_columns": (
+        "voting", dict(_CPU8, num_features=12, top_k=2),
+        ("scatter", "voting", "psum", False)),
+    "one_device_data": ("data", dict(platform="tpu", num_devices=1),
+                        ("mxu", "serial", "psum", False)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DECISIONS))
+def test_resolve_learner_decision_table(case, monkeypatch):
+    from lightgbm_tpu.distributed import crossbar
+    warned = []
+    monkeypatch.setattr(crossbar.Log, "warning",
+                        lambda msg, *a: warned.append(msg % a))
+    tree_learner, depends_on, expected = _DECISIONS[case]
+    spec = crossbar.resolve_learner(tree_learner, **depends_on)
+    cliff = [w for w in warned if "lower throughput" in w]
+    assert (spec.device, spec.mode, spec.hist_agg, bool(cliff)) == expected
+    assert len(cliff) <= 1
+    assert spec == dataclasses.replace(
+        crossbar.CROSSBAR[spec.device, spec.mode], hist_agg=spec.hist_agg)
+    if case == "one_device_data":
+        assert any("falling back to serial" in w for w in warned)
+
+
+@pytest.mark.parametrize("mode,device,serial_mxu", [
+    ("serial", "mxu", True), ("data", "mxu", False),
+    ("serial", "pallas", False), ("serial", "scatter", False)])
+def test_serial_mxu_is_the_unsharded_mxu_grower_only(mode, device,
+                                                     serial_mxu):
+    # what the packed 4-bit bins and the one-hot score update ask: the
+    # sharded MXU learner keeps unpacked bins and the row gather
+    from lightgbm_tpu.distributed.crossbar import CROSSBAR
+    assert CROSSBAR[device, mode].serial_mxu is serial_mxu
+
+
+def test_the_booster_keeps_one_value():
+    # GBDT holds the resolved cell; _hist_impl is its serial kernel
+    # path's NAME, read from and written through to that one value
+    X, y, obj = _make("regression")
+    g = lgb.Booster(params={"objective": obj, "verbose": -1},
+                    train_set=lgb.Dataset(X, label=y)).gbdt
+    assert (g._learner.mode, g._learner.device) == ("serial", "scatter")
+    assert g._hist_impl == "scatter" and not g._learner.serial_mxu
+    g._hist_impl = "mxu"
+    assert g._learner.serial_mxu and g._hist_impl == "mxu"
+    assert "_hist_impl" not in vars(g)
+    sharded = lgb.Booster(
+        params={"objective": obj, "verbose": -1, "tree_learner": "data"},
+        train_set=lgb.Dataset(X, label=y)).gbdt
+    assert sharded._learner.is_parallel and sharded._grower is not None
+    assert sharded._hist_impl == "scatter"
+    assert not sharded._learner.serial_mxu
 
 
 # ---------------------------------------------------------------------------

@@ -368,6 +368,42 @@ class TestBackendResolution:
                             else "onehot"), (sk, form)
 
 
+class TestGrowthPlan:
+    """grower_mxu.growth_plan: the static schedule the one growth
+    program runs, a pure function of the configuration."""
+
+    @pytest.mark.parametrize("gate", [0.0, 0.93])
+    @pytest.mark.parametrize("num_leaves,overshoot",
+                             [(31, 0.0), (31, 2.0), (255, 2.0)])
+    def test_schedule_doubles_up_to_the_frontier(self, num_leaves,
+                                                 overshoot, gate):
+        from lightgbm_tpu.learner.grower_mxu import growth_plan
+        plan = growth_plan(num_leaves=num_leaves, overshoot=overshoot,
+                           bridge_gate=gate)
+        grown = int(np.ceil(num_leaves * overshoot)) if overshoot \
+            else num_leaves
+        assert (plan.L_g, plan.s_max) == (grown, grown + 1)
+        # 2, 4, 8, ... and the last pass scans the whole frontier
+        assert plan.schedule[-1] == plan.s_max
+        assert plan.schedule[:-1] == [2 ** (i + 1) for i in
+                                      range(len(plan.schedule) - 1)]
+        assert plan.schedule[-2] < plan.s_max <= 2 * plan.schedule[-2]
+        # the route tables hold every node id a pass can meet
+        assert all(2 * s <= plan.m_cap_of(s) <= plan.m_pad
+                   for s in plan.schedule)
+        # overgrowing: the fixup body covers the frontier up to 512
+        # slots; without it the tail runs narrow
+        assert plan.s_fix == (min(512, plan.s_max) if overshoot
+                              else min(64, plan.s_max))
+        assert plan.k_fix == plan.s_fix // 2
+        # the bridge gate never stops growth short of the leaf budget
+        if overshoot and gate:
+            assert num_leaves <= plan.gate_leaves <= plan.L_g
+            assert plan.gate_leaves >= int(gate * plan.L_g)
+        else:
+            assert plan.gate_leaves is None
+
+
 class TestPassRule:
     """histogram_pallas.use_grouped / grower_mxu.pass_formulation: which
     formulation a pass uses, a pure function of static shapes."""
@@ -518,10 +554,11 @@ class TestOperandsPreparedOncePerTree:
         import lightgbm_tpu as lgb
         from lightgbm_tpu.learner import grower_mxu as gm
         from lightgbm_tpu.observability import registry
-        traced, core = [], gm._make_grow_core
+        # grow_tree_mxu's body prepares the operands once per trace
+        traced, prepare = [], gm.prepare_hist_operands
         monkeypatch.setattr(
-            gm, "_make_grow_core",
-            lambda *a, **k: traced.append(1) or core(*a, **k))
+            gm, "prepare_hist_operands",
+            lambda *a, **k: traced.append(1) or prepare(*a, **k))
         jax.clear_caches()
         rng = np.random.RandomState(3)
         X = rng.randn(300, 4).astype(np.float32)
@@ -599,6 +636,31 @@ def _per_pass_form(monkeypatch):
     for name in ("fused_route_hist_mxu", "build_histograms_scatter",
                  "build_histograms_mxu_auto"):
         monkeypatch.setattr(gm, name, unprepared(getattr(gm, name)))
+
+
+def test_one_growth_program_per_shape():
+    # the dataset is an argument of grow_tree_mxu, not a constant of
+    # it: two datasets of one shape run ONE program, a third of another
+    # shape builds one more (JAX's own events, the compile ledger)
+    from lightgbm_tpu.learner.grower_mxu import grow_tree_mxu
+    from lightgbm_tpu.observability import registry
+    kw = dict(_grow_args(n=384, f=5)[1], num_leaves=7)
+    jax.clear_caches()
+    registry.compiles.reset()
+
+    def grown(n, seed):
+        args, kw_n = _grow_args(n=n, f=5, seed=seed)
+        assert kw_n["bmax"] == kw["bmax"]
+        tree, row_node = grow_tree_mxu(*args, **kw)
+        assert int(tree.num_leaves) > 1 and row_node.shape == (n,)
+        return registry.compiles.snapshot()["grow_tree_mxu"]
+
+    first = grown(384, seed=1)
+    assert first["lowered"] == first["built"] == 1
+    again = grown(384, seed=2)
+    assert again["lowered"] == again["built"] == 1
+    other = grown(512, seed=3)
+    assert other["lowered"] == other["built"] == 2
 
 
 class TestPerPassRuleGrowsTheSameTree:

@@ -342,67 +342,9 @@ class TestQuantizedGrad:
         assert same >= 0.9
 
 
-class TestScanKernel:
-    """Fused best-split scan kernel parity vs find_best_splits
-    (split_kernel.py; opt-in via grow_tree_mxu(use_scan_kernel=True))."""
-
-    @pytest.mark.parametrize("mono_on,nan_on", [(False, False),
-                                                (False, True),
-                                                (True, False),
-                                                (True, True)])
-    def test_matches_xla_scan(self, mono_on, nan_on):
-        from lightgbm_tpu.learner.split import find_best_splits
-        from lightgbm_tpu.learner.split_kernel import (
-            find_best_splits_kernel)
-        rng = np.random.RandomState(3)
-        S, F, B = 6, 5, 31
-        hist = jnp.asarray(np.abs(rng.rand(S, F, B, 3)) *
-                           np.array([1.0, 1.0, 50.0]))
-        pg = jnp.asarray(np.asarray(hist[..., 0]).sum((1, 2)) / F)
-        ph = jnp.asarray(np.asarray(hist[..., 1]).sum((1, 2)) / F)
-        pc = jnp.asarray(np.asarray(hist[..., 2]).sum((1, 2)) / F)
-        hist = hist / hist.sum(2, keepdims=True) * \
-            jnp.stack([pg, ph, pc], -1)[:, None, None, :]
-        hp = SplitHyperParams(min_data_in_leaf=3, has_monotone=mono_on)
-        kw = dict(monotone=jnp.asarray([1, -1, 0, 0, 0], jnp.int32),
-                  cons_min=jnp.full(S, -0.5), cons_max=jnp.full(S, 0.5),
-                  depth=jnp.arange(S)) if mono_on else {}
-        mnan = jnp.asarray([nan_on] * 2 + [False] * (F - 2))
-        args = (hist, pg, ph, pc, jnp.zeros(S), jnp.full(F, B, jnp.int32),
-                mnan, jnp.zeros(F, bool), jnp.ones(F, jnp.float32), hp)
-        a = find_best_splits(*args, **kw)
-        b = find_best_splits_kernel(*args, interpret=True, **kw)
-        for fld in ("feature", "threshold_bin", "default_left"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(a, fld)), np.asarray(getattr(b, fld)),
-                err_msg=fld)
-        for fld in ("gain", "left_grad", "left_hess", "left_count",
-                    "left_output", "right_output"):
-            np.testing.assert_allclose(
-                np.asarray(getattr(a, fld)), np.asarray(getattr(b, fld)),
-                rtol=2e-5, atol=1e-5, err_msg=fld)
-
-    def test_grower_with_scan_kernel_matches(self):
-        ds, g, h = _data(n=3000, seed=9)
-        args = _mxu_args(ds, g, h)
-        kw = dict(num_leaves=15, max_depth=0,
-                  hp=SplitHyperParams(min_data_in_leaf=20),
-                  bmax=int(ds.num_bins.max()), interpret=True)
-        t0, r0 = grow_tree_mxu(*args, **kw)
-        t1, r1 = grow_tree_mxu(*args, **kw, use_scan_kernel=True)
-        nn = int(t0.num_nodes)
-        assert int(t1.num_nodes) == nn
-        np.testing.assert_array_equal(np.asarray(t0.split_feature)[:nn],
-                                      np.asarray(t1.split_feature)[:nn])
-        np.testing.assert_allclose(np.asarray(t0.leaf_value)[:nn],
-                                   np.asarray(t1.leaf_value)[:nn],
-                                   rtol=1e-5, atol=1e-6)
-        np.testing.assert_array_equal(np.asarray(r0), np.asarray(r1))
-
-
 class TestPerPassRule:
     """hist_backend=auto (a formulation per pass, from static shapes)
-    through the three drivers of the shared growth core. The rule's
+    through the drivers of grow_tree_mxu. The rule's
     constants are lowered (conftest.low_crossover) so that one small
     tree mixes one-hot and slot-grouped passes."""
 
@@ -412,22 +354,6 @@ class TestPerPassRule:
             assert np.asarray(x).tobytes() == np.asarray(y).tobytes(), fld
         assert np.asarray(out_a[1]).tobytes() == \
             np.asarray(out_b[1]).tobytes()
-
-    @pytest.mark.parametrize("quant", [False, True])
-    def test_pipelined_stays_byte_equal_to_monolith(self, low_crossover,
-                                                    quant):
-        # same traced core, same choice per pass: byte-equal whatever
-        # the formulation, exact or quantized
-        from lightgbm_tpu.learner.grower_pipeline import grow_tree_pipelined
-        ds, g, h = _data(n=1500, f=5, seed=5)
-        kw = dict(num_leaves=15, max_depth=0,
-                  hp=SplitHyperParams(min_data_in_leaf=20),
-                  bmax=int(ds.num_bins.max()), interpret=True,
-                  hist_backend="auto", quantized_grad=quant,
-                  rng_key=jax.random.PRNGKey(1))
-        args = _mxu_args(ds, g, h)
-        self._bytes_equal(grow_tree_pipelined(*args, lookahead=2, **kw),
-                          grow_tree_mxu(*args, **kw))
 
     @pytest.mark.parametrize("quant", [False, True])
     def test_sharded_rule_matches_sharded_onehot(self, low_crossover,
