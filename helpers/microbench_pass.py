@@ -32,12 +32,14 @@ All timings are CHAINED IN-JIT: k dependency-chained repetitions in one
 dispatch, long minus short, so the host's dispatch and sync cost cancels
 out of a kernel's number. Every repetition starts from the same state.
 
-With --check each pass is first built once by both formulations and the
-row carries their largest difference over the largest entry, per output
-channel (`agree_rel`: 0.0 three times in the quantized posture, f32
-summation noise in exact mode): a time for a kernel that computes
-something else is worth nothing, and interpret mode on a CPU cannot see
-what the chip's compiler does to a kernel.
+With --check the route stage's node and slot of every row are first
+compared, exactly, with the same splits replayed in NumPy (`route_exact`;
+false makes the exit code 1), then each pass is built once by both
+formulations and the row carries their largest difference over the
+largest entry, per output channel (`agree_rel`: 0.0 three times in the
+quantized posture, f32 summation noise in exact mode): a time for a
+kernel that computes something else is worth nothing, and interpret mode
+on a CPU cannot see what the chip's compiler does to a kernel.
 
 Usage: python helpers/microbench_pass.py [--rows N] [--sk 24,40,...]
        [--nchan 5,3] [--stages onehot,grouped,...] [--reps K] [--check]
@@ -158,20 +160,21 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
     ng = -(-sk // sg)
     rb = onehot_row_block(sk, quant)
 
+    ops = jax.jit(lambda: prepare_hist_operands(
+        bins, g, h, cnt, lanes=True, table=True, route=True, **kw))()
+
     def route(c):
-        return route_rows_mxu(bins, row_node + c, tbl, member, feat_tbl,
+        return route_rows_mxu(None, row_node + c, tbl, member, feat_tbl,
                               emit_counts=True, num_slots=sk,
-                              interpret=interpret)
+                              operands=ops, interpret=interpret)
 
     # the stages' inputs, computed once outside the clock
-    _, rs, cts = jax.jit(route)(jnp.int32(0))
+    rn0, rs, cts = jax.jit(route)(jnp.int32(0))
     block_group, used, src, src_slot = jax.jit(lambda: hp.partition_rows(
         rs, num_slots=sk, row_block=nb, group=sg, counts=cts,
         interpret=interpret))()
     data, _ = _hist_channels(g, h, cnt, True, quant)
     live_share = float(jnp.mean((rs >= 0).astype(jnp.float32)))
-    ops = jax.jit(lambda: prepare_hist_operands(
-        bins, g, h, cnt, lanes=True, table=True, **kw))()
     chunk = min(hp._GATHER_CHUNK_BLOCKS, block_group.shape[0]) * nb
 
     def table_of(c):
@@ -214,9 +217,8 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
     def grouped(c, operands=None):
         raw = plain(c) if operands is None else (None,) * 4
         rn, rs_, cts_ = route_rows_mxu(
-            raw[0] if operands is None else operands.bins, row_node + c,
-            tbl, member, feat_tbl, emit_counts=True, num_slots=sk,
-            interpret=interpret)
+            raw[0], row_node + c, tbl, member, feat_tbl, emit_counts=True,
+            num_slots=sk, operands=operands, interpret=interpret)
         return opaque(hp.build_histograms_scatter(
             *raw, rs_, num_slots=sk, bmax=BMAX, slot_counts=cts_,
             operands=operands, interpret=interpret, **kw)) + opaque(rn)
@@ -253,10 +255,28 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
            "blocks_used": int(used), "blocks_static": int(
                block_group.shape[0])}
     if check:
-        h_one = np.asarray(jax.jit(lambda: fused_route_hist_mxu(
+        # the route stage against make_state's splits replayed in NumPy:
+        # node and slot of every row, exactly, from the prepared and the
+        # self-preparing form and from the fused kernel's own routing
+        node = np.asarray(row_node)
+        left = np.asarray(bins)[np.arange(n), node % F] <= BMAX // 2 - 1
+        want_node = sk + 2 * node + np.where(left, 0, 1)
+        want_slot = np.where(left, node, -1)
+        rn1, rs1 = jax.jit(lambda: route_rows_mxu(
+            bins, row_node, tbl, member, feat_tbl, interpret=interpret))()
+        h_one, rn2 = jax.jit(lambda: fused_route_hist_mxu(
             bins, g, h, cnt, row_node, tbl, member, feat_tbl, num_slots=sk,
             bmax=BMAX, has_cat=False, row_block=rb, interpret=interpret,
-            **kw)[0])())
+            **kw))()
+        h_one = np.asarray(h_one)
+        row["route_exact"] = bool(
+            all(np.array_equal(np.asarray(x), want_node)
+                for x in (rn0, rn1, rn2)) and
+            all(np.array_equal(np.asarray(x), want_slot)
+                for x in (rs, rs1)) and
+            np.array_equal(np.asarray(cts),
+                           np.bincount(node[left], minlength=sk)))
+        print("  route_exact %s" % row["route_exact"], flush=True)
         h_grp = np.asarray(jax.jit(lambda: hp.build_histograms_scatter(
             bins, g, h, cnt, rs, num_slots=sk, bmax=BMAX, slot_counts=cts,
             interpret=interpret, **kw))())
@@ -322,15 +342,17 @@ def main():
                 json.dump({"platform": dev.platform,
                            "device_kind": dev.device_kind, "rows": rows},
                           fh, indent=1)
+    ok = all(r.get("route_exact", True) for r in rows)
     cols = ["sk", "nchan", "onehot_ms", "onehot_prepared_ms",
             "grouped_ms", "grouped_prepared_ms", "route_ms", "part_ms",
             "scatter_ms", "table_ms", "gather_ms", "kernel_ms",
             "part_argsort_ms", "live_share", "blocks_used", "agree_rel",
-            "prepared_identical"]
+            "prepared_identical", "route_exact"]
     print("\t".join(cols))
     for r in rows:
         print("\t".join(str(r.get(c, "")) for c in cols))
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

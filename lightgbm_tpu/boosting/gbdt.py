@@ -685,7 +685,9 @@ class GBDT:
             return {}
         return {"operand_builds_per_tree": ",".join(
                     "%s:%d" % kv for kv in built["per_tree"].items()),
-                "operand_builds_per_pass": built["per_pass"]}
+                "operand_builds_per_pass": built["per_pass"],
+                "id_columns_per_tree": built["id_columns_per_tree"],
+                "id_columns_per_pass": built["id_columns_per_pass"]}
 
     def _hist_pass_plan(self, hist_backend: str) -> list:
         """[(stage, kernel slots, formulation)] of this booster's growth

@@ -293,17 +293,20 @@ def hist_pass_plan(*, rows: int, num_leaves: int, overshoot: float = 0.0,
 #: tree (bins, gradients, count weights): what belongs outside every
 #: pass. "rank_scatter" is the one row-sized equation a grouped pass
 #: keeps by nature, listed so that its count can be held to one a pass.
-OPERAND_EQUATIONS = ("bins_row_pad", "bins_lane_pad", "channels_stack",
-                     "channels_split", "channels_pad", "table_bins",
-                     "table_concat")
+OPERAND_EQUATIONS = ("bins_row_pad", "bins_lane_pad", "bins_transpose",
+                     "channels_stack", "channels_split", "channels_pad",
+                     "table_bins", "table_concat")
 
 
 def _operand_equation(eqn, rows: int) -> Optional[str]:
     """Which of OPERAND_EQUATIONS (or "rank_scatter") `eqn` is, by its
-    primitive and its output's shape and dtype; None for the rest."""
+    primitive and its output's shape and dtype; None for the rest.
+    Operands with a row of the data per row ([R, k]: bins, the row
+    table) and those with the rows along lanes ([k, R]: the channels,
+    the transposed bins) are told apart by where the row count sits."""
     out = eqn.outvars[0].aval
     shape = getattr(out, "shape", ())
-    if not shape or len(shape) > 2 or shape[0] < rows:
+    if not shape or len(shape) > 2 or max(shape) < rows:
         return None
     name, dt = eqn.primitive.name, out.dtype
     if len(shape) == 1:
@@ -312,22 +315,48 @@ def _operand_equation(eqn, rows: int) -> Optional[str]:
         if name == "scatter" and dt == jnp.int32:
             return "rank_scatter"          # partition_rows' inversion
         return None
+    if shape[0] < rows:                    # rows along lanes
+        if name == "transpose" and jnp.issubdtype(dt, jnp.integer):
+            return "bins_transpose"        # _bins_t
+        if shape[0] != 8 or dt != jnp.float32:
+            return None
+        if name == "concatenate" and shape[1] == rows:
+            return "channels_stack"        # _hist_channels' [8, N]
+        if name == "pad" and eqn.invars[0].aval.shape == (8, rows):
+            return "channels_pad"
+        return None
     if name not in ("pad", "concatenate", "convert_element_type"):
         return None
     src = eqn.invars[0].aval
     if name == "pad" and jnp.issubdtype(dt, jnp.integer):
         return "bins_row_pad" if shape[1] == src.shape[1] \
             else "bins_lane_pad"
-    if name == "pad" and dt == jnp.float32:
-        return "channels_pad"
-    if name == "concatenate" and dt == jnp.float32:
-        return "channels_stack"            # _hist_channels' [N, 8]
     if name == "concatenate" and dt == jnp.bfloat16:
         return "table_concat"              # _row_table, its padding row
     if name == "convert_element_type" and dt == jnp.bfloat16 and \
             jnp.issubdtype(src.dtype, jnp.integer):
         return "table_bins"                # _row_table's bin columns
     return None
+
+
+def _id_column(eqn, rows: int) -> bool:
+    """Whether `eqn` makes a per-row scalar a COLUMN or reads one back:
+    [r] -> [r, 1], or [r', k < 128] -> [r], of a 4-byte type at r >=
+    rows. On the TPU an [r, k] array with k < 128 is tiled (8, 128), 512
+    bytes a row, so a node or slot id that crosses a kernel boundary
+    this way costs a hundred times its content; the kernels take and
+    return [1, r] instead."""
+    out = eqn.outvars[0].aval
+    if not eqn.invars or not hasattr(eqn.invars[0], "aval"):
+        return False
+    src = eqn.invars[0].aval
+    oshape, sshape = getattr(out, "shape", ()), getattr(src, "shape", ())
+    if not oshape or oshape[0] < rows or out.dtype.itemsize != 4:
+        return False
+    if len(sshape) == 1 and oshape == (sshape[0], 1):
+        return True                        # x[:, None] into a kernel
+    return len(oshape) == 1 and len(sshape) == 2 and \
+        sshape[0] >= rows and sshape[1] < 128   # out[:n, c]
 
 
 def _sub_jaxprs(eqn):
@@ -366,13 +395,17 @@ def operand_builds(jaxpr, rows: Optional[int] = None) -> dict:
     while_loop's body: where XLA shares nothing with the other passes)
     and for what lies outside them. Kernels are not entered.
 
-    Returns {"per_tree": {"bins_pad", "channels", "row_table"}: builds
-    outside every pass (the channel operand counts 2 in the quantized
-    posture: the exact leaf refit stacks its own), "per_pass": the most
-    OPERAND_EQUATIONS any one pass body holds (0 when every operand is
-    prepared per tree), "tree": the raw counts outside, "passes": the
-    raw counts of each pass body that has any}. Static: read from the
-    trace, no device involved."""
+    Returns {"per_tree": {"bins_pad", "bins_t", "channels",
+    "row_table"}: builds outside every pass (the channel operand counts
+    2 in the quantized posture: the exact leaf refit stacks its own),
+    "per_pass": the most OPERAND_EQUATIONS any one pass body holds (0
+    when every operand is prepared per tree), "tree": the raw counts
+    outside, "passes": the raw counts of each pass body that has any,
+    "id_columns_per_tree" / "id_columns_per_pass": the per-row scalars
+    made or read as lane-padded columns (_id_column) outside every pass
+    and the most in any one pass body (0 and 0 since the kernels take
+    and return them along lanes)}. Static: read from the trace, no
+    device involved."""
     while not hasattr(jaxpr, "eqns"):
         jaxpr = jaxpr.jaxpr
     body = _find_jit(jaxpr, "grow_tree_mxu") or jaxpr
@@ -382,8 +415,14 @@ def operand_builds(jaxpr, rows: Optional[int] = None) -> dict:
     passes = []
 
     def walk(jx, into):
+        # the index operand of an XLA scatter or gather is [r, 1] by
+        # that operation's own signature: not a kernel boundary
+        indices = {id(e.invars[1]) for e in jx.eqns
+                   if e.primitive.name.startswith(("scatter", "gather"))}
         for eqn in jx.eqns:
-            kind = _operand_equation(eqn, rows)
+            kind = _operand_equation(eqn, rows) or (
+                id(eqn.outvars[0]) not in indices and
+                _id_column(eqn, rows) and "id_column")
             if kind:
                 into[kind] = into.get(kind, 0) + 1
             name = eqn.primitive.name
@@ -399,10 +438,14 @@ def operand_builds(jaxpr, rows: Optional[int] = None) -> dict:
     walk(body, tree)
     return {
         "per_tree": {"bins_pad": tree.get("bins_row_pad", 0),
+                     "bins_t": tree.get("bins_transpose", 0),
                      "channels": tree.get("channels_stack", 0),
                      "row_table": tree.get("table_bins", 0)},
         "per_pass": max([sum(c.get(k, 0) for k in OPERAND_EQUATIONS)
                          for c in passes] or [0]),
+        "id_columns_per_tree": tree.get("id_column", 0),
+        "id_columns_per_pass": max([c.get("id_column", 0)
+                                    for c in passes] or [0]),
         "tree": tree, "passes": [c for c in passes if c]}
 
 
@@ -648,7 +691,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     ops = prepare_hist_operands(
         bins, h_grad, h_hess, cnt_weight, double_prec=hist_double_prec,
         quantized=quant, const_hess=ch, lanes="onehot" in forms,
-        channels="onehot" in forms, table="grouped" in forms)
+        channels="onehot" in forms, table="grouped" in forms, route=True)
 
     def hist_cfg(s):
         # empirically tuned on v5e: wider feature chunks while the output
@@ -676,9 +719,10 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # route + per-slot counts in one sweep, then build from the
             # partitioned live rows (grouped) or by the XLA oracle
             rn, rs, cts = route_rows_mxu(
-                ops.bins, row_node, tbl_c, member_c, feat_tbl,
+                None, row_node, tbl_c, member_c, feat_tbl,
                 num_features=nf_packed, emit_counts=True,
-                num_slots=nslots, interpret=interpret)
+                num_slots=nslots, has_cat=hp.has_categorical,
+                operands=ops, interpret=interpret)
             if form == "grouped":
                 h = build_histograms_scatter(
                     None, None, None, None, rs,
@@ -733,11 +777,12 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 efb_range=efb_seg, row_block=rb, const_hess=ch,
                 operands=ops, interpret=interpret)
         else:
-            rn, rs = route_rows_mxu(ops.bins, row_node, tbl_c, member_c,
+            rn, rs = route_rows_mxu(None, row_node, tbl_c, member_c,
                                     feat_tbl, num_features=nf_packed,
+                                    has_cat=hp.has_categorical,
                                     loc_table=None if efb_seg
                                     else loc_tbl, efb_range=efb_seg,
-                                    interpret=interpret)
+                                    operands=ops, interpret=interpret)
             # (the chunked v1 fallback pads to its own selector layout
             # from the plain arguments)
             h = build_histograms_mxu_auto(
@@ -1232,10 +1277,12 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # ---- epilogue: flush routing, prune to best-first, exact refit ----
     # flush the routing of the last pass's splits (sweeps route at the
     # START of a pass, so the final commits have not moved rows yet)
-    row_node, _ = route_rows_mxu(ops.bins, state[1], state[2], state[3],
+    row_node, _ = route_rows_mxu(None, state[1], state[2], state[3],
                                  feat_tbl, num_features=nf_packed,
+                                 has_cat=hp.has_categorical,
                                  loc_table=None if efb_seg else loc_tbl,
-                                 efb_range=efb_seg, interpret=interpret)
+                                 efb_range=efb_seg, operands=ops,
+                                 interpret=interpret)
     tree_out = state[0]
     cmin, cmax = state[6], state[7]
     if over:

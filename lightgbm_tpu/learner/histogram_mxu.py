@@ -24,6 +24,15 @@ every per-row memory op from the growth pass:
   no gathers. Categorical bitset words are carried as two 16-bit halves so
   every table value stays exactly representable in f32.
 
+Per-row scalars (node id, slot id, the channel operand, a looked-up
+value) cross every kernel boundary ALONG LANES: [1, rows], [8, rows].
+An [rows, k] array with k < 128 is tiled (8, 128) on the TPU, 512 bytes
+a row in HBM for 4 bytes of content, and a [nb, 1] column in VMEM is
+nb/8 vector registers with one lane of 128 in use; so the kernels take
+and return these vectors lane-dense and compute on them in that
+orientation ([1, nb] per-row values, [X, nb] per-row table rows, the
+node lookup tbl^T [K, M] x node_oh^T [M, nb]).
+
 HBM traffic per pass: one read of the binned matrix + small blocks;
 flops: nchan * S * N * F * B MACs (bf16; nchan = 5 with double-precision
 sums, 4 with single-bf16 hessians) for the histogram, negligible for
@@ -143,9 +152,8 @@ def _hist_kernel(nb: int, fc: int, b: int, s: int, flane: int,
         # (slot -1); blocks with no active row skip all compute
         @pl.when(block_any_ref[ri] != 0)
         def _():
-            slot = slot_ref[:, 0]                            # [nb] i32
-            iota_s = jax.lax.broadcasted_iota(jnp.int32, (nb, s), 1)
-            slot_oh = (slot[:, None] == iota_s)              # [nb, S] bool
+            iota_s = jax.lax.broadcasted_iota(jnp.int32, (s, nb), 0)
+            slot_oh = (slot_ref[:] == iota_s)                # [S, nb] bool
 
             # chunk-extract without lane slicing: a [flane, fc*B] 0/1
             # selector copies feature ci*fc+j//B into one-hot column space
@@ -162,13 +170,13 @@ def _hist_kernel(nb: int, fc: int, b: int, s: int, flane: int,
             bin_oh = (ext == binidx.astype(jnp.float32)) \
                 .astype(mm_dtype)                            # [nb, fc*B]
 
-            data = data_ref[:]                               # [nb, 8] f32
+            data = data_ref[:]                               # [8, nb] f32
             for c in range(nchan):  # hi/lo pairs + cnt, or g/h/cnt
-                lhs = jnp.where(slot_oh, data[:, c:c + 1],
+                lhs = jnp.where(slot_oh, data[c:c + 1, :],
                                 jnp.float32(0.0)).astype(mm_dtype)
                 part = jax.lax.dot_general(
                     lhs, bin_oh,
-                    dimension_numbers=(((0,), (0,)), ((), ())),
+                    dimension_numbers=(((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)      # [S, fc*B]
                 out_ref[0, c * s:(c + 1) * s, :] += part
 
@@ -187,8 +195,9 @@ def hist_num_channels(double_prec: bool = True, quantized: bool = False,
 
 def _hist_channels(grad, hess, cnt, double_prec: bool,
                    quantized: bool = False, const_hess: float = 0.0):
-    """Channel matrix [N, 8] for the histogram kernels (hi/lo bf16 pairs
-    + count, or grad-hi/lo + single-bf16 hessian + count).
+    """Channel matrix [8, N] (rows along lanes) for the histogram kernels
+    (hi/lo bf16 pairs + count, or grad-hi/lo + single-bf16 hessian +
+    count).
 
     quantized=True: the caller passes stochastically-rounded INTEGER
     gradients/hessians in [-127, 127] (quantize_gradients) — bf16-exact,
@@ -213,12 +222,11 @@ def _hist_channels(grad, hess, cnt, double_prec: bool,
                                             mantissa_bits=7)
             chans = [g_hi, g - g_hi, cnt.astype(jnp.float32)]
         nchan = len(chans)
-        data = jnp.stack(chans + [jnp.zeros_like(g)] * (8 - nchan),
-                         axis=1)
+        data = jnp.stack(chans + [jnp.zeros_like(g)] * (8 - nchan))
         return data, nchan
     if quantized:
         chans = [g, h, cnt.astype(jnp.float32)]
-        data = jnp.stack(chans + [jnp.zeros_like(g)] * 5, axis=1)
+        data = jnp.stack(chans + [jnp.zeros_like(g)] * 5)
         return data, 3
     # reduce_precision (not a bf16 round-trip, which XLA elides under
     # --xla_allow_excess_precision) keeps the hi/lo split honest
@@ -232,8 +240,7 @@ def _hist_channels(grad, hess, cnt, double_prec: bool,
         # smoothed by lambda_l2/min_hessian and tolerates ~2^-9 error
         chans = [g_hi, g - g_hi, h, cnt.astype(jnp.float32)]
     nchan = len(chans)
-    data = jnp.stack(chans + [jnp.zeros_like(g)] * (8 - nchan),
-                     axis=1)                                 # [N, 8]
+    data = jnp.stack(chans + [jnp.zeros_like(g)] * (8 - nchan))  # [8, N]
     return data, nchan
 
 
@@ -247,8 +254,9 @@ class HistOperands(NamedTuple):
     comes with it; rows past it are padding (bins 0, channels 0)."""
     bins: jax.Array                        # [R, fcols], rows padded
     lanes: Optional[jax.Array]             # [R, plane]: + 128-lane pad
-    data: Optional[jax.Array]              # [R, 8] f32 (_hist_channels)
+    data: Optional[jax.Array]              # [8, R] f32 (_hist_channels)
     table: Optional[jax.Array] = None      # [n + 1, W] bf16 (_row_table)
+    bins_t: Optional[jax.Array] = None     # [fsub, R]: bins transposed
 
 
 #: rows of per-tree operands are padded to a multiple of this: every
@@ -256,26 +264,46 @@ class HistOperands(NamedTuple):
 OPERAND_ROW_MULTIPLE = 8192
 
 
-def _pad_rows(x, rows: int, **kw):
-    """x padded along axis 0 to `rows` (no-op when already there)."""
-    extra = rows - x.shape[0]
+def _pad_rows(x, rows: int, axis: int = 0, **kw):
+    """x padded along `axis` to `rows` (no-op when already there)."""
+    extra = rows - x.shape[axis]
     if not extra:
         return x
-    return jnp.pad(x, ((0, extra),) + ((0, 0),) * (x.ndim - 1), **kw)
+    return jnp.pad(x, [(0, extra if a == axis else 0)
+                       for a in range(x.ndim)], **kw)
+
+
+def _lane_row(x, rows: int, **kw):
+    """A per-row vector [n] as the kernels take it: [1, rows] int32,
+    rows along lanes (a bitcast of the padded vector, 4 bytes a row)."""
+    return _pad_rows(x.astype(jnp.int32), rows, **kw)[None, :]
+
+
+def _bins_t(bins: jax.Array, rows: int) -> jax.Array:
+    """The routing kernels' view of the bins: [fsub, rows], a feature
+    per sublane row so that a row's split-feature bin is pulled across
+    sublanes with the row ids along lanes; the feature axis is padded
+    to the dtype's sublane tile (32 rows of uint8). Taken from the
+    bins as they come, not from a padded copy that the row-major
+    operands share: XLA would give that copy the transposed layout and
+    pay a relayout of the 128-lane operand in every pass."""
+    fsub = _round_up(bins.shape[1], 32 // bins.dtype.itemsize)
+    return jnp.pad(bins.T, ((0, fsub - bins.shape[1]),
+                            (0, rows - bins.shape[0])))
 
 
 def _row_table(bins: jax.Array, data: jax.Array, nchan: int) -> jax.Array:
     """Everything the slot-grouped kernel (histogram_pallas) reads of a
     row as ONE bf16 row, so a pass gathers once: the bin columns (byte
     values, exact in bf16), then the channels as the very bf16 operand
-    the one-hot kernels build from `data` (the MXU is fed bf16 either
-    way, so nothing is lost), then a column for the row's slot within
-    its group, which is the only part a pass writes (after the gather;
-    255: none). One extra all-zero, slot-less row at the end stands for
-    padding."""
+    the one-hot kernels build from `data` ([8, n]; the MXU is fed bf16
+    either way, so nothing is lost), then a column for the row's slot
+    within its group, which is the only part a pass writes (after the
+    gather; 255: none). One extra all-zero, slot-less row at the end
+    stands for padding."""
     n = bins.shape[0]
     tab = jnp.concatenate(
-        [bins.astype(jnp.bfloat16), data[:, :nchan].astype(jnp.bfloat16),
+        [bins.astype(jnp.bfloat16), data[:nchan].T.astype(jnp.bfloat16),
          jnp.full((n, 1), 255, jnp.bfloat16)], axis=1)
     pad = jnp.zeros((1, tab.shape[1]), jnp.bfloat16).at[0, -1].set(255)
     return jnp.concatenate([tab, pad])
@@ -285,15 +313,17 @@ def prepare_hist_operands(bins, grad, hess, cnt, *, double_prec=True,
                           quantized=False, const_hess=0.0,
                           row_multiple: int = OPERAND_ROW_MULTIPLE,
                           lanes: bool = False, channels: bool = True,
-                          table: bool = False) -> HistOperands:
+                          table: bool = False,
+                          route: bool = False) -> HistOperands:
     """The per-tree operands of the histogram and routing kernels from
     the binned matrix and one tree's gradients: bins padded in rows to
     `row_multiple` (and, lanes=True, to 128 lanes: the one-hot kernels'
-    block), the channel operand of _hist_channels padded likewise, and
-    (table=True) the slot-grouped build's row table. The grower calls
-    this once per tree and asks for what its static plan uses; a
-    wrapper without `operands=` calls it with its own row block, so the
-    arrays a kernel sees are the same either way."""
+    block), the channel operand of _hist_channels padded likewise,
+    (table=True) the slot-grouped build's row table and (route=True)
+    the transposed bins of the routing kernels. The grower calls this
+    once per tree and asks for what its static plan uses; a wrapper
+    without `operands=` calls it with its own row block, so the arrays
+    a kernel sees are the same either way."""
     rows = _round_up(bins.shape[0], row_multiple)
     bins_p = _pad_rows(bins, rows)
     plane = _round_up(bins.shape[1], 128)
@@ -302,30 +332,35 @@ def prepare_hist_operands(bins, grad, hess, cnt, *, double_prec=True,
         # padded lanes are never sliced by the kernels (j < f); the
         # value only needs to be in range for the int cast
         lanes_p = bins_p if plane == bins.shape[1] else \
-            jnp.pad(bins_p, ((0, 0), (0, plane - bins.shape[1])))
+            jnp.pad(bins, ((0, rows - bins.shape[0]),
+                           (0, plane - bins.shape[1])))
     data = tab = None
     if channels or table:
         data, nchan = _hist_channels(grad, hess, cnt, double_prec,
-                                     quantized, const_hess)  # [N, 8]
+                                     quantized, const_hess)  # [8, N]
         if table:
             tab = _row_table(bins, data, nchan)
-        data = _pad_rows(data, rows) if channels else None
-    return HistOperands(bins_p, lanes_p, data, tab)
+        data = _pad_rows(data, rows, axis=1) if channels else None
+    return HistOperands(bins_p, lanes_p, data, tab,
+                        _bins_t(bins, rows) if route else None)
 
 
 def _kernel_operands(operands: Optional[HistOperands], nb: int, bins,
-                     grad, hess, cnt, **posture) -> HistOperands:
+                     grad, hess, cnt, route: bool = False,
+                     **posture) -> HistOperands:
     """A one-hot kernel's operands at its row block `nb`: the tree's
     (whose rows `nb` divides already, for every block that divides
     OPERAND_ROW_MULTIPLE), else prepared here from the plain arrays."""
     if operands is None:
         return prepare_hist_operands(bins, grad, hess, cnt,
                                      row_multiple=nb, lanes=True,
-                                     **posture)
+                                     route=route, **posture)
     rows = _round_up(operands.bins.shape[0], nb)
-    return operands._replace(bins=_pad_rows(operands.bins, rows),
-                             lanes=_pad_rows(operands.lanes, rows),
-                             data=_pad_rows(operands.data, rows))
+    return operands._replace(
+        bins=_pad_rows(operands.bins, rows),
+        lanes=_pad_rows(operands.lanes, rows),
+        data=_pad_rows(operands.data, rows, axis=1),
+        bins_t=_pad_rows(operands.bins_t, rows, axis=1) if route else None)
 
 
 def quantize_gradients(grad, hess, key, *, pmax_axis=None):
@@ -387,17 +422,26 @@ def _combine_hist(out, *, nchan: int, s: int, f: int, b: int, bmax: int,
                      axis=-1)
 
 
-def _hist_accumulate(hist_ref, slot, bins_i, data, *, nb: int, f: int,
-                     b: int, s: int, nchan: int, mm_dtype, fh: int = 0):
-    """Shared accumulation body of the v2/fused kernels: slot-masked
-    channel operand, per-feature-group bin one-hots, accumulating dots.
-    slot: [nb, 1] i32 (-1 = no slot); bins_i: [nb, lanes] i32 (fh > 0:
-    4-bit packed columns, feature j at column j % fh, nibble j // fh)."""
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (nb, s), 1)
-    slot_oh = (slot == iota_s)                               # [nb, S] bool
-    lhs = jnp.concatenate(
-        [jnp.where(slot_oh, data[:, c:c + 1], jnp.float32(0.0))
-         for c in range(nchan)], axis=1).astype(mm_dtype)    # [nb, C*S]
+def _slot_lhs(slot, data, *, nb: int, s: int, nchan: int, mm_dtype):
+    """The histogram dots' left operand, transposed: [C*S, nb], row
+    c*S + k holding channel c of the rows in slot k and 0 elsewhere.
+    slot: [1, nb] i32 (-1 = no slot); data: [8, nb] f32 channels."""
+    iota_s = jax.lax.broadcasted_iota(jnp.int32, (s, nb), 0)
+    slot_oh = (slot == iota_s)                               # [S, nb] bool
+    return jnp.concatenate(
+        [jnp.where(slot_oh, data[c:c + 1, :], jnp.float32(0.0))
+         for c in range(nchan)], axis=0).astype(mm_dtype)    # [C*S, nb]
+
+
+def _hist_accumulate(hist_ref, lhs, bins_i, *, nb: int, f: int, b: int,
+                     mm_dtype, fh: int = 0, rows_axis: int = 1):
+    """Shared accumulation body of the v2/fused/grouped kernels:
+    per-feature-group bin one-hots, accumulating dots against the
+    slot-masked channel operand `lhs`, whose rows run along `rows_axis`:
+    [C*S, nb] from _slot_lhs (a plain matmul), or [nb, C*S] where slot
+    and channels come out of a row-major table (histogram_pallas).
+    bins_i: [nb, lanes] i32 (fh > 0: 4-bit packed columns, feature j at
+    column j % fh, nibble j // fh)."""
     iota_b = jax.lax.broadcasted_iota(jnp.int32, (nb, b), 1)
     for gj in range(0, f, _FGROUP):
         js = range(gj, min(gj + _FGROUP, f))
@@ -407,33 +451,66 @@ def _hist_accumulate(hist_ref, slot, bins_i, data, *, nb: int, f: int,
             [(c == iota_b) for c in cols],
             axis=1).astype(mm_dtype)                         # [nb, G*B]
         part = jax.lax.dot_general(
-            lhs, oh, dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs, oh, dimension_numbers=(((rows_axis,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # [C*S, G*B]
         hist_ref[0, :, gj * b:(gj + len(js)) * b] += part
 
 
-def _route_decide(node, gath, bins_blk, ftbl, memb, *, nb: int,
-                  fh: int = 0, loc=None, efb_range: bool = False):
+def _node_lookup(node, tbl_t, m: int, nb: int):
+    """The node-table row of every row, across lanes: (node_oh^T
+    [M, nb] bf16, tbl^T [K, M] x node_oh^T -> [K, nb] f32). bf16
+    operands are exact: the node table was designed around base-256
+    digits (every entry <= 256), and one-hot columns make the f32
+    accumulation a pure selection."""
+    iota_m = jax.lax.broadcasted_iota(jnp.int32, (m, nb), 0)
+    node_oh = (node == iota_m).astype(jnp.bfloat16)          # [M, nb]
+    gath = jax.lax.dot_general(
+        tbl_t.astype(jnp.bfloat16), node_oh,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [K, nb]
+    return node_oh, gath
+
+
+def _own_slot(gath):
+    """[1, nb] f32: the node's own next-pass slot (unsplit nodes)."""
+    return (gath[_COL_SLOT_Q:_COL_SLOT_Q + 1, :] * 256.0 +
+            gath[_COL_SLOT_R:_COL_SLOT_R + 1, :])
+
+
+def _route_decide(node, node_oh, gath, bins_blk, ftbl, member_t, *,
+                  nb: int, fh: int = 0, loc_t=None,
+                  efb_range: bool = False):
     """Shared split-decision math of the route/fused kernels: numerical
     thresholds, NaN-bin default direction, categorical bitset membership.
-    gath: [nb, K] node-table row per row; bins_blk: [nb, lanes] f32
-    (fh > 0: 4-bit packed byte columns, feature j at column j % fh,
-    nibble j // fh — byte values <= 255 stay f32-exact, the nibble is
-    recovered arithmetically after the column pick);
-    loc is not None: bins_blk holds EFB bundle columns; the split
+    Every per-row value is a [1, nb] lane vector and every per-row table
+    row an [X, nb] block, reduced across sublanes.
+    node: [1, nb] i32; node_oh, gath: _node_lookup's ([M, nb] bf16,
+    [K, nb] f32 node-table row per row); bins_blk: [fsub, nb] f32, the
+    transposed bins (fh > 0: 4-bit packed byte rows, feature j at row
+    j % fh, nibble j // fh — byte values <= 255 stay f32-exact, the
+    nibble is recovered arithmetically after the row pick);
+    ftbl: [Fp, 2] f32 per-feature (num_bins, missing_is_nan);
+    loc_t is not None: bins_blk holds EFB bundle columns; the split
     feature's bundle column (_COL_BCOL) is selected, then the original
-    local bin is decoded through the [F, Bb] loc_table (efb.py: default
-    bin folded in for out-of-segment positions) — the decision math
-    below then runs on original bins unchanged;
-    memb: [nb, Bpad] categorical left-set membership or None when the
-    table holds no categorical splits. Returns (new node ids, next-pass
-    kernel slot) as [nb, 1] f32 pairs — rows of unsplit nodes keep
-    their node and their own slot; routed rows take the chosen child's
-    slot, carried in the PARENT row (_COL_SLOTL/_COL_SLOTR) so no
-    second node-table lookup is needed."""
+    local bin is decoded through the [Bb, Fp] transposed loc_table
+    (efb.py: default bin folded in for out-of-segment positions) — the
+    decision math below then runs on original bins unchanged;
+    member_t: [Bpad, M] categorical left-set membership per bin, or None
+    when the table holds no categorical splits. Returns (new node ids,
+    next-pass kernel slot) as [1, nb] f32 pairs — rows of unsplit nodes
+    keep their node and their own slot; routed rows take the chosen
+    child's slot, carried in the PARENT row (_COL_SLOTL/_COL_SLOTR) so
+    no second node-table lookup is needed."""
 
     def col(c):
-        return gath[:, c:c + 1]                              # [nb, 1] f32
+        return gath[c:c + 1, :]                              # [1, nb] f32
+
+    def pull(idx, table):
+        # table[idx[r], r] per row r: a one-hot select down the sublanes
+        iota = jax.lax.broadcasted_iota(
+            jnp.int32, table.shape, 0).astype(jnp.float32)
+        return jnp.sum(jnp.where(idx == iota, table, 0.0), axis=0,
+                       keepdims=True)                        # [1, nb] f32
 
     split = col(_COL_SPLIT)
     pf = col(_COL_FEAT_Q) * 256.0 + col(_COL_FEAT_R)
@@ -455,11 +532,7 @@ def _route_decide(node, gath, bins_blk, ftbl, memb, *, nb: int,
         # original-bin decode, no [rows, F]-wide work — identity columns
         # (dense numerics, categoricals) reduce to the plain bin compare
         # because their segment spans the whole column.
-        bcol = col(_COL_BCOL_Q) * 256.0 + col(_COL_BCOL_R)
-        iota_c = jax.lax.broadcasted_iota(
-            jnp.int32, (nb, bins_blk.shape[1]), 1).astype(jnp.float32)
-        pval = jnp.sum(jnp.where(bcol == iota_c, bins_blk, 0.0),
-                       axis=1, keepdims=True)                # [nb, 1] f32
+        pval = pull(col(_COL_BCOL_Q) * 256.0 + col(_COL_BCOL_R), bins_blk)
         seg_lo = col(_COL_SEG_LO)
         seg_hi = col(_COL_SEG_HI)
         pt = col(_COL_PT)
@@ -472,71 +545,58 @@ def _route_decide(node, gath, bins_blk, ftbl, memb, *, nb: int,
             (one - in_f) * dbl
         binv = pval  # categorical columns are identity: bin == position
     else:
-        if fh:
-            # packed storage: pick byte column pf % fh, then the nibble
-            fh_f = jnp.float32(fh)
-            is_hi = jnp.where(pf >= fh_f, jnp.float32(1.0),
-                              jnp.float32(0.0))
-            pcol = pf - is_hi * fh_f
-            iota_p = jax.lax.broadcasted_iota(
-                jnp.int32, (nb, bins_blk.shape[1]), 1).astype(jnp.float32)
-            pbyte = jnp.sum(jnp.where(pcol == iota_p, bins_blk, 0.0),
-                            axis=1, keepdims=True)           # [nb, 1] f32
-            hi_val = jnp.floor(pbyte * jnp.float32(1.0 / 16.0))
-            binv = is_hi * hi_val + (1.0 - is_hi) * \
-                (pbyte - 16.0 * hi_val)
         # per-feature flags (num_bins, missing_is_nan) index the
         # full-width feature table regardless of bin packing/bundling
         iota_f = jax.lax.broadcasted_iota(
-            jnp.int32, (nb, ftbl.shape[0]), 1).astype(jnp.float32)
-        feat_oh = (pf == iota_f)                             # [nb, L] bool
-        if loc is not None:
+            jnp.int32, (ftbl.shape[0], nb), 0).astype(jnp.float32)
+        feat_oh = (pf == iota_f)                             # [Fp, nb] bool
+        if loc_t is not None:
             # EFB expansion fallback: bundle-column select, then
-            # original-local-bin decode through the [F, Bb] loc table
-            bcol = col(_COL_BCOL_Q) * 256.0 + col(_COL_BCOL_R)
-            iota_c = jax.lax.broadcasted_iota(
-                jnp.int32, (nb, bins_blk.shape[1]), 1).astype(jnp.float32)
-            pval = jnp.sum(jnp.where(bcol == iota_c, bins_blk, 0.0),
-                           axis=1, keepdims=True)            # [nb, 1] f32
-            # loc row of the split feature: one MXU dot (entries <= 256,
-            # bf16-exact; 0/1 lhs keeps the accumulation a selection)
+            # original-local-bin decode through the loc table: the loc
+            # row of the split feature is one MXU dot (entries <= 256,
+            # bf16-exact; the 0/1 operand keeps the accumulation a
+            # selection)
+            pval = pull(col(_COL_BCOL_Q) * 256.0 + col(_COL_BCOL_R),
+                        bins_blk)
             loc_row = jax.lax.dot_general(
-                feat_oh.astype(jnp.bfloat16), loc.astype(jnp.bfloat16),
+                loc_t.astype(jnp.bfloat16), feat_oh.astype(jnp.bfloat16),
                 dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [nb, Bb]
-            iota_b2 = jax.lax.broadcasted_iota(
-                jnp.int32, (nb, loc.shape[1]), 1).astype(jnp.float32)
-            binv = jnp.sum(jnp.where(pval == iota_b2, loc_row, 0.0),
-                           axis=1, keepdims=True)            # [nb, 1] f32
-        elif not fh:
-            # column select: binv[r] = bins[r, pf[r]] via one-hot sum
-            binv = jnp.sum(jnp.where(feat_oh, bins_blk, 0.0), axis=1,
-                           keepdims=True)                    # [nb, 1] f32
-        nbins = jnp.sum(jnp.where(feat_oh, ftbl[:, 0][None, :], 0.0),
-                        axis=1, keepdims=True)
-        mnan = jnp.sum(jnp.where(feat_oh, ftbl[:, 1][None, :], 0.0),
-                       axis=1, keepdims=True) > 0.5
+                preferred_element_type=jnp.float32)          # [Bb, nb]
+            binv = pull(pval, loc_row)
+        elif fh:
+            # packed storage: pick byte row pf % fh, then the nibble
+            fh_f = jnp.float32(fh)
+            is_hi = jnp.where(pf >= fh_f, one, zero)
+            pbyte = pull(pf - is_hi * fh_f, bins_blk)
+            hi_val = jnp.floor(pbyte * jnp.float32(1.0 / 16.0))
+            binv = is_hi * hi_val + (1.0 - is_hi) * \
+                (pbyte - 16.0 * hi_val)
+        else:
+            binv = pull(pf, bins_blk)       # bins_t[pf[r], r]
+        nbins = jnp.sum(jnp.where(feat_oh, ftbl[:, 0:1], 0.0), axis=0,
+                        keepdims=True)
+        mnan = jnp.sum(jnp.where(feat_oh, ftbl[:, 1:2], 0.0), axis=0,
+                       keepdims=True) > 0.5
         is_nan_bin = mnan & (binv == nbins - 1.0)
         nan_f = jnp.where(is_nan_bin, one, zero)
         le_f = jnp.where(binv <= thr, one, zero)
         num_gl = nan_f * defl_f + (one - nan_f) * le_f
-    if memb is not None:
+    if member_t is not None:
         iscat_f = jnp.where(col(_COL_ISCAT) > 0.5, one, zero)
-        bpad = memb.shape[1]
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (nb, bpad), 1) \
-            .astype(jnp.float32)
-        in_set_f = jnp.sum(jnp.where(binv == iota_b, memb, 0.0),
-                           axis=1, keepdims=True)            # 0/1 f32
+        memb = jax.lax.dot_general(
+            member_t.astype(jnp.bfloat16), node_oh,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [Bpad, nb]
+        in_set_f = pull(binv, memb)                          # 0/1 f32
         gl_f = iscat_f * in_set_f + (one - iscat_f) * num_gl
     else:
         gl_f = num_gl
     child_f = gl_f * child_l + (one - gl_f) * child_r
-    slot_own = col(_COL_SLOT_Q) * 256.0 + col(_COL_SLOT_R)
     slot_l = col(_COL_SLOTL_Q) * 256.0 + col(_COL_SLOTL_R)
     slot_r = col(_COL_SLOTR_Q) * 256.0 + col(_COL_SLOTR_R)
     slot_child = gl_f * slot_l + (one - gl_f) * slot_r
     new_node = split * child_f + (one - split) * node.astype(jnp.float32)
-    new_slot = split * slot_child + (one - split) * slot_own
+    new_slot = split * slot_child + (one - split) * _own_slot(gath)
     return new_node, new_slot
 
 
@@ -557,10 +617,11 @@ def _hist_kernel_v2(nb: int, f: int, b: int, s: int,
 
         @pl.when(block_any_ref[ri] != 0)
         def _():
-            _hist_accumulate(out_ref, slot_ref[:],
-                             bins_ref[:].astype(jnp.int32), data_ref[:],
-                             nb=nb, f=f, b=b, s=s, nchan=nchan,
-                             mm_dtype=mm_dtype, fh=fh)
+            _hist_accumulate(
+                out_ref, _slot_lhs(slot_ref[:], data_ref[:], nb=nb, s=s,
+                                   nchan=nchan, mm_dtype=mm_dtype),
+                bins_ref[:].astype(jnp.int32), nb=nb, f=f, b=b,
+                mm_dtype=mm_dtype, fh=fh)
 
     return kernel
 
@@ -611,8 +672,7 @@ def build_histograms_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     data, nchan = _hist_channels(grad, hess, cnt, double_prec, quantized,
                                  const_hess)
-    if npad:
-        data = jnp.pad(data, ((0, npad), (0, 0)))
+    data = _pad_rows(data, n + npad, axis=1)
 
     nblocks = (n + npad) // nb
     block_any = jnp.max(
@@ -621,9 +681,9 @@ def build_histograms_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         num_scalar_prefetch=1,
         grid=(nchunks, nblocks),
         in_specs=[
-            pl.BlockSpec((nb, 1), lambda ci, ri, ba: (ri, 0)),
+            pl.BlockSpec((1, nb), lambda ci, ri, ba: (0, ri)),
             pl.BlockSpec((nb, flane), lambda ci, ri, ba: (ri, 0)),
-            pl.BlockSpec((nb, 8), lambda ci, ri, ba: (ri, 0)),
+            pl.BlockSpec((8, nb), lambda ci, ri, ba: (0, ri)),
         ],
         out_specs=pl.BlockSpec((1, nchan * s, fc * b),
                                lambda ci, ri, ba: (ci, 0, 0)))
@@ -636,7 +696,7 @@ def build_histograms_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                                        jnp.float32),
         interpret=interpret,
         **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(block_any, slot[:, None], bins, data)
+    )(block_any, slot[None, :], bins, data)
 
     # [nchunks, C*S, fc*B] -> [S, F, B, 3]
     out = out.reshape(nchunks, nchan, s, fc, b)
@@ -734,9 +794,9 @@ def build_histograms_mxu_v2(bins: jax.Array, grad: jax.Array,
     flane = ops.lanes.shape[1]
     nchan = hist_num_channels(double_prec, quantized, const_hess)
 
-    slot = jnp.where((row_slot < 0) | (row_slot >= s), -1, row_slot) \
-        .astype(jnp.int32)
-    slot = _pad_rows(slot, rows, constant_values=-1)
+    slot = _lane_row(
+        jnp.where((row_slot < 0) | (row_slot >= s), -1, row_slot), rows,
+        constant_values=-1)
 
     nblocks = rows // nb
     block_any = jnp.max(
@@ -745,9 +805,9 @@ def build_histograms_mxu_v2(bins: jax.Array, grad: jax.Array,
         num_scalar_prefetch=1,
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((nb, 1), lambda ri, ba: (ri, 0)),
+            pl.BlockSpec((1, nb), lambda ri, ba: (0, ri)),
             pl.BlockSpec((nb, flane), lambda ri, ba: (ri, 0)),
-            pl.BlockSpec((nb, 8), lambda ri, ba: (ri, 0)),
+            pl.BlockSpec((8, nb), lambda ri, ba: (0, ri)),
         ],
         out_specs=pl.BlockSpec((1, nchan * s, f * b),
                                lambda ri, ba: (0, 0, 0)))
@@ -759,7 +819,7 @@ def build_histograms_mxu_v2(bins: jax.Array, grad: jax.Array,
         out_shape=jax.ShapeDtypeStruct((1, nchan * s, f * b), jnp.float32),
         interpret=interpret,
         **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(block_any, slot[:, None], ops.lanes, ops.data)
+    )(block_any, slot, ops.lanes, ops.data)
 
     return _combine_hist(out, nchan=nchan, s=s, f=f, b=b, bmax=bmax,
                          double_prec=double_prec, const_hess=const_hess)
@@ -793,8 +853,34 @@ def build_histograms_mxu_auto(bins, grad, hess, cnt, row_slot, *,
         **v1_cfg)
 
 
-def _fused_kernel(nb: int, f: int, flane: int, b: int, s: int, m: int,
-                  bpad: int, mm_dtype=jnp.bfloat16, nchan: int = 5,
+def _route_tables(tbl, member, feat_tbl, loc_table, *, f_route: int,
+                  has_efb: bool):
+    """The small per-pass tables as the routing kernels take them, rows
+    of a table along lanes: tbl^T [Kp, M] (Kp: _N_COLS padded to the
+    bf16 sublane tile), member^T [Bpad, M], feat_tbl padded or cut to
+    [fp, 2] (fp: the routed feature count, a contraction dim under
+    decode-mode EFB and lane-aligned there; cut only in range mode,
+    where the table is unused), loc_table^T [Bb8, fp] (a placeholder
+    without decode-mode EFB)."""
+    fp = _round_up(f_route, 128 if has_efb else 8)
+    tbl_t = _pad_rows(tbl.T, _round_up(tbl.shape[1], 16))
+    feat_tbl = _pad_rows(feat_tbl[:fp], fp)
+    if has_efb:
+        loc_t = _pad_rows(_pad_rows(loc_table.astype(jnp.float32).T,
+                                    _round_up(loc_table.shape[1], 8)),
+                          fp, axis=1)
+    else:
+        loc_t = jnp.zeros((8, 128), jnp.float32)  # unused placeholder
+    return tbl_t, member.T, feat_tbl, loc_t
+
+
+def _whole(x):
+    """BlockSpec of a small table that every grid step reads whole."""
+    return pl.BlockSpec(x.shape, lambda ri: (0, 0))
+
+
+def _fused_kernel(nb: int, f: int, b: int, s: int, m: int,
+                  mm_dtype=jnp.bfloat16, nchan: int = 5,
                   has_cat: bool = True, fh: int = 0,
                   has_efb: bool = False, efb_range: bool = False):
     """Route + histogram in ONE sweep over the binned matrix: advance each
@@ -805,69 +891,49 @@ def _fused_kernel(nb: int, f: int, flane: int, b: int, s: int, m: int,
     in unsplit nodes skip everything except the cheap node-table gather
     (their rows keep their node and contribute to no slot)."""
 
-    def kernel(node_ref, bins_ref, data_ref, tbl_ref, member_ref,
-               feat_tbl_ref, loc_ref, hist_ref, node_out_ref):
+    def kernel(node_ref, bins_ref, bins_t_ref, data_ref, tbl_ref,
+               member_ref, feat_tbl_ref, loc_ref, hist_ref, node_out_ref,
+               slot_ref):
         ri = pl.program_id(0)
 
         @pl.when(ri == 0)
         def _():
             hist_ref[0] = jnp.zeros_like(hist_ref[0])
 
-        node = node_ref[:]                                   # [nb, 1] i32
-        iota_m = jax.lax.broadcasted_iota(jnp.int32, (nb, m), 1)
-        # bf16 operands: the node table was designed around base-256
-        # digits (every entry <= 256, bf16-exact), and one-hot rows make
-        # the f32 accumulation a pure selection — bit-exact at 1/4 the
-        # MXU passes of an f32 dot
-        node_oh = (node == iota_m).astype(jnp.bfloat16)      # [nb, M]
-        tbl_bf = tbl_ref[:].astype(jnp.bfloat16)
-        gath = jax.lax.dot_general(
-            node_oh, tbl_bf, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [nb, K]
-
-        def col(c):
-            return gath[:, c:c + 1]                          # [nb, 1] f32
-
-        split = col(_COL_SPLIT)
-        block_has_split = jnp.sum(split) > 0.5
-
-        def own_slot():
-            return (gath[:, _COL_SLOT_Q:_COL_SLOT_Q + 1] * 256.0 +
-                    gath[:, _COL_SLOT_R:_COL_SLOT_R + 1])
+        node = node_ref[:]                                   # [1, nb] i32
+        node_oh, gath = _node_lookup(node, tbl_ref[:], m, nb)
+        block_has_split = jnp.sum(gath[_COL_SPLIT:_COL_SPLIT + 1, :]) > 0.5
 
         @pl.when(~block_has_split)
         def _():
-            node_out_ref[:] = jnp.concatenate(
-                [node.astype(jnp.float32), own_slot()],
-                axis=1).astype(jnp.int32)
+            node_out_ref[:] = node
+            slot_ref[:] = _own_slot(gath).astype(jnp.int32)
 
         @pl.when(block_has_split)
         def _():
-            memb = jax.lax.dot_general(
-                node_oh, member_ref[:].astype(jnp.bfloat16),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) if has_cat else None
             new_node_f, new_slot_f = _route_decide(
-                node, gath, bins_ref[:].astype(jnp.int32)
-                .astype(jnp.float32), feat_tbl_ref[:], memb,
+                node, node_oh, gath, bins_t_ref[:].astype(jnp.int32)
+                .astype(jnp.float32), feat_tbl_ref[:],
+                member_ref[:] if has_cat else None,
                 nb=nb, fh=fh, efb_range=efb_range,
-                loc=loc_ref[:] if has_efb else None)
-            node_out_ref[:] = jnp.concatenate(
-                [new_node_f, new_slot_f], axis=1).astype(jnp.int32)
+                loc_t=loc_ref[:] if has_efb else None)
+            node_out_ref[:] = new_node_f.astype(jnp.int32)
+            slot_ref[:] = new_slot_f.astype(jnp.int32)
 
         # ---- histogram accumulation for every block holding slotted
         # rows. The slot rode along with the route (child slots live in
         # the parent's table row; unsplit nodes carry their own slot,
         # -1 outside the initial root pass) — no second node lookup.
-        slot = node_out_ref[:, 1:2]                          # [nb, 1] i32
+        slot = slot_ref[:]                                   # [1, nb] i32
         block_any_slot = jnp.max(slot) >= 0
 
         @pl.when(block_any_slot)
         def _():
-            _hist_accumulate(hist_ref, slot,
-                             bins_ref[:].astype(jnp.int32), data_ref[:],
-                             nb=nb, f=f, b=b, s=s, nchan=nchan,
-                             mm_dtype=mm_dtype, fh=fh)
+            _hist_accumulate(
+                hist_ref, _slot_lhs(slot, data_ref[:], nb=nb, s=s,
+                                    nchan=nchan, mm_dtype=mm_dtype),
+                bins_ref[:].astype(jnp.int32), nb=nb, f=f, b=b,
+                mm_dtype=mm_dtype, fh=fh)
 
     return kernel
 
@@ -907,14 +973,18 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     the bundle-RANGE table columns instead — no loc table, no
     original-feature-width work (pack_route_tables efb=).
 
-    operands: the tree's prepared bins and channel operand
-    (prepare_hist_operands(lanes=True)), read instead of bins, grad,
-    hess and cnt; row_node keeps the true row count, and padding rows
-    ride along at node 0 with all-zero channels."""
+    operands: the tree's prepared bins (row-major and transposed) and
+    channel operand (prepare_hist_operands(lanes=True, route=True)),
+    read instead of bins, grad, hess and cnt; row_node keeps the true
+    row count, and padding rows ride along at node 0 with all-zero
+    channels.
+
+    The node ids go in and come out as [1, rows] (rows along lanes); the
+    new slot of a row never leaves VMEM."""
     nb = row_block
     ops = _kernel_operands(operands, nb, bins, grad, hess, cnt,
-                           double_prec=double_prec, quantized=quantized,
-                           const_hess=const_hess)
+                           route=True, double_prec=double_prec,
+                           quantized=quantized, const_hess=const_hess)
     n = row_node.shape[0]
     rows, fcols = ops.bins.shape
     has_efb = loc_table is not None and not efb_range
@@ -923,58 +993,44 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     s = num_slots
     b = ((bmax + 127) // 128) * 128
     plane = ops.lanes.shape[1]               # bins block width (packed)
-    # route tables are original-feature-indexed under decode-mode EFB
-    f_route = loc_table.shape[0] if has_efb else f
-    flane = ((f_route + 127) // 128) * 128
-    m, kcols = tbl.shape
-    bpad = member.shape[1]
+    fsub = ops.bins_t.shape[0]
+    m = tbl.shape[0]
     nchan = hist_num_channels(double_prec, quantized, const_hess)
-
-    row_node = _pad_rows(row_node, rows)
-    if feat_tbl.shape[0] > flane:
-        feat_tbl = feat_tbl[:flane]   # range mode: ftbl is unused
-    elif feat_tbl.shape[0] < flane:
-        feat_tbl = jnp.pad(feat_tbl,
-                           ((0, flane - feat_tbl.shape[0]), (0, 0)))
-    if has_efb:
-        bb_lane = ((loc_table.shape[1] + 127) // 128) * 128
-        loc = jnp.pad(loc_table.astype(jnp.float32),
-                      ((0, flane - loc_table.shape[0]),
-                       (0, bb_lane - loc_table.shape[1])))
-    else:
-        loc = jnp.zeros((8, 128), jnp.float32)  # unused placeholder
+    # route tables are original-feature-indexed under decode-mode EFB
+    tbl_t, member_t, feat_tbl, loc_t = _route_tables(
+        tbl, member, feat_tbl, loc_table, has_efb=has_efb,
+        f_route=loc_table.shape[0] if has_efb else f)
 
     nblocks = rows // nb
     hist, node_out = pl.pallas_call(
-        _fused_kernel(nb, f, flane, b, s, m, bpad, nchan=nchan,
-                      has_cat=has_cat, fh=fh, has_efb=has_efb,
-                      efb_range=efb_range),
+        _fused_kernel(nb, f, b, s, m, nchan=nchan, has_cat=has_cat, fh=fh,
+                      has_efb=has_efb, efb_range=efb_range),
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((nb, 1), lambda ri: (ri, 0)),
+            pl.BlockSpec((1, nb), lambda ri: (0, ri)),
             pl.BlockSpec((nb, plane), lambda ri: (ri, 0)),
-            pl.BlockSpec((nb, 8), lambda ri: (ri, 0)),
-            pl.BlockSpec((m, kcols), lambda ri: (0, 0)),
-            pl.BlockSpec((m, bpad), lambda ri: (0, 0)),
-            pl.BlockSpec((flane, 2), lambda ri: (0, 0)),
-            pl.BlockSpec(loc.shape, lambda ri: (0, 0)),
+            pl.BlockSpec((fsub, nb), lambda ri: (0, ri)),
+            pl.BlockSpec((8, nb), lambda ri: (0, ri)),
+            _whole(tbl_t), _whole(member_t), _whole(feat_tbl),
+            _whole(loc_t),
         ],
         out_specs=[
             pl.BlockSpec((1, nchan * s, f * b), lambda ri: (0, 0, 0)),
-            pl.BlockSpec((nb, 2), lambda ri: (ri, 0)),
+            pl.BlockSpec((1, nb), lambda ri: (0, ri)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, nchan * s, f * b), jnp.float32),
-            jax.ShapeDtypeStruct((rows, 2), jnp.int32),
+            jax.ShapeDtypeStruct((1, rows), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((1, nb), jnp.int32)],
         interpret=interpret,
         **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(row_node.astype(jnp.int32)[:, None], ops.lanes, ops.data, tbl,
-      member, feat_tbl, loc)
+    )(_lane_row(row_node, rows), ops.lanes, ops.bins_t, ops.data, tbl_t,
+      member_t, feat_tbl, loc_t)
 
     h3 = _combine_hist(hist, nchan=nchan, s=s, f=f, b=b, bmax=bmax,
                        double_prec=double_prec, const_hess=const_hess)
-    return h3, node_out[:n, 0]
+    return h3, node_out[0, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -1084,53 +1140,39 @@ def pack_route_tables(split_mask, feat, thr, default_left, is_cat,
     return tbl, member
 
 
-def _route_kernel(nb: int, f: int, m: int, bpad: int,
-                  has_cat: bool = True, fh: int = 0,
+def _route_kernel(nb: int, m: int, has_cat: bool = True, fh: int = 0,
                   has_efb: bool = False, efb_range: bool = False,
                   counts_spad: int = 0, valid_rows: int = 0):
-    # every per-row quantity is kept [nb, 1] (2-D) — Mosaic lowers 2-D
-    # masks/selects cleanly where 1-D bool vectors hit unsupported i1 casts.
+    # every per-row quantity is kept [1, nb] (2-D, rows along lanes) —
+    # Mosaic lowers 2-D masks/selects cleanly where 1-D bool vectors hit
+    # unsupported i1 casts.
     # counts_spad > 0: the same sweep also accumulates per-slot row counts
-    # ([8, counts_spad] f32 broadcast rows, exact to 2^24) — routing AND
-    # the partition metadata of the scatter histogram in one pass.
-    def kernel(node_ref, bins_ref, tbl_ref, member_ref, feat_tbl_ref,
-               loc_ref, out_ref, *counts_refs):
-        node = node_ref[:]                                   # [nb, 1] i32
-        iota_m = jax.lax.broadcasted_iota(jnp.int32, (nb, m), 1)
-        # bf16 operands are exact here: table entries <= 256 by design
-        node_oh = (node == iota_m).astype(jnp.bfloat16)      # [nb, M]
-        tbl_bf = tbl_ref[:].astype(jnp.bfloat16)
-        gath = jax.lax.dot_general(
-            node_oh, tbl_bf, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [nb, K]
+    # ([counts_spad, 128] f32 broadcast columns, exact to 2^24) — routing
+    # AND the partition metadata of the scatter histogram in one pass.
+    def kernel(node_ref, bins_t_ref, tbl_ref, member_ref, feat_tbl_ref,
+               loc_ref, node_out_ref, slot_out_ref, *counts_refs):
+        node = node_ref[:]                                   # [1, nb] i32
+        node_oh, gath = _node_lookup(node, tbl_ref[:], m, nb)
 
         # blocks whose rows all sit in unsplit nodes (the common case in
         # late, narrow growth passes) skip the decision math entirely
-        block_has_split = jnp.sum(gath[:, _COL_SPLIT:_COL_SPLIT + 1]) > 0.5
-
-        def own_slot():
-            return (gath[:, _COL_SLOT_Q:_COL_SLOT_Q + 1] * 256.0 +
-                    gath[:, _COL_SLOT_R:_COL_SLOT_R + 1])
+        block_has_split = jnp.sum(gath[_COL_SPLIT:_COL_SPLIT + 1, :]) > 0.5
 
         @pl.when(~block_has_split)
         def _():
-            node_f = node.astype(jnp.float32)
-            out_ref[:] = jnp.concatenate(
-                [node_f, own_slot()], axis=1).astype(jnp.int32)
+            node_out_ref[:] = node
+            slot_out_ref[:] = _own_slot(gath).astype(jnp.int32)
 
         @pl.when(block_has_split)
         def _():
-            memb = jax.lax.dot_general(
-                node_oh, member_ref[:].astype(jnp.bfloat16),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) if has_cat else None
             new_node_f, new_slot_f = _route_decide(
-                node, gath, bins_ref[:].astype(jnp.int32)
-                .astype(jnp.float32), feat_tbl_ref[:], memb,
+                node, node_oh, gath, bins_t_ref[:].astype(jnp.int32)
+                .astype(jnp.float32), feat_tbl_ref[:],
+                member_ref[:] if has_cat else None,
                 nb=nb, fh=fh, efb_range=efb_range,
-                loc=loc_ref[:] if has_efb else None)
-            out_ref[:] = jnp.concatenate(
-                [new_node_f, new_slot_f], axis=1).astype(jnp.int32)
+                loc_t=loc_ref[:] if has_efb else None)
+            node_out_ref[:] = new_node_f.astype(jnp.int32)
+            slot_out_ref[:] = new_slot_f.astype(jnp.int32)
 
         if counts_spad:
             counts_ref, = counts_refs
@@ -1142,35 +1184,42 @@ def _route_kernel(nb: int, f: int, m: int, bpad: int,
 
             # read the routed slot back (same trick as the fused kernel:
             # child slots rode along in the parent's table row)
-            slot = out_ref[:, 1:2]                       # [nb, 1] i32
+            slot = slot_out_ref[:]                       # [1, nb] i32
             iota_s = jax.lax.broadcasted_iota(
-                jnp.int32, (nb, counts_spad), 1)
+                jnp.int32, (counts_spad, nb), 0)
             rid = ri * nb + jax.lax.broadcasted_iota(
-                jnp.int32, (nb, counts_spad), 0)
+                jnp.int32, (1, nb), 1)
             ohc = ((slot == iota_s) & (rid < valid_rows)) \
-                .astype(jnp.float32)                     # [nb, spad]
-            csum = jnp.sum(ohc, axis=0, keepdims=True)   # [1, spad]
-            counts_ref[0] += jnp.broadcast_to(csum, (8, counts_spad))
+                .astype(jnp.float32)                     # [spad, nb]
+            csum = jnp.sum(ohc, axis=1, keepdims=True)   # [spad, 1]
+            counts_ref[0] += jnp.broadcast_to(csum, (counts_spad, 128))
 
     return kernel
 
 
 @functools.partial(
     jax.jit, static_argnames=("row_block", "num_features", "efb_range",
-                              "interpret", "emit_counts", "num_slots"))
+                              "interpret", "emit_counts", "num_slots",
+                              "has_cat"))
 def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
                    member: jax.Array, feat_tbl: jax.Array, *,
                    row_block: int = 0, num_features: int = 0,
+                   has_cat: bool = True,
                    loc_table=None, efb_range: bool = False,
                    emit_counts: bool = False, num_slots: int = 0,
+                   operands: Optional[HistOperands] = None,
                    interpret: bool = False):
     """Advance rows one level and emit (new row_node, new row_slot).
 
-    bins may come padded in rows (HistOperands.bins: once per tree
-    instead of once per call): the true row count is row_node's, and
-    rows past it route nowhere and are not counted.
+    operands: the tree's prepared operands (prepare_hist_operands(
+    route=True)), whose transposed bins are read instead of `bins`
+    (once per tree instead of a transpose per call): the true row count
+    is row_node's, and rows past it route nowhere and are not counted.
     tbl/member: from pack_route_tables (M_pad lane-friendly).
     feat_tbl: [F, 2] f32: (num_bins, missing_is_nan).
+    has_cat=False (as in fused_route_hist_mxu): no node of the table
+    decides on a categorical, so the membership lookup, a [Bpad, M] x
+    [M, nb] matmul that is most of a wide call's MXU work, is skipped.
     num_features > 0 marks `bins` as 4-bit packed (pack_bins_4bit).
     loc_table marks `bins` as EFB bundle columns decoded per row
     (expansion fallback); efb_range=True instead runs the bundle-RANGE
@@ -1185,20 +1234,26 @@ def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
     pass. Returns (row_node, row_slot, counts) instead of 2-tuple.
     Both partition implementations consume these counts for the
     groups' block starts; neither counts again.
+
+    Node and slot ids cross the kernel as [1, rows] vectors (rows along
+    lanes), 4 bytes a row each.
     """
     n = row_node.shape[0]
-    fcols = bins.shape[1]
+    if operands is not None:
+        bins_p, bins_t = operands.bins, operands.bins_t
+    else:
+        bins_p, bins_t = bins, None
+    fcols = bins_p.shape[1]
     has_efb = loc_table is not None and not efb_range
     f = num_features if num_features else fcols
-    f_route = loc_table.shape[0] if has_efb else f
     fh = fcols if num_features else 0
-    m, kcols = tbl.shape
+    m = tbl.shape[0]
     # row_block 0 = auto: 4096 (fewer grid steps) at the flagship
-    # shape, but ONLY for narrow-input dense routing — wide tables ([nb, m] one-hot), wide
-    # bins blocks, and both EFB modes (the expansion decode OOM'd at a
-    # 2048 block on 250-column bundles, grower_mxu.py sweep note) keep
-    # the conservative 1024. The table cutoff is m <= 1024: the one-hot
-    # route tensor is [nb, m] f32, so nb=4096 at m=2048 is a 32 MiB
+    # shape, but ONLY for narrow-input dense routing — wide tables
+    # ([m, nb] one-hot), wide bins blocks, and both EFB modes (the
+    # expansion decode OOM'd at a 2048 block on 250-column bundles,
+    # grower_mxu.py sweep note) keep the conservative 1024. The table cutoff is m <= 1024: the one-hot
+    # route tensor is [m, nb] f32, so nb=4096 at m=2048 is a 32 MiB
     # operand (4096*2048*4) before the matmul's output — past the
     # ~16 MiB/core VMEM budget the measured case (m=896, 14 MiB) stays
     # inside, and exactly the fits_v2-style bound the histogram side
@@ -1210,50 +1265,44 @@ def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
         nb = 4096
     else:
         nb = 1024
-    bpad = member.shape[1]
-    rows = _round_up(bins.shape[0], nb)
-    bins = _pad_rows(bins, rows)
-    row_node = _pad_rows(row_node, rows)
-    if feat_tbl.shape[0] > f_route:
-        feat_tbl = feat_tbl[:f_route]  # range mode: ftbl is unused
-    elif feat_tbl.shape[0] < f_route:
-        feat_tbl = jnp.pad(feat_tbl,
-                           ((0, f_route - feat_tbl.shape[0]), (0, 0)))
-    loc = loc_table.astype(jnp.float32) if has_efb else \
-        jnp.zeros((8, 128), jnp.float32)
+    rows = _round_up(bins_p.shape[0], nb)
+    if bins_t is None:
+        bins_t = _bins_t(bins_p, rows)
+    bins_t = _pad_rows(bins_t, rows, axis=1)
+    fsub = bins_t.shape[0]
+    tbl_t, member_t, feat_tbl, loc_t = _route_tables(
+        tbl, member, feat_tbl, loc_table, has_efb=has_efb,
+        f_route=loc_table.shape[0] if has_efb else f)
+
     nblocks = rows // nb
     spad = ((max(num_slots, 1) + 127) // 128) * 128 if emit_counts else 0
-    out_specs = pl.BlockSpec((nb, 2), lambda ri: (ri, 0))
-    out_shape = jax.ShapeDtypeStruct((rows, 2), jnp.int32)
+    out_specs = [pl.BlockSpec((1, nb), lambda ri: (0, ri))] * 2
+    out_shape = [jax.ShapeDtypeStruct((1, rows), jnp.int32)] * 2
     if emit_counts:
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, 8, spad), lambda ri: (0, 0, 0))]
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((1, 8, spad), jnp.float32)]
+        out_specs.append(pl.BlockSpec((1, spad, 128), lambda ri: (0, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((1, spad, 128), jnp.float32))
     out = pl.pallas_call(
-        _route_kernel(nb, f, m, bpad, fh=fh, has_efb=has_efb,
+        _route_kernel(nb, m, has_cat=has_cat, fh=fh, has_efb=has_efb,
                       efb_range=efb_range, counts_spad=spad,
                       valid_rows=n),
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((nb, 1), lambda ri: (ri, 0)),
-            pl.BlockSpec((nb, fcols), lambda ri: (ri, 0)),
-            pl.BlockSpec((m, kcols), lambda ri: (0, 0)),
-            pl.BlockSpec((m, bpad), lambda ri: (0, 0)),
-            pl.BlockSpec((f_route, 2), lambda ri: (0, 0)),
-            pl.BlockSpec(loc.shape, lambda ri: (0, 0)),
+            pl.BlockSpec((1, nb), lambda ri: (0, ri)),
+            pl.BlockSpec((fsub, nb), lambda ri: (0, ri)),
+            _whole(tbl_t), _whole(member_t), _whole(feat_tbl),
+            _whole(loc_t),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(row_node.astype(jnp.int32)[:, None], bins, tbl, member, feat_tbl,
-      loc)
+    )(_lane_row(row_node, rows), bins_t, tbl_t, member_t, feat_tbl, loc_t)
     if emit_counts:
-        out, counts = out
-        return (out[:n, 0], out[:n, 1],
-                counts[0, 0, :num_slots].astype(jnp.int32))
-    return out[:n, 0], out[:n, 1]
+        node_out, slot_out, counts = out
+        return (node_out[0, :n], slot_out[0, :n],
+                counts[0, :num_slots, 0].astype(jnp.int32))
+    node_out, slot_out = out
+    return node_out[0, :n], slot_out[0, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -1268,16 +1317,14 @@ def _node_sums_kernel(nb: int, m: int):
         def _():
             out_ref[0] = jnp.zeros_like(out_ref[0])
 
-        node = node_ref[:]                                   # [nb, 1] i32
-        iota_m = jax.lax.broadcasted_iota(jnp.int32, (nb, m), 1)
-        # full-f32 contraction: only 8 output columns, so unlike the
+        iota_m = jax.lax.broadcasted_iota(jnp.int32, (m, nb), 0)
+        # full-f32 contraction: only 8 output rows, so unlike the
         # histogram dots this one is cheap enough to keep exact — the
         # "exact leaf refit" contract of node_sums_mxu depends on it
-        oh = (node == iota_m).astype(jnp.float32)            # [nb, M]
-        data = data_ref[:]                                   # [nb, 8] f32
+        oh = (node_ref[:] == iota_m).astype(jnp.float32)     # [M, nb]
         out_ref[0] += jax.lax.dot_general(
-            oh, data, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [M, 8]
+            data_ref[:], oh, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [8, M]
 
     return kernel
 
@@ -1297,26 +1344,23 @@ def node_sums_mxu(row_node: jax.Array, grad: jax.Array, hess: jax.Array,
     n = row_node.shape[0]
     m = _round_up(num_nodes, 128)
     nb = row_block
-    data, _ = _hist_channels(grad, hess, cnt, double_prec=True)
-    npad = (-n) % nb
-    node = row_node.astype(jnp.int32)
-    if npad:
-        node = jnp.pad(node, (0, npad), constant_values=-1)
-        data = jnp.pad(data, ((0, npad), (0, 0)))
+    rows = _round_up(n, nb)
+    data, _ = _hist_channels(grad, hess, cnt, double_prec=True)  # [8, n]
     out = pl.pallas_call(
         _node_sums_kernel(nb, m),
-        grid=((n + npad) // nb,),
+        grid=(rows // nb,),
         in_specs=[
-            pl.BlockSpec((nb, 1), lambda ri: (ri, 0)),
-            pl.BlockSpec((nb, 8), lambda ri: (ri, 0)),
+            pl.BlockSpec((1, nb), lambda ri: (0, ri)),
+            pl.BlockSpec((8, nb), lambda ri: (0, ri)),
         ],
-        out_specs=pl.BlockSpec((1, m, 8), lambda ri: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, m, 8), jnp.float32),
+        out_specs=pl.BlockSpec((1, 8, m), lambda ri: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, 8, m), jnp.float32),
         interpret=interpret,
         **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(node[:, None], data)[0, :num_nodes]
-    return jnp.stack([out[:, 0] + out[:, 1], out[:, 2] + out[:, 3],
-                      out[:, 4]], axis=-1)                   # [M, 3]
+    )(_lane_row(row_node, rows, constant_values=-1),
+      _pad_rows(data, rows, axis=1))[0, :, :num_nodes]
+    return jnp.stack([out[0] + out[1], out[2] + out[3], out[4]],
+                     axis=-1)                                # [M, 3]
 
 
 # ---------------------------------------------------------------------------
@@ -1325,16 +1369,15 @@ def node_sums_mxu(row_node: jax.Array, grad: jax.Array, hess: jax.Array,
 
 def _values_kernel(nb: int, m: int):
     def kernel(node_ref, tbl_ref, out_ref):
-        node = node_ref[:]                                   # [nb, 1] i32
-        iota_m = jax.lax.broadcasted_iota(jnp.int32, (nb, m), 1)
-        node_oh = (node == iota_m).astype(jnp.float32)
+        iota_m = jax.lax.broadcasted_iota(jnp.int32, (m, nb), 0)
+        node_oh = (node_ref[:] == iota_m).astype(jnp.float32)  # [M, nb]
         # the MXU truncates f32 operands to bf16, so the table carries a
-        # (hi, lo) split; summing the two product columns restores ~f32
+        # (hi, lo) split; summing the two product rows restores ~f32
         # accuracy (boosting scores drift and stall trees otherwise)
         got = jax.lax.dot_general(
-            node_oh, tbl_ref[:], dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [nb, 2]
-        out_ref[:] = got[:, 0:1] + got[:, 1:2]
+            tbl_ref[:], node_oh, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [8, nb]
+        out_ref[:] = got[0:1, :] + got[1:2, :]
 
     return kernel
 
@@ -1346,8 +1389,8 @@ def node_values_mxu(row_node: jax.Array, values: jax.Array, *,
     """values[row_node] without a gather: [N] <- [M] table via one-hot
     matmul (score updates, reference score_updater.hpp:21-110).
     row_block 0 = auto: 8192 (fewer grid steps) at the common table
-    sizes; narrower for very wide tables (the [nb, m] f32 one-hot
-    lives in VMEM)."""
+    sizes; narrower for very wide tables (the [m, nb] f32 one-hot
+    lives in VMEM). Node ids in and values out are [1, rows] vectors."""
     n = row_node.shape[0]
     m1 = values.shape[0]
     m = _round_up(m1, 128)
@@ -1359,25 +1402,20 @@ def node_values_mxu(row_node: jax.Array, values: jax.Array, *,
     v = values.astype(jnp.float32)
     v = jnp.where(jnp.isfinite(v), v, 0.0)
     v_hi = jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
-    tbl = jnp.stack([v_hi, v - v_hi], axis=1)                # [m1, 2]
-    if m > m1:
-        tbl = jnp.pad(tbl, ((0, m - m1), (0, 0)))
+    tbl_t = _pad_rows(_pad_rows(jnp.stack([v_hi, v - v_hi]), 8), m,
+                      axis=1)                                # [8, m]
     nb = row_block
-    npad = (-n) % nb
-    node = row_node.astype(jnp.int32)
-    if npad:
-        node = jnp.pad(node, (0, npad))
+    rows = _round_up(n, nb)
     out = pl.pallas_call(
         _values_kernel(nb, m),
-        grid=((n + npad) // nb,),
+        grid=(rows // nb,),
         in_specs=[
-            pl.BlockSpec((nb, 1), lambda ri: (ri, 0)),
-            pl.BlockSpec((m, 2), lambda ri: (0, 0)),
+            pl.BlockSpec((1, nb), lambda ri: (0, ri)),
+            pl.BlockSpec((8, m), lambda ri: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((nb, 1), lambda ri: (ri, 0)),
-        out_shape=jax.ShapeDtypeStruct((n + npad, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, nb), lambda ri: (0, ri)),
+        out_shape=jax.ShapeDtypeStruct((1, rows), jnp.float32),
         interpret=interpret,
         **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
-    )(node[:, None], tbl)
-    return out[:n, 0]
-
+    )(_lane_row(row_node, rows), tbl_t)
+    return out[0, :n]

@@ -229,11 +229,6 @@ def partition_rows(row_slot: jax.Array, *, num_slots: int, row_block: int,
     n = row_slot.shape[0]
     s, nb = num_slots, row_block
     ng = -(-s // group)
-    # one dense copy for the readers below: row_slot arrives as a column
-    # sliced out of the route kernel's lane-padded [rows, 2] output, and
-    # XLA would fuse that slice into every one of them (the mask, the
-    # group, the slot within it), each re-reading 512 bytes a row
-    row_slot = jax.lax.optimization_barrier(row_slot)
     live = (row_slot >= 0) & (row_slot < s)
     grp = jnp.where(live, row_slot // group, -1).astype(jnp.int32)
     slot_local = row_slot % group
@@ -338,13 +333,19 @@ def _grouped_kernel(nb: int, f: int, b: int, sg: int, nchan: int,
         @pl.when(i < used_ref[0])
         def _():
             tab = tab_ref[:].astype(jnp.float32)             # [Nb, W]
-            _hist_accumulate(
-                out_ref,
-                tab[:, fcols + nchan:fcols + nchan + 1].astype(jnp.int32),
-                tab[:, :fcols].astype(jnp.int32),
-                tab[:, fcols:fcols + nchan],
-                nb=nb, f=f, b=b, s=sg, nchan=nchan, mm_dtype=mm_dtype,
-                fh=fh)
+            # slot and channels come out of the row-major table, so the
+            # slot-masked channel operand is built row-major too and
+            # contracted over its rows
+            slot = tab[:, fcols + nchan:fcols + nchan + 1].astype(jnp.int32)
+            slot_oh = slot == jax.lax.broadcasted_iota(
+                jnp.int32, (nb, sg), 1)                      # [Nb, Sg] bool
+            lhs = jnp.concatenate(
+                [jnp.where(slot_oh, tab[:, fcols + c:fcols + c + 1],
+                           jnp.float32(0.0)) for c in range(nchan)],
+                axis=1).astype(mm_dtype)                     # [Nb, C*Sg]
+            _hist_accumulate(out_ref, lhs, tab[:, :fcols].astype(jnp.int32),
+                             nb=nb, f=f, b=b, mm_dtype=mm_dtype, fh=fh,
+                             rows_axis=0)
 
     return kernel
 

@@ -210,7 +210,8 @@ class ObservabilityRegistry:
         and the bridge built slot-grouped (the fixup body runs as often
         as a tree needs, so it is listed and not counted). Once the
         growth program has been traced, `operand_builds_per_tree`
-        ({bins_pad, channels, row_table}) and `operand_builds_per_pass`
+        ({bins_pad, bins_t, channels, row_table}) and
+        `operand_builds_per_pass`
         say where that program builds its row-sized kernel operands
         (grower_mxu.operand_builds: counted in the trace). The strings
         ride the JSON snapshot/bench tail; the Prometheus exporter

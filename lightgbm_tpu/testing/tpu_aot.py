@@ -97,33 +97,62 @@ def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
     def hist_scatter(b, g, h, c, sl):
         return build_histograms_scatter(b, g, h, c, sl, **hist_kw)
 
-    def fused_route_hist(b, g, h, c, node, t, mem, ft):
-        return hm.fused_route_hist_mxu(b, g, h, c, node, t, mem, ft,
-                                       has_cat=False, **hist_kw)
-
-    def route_rows(b, node, t, mem, ft):
-        return hm.route_rows_mxu(b, node, t, mem, ft, emit_counts=True,
-                                 num_slots=s)
-
     def node_sums(node, g, h, c):
         return hm.node_sums_mxu(node, g, h, c, num_nodes=2 * s)
 
     def node_values(node, vals):
         return hm.node_values_mxu(node, vals)
 
-    return {
+    sites = {
         "build_histograms_mxu": (hist_v1, [bins, vec, vec, vec, ivec]),
         "build_histograms_mxu_v2": (hist_v2, [bins, vec, vec, vec, ivec]),
         "build_histograms_scatter": (hist_scatter,
                                      [bins, vec, vec, vec, ivec]),
-        "fused_route_hist_mxu": (fused_route_hist,
-                                 [bins, vec, vec, vec, ivec, tbl, member,
-                                  feat_tbl]),
-        "route_rows_mxu": (route_rows, [bins, ivec, tbl, member, feat_tbl]),
         "node_sums_mxu": (node_sums, [ivec, vec, vec, vec]),
         "node_values_mxu": (node_values, [ivec, _sds((2 * s,),
                                                      jnp.float32)]),
     }
+
+    # the routing kernels, one site per variant of _route_decide: what
+    # differs between variants is static (the bins' storage, the tables)
+    fh = (f + 1) // 2
+    f_orig, bb = 4 * f, min(bmax, 64)      # EFB: 4 features a bundle
+    variants = {
+        "": (bins, feat_tbl, {}),
+        "_categorical": (bins, feat_tbl, dict(has_cat=True)),
+        "_packed4": (_sds((n, fh), jnp.uint8), feat_tbl,
+                     dict(num_features=f)),
+        "_efb_decode": (bins, _sds((f_orig, 2), jnp.float32),
+                        dict(loc_table=_sds((f_orig, bb), jnp.int32))),
+        "_efb_range": (bins, _sds((f_orig, 2), jnp.float32),
+                       dict(efb_range=True)),
+    }
+    for tag, (vbins, vft, kw) in variants.items():
+        loc = kw.pop("loc_table", None)
+        extra = [] if loc is None else [loc]
+        route_kw = {"has_cat": False, **kw}
+        fused_kw = {**hist_kw, **route_kw}
+        if "num_features" in kw:
+            fused_kw["bmax"] = 15
+        # EFB keeps the 1024 block in both modes (grower_mxu.sweep)
+        block = dict(row_block=1024) if "efb" in tag else {}
+
+        def fused(b, g, h, c, node, t, mem, ft, *loc_, kw=fused_kw,
+                  block=block):
+            return hm.fused_route_hist_mxu(
+                b, g, h, c, node, t, mem, ft, **block, **kw,
+                **(dict(loc_table=loc_[0]) if loc_ else {}))
+
+        def route(b, node, t, mem, ft, *loc_, kw=route_kw):
+            return hm.route_rows_mxu(
+                b, node, t, mem, ft, emit_counts=True, num_slots=s, **kw,
+                **(dict(loc_table=loc_[0]) if loc_ else {}))
+
+        sites["fused_route_hist_mxu" + tag] = (
+            fused, [vbins, vec, vec, vec, ivec, tbl, member, vft] + extra)
+        sites["route_rows_mxu" + tag] = (
+            route, [vbins, ivec, tbl, member, vft] + extra)
+    return sites
 
 
 def _synthetic(rows: int, features: int, seed: int = 17):

@@ -516,6 +516,7 @@ class TestOperandsPreparedOncePerTree:
         tree = built["tree"]
         assert tree.get("bins_row_pad", 0) == 1
         assert tree.get("bins_lane_pad", 0) == int("onehot" in forms)
+        assert tree.get("bins_transpose", 0) == 1
         assert tree.get("channels_stack", 0) == 1 + quant
         assert tree.get("channels_pad", 0) == \
             int("onehot" in forms) + quant
@@ -523,8 +524,13 @@ class TestOperandsPreparedOncePerTree:
         assert tree.get("table_bins", 0) == int("grouped" in forms)
         assert tree.get("table_concat", 0) == 2 * int("grouped" in forms)
         assert built["per_tree"] == {
-            "bins_pad": 1, "channels": 1 + quant,
+            "bins_pad": 1, "bins_t": 1, "channels": 1 + quant,
             "row_table": int("grouped" in forms)}
+        # no per-row scalar is made or read as a lane-padded column:
+        # node and slot ids cross every kernel boundary along lanes
+        assert built["id_columns_per_tree"] == 0
+        assert built["id_columns_per_pass"] == 0
+        assert "id_column" not in tree
         # the scatter that inverts the rank stays: one a grouped pass
         scatters = [b["rank_scatter"] for b in built["passes"]]
         assert scatters == [1] * forms.count("grouped")
@@ -579,16 +585,59 @@ class TestOperandsPreparedOncePerTree:
         snap = registry.hist_backend_snapshot()
         assert snap["operand_builds_per_pass"] == 0
         assert snap["operand_builds_per_tree"] == {
-            "bins_pad": 1, "channels": 1, "row_table": 0}
+            "bins_pad": 1, "bins_t": 1, "channels": 1, "row_table": 0}
         assert snap["operand_builds_per_tree"] == \
             g._operand_builds["per_tree"]
         attrs = [sp["attrs"] for sp in registry.trace.spans()
                  if sp["name"] == "boosting.build_program"][-1]
         assert attrs["operand_builds_per_pass"] == 0
         assert attrs["operand_builds_per_tree"] == \
-            "bins_pad:1,channels:1,row_table:0"
+            "bins_pad:1,bins_t:1,channels:1,row_table:0"
+        assert attrs["id_columns_per_tree"] == 0
+        assert attrs["id_columns_per_pass"] == 0
         assert "lightgbm_tpu_hist_backend_operand_builds_per_pass 0" \
             in registry.prometheus_text()
+
+
+def test_id_columns_counts_the_parents_idiom():
+    # the negative case of id_columns_per_tree / _per_pass == 0 above:
+    # what the kernels' wrappers did before the ids rode the lanes (an
+    # [R] vector made an [R, 1] column on the way in, columns cut out of
+    # an [R, 2] result on the way out) is what the counter finds, per
+    # tree and per pass; the lane form ([1, R]) reads zero
+    from lightgbm_tpu.learner import grower_mxu as gm
+    rows = 4096
+
+    def kernel_with_columns(node_col):               # [R, 1] -> [R, 2]
+        return jnp.concatenate([node_col, node_col + 1], axis=1)
+
+    def column_form(bins, row_node):
+        def one_pass(rn):
+            out = kernel_with_columns(rn.astype(jnp.int32)[:, None])
+            return out[:rows - 8, 0], out[:rows - 8, 1]
+        rn, rs = jax.lax.cond(bins[0, 0] > 0, one_pass,
+                              lambda rn: (rn[:rows - 8], rn[:rows - 8]),
+                              row_node)
+        flushed = kernel_with_columns(row_node[:, None])[:, 0]
+        return rn, rs, flushed
+
+    def lane_form(bins, row_node):
+        def one_pass(rn):
+            out = jnp.concatenate([rn[None, :], rn[None, :] + 1])
+            return out[0, :rows - 8], out[1, :rows - 8]
+        rn, rs = jax.lax.cond(bins[0, 0] > 0, one_pass,
+                              lambda rn: (rn[:rows - 8], rn[:rows - 8]),
+                              row_node)
+        return rn, rs, (row_node[None, :] * 2)[0]
+
+    args = (jax.ShapeDtypeStruct((rows - 8, 4), jnp.uint8),
+            jax.ShapeDtypeStruct((rows,), jnp.int32))
+    built = gm.operand_builds(jax.make_jaxpr(column_form)(*args))
+    assert built["id_columns_per_pass"] == 3      # 1 in, 2 out
+    assert built["id_columns_per_tree"] == 2      # the flush: 1 in, 1 out
+    built = gm.operand_builds(jax.make_jaxpr(lane_form)(*args))
+    assert built["id_columns_per_pass"] == 0
+    assert built["id_columns_per_tree"] == 0
 
 
 def _grow_args(n=1500, f=4, seed=0):

@@ -155,3 +155,182 @@ class TestPackedKernels:
             np.asarray(t_ref.leaf_value)[:nn],
             np.asarray(t_pk.leaf_value)[:nn])
         np.testing.assert_array_equal(np.asarray(r_ref), np.asarray(r_pk))
+
+
+# ----------------------------------------------------------------------
+# routing against NumPy, every variant of _route_decide
+def _forest_level(ds, m1, nslots, seed, with_cat=False):
+    """One random level of a forest over `ds`: which of the m1 nodes
+    split, on what, into which children, and the next-pass slot of every
+    node. Children are arbitrary ids below m1 (routing reads tables, not
+    a tree's shape); ids above 256 exercise the base-256 pairs."""
+    rng = np.random.RandomState(seed)
+    f = ds.num_features
+    nbins = np.asarray(ds.num_bins)
+    is_cat_feat = np.asarray(ds.is_categorical)
+    split = rng.rand(m1) < 0.6
+    feat = rng.randint(0, f, size=m1)
+    if with_cat:   # half of the split nodes decide on a categorical
+        cats = np.flatnonzero(is_cat_feat)
+        pick = rng.rand(m1) < 0.5
+        feat = np.where(pick, cats[rng.randint(0, len(cats), m1)], feat)
+    is_cat = is_cat_feat[feat] & split
+    thr = (rng.rand(m1) * np.maximum(nbins[feat] - 1, 1)).astype(np.int64)
+    w_cat = (int(nbins.max()) + 31) // 32
+    bitset = rng.randint(0, 2 ** 31, size=(m1, w_cat)).astype(np.uint32)
+    return dict(
+        split=split, feat=feat, thr=thr, defl=rng.rand(m1) < 0.5,
+        is_cat=is_cat, bitset=bitset,
+        child_l=rng.randint(0, m1, size=m1),
+        child_r=rng.randint(0, m1, size=m1),
+        slot_of=rng.randint(-1, nslots, size=m1),
+        row_node=rng.randint(0, m1, size=ds.num_data))
+
+
+def _numpy_route(bins, lvl, num_bins, missing_is_nan):
+    """(new node, new slot) of every row, one row at a time."""
+    node = lvl["row_node"]
+    out_node, out_slot = node.copy(), lvl["slot_of"][node]
+    for r in np.flatnonzero(lvl["split"][node]):
+        nd = node[r]
+        ft = lvl["feat"][nd]
+        b = int(bins[r, ft])
+        if lvl["is_cat"][nd]:
+            left = bool((lvl["bitset"][nd, b // 32] >> (b % 32)) & 1)
+        elif missing_is_nan[ft] and b == num_bins[ft] - 1:
+            left = bool(lvl["defl"][nd])
+        else:
+            left = b <= lvl["thr"][nd]
+        out_node[r] = lvl["child_l"][nd] if left else lvl["child_r"][nd]
+        out_slot[r] = lvl["slot_of"][out_node[r]]
+    return out_node, out_slot
+
+
+def _routing_case(variant, m_cap, nslots, seed):
+    """(kernel bins, kernel kwargs, tables, feat_tbl, level, oracle)"""
+    from lightgbm_tpu.efb import (build_plan, bundle_matrix,
+                                  make_device_tables)
+    rng = np.random.RandomState(seed)
+    n = 1531                       # no row block divides it
+    efb_dev, kw = None, {}
+    if variant.startswith("efb"):
+        f = 16
+        X = np.zeros((n, f))
+        for g in range(0, f, 8):   # exclusive features: no conflicts
+            X[np.arange(n), rng.randint(g, g + 8, size=n)] = \
+                rng.rand(n) + 0.5
+        X[rng.rand(n) < 0.05, 1] = np.nan
+    else:
+        f = 7
+        X = rng.randn(n, f)
+        X[rng.rand(n) < 0.05, 1] = np.nan
+        if variant == "categorical":
+            X[:, 3] = rng.randint(0, 9, size=n)
+            X[:, 5] = rng.randint(0, 5, size=n)
+    ds = BinnedDataset.from_raw(
+        X.astype(np.float32), Metadata(n, label=np.zeros(n, np.float32)),
+        max_bin=15,
+        categorical_features=[3, 5] if variant == "categorical" else None)
+    bins = np.asarray(ds.bins)
+    num_bins = np.asarray(ds.num_bins)
+    mnan = np.asarray(ds.missing_types == 2)
+    m1 = m_cap - 28                # ids up to ~1000 at m_cap 1024
+    lvl = _forest_level(ds, m1, nslots, seed + 1,
+                        with_cat=variant == "categorical")
+    kbins = jnp.asarray(bins)
+    bcol = None
+    if variant == "packed4":
+        kbins, kw = pack_bins_4bit(kbins), dict(num_features=f)
+    elif variant.startswith("efb"):
+        plan = build_plan(bins, ds.num_bins, ds.default_bins,
+                          np.asarray(ds.is_categorical),
+                          max_bundle_bins=256)
+        assert plan is not None and plan.effective
+        seg = variant == "efb_range"
+        efb_dev = make_device_tables(
+            plan, ds.default_bins,
+            num_bins=ds.num_bins if seg else None,
+            missing_is_nan=mnan if seg else None,
+            is_cat=np.asarray(ds.is_categorical) if seg else None)
+        kbins = jnp.asarray(bundle_matrix(bins, plan))
+        bcol = efb_dev.col_of_feat[jnp.asarray(lvl["feat"])]
+        kw = dict(efb_range=True) if seg else \
+            dict(loc_table=efb_dev.loc_table)
+    tbl, member = pack_route_tables(
+        jnp.asarray(lvl["split"]), jnp.asarray(lvl["feat"], jnp.int32),
+        jnp.asarray(lvl["thr"], jnp.int32), jnp.asarray(lvl["defl"]),
+        jnp.asarray(lvl["is_cat"]), jnp.asarray(lvl["child_l"], jnp.int32),
+        jnp.asarray(lvl["child_r"], jnp.int32),
+        jnp.asarray(lvl["slot_of"], jnp.int32), jnp.asarray(lvl["bitset"]),
+        m_cap, int(num_bins.max()), bcol=bcol, efb=efb_dev)
+    assert tbl.shape[0] == m_cap
+    feat_tbl = jnp.stack([jnp.asarray(num_bins, jnp.float32),
+                          jnp.asarray(mnan, jnp.float32)], axis=1)
+    want = _numpy_route(bins, lvl, num_bins, mnan)
+    return ds, kbins, kw, tbl, member, feat_tbl, lvl, want, efb_dev
+
+
+_VARIANTS = ["plain", "categorical", "packed4", "efb_decode", "efb_range"]
+
+
+class TestRoutingAgainstNumpy:
+    """Node and slot ids after one level, EXACTLY, against a routing
+    written here in NumPy on the original bins: every variant of
+    _route_decide, both table widths, a row count no row block divides.
+    The ids go in and come out of the kernels along lanes; the public
+    contract stays [N] int32 in, [N] int32 out."""
+
+    @pytest.mark.parametrize("emit_counts", [False, True],
+                             ids=["plain_out", "emit_counts"])
+    @pytest.mark.parametrize("m_cap", [128, 1024])
+    @pytest.mark.parametrize("variant", _VARIANTS)
+    def test_route_rows_mxu(self, variant, m_cap, emit_counts):
+        nslots = 40
+        _, kbins, kw, tbl, member, feat_tbl, lvl, want, _ = _routing_case(
+            variant, m_cap, nslots, seed=11)
+        # without categorical splits the membership lookup may be
+        # skipped or kept: both settings run, one per table width
+        out = route_rows_mxu(
+            kbins, jnp.asarray(lvl["row_node"], jnp.int32), tbl, member,
+            feat_tbl, emit_counts=emit_counts, num_slots=nslots,
+            has_cat=variant == "categorical" or m_cap == 128,
+            interpret=True, **kw)
+        assert out[0].shape == out[1].shape == (len(want[0]),)
+        assert out[0].dtype == out[1].dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(out[0]), want[0])
+        np.testing.assert_array_equal(np.asarray(out[1]), want[1])
+        if emit_counts:
+            np.testing.assert_array_equal(
+                np.asarray(out[2]),
+                np.bincount(want[1][want[1] >= 0], minlength=nslots))
+
+    @pytest.mark.parametrize("m_cap", [128, 1024])
+    @pytest.mark.parametrize("variant", _VARIANTS)
+    def test_fused_route_hist_mxu(self, variant, m_cap):
+        nslots = 6
+        ds, kbins, kw, tbl, member, feat_tbl, lvl, want, efb_dev = \
+            _routing_case(variant, m_cap, nslots, seed=23)
+        n = ds.num_data
+        rng = np.random.RandomState(5)
+        g = jnp.asarray(rng.randn(n).astype(np.float32))
+        h = jnp.asarray(rng.rand(n).astype(np.float32))
+        bmax = efb_dev.bundle_bmax if efb_dev is not None \
+            else int(np.asarray(ds.num_bins).max())
+        hist, rn = fused_route_hist_mxu(
+            kbins, g, h, jnp.ones(n, jnp.float32),
+            jnp.asarray(lvl["row_node"], jnp.int32), tbl, member, feat_tbl,
+            num_slots=nslots, bmax=bmax, row_block=1024,
+            has_cat=variant == "categorical", interpret=True, **kw)
+        assert rn.shape == (n,) and rn.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(rn), want[0])
+        # the slot never leaves the kernel: read it from the histogram
+        # it selected, rows per slot and the sums of a feature's bins
+        hist = np.asarray(hist)
+        live = want[1] >= 0
+        np.testing.assert_array_equal(
+            hist[:, 0, :, 2].sum(axis=1),
+            np.bincount(want[1][live], minlength=nslots))
+        gsum = np.zeros(nslots)
+        np.add.at(gsum, want[1][live], np.asarray(g, np.float64)[live])
+        np.testing.assert_allclose(hist[:, 0, :, 0].sum(axis=1), gsum,
+                                   rtol=1e-4, atol=1e-4)
