@@ -83,7 +83,12 @@ def make_lambdarank(n, seed, qsize=20):
     # 5-level relevance by global quantile (label_gain default covers it)
     qs = np.quantile(raw, [0.5, 0.75, 0.9, 0.97])
     y = np.digitize(raw, qs).astype(np.float32)
-    group = np.full(nq, qsize, np.int32)
+    # uneven queries of the same total (a heavy tail, as real query logs
+    # have): equal lengths hid a layout padded to the longest query
+    w = rng.lognormal(0.0, 0.8, nq)
+    group = np.maximum(1, np.floor(w / w.sum() * (n - nq)).astype(np.int32)
+                       + 1)
+    group[np.argmax(group)] += n - group.sum()
     return X, y, group
 
 

@@ -87,8 +87,9 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     """Return run(score, it0, k, sample_keys=None) ->
     (score', stacked TreeArrays).
 
-    The bin matrix and the objective's per-row state (label, weight,
-    ...) enter the compiled program as ARGUMENTS, not as values the
+    The bin matrix, the objective's per-row state (label, weight, ...)
+    and the tables it names in `table_state` (a ranking objective's
+    query buckets) enter the compiled program as ARGUMENTS, not as values the
     trace closes over: a closed-over array is lowered as a literal, so
     the program would carry a second copy of the dataset, and its
     persistent-cache key would change with every dataset of the same
@@ -122,6 +123,10 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
 
     num_data = bins.shape[0]
     row_names, row_arrays = objective_row_state(objective, num_data)
+    # what the objective laid out at init that is not one value a row
+    # (a ranking objective's query buckets): arguments too, by name
+    table_names = tuple(getattr(objective, "table_state", ()))
+    table_arrays = tuple(getattr(objective, n) for n in table_names)
     shrink = jnp.float32(shrinkage)
     interpret = bool(grower_kwargs.get("interpret", False))
 
@@ -144,7 +149,8 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
 
     def body(bins, obj, score, xs):
         it, key = xs
-        grad, hess = obj.get_gradients(score)
+        with jax.named_scope("objective." + getattr(obj, "name", "custom")):
+            grad, hess = obj.get_gradients(score)
         if sample_fn is not None:
             grad, hess, cnt = sample_fn(grad, hess, it, key)
         else:
@@ -175,18 +181,19 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     # result and its fault paths check .is_deleted() before reusing the
     # old buffer — tpulint JIT004 guards the bare-name discipline.
     @functools.partial(jax.jit, donate_argnames=("score",))
-    def program(score, it0, sample_keys, bins, row_state):
+    def program(score, it0, sample_keys, bins, row_state, tables):
         # the block length is sample_keys' leading axis: a static shape,
         # so each distinct length is its own compiled program
         k = sample_keys.shape[0]
         its = jnp.asarray(it0, jnp.int32) + jnp.arange(k, dtype=jnp.int32)
         obj = copy.copy(objective)
-        for name, arr in zip(row_names, row_state):
+        for name, arr in zip(row_names + list(table_names),
+                             row_state + tables):
             setattr(obj, name, arr)
         return jax.lax.scan(functools.partial(body, bins, obj), score,
                             (its, sample_keys))
 
-    operands = (bins, tuple(row_arrays))
+    operands = (bins, tuple(row_arrays), table_arrays)
 
     def arguments(score, it0, *, k: int, sample_keys=None):
         if sample_keys is None:

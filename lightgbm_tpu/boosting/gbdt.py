@@ -693,6 +693,7 @@ class GBDT:
         """[(stage, kernel slots, formulation)] of this booster's growth
         program (grower_mxu.hist_pass_plan); rows are ONE device's."""
         from ..learner.grower_mxu import hist_pass_plan
+        from ..learner.histogram_pallas import hist_columns
         cfg = self.config
         sharded = self._learner.is_parallel
         ndev = int(self.mesh.devices.size) if sharded else 1
@@ -706,7 +707,8 @@ class GBDT:
             quantized_grad=cfg.use_quantized_grad,
             # the sharded learner keeps const-hessian off (its kwargs)
             const_hessian=0.0 if sharded else self._const_hessian(),
-            has_efb=self._efb is not None)
+            has_efb=self._efb is not None,
+            columns=hist_columns(int(self.num_bins_d.shape[0]), self.bmax))
 
     def _mxu_grow_kwargs(self):
         """Static grow_tree_mxu settings — single source shared by the
@@ -1009,8 +1011,12 @@ class GBDT:
         with span("boosting.gradients", iter=it) as sp:
             if gradients is None or hessians is None:
                 init_scores = self._take_initial_bias()
-                gradients, hessians = self.objective.get_gradients(
-                    self.train_score)
+                # the name the fused scan gives the same computation
+                with jax.named_scope(
+                        "objective." + getattr(self.objective, "name",
+                                               "custom")):
+                    gradients, hessians = self.objective.get_gradients(
+                        self.train_score)
         phases.append(sp)
 
         guard = cfg.guard_nonfinite

@@ -45,7 +45,9 @@ from .histogram_mxu import (_round_up, build_histograms_mxu_auto, fits_v2,
                             pack_route_tables, prepare_hist_operands,
                             quantize_gradients, route_rows_mxu,
                             unpack_bins_4bit)
-from .histogram_pallas import build_histograms_scatter, use_grouped
+from .histogram_pallas import (GROUPED_MIN_WIDTH_COLUMNS,
+                               build_histograms_scatter, hist_columns,
+                               use_grouped)
 from .split import (BestSplits, SplitHyperParams, find_best_splits,
                     leaf_gain, leaf_output, _split_gain)
 
@@ -235,7 +237,8 @@ def growth_plan(*, num_leaves: int, overshoot: float = 0.0,
 
 
 def pass_formulation(nslots: int, *, hist_backend: str, nchan: int,
-                     rows: int, has_efb: bool = False) -> str:
+                     rows: int, has_efb: bool = False,
+                     columns: int = GROUPED_MIN_WIDTH_COLUMNS) -> str:
     """Which histogram formulation a pass with `nslots` kernel slots
     uses: "onehot" (histogram_mxu: every row against every slot),
     "grouped" (histogram_pallas: live rows partitioned by slot group,
@@ -248,7 +251,8 @@ def pass_formulation(nslots: int, *, hist_backend: str, nchan: int,
         return "onehot"
     if hist_backend == "scatter":
         return "scatter"
-    if hist_backend == "pallas" or use_grouped(nchan * nslots, rows):
+    if hist_backend == "pallas" or use_grouped(nchan * nslots, rows,
+                                               columns=columns):
         return "grouped"
     return "onehot"
 
@@ -258,12 +262,13 @@ def hist_pass_plan(*, rows: int, num_leaves: int, overshoot: float = 0.0,
                    bridge_gate: float = 0.0, hist_backend: str = "auto",
                    hist_double_prec: bool = True,
                    quantized_grad: bool = False,
-                   const_hessian: float = 0.0, has_efb: bool = False):
+                   const_hessian: float = 0.0, has_efb: bool = False,
+                   columns: int = GROUPED_MIN_WIDTH_COLUMNS):
     """The growth program's histogram passes as [(stage, kernel slots,
     formulation)], from static configuration alone: what sweep() will
     decide at trace time, computed by the same function, so a caller can
     record it without a device sync. `rows` is the row count ONE device
-    holds. Stages: "pass" (the doubling schedule), "bridge", "fixup"
+    holds, `columns` one slot's histogram columns (hist_columns). Stages: "pass" (the doubling schedule), "bridge", "fixup"
     (the while_loop's body, run as often as the tree needs)."""
     plan = growth_plan(num_leaves=num_leaves, overshoot=overshoot,
                        tail_split_cap=tail_split_cap,
@@ -282,7 +287,7 @@ def hist_pass_plan(*, rows: int, num_leaves: int, overshoot: float = 0.0,
                    else plan.s_fix))
     return [(stage, sk, pass_formulation(
         sk, hist_backend=hist_backend, nchan=nchan, rows=rows,
-        has_efb=has_efb)) for stage, sk in stages]
+        has_efb=has_efb, columns=columns)) for stage, sk in stages]
 
 
 # ---------------------------------------------------------------------------
@@ -675,6 +680,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     was_forced0 = jnp.zeros(m1 if use_forced else 1, bool)
 
     nchan = hist_num_channels(hist_double_prec, quant, ch)
+    columns = hist_columns(fk, bk)
 
     # what the kernels read of the rows and that no pass changes (the
     # padded bins, the channel operand, the grouped build's row table)
@@ -687,7 +693,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         tail_split_cap=tail_split_cap, hist_subtraction=hist_subtraction,
         bridge_gate=bridge_gate, hist_backend=hist_backend,
         hist_double_prec=hist_double_prec, quantized_grad=quant,
-        const_hessian=ch, has_efb=efb is not None)}
+        const_hessian=ch, has_efb=efb is not None, columns=columns)}
     ops = prepare_hist_operands(
         bins, h_grad, h_hess, cnt_weight, double_prec=hist_double_prec,
         quantized=quant, const_hess=ch, lanes="onehot" in forms,
@@ -714,7 +720,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             member_c = member_c[:m_cap]
         form = pass_formulation(nslots, hist_backend=hist_backend,
                                 nchan=nchan, rows=n,
-                                has_efb=efb is not None)
+                                has_efb=efb is not None, columns=columns)
         if form != "onehot":
             # route + per-slot counts in one sweep, then build from the
             # partitioned live rows (grouped) or by the XLA oracle

@@ -87,6 +87,18 @@ GROUPED_ROW_BLOCK = 2048
 #: with this constant at 400 is 27.7 ms a tree slower, PERF.md section
 #: 6), while at width 200 the one-hot pass (59 ms) is still the cheaper.
 GROUPED_MIN_WIDTH = 320
+#: ...at the histogram columns (features x bins, the bins padded to a
+#: lane tile) of the shape that derivation was made at: 28 x 256. The
+#: one-hot pass grows with the columns, which are its MXU weight tiles;
+#: of the grouped pass only the gather and the kernel do, its route,
+#: rank and scatter do not see them. At MS LTR's 137 x 256 = 35,072
+#: columns the pass at width 200 costs 186 ms one-hot and 61 grouped
+#: (the same tree with this crossover at 200: 893.8 against 1,018.4 ms,
+#: PERF.md section 6), so the crossover width falls as the columns
+#: grow: by their square root, the gentlest law through the two shapes
+#: measured, which puts it at 145 there (the pass at width 120, one MXU
+#: tile, stays one-hot: not measured). Narrower data keeps 320.
+GROUPED_MIN_WIDTH_COLUMNS = 28 * 256
 
 #: ...and only where the rows outweigh the layout's padding (one block
 #: per group at most) this many times: a padded row costs the gather
@@ -113,15 +125,28 @@ def group_width(nchan: int) -> int:
     return max(1, 128 // nchan)
 
 
-def use_grouped(width: int, rows: int, row_block: int = GROUPED_ROW_BLOCK
-                ) -> bool:
+def hist_columns(num_features: int, bmax: int) -> int:
+    """Columns of one slot's histogram as the kernels lay it out: every
+    feature's bins padded to a lane tile."""
+    return num_features * (-(-bmax // 128) * 128)
+
+
+def grouped_min_width(columns: int) -> float:
+    """The operand width from which a pass is built slot-grouped, at
+    this many histogram columns (see GROUPED_MIN_WIDTH)."""
+    return GROUPED_MIN_WIDTH * min(
+        1.0, (GROUPED_MIN_WIDTH_COLUMNS / max(columns, 1)) ** 0.5)
+
+
+def use_grouped(width: int, rows: int, row_block: int = GROUPED_ROW_BLOCK,
+                columns: int = GROUPED_MIN_WIDTH_COLUMNS) -> bool:
     """Whether a pass whose one-hot operand would be `width` = nchan *
-    slots wide, over `rows` rows, is built slot-grouped. A pure function
-    of static shapes: the same answer on every platform and in every
-    trace of a shape."""
+    slots wide, over `rows` rows and `columns` histogram columns, is
+    built slot-grouped. A pure function of static shapes: the same
+    answer on every platform and in every trace of a shape."""
     groups = -(-width // 128)
     pad_rows = groups * row_block
-    return (width >= GROUPED_MIN_WIDTH and
+    return (width >= grouped_min_width(columns) and
             rows >= GROUPED_MIN_ROWS_PER_PAD * pad_rows and
             rows + pad_rows < _MAX_POSITIONS)
 

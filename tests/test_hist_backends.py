@@ -423,6 +423,33 @@ class TestPassRule:
         assert pass_formulation(sk, **kw) == "grouped"
         assert pass_formulation(sk - 1, **kw) == "onehot"
 
+    @pytest.mark.parametrize("features,bmax,first_grouped", [
+        (28, 255, 72),      # Higgs: the shape the crossover was derived at
+        (28, 63, 72),       # narrower than that: the same 320
+        (137, 255, 40),     # MS LTR: measured, the 200-wide pass grouped
+    ])
+    def test_the_crossover_falls_with_the_columns(self, features, bmax,
+                                                  first_grouped):
+        from lightgbm_tpu.learner import histogram_pallas as hp
+        from lightgbm_tpu.learner.grower_mxu import hist_pass_plan
+        columns = hp.hist_columns(features, bmax)
+        # only these two shapes were measured (PERF.md section 6): what
+        # the law gives between and beyond them is pinned by no test
+        assert hp.grouped_min_width(columns) <= hp.GROUPED_MIN_WIDTH
+        plan = hist_pass_plan(rows=self.ROWS, num_leaves=255,
+                              overshoot=2.0, columns=columns)
+        grouped = [sk for stage, sk, form in plan
+                   if form == "grouped" and stage == "pass"]
+        assert min(grouped) == first_grouped
+        # one threshold: every pass wider than a grouped one is grouped
+        assert all(form == "grouped" for _, sk, form in plan
+                   if sk >= first_grouped)
+        # the default is the derivation's own shape
+        assert hist_pass_plan(rows=self.ROWS, num_leaves=255,
+                              overshoot=2.0) == hist_pass_plan(
+            rows=self.ROWS, num_leaves=255, overshoot=2.0,
+            columns=hp.hist_columns(28, 255))
+
     def test_tiny_data_stays_onehot(self):
         # the layout pads one row block per group at least: where that
         # outweighs the rows, the one-hot kernel keeps the pass
