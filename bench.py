@@ -139,10 +139,10 @@ class _Bench:
 def _pipeline_bench(bench, result):
     """Pipelined-executor record (pipeline/executor.py): train extra
     trees on the already-compiled bench booster through run_pipelined
-    (no valid sets — the overlap under measurement is stacked-tree
-    unpacking against the next block's device compute) and merge the
-    overlap fraction plus per-block host/device wall columns into the
-    JSON record. Keys MERGE like _serve_bench. BENCH_PIPELINE_TREES=0
+    (no valid sets, so the host runs a block ahead) and merge the share
+    of blocks enqueued behind a running one (`overlap_frac`) plus
+    per-block host/device wall columns into the JSON record. Keys
+    MERGE like _serve_bench. BENCH_PIPELINE_TREES=0
     skips (the training headline is unaffected)."""
     n_trees = int(os.environ.get("BENCH_PIPELINE_TREES", 2 * BLOCK_TREES))
     if n_trees <= 0:
@@ -163,7 +163,8 @@ def _pipeline_bench(bench, result):
     print(f"# pipeline detail: {d['blocks']} blocks / "
           f"{d['iterations']} trees, sizes {d['block_sizes']}, "
           f"host ms {d['host_ms']}, device ms {d['device_ms']}, "
-          f"overlap {100.0 * d['overlap_frac']:.1f}%",
+          f"enqueued behind a running block "
+          f"{100.0 * d['overlap_frac']:.1f}%",
           file=sys.stderr)
 
 

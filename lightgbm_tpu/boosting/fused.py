@@ -37,8 +37,9 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["build_fused_train", "stacked_score_traj"]
+__all__ = ["build_fused_train", "split_block", "stacked_score_traj"]
 
 # Score carries are donated (jax.jit donate_argnames): XLA reuses the
 # input buffer for the output instead of allocating a fresh [N] (or
@@ -77,6 +78,21 @@ def stacked_score_traj(stacked, score0, bins, num_bins, missing_is_nan,
         return s, s
 
     return jax.lax.scan(body, score0, stacked)
+
+
+@jax.jit
+def split_block(stacked):
+    """Every tree of a stacked block as TreeArrays of its own, in the
+    order the tree list wants them (iteration-major, class-minor: the
+    leading axes of a stacked field are [k] or [k, num_class]), and
+    the leaf count the stop poll reads: the block's last tree's, the
+    largest over its classes (a model has stalled only if EVERY class
+    has). ONE program per block length and class count, where slicing
+    the fields tree by tree from the host was one program and one
+    index transfer a field a tree (170 a block of ten)."""
+    trees = [jax.tree_util.tree_map(lambda a: a[ix], stacked)
+             for ix in np.ndindex(stacked.num_leaves.shape)]
+    return trees, jnp.max(stacked.num_leaves[-1])
 
 
 def build_fused_train(*, objective, bins, feature_mask_fn,

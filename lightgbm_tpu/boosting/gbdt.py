@@ -82,6 +82,7 @@ class GBDT:
         self.tree_class: List[int] = []
         self.linear_models: List = []           # LinearLeaves or None, per tree
         self._pending_nleaves = None            # device scalar, lagged poll
+        self._earlier_nleaves = None            # the count before it
         self._exact_stop_poll = False
         self._stop_poll_every = 8               # host-sync amortization
         self.models_meta: List[dict] = []       # host-side per-tree info
@@ -1081,12 +1082,7 @@ class GBDT:
                               what="stop_poll"):
                         stop_hint = int(prev) <= 1
                 nleaves = 2
-            pending = tree.num_leaves
-            try:
-                pending.copy_to_host_async()
-            except Exception:
-                pass
-            self._pending_nleaves = pending
+            self._note_nleaves(tree.num_leaves)
             lin = None
             if nleaves > 1:
                 if not stop_hint:
@@ -1423,34 +1419,36 @@ class GBDT:
         return self.finalize_block(self.train_many_dispatch(k))
 
     def finalize_block(self, handle: dict) -> bool:
-        """Second half of train_many: unpack the dispatched block's
-        stacked trees into per-tree views on self.trees. Its only
-        effect is the tree list — scores, RNG, iter_, valid
-        trajectories and the stall poll were already advanced by
-        train_many_dispatch, so the pipelined executor defers this call
-        until the NEXT block has been dispatched.
+        """Second half of train_many: put the dispatched block's trees
+        on self.trees. Its only effect is the tree list — scores, RNG,
+        iter_, valid trajectories and the stall poll were already
+        advanced by train_many_dispatch, so the pipelined executor
+        defers this call until the NEXT block has been dispatched.
 
-        It is host code that makes no explicit sync, but it is not free
-        of the device: every slice is a small device program. On the
-        chip those queue behind the block in flight, so the first
-        `entry.unpack_tree` of a block waits that block out and the
-        rest run with the device idle, about 16 ms a tree (PERF.md
-        section 6). The wall of the `entry.unpack_block` span is left on
-        the handle as `unpack_s`."""
+        It dispatches nothing: the per-tree views were made by the one
+        split program train_many_dispatch enqueued right behind the
+        block (`programs` on the span says how many device programs
+        the unpack itself ran). It WAITS for its own block, and for
+        that block only: the views of block k are ready when block k
+        is, whatever was enqueued after it. That wait (`waited_ms`) is
+        the executor's backpressure: the host runs one block ahead of
+        the device and never two. The span's wall after the wait is
+        left on the handle as `host_s`."""
         if handle["mode"] == "fused":
-            stacked, kcls = handle["stacked"], handle["kcls"]
+            views, kcls = handle.pop("trees"), handle["kcls"]
             it0, k = handle["iter"], handle["k"]
-            with span("entry.unpack_block", iter=it0, k=k) as unpack:
-                for i in range(k):
-                    for c in range(kcls):
-                        with span("entry.unpack_tree", iter=it0, k=k,
-                                  tree=(it0 + i) * kcls + c):
-                            self.trees.append(jax.tree_util.tree_map(
-                                (lambda a: a[i, c]) if kcls > 1
-                                else (lambda a: a[i]), stacked))
-                            self.tree_class.append(c if kcls > 1 else 0)
-                            self.linear_models.append(None)
-            handle["unpack_s"] = unpack.duration
+            with span("entry.unpack_block", iter=it0, k=k,
+                      programs=0) as unpack:
+                jax.block_until_ready(views)
+                ready_at = time.perf_counter()
+                unpack.attrs["waited_ms"] = (ready_at - unpack.start) * 1e3
+                for n, tree in enumerate(views):
+                    with span("entry.unpack_tree", iter=it0, k=k,
+                              tree=it0 * kcls + n):
+                        self.trees.append(tree)
+                        self.tree_class.append(n % kcls)
+                        self.linear_models.append(None)
+            handle["host_s"] = unpack.end - ready_at
             self._obs_close_block(it0, unpack.end)
         return handle["stop"]
 
@@ -1493,10 +1491,13 @@ class GBDT:
         self.trees lags self.iter_ by the fused block.
 
         The split exists for the pipelined executor
-        (pipeline/executor.py): unpacking stacked trees into Tree
-        objects is host-only work with no effect on the next dispatch's
-        inputs, so the executor overlaps it with the next block's
-        device compute."""
+        (pipeline/executor.py): the tree list has no effect on the
+        next dispatch's inputs, so the executor enqueues the next block
+        first and comes for this one's trees while that one runs. This
+        half waits for no block in flight: the fused program, the one
+        split program that makes the block's per-tree views and the
+        valid replay are enqueued, and the stop poll reads a count that
+        is already there."""
         # per-iteration valid-score trajectory of this batch (engine
         # block dispatch evaluates/early-stops from it). EVERY path
         # through this method — fused, per-iteration fallback, stalled —
@@ -1625,6 +1626,12 @@ class GBDT:
                     backoff_ms=cfg.retry_backoff_ms,
                     backoff_max_ms=cfg.retry_backoff_max_ms,
                     retry_on=transient, site="fused_dispatch")
+                # whether this block was enqueued behind a running one
+                # (asks, waits for nothing): the last tree before it
+                # had not reported its leaf count yet
+                prev = self._pending_nleaves
+                dispatch.attrs["in_flight"] = \
+                    prev is not None and not self._is_ready(prev)
         except transient as exc:
             # rewind the RNG stream so the per-iteration fallback draws
             # the IDENTICAL key sequence the fused dispatch consumed —
@@ -1695,30 +1702,52 @@ class GBDT:
                 trajs.append(traj)
             self._fused_valid_traj = trajs
         self.iter_ += k
+        # the block's trees as the tree list wants them, and its last
+        # leaf count: ONE program, enqueued right behind the block, so
+        # finalize_block finds them ready the moment the block is
+        from .fused import split_block
+        views, pending = split_block(model_trees)
         # lagged stall poll (see train_one_iter): a stalled model keeps
-        # producing all-zero trees, so checking the batch's last tree
+        # producing all-zero trees, so checking a batch's last tree
         # roughly every _stop_poll_every ITERATIONS is enough — poll
         # when this batch crossed a poll boundary, whatever its size
-        prev = self._pending_nleaves
         crossed = (self.iter_ // self._stop_poll_every !=
                    (self.iter_ - k) // self._stop_poll_every)
         stop_hint = False
-        if prev is not None and not self._exact_stop_poll and crossed:
-            # the one place this path waits for the device: the LAST
-            # block's final leaf count, normally long on the host
-            with span("entry.wait_device", iter=iter0, k=k,
-                      what="stop_poll"):
-                stop_hint = int(prev) <= 1
-        pending = stacked.num_leaves[k - 1]
-        if kcls > 1:
-            pending = jnp.max(pending)  # stalled only if EVERY class is
+        if not self._exact_stop_poll and crossed:
+            # the newest count that is already there: the last block's
+            # when the host has waited for it (valid sets, train_many),
+            # the one before when the last block is still running. No
+            # block in flight is waited for, so under the pipelined
+            # executor a stalled model trains one more block of
+            # constant trees before the stop is seen
+            seen = next((c for c in (self._pending_nleaves,
+                                     self._earlier_nleaves)
+                         if c is not None and self._is_ready(c)), None)
+            if seen is not None:
+                with span("entry.wait_device", iter=iter0, k=k,
+                          what="stop_poll"):
+                    stop_hint = int(seen) <= 1
+        self._note_nleaves(pending)
+        return {"mode": "fused", "trees": views, "k": k, "kcls": kcls,
+                "stop": stop_hint, "iter": iter0,
+                "in_flight": dispatch.attrs["in_flight"]}
+
+    @staticmethod
+    def _is_ready(arr) -> bool:
+        """Whether a device value has been computed; waits for
+        nothing."""
+        return bool(arr.is_ready())
+
+    def _note_nleaves(self, pending) -> None:
+        """A fresh tree's leaf count (a device scalar) for the lagged
+        stop poll, its copy to the host started now."""
         try:
             pending.copy_to_host_async()
         except Exception:
             pass
+        self._earlier_nleaves = self._pending_nleaves
         self._pending_nleaves = pending
-        return {"mode": "fused", "stacked": model_trees, "k": k,
-                "kcls": kcls, "stop": stop_hint, "iter": iter0}
 
     @staticmethod
     def _add_bias_to_first(stacked: TreeArrays, bias: List[float],

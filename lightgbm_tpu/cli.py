@@ -282,15 +282,17 @@ class Application:
         stats = getattr(getattr(booster, "gbdt", None),
                         "_pipeline_stats", None)
         if stats is not None and stats.blocks:
-            # not an overlap: on the chip the unpacking waits out the
-            # block in flight and then runs with the device idle
-            # (PERF.md section 6), so this share nears 100% whatever
-            # the device hid
+            # the share of blocks the host enqueued while the block
+            # before was still running: the boundaries the device
+            # crossed without waiting for the host (none with valid
+            # sets, whose callbacks wait for every block's metrics)
             Log.info("pipelined executor: %d blocks / %d iterations, "
-                     "tree unpacking (entry.unpack_block) %.1f%% of the "
-                     "block walls, its waits for the device included",
+                     "%.1f%% of the blocks enqueued behind a running "
+                     "one, %.1f ms a block of host work putting trees "
+                     "on the list",
                      stats.blocks, stats.iterations,
-                     100.0 * stats.overlap_frac)
+                     100.0 * stats.overlap_frac,
+                     sum(stats.host_ms) / max(len(stats.host_ms), 1))
         booster.save_model(cfg.output_model)
         Log.info("Finished training, model saved to %s", cfg.output_model)
         if cfg.observe and cfg.observe_trace_file:

@@ -56,9 +56,10 @@ class ObservabilityRegistry:
         self.training = TrainingTelemetry()
         self.compiles = _compile_ledger
         self.mfu = DeviceUtilization()
-        # pipelined-executor aggregates (pipeline/executor.py): how much
-        # of the block walls the overlapped host work covered
-        self._pipeline = {"blocks": 0, "iterations": 0,
+        # pipelined-executor aggregates (pipeline/executor.py): the
+        # blocks enqueued behind a running one, the host's own work in
+        # the unpacking and the block walls
+        self._pipeline = {"blocks": 0, "iterations": 0, "in_flight": 0,
                           "host_seconds": 0.0, "wall_seconds": 0.0}
         # streamed-ingestion aggregates (streaming/loader.py): chunk and
         # byte volume per pass plus the frozen sketch sample size
@@ -154,7 +155,7 @@ class ObservabilityRegistry:
         self.compiles.reset()
         self.mfu.reset()
         with self._lock:
-            self._pipeline = {"blocks": 0, "iterations": 0,
+            self._pipeline = {"blocks": 0, "iterations": 0, "in_flight": 0,
                               "host_seconds": 0.0, "wall_seconds": 0.0}
             self._streaming = {"chunks": 0, "rows": 0, "bytes": 0,
                                "wall_seconds": 0.0, "sample_rows": 0,
@@ -184,9 +185,9 @@ class ObservabilityRegistry:
     def pipeline_snapshot(self) -> Dict:
         with self._lock:
             p = dict(self._pipeline)
-        frac = min(1.0, p["host_seconds"] / p["wall_seconds"]) \
-            if p["wall_seconds"] > 0 else 0.0
+        frac = p["in_flight"] / p["blocks"] if p["blocks"] else 0.0
         return {"blocks": p["blocks"], "iterations": p["iterations"],
+                "in_flight": p["in_flight"],
                 "host_seconds": round(p["host_seconds"], 6),
                 "wall_seconds": round(p["wall_seconds"], 6),
                 "overlap_frac": round(frac, 4)}
@@ -552,20 +553,22 @@ class ObservabilityRegistry:
         if macs:
             self.mfu.add(macs, wall_s, trees)
 
-    def record_pipeline_block(self, k: int, wall_s: float,
-                              host_s: float) -> None:
+    def record_pipeline_block(self, k: int, wall_s: float, host_s: float,
+                              in_flight: bool = False) -> None:
         """One pipelined-executor block, from its `entry.block` span:
         wall_s runs from the dispatch to the end of the metric sync,
-        host_s is the previous block's tree unpacking inside it. On the
-        chip that unpacking does NOT overlap the device (PERF.md section
-        6): its slice programs queue behind the running block, so
-        host_s reads about the block's wall."""
+        host_s is the host's own work unpacking the previous block's
+        trees inside it (its wait for that block left out), in_flight
+        whether the block was enqueued while the one before it was
+        still running. `overlap_frac` of the snapshot is the share of
+        blocks for which it was."""
         if not self.enabled:
             return
         with self._lock:
             p = self._pipeline
             p["blocks"] += 1
             p["iterations"] += int(k)
+            p["in_flight"] += bool(in_flight)
             p["host_seconds"] += float(host_s)
             p["wall_seconds"] += float(wall_s)
 

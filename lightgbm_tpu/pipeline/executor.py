@@ -1,24 +1,33 @@
-"""Double-buffered training loop: host work deferred behind the next
-block's dispatch.
+"""Double-buffered training loop: the host runs one block ahead of the
+device.
 
 The non-pipelined block loop in engine.train alternates strictly:
-dispatch a fused block, sync, unpack its stacked trees, evaluate, run
-callbacks, repeat — the device idles through all host work. This
-executor reorders the same steps around JAX's async dispatch so the
-expensive host step (unpacking K stacked TreeArrays into per-tree
-views) is issued after the NEXT block's dispatch:
+dispatch a fused block, wait, put its trees on the list, evaluate, run
+callbacks, repeat. This executor reorders the same steps around JAX's
+async dispatch so that block k+1 is enqueued while block k runs, and
+the device goes from one block to the next without waiting for the
+host:
 
     entry.block
-      entry.dispatch      block k (async; in GBDT.train_many_dispatch)
+      entry.dispatch      block k (async; in GBDT.train_many_dispatch),
+                          `in_flight`: block k-1 was still running
       entry.unpack_block  finalize block k-1's trees, one
-                          entry.unpack_tree each
+                          entry.unpack_tree each: waits for block k-1
+                          and for nothing else (`waited_ms`)
       entry.sync_metrics  block k's metrics: the explicit sync point
+                          of a run with valid sets
       entry.callbacks     j = 0..b-1 (early stop may raise)
 
-What the chip showed (PERF.md section 6): the unpacking does NOT run
-beside the device. Its ~170 slice programs queue behind the block in
-flight, so the first entry.unpack_tree of a block waits out that block
-and the rest run with the device idle, about 16 ms a tree.
+Between two dispatches the host does nothing that waits for, or queues
+behind, the block in flight. A block's per-tree views come from ONE
+split program enqueued right behind the block (boosting/fused.py
+split_block), so the unpack dispatches nothing and finds them ready
+when its own block is; the lagged stop poll reads the newest leaf count
+that is already there. The unpack's wait for block k-1 is the loop's
+backpressure: the host is one block ahead and never two. A run with
+valid sets waits for every block at entry.sync_metrics, because its
+callbacks decide on that block's metrics: it gains the cheaper unpack
+and not the run-ahead.
 
 Nothing is speculative: block k+1 is never dispatched before block k's
 early-stop decisions, so the executor trains the byte-identical model
@@ -39,6 +48,7 @@ from __future__ import annotations
 import collections
 from typing import Callable, List, Optional
 
+import jax
 import numpy as np
 
 from ..callback import EarlyStopException
@@ -55,28 +65,35 @@ class PipelineStats:
     """Per-run pipeline accounting, attached to the booster's GBDT as
     `_pipeline_stats` unconditionally: a view over the run's
     `entry.block` spans, fed from them block by block (the executor
-    reads no clock of its own). `blocks` and `iterations` are whole-run
-    counts; the per-block lists keep the last 4096 blocks.
+    reads no clock of its own). `blocks`, `iterations` and `in_flight`
+    are whole-run counts; the per-block lists keep the last 4096
+    blocks.
 
     `device_ms` is the host wall from a block's dispatch to the end of
-    its metric sync, `host_ms` the `entry.unpack_block` span inside it.
-    The names date from when the unpacking was thought to overlap the
-    device. On the chip it does not (PERF.md section 6): the unpacking
-    waits out the block in flight, so `host_ms` reads about the block's
-    wall and `overlap_frac` about 1 whatever the device did.
-    bench.py and chip_smoke.py still read the fields under these
-    names."""
+    its metric sync: once the host runs ahead, the time from one
+    dispatch to the next, which the unpack's wait for the block before
+    holds to what the device takes. `host_ms` is the host's own work in
+    the `entry.unpack_block` span inside it, its wait left out.
+    `in_flight` counts the blocks enqueued while the block before them
+    was still running, and `overlap_frac` is their share: the share of
+    block boundaries the device crossed without waiting for the host
+    (the first block of a run has no block before it, and a run with
+    valid sets waits at every boundary). bench.py and chip_smoke.py
+    read the fields under these names."""
 
     _KEEP = 4096
 
     def __init__(self):
         self.blocks = 0
         self.iterations = 0
+        self.in_flight = 0
         self._recent = collections.deque(maxlen=self._KEEP)
 
-    def add(self, k: int, host_ms: float, device_ms: float) -> None:
+    def add(self, k: int, host_ms: float, device_ms: float,
+            in_flight: bool = False) -> None:
         self.blocks += 1
         self.iterations += int(k)
+        self.in_flight += bool(in_flight)
         self._recent.append((int(k), float(host_ms), float(device_ms)))
 
     @property
@@ -85,7 +102,7 @@ class PipelineStats:
 
     @property
     def host_ms(self) -> List[float]:
-        """`entry.unpack_block` wall per block (see the class note)."""
+        """`entry.unpack_block` wall per block, less its wait."""
         return [r[1] for r in self._recent]
 
     @property
@@ -95,17 +112,14 @@ class PipelineStats:
 
     @property
     def overlap_frac(self) -> float:
-        """Unpacking wall over block wall (see the class note: on the
-        chip this is NOT the share of host work the device hid)."""
-        wall = sum(self.device_ms)
-        if wall <= 0:
-            return 0.0
-        return min(1.0, sum(self.host_ms) / wall)
+        """Share of blocks enqueued behind a running one."""
+        return self.in_flight / self.blocks if self.blocks else 0.0
 
     def as_dict(self) -> dict:
         return {
             "blocks": self.blocks,
             "iterations": self.iterations,
+            "in_flight": self.in_flight,
             "block_sizes": self.block_sizes,
             "host_ms": [round(v, 3) for v in self.host_ms],
             "device_ms": [round(v, 3) for v in self.device_ms],
@@ -210,25 +224,45 @@ def run_pipelined(booster, *, start_iter: int, num_boost_round: int,
                         # live device capture: force the async block to
                         # complete inside the trace window (costs the
                         # asynchrony for this one profiled block only)
-                        import jax
                         jax.block_until_ready((handle, traj, mx))
-                # ---- the previous block's trees unpack behind this
-                # block's dispatch (entry.unpack_block, in finalize_block)
-                unpack_s = 0.0
+                # ---- the previous block's trees go on the list behind
+                # this block's dispatch (entry.unpack_block, in
+                # finalize_block): it waits for THAT block, which is
+                # this loop's backpressure
+                host_s = 0.0
                 if pending is not None:
                     booster.finalize_block(pending)
-                    unpack_s = pending.get("unpack_s", 0.0)
+                    host_s = pending.get("host_s", 0.0)
                     pending = None
-                # ---- explicit sync: small metric arrays in device-eval
-                # mode; in host mode the trajectory syncs lazily when the
-                # metrics first touch it below
+                # ---- explicit sync, where callbacks decide on this
+                # block's metrics: small metric arrays in device-eval
+                # mode, the score trajectory itself in host mode
+                synced = has_valid and traj is not None
                 with span("entry.sync_metrics", iter=i, k=b) as sync:
                     mhost = [None if a is None else np.asarray(a)
                              for a in mx] if mx is not None else None
+                    if synced and mx is None:
+                        jax.block_until_ready(traj)
                 wall_s = sync.end - blk.start
-                stats.add(b, unpack_s * 1e3, wall_s * 1e3)
+                in_flight = bool(handle.get("in_flight"))
+                stats.add(b, host_s * 1e3, wall_s * 1e3, in_flight)
                 if _obs.enabled:
-                    _obs.record_pipeline_block(b, wall_s, unpack_s)
+                    _obs.record_pipeline_block(b, wall_s, host_s,
+                                               in_flight)
+                # the scheduler learns where this loop waited for the
+                # block (valid sets; a block that fell back to the
+                # per-iteration path and was worked off inside its
+                # dispatch): there every block costs the host a round
+                # trip that a longer block amortises, and the wall to
+                # the end of the sync is what the block took. Where the
+                # host runs ahead it is fed NOTHING: the device crosses
+                # block boundaries without waiting for the host, so a
+                # longer block would amortise nothing and cost a
+                # program, and the wall of a loop iteration, which is
+                # milliseconds after any sync, says nothing of the
+                # device
+                if synced or handle["mode"] != "fused":
+                    sched.observe(b, wall_s, compiled=was_built)
                 # ---- per-iteration metric/callback protocol (identical
                 # to the engine block loop; early stop decisions gate the
                 # next dispatch, so nothing downstream is speculative)
@@ -238,7 +272,6 @@ def run_pipelined(booster, *, start_iter: int, num_boost_round: int,
                         has_valid, run_callbacks, evlist)
             pending = handle
             i += b
-            sched.observe(b, wall_s, compiled=was_built)
     finally:
         if pending is not None:
             booster.finalize_block(pending)

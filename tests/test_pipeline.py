@@ -119,22 +119,34 @@ class TestAdaptiveBlockScheduler:
 
 class TestPipelineStats:
     def test_overlap_frac_and_dict(self):
+        # overlap_frac: the share of blocks enqueued while the block
+        # before them was still running (the first never is)
         st = PipelineStats()
-        st.add(5, host_ms=30.0, device_ms=100.0)
-        st.add(5, host_ms=20.0, device_ms=100.0)
-        assert st.blocks == 2 and st.iterations == 10
-        assert st.overlap_frac == pytest.approx(0.25)
+        st.add(5, host_ms=0.3, device_ms=100.0)
+        st.add(5, host_ms=0.2, device_ms=100.0, in_flight=True)
+        st.add(5, host_ms=0.2, device_ms=100.0, in_flight=True)
+        st.add(5, host_ms=0.2, device_ms=100.0, in_flight=False)
+        assert st.blocks == 4 and st.iterations == 20
+        assert st.in_flight == 2
+        assert st.overlap_frac == pytest.approx(0.5)
         d = st.as_dict()
-        assert d["block_sizes"] == [5, 5]
-        assert d["host_ms"] == [30.0, 20.0]
-        assert d["device_ms"] == [100.0, 100.0]
-        assert d["overlap_frac"] == pytest.approx(0.25)
+        assert d["block_sizes"] == [5, 5, 5, 5]
+        assert d["host_ms"] == [0.3, 0.2, 0.2, 0.2]
+        assert d["device_ms"] == [100.0] * 4
+        assert d["in_flight"] == 2
+        assert d["overlap_frac"] == pytest.approx(0.5)
 
-    def test_overlap_frac_clamped_and_empty(self):
+    def test_overlap_frac_empty_and_whole(self):
+        # no block, no share; and the share is of blocks, whatever the
+        # host's milliseconds were (it used to be unpack over wall,
+        # clamped at 1, which the chip read as ~1 with nothing hidden)
         st = PipelineStats()
         assert st.overlap_frac == 0.0
         st.add(1, host_ms=500.0, device_ms=100.0)
-        assert st.overlap_frac == 1.0
+        assert st.overlap_frac == 0.0
+        st.add(1, host_ms=500.0, device_ms=100.0, in_flight=True)
+        st.add(1, host_ms=0.0, device_ms=100.0, in_flight=True)
+        assert st.overlap_frac == pytest.approx(2 / 3)
 
 
 class TestDeviceEvalSupport:
@@ -238,6 +250,202 @@ class TestExecutorCpuFallback:
         assert cfg.pipeline_adaptive_blocks is True
         assert cfg.pipeline_target_block_ms > 0
         assert cfg.pipeline_max_block >= 1
+
+
+# ----------------------------------------------------------------------
+# the host runs a block ahead (PR 33): what the entry layer does between
+# two dispatches of the fused block. Small fused blocks on the forced
+# MXU interpret path: seconds each, so they run in tier 1
+def _mxu_booster(params, data):
+    X, y = data
+    return _MxuBooster(params={**PARAMS, **params},
+                       train_set=lgb.Dataset(X, label=y,
+                                             params={"max_bin": 31}))
+
+
+def _three_class():
+    X, _ = _data(seed=23)
+    return X, (X[:, 0] > 0).astype(np.float32) + (X[:, 1] > 0.5)
+
+
+def _programs_run(fn):
+    """How many device programs `fn` dispatched and which: the
+    PjitFunction host events of a profiler capture around it."""
+    import glob
+    import tempfile
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with tempfile.TemporaryDirectory() as logdir:
+        jax.profiler.start_trace(logdir, profiler_options=opts)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(logdir + "/**/*.xplane.pb", recursive=True)[0]
+        host = next(p for p in ProfileData.from_file(path).planes
+                    if p.name == "/host:CPU")
+        return [e.name for line in host.lines for e in line.events
+                if e.name.startswith("PjitFunction")]
+
+
+class TestUnpackDispatchesNothing:
+    @pytest.mark.parametrize("task,mkdata,kcls", [
+        ({}, _data, 1),
+        ({"objective": "multiclass", "num_class": 3}, _three_class, 3),
+    ], ids=["binary", "multiclass"])
+    def test_finalize_block_runs_at_most_one_program(self, task, mkdata,
+                                                     kcls):
+        import jax
+        from lightgbm_tpu.observability import registry as obs
+        k = 2
+        bst = _mxu_booster({**task, "num_leaves": 4}, mkdata())
+        bst.update_batch(k)                     # warm-up: builds
+        assert bst.gbdt._fused_warm_at(k), "the fused path did not engage"
+        handle = bst.update_batch_dispatch(k)
+        assert handle["mode"] == "fused"
+        before = len(bst.gbdt.trees)
+        obs.trace.reset()
+
+        def unpack():
+            # no index (nor anything else) may be put on the device
+            with jax.transfer_guard_host_to_device("disallow"):
+                bst.finalize_block(handle)
+
+        ran = _programs_run(unpack)
+        assert len(ran) <= 1, ran
+        assert len(bst.gbdt.trees) == before + k * kcls
+        assert bst.gbdt.tree_class[before:] == list(range(kcls)) * k
+        (span,) = [s for s in obs.trace.spans()
+                   if s["name"] == "entry.unpack_block"]
+        assert span["attrs"]["programs"] == len(ran)
+        assert span["attrs"]["waited_ms"] >= 0.0
+        trees = [s["attrs"]["tree"] for s in obs.trace.spans()
+                 if s["name"] == "entry.unpack_tree"]
+        assert trees == list(range(before, before + k * kcls))
+        # the views are the block's own trees: the model they make is
+        # the one the per-tree path would have written
+        ref = _mxu_booster({**task, "num_leaves": 4}, mkdata())
+        ref.update_batch(k)
+        ref.update_batch(k)
+        assert bst.model_to_string() == ref.model_to_string()
+
+
+class TestHostRunsAhead:
+    @staticmethod
+    def _fed(monkeypatch):
+        fed = []
+        real = AdaptiveBlockScheduler.observe
+
+        def observe(self, k, wall_s, compiled=False):
+            fed.append((k, wall_s, compiled))
+            real(self, k, wall_s, compiled)
+
+        monkeypatch.setattr(AdaptiveBlockScheduler, "observe", observe)
+        return fed
+
+    def test_scheduler_learns_no_rate_the_device_did_not_show(
+            self, mxu_engine, monkeypatch):
+        # tiny blocks on the CPU: the device takes some 25 ms over a
+        # block of two trees, the host a millisecond or two to enqueue
+        # it. Fed the wall of a loop iteration, a scheduler whose host
+        # runs ahead reads a thousand trees a second from the first
+        # block after any sync and asks for a longer block: a new
+        # program (at 10 ms a dispatch the device's own rate, under a
+        # hundred trees a second, keeps the base block; the host's
+        # would not). Where the host runs ahead the scheduler is fed
+        # nothing: the device crosses block boundaries without waiting
+        # for the host, so a longer block has nothing to amortise
+        fed = self._fed(monkeypatch)
+        bst = _train(mxu_engine,
+                     {**PARAMS, "num_leaves": 4, "fused_block_size": 2,
+                      "pipeline_adaptive_blocks": True,
+                      "pipeline_target_block_ms": 10.0,
+                      "pipeline_max_block": 200}, _data(seed=29), 16)
+        st = bst.gbdt._pipeline_stats
+        assert st.block_sizes == [2] * 8
+        assert st.iterations == 16
+        assert fed == []
+
+    def test_scheduler_learns_where_the_loop_waits_for_every_block(
+            self, mxu_engine, monkeypatch):
+        # with a valid set the callbacks decide on each block's
+        # metrics, so the loop waits for every block at its sync: each
+        # block costs a round trip that a longer block amortises, and
+        # the wall from dispatch to the end of the sync is the block's
+        # time. It is fed every block, and none is in flight
+        from lightgbm_tpu.observability import registry as obs
+        fed = self._fed(monkeypatch)
+        obs.trace.reset()
+        bst = _train(mxu_engine,
+                     {**PARAMS, "num_leaves": 4, "fused_block_size": 2,
+                      "pipeline_adaptive_blocks": False},
+                     _data(seed=29), 8, valid=_noisy_valid())
+        st = bst.gbdt._pipeline_stats
+        assert st.block_sizes == [2] * 4 and st.in_flight == 0
+        assert [k for k, _, _ in fed] == [2] * 4
+        assert fed[0][2] and not any(c for _, _, c in fed[1:])
+        syncs = [s for s in obs.trace.spans()
+                 if s["name"] == "entry.sync_metrics"]
+        blocks = [s for s in obs.trace.spans()
+                  if s["name"] == "entry.block"]
+        for (_, wall_s, _), sync, blk in zip(fed, syncs, blocks):
+            assert wall_s == pytest.approx(
+                sync["ts"] + sync["dur"] - blk["ts"], abs=1e-6)
+
+
+class TestLaggedStopPoll:
+    """A model that stalls (constant labels: no split has a gain) still
+    stops. The poll reads the newest leaf count that is already on the
+    host and waits for no block in flight, so when the host runs ahead
+    the stop is seen one block later than when it waits for every
+    block; the extra block is constant trees."""
+
+    def _stalled(self):
+        X, _ = _data(seed=37)
+        return _mxu_booster(
+            {"objective": "regression", "num_leaves": 4,
+             "boost_from_average": True},
+            (X, np.full(len(X), 3.0, np.float32)))
+
+    def test_train_many_stops_as_before(self):
+        # dispatch then finalize at once: every block has been waited
+        # for when the next is dispatched, so the poll sees the last
+        # block's count, as it always did. Blocks of 8 cross a poll
+        # boundary each: block 0 has no count before it, block 1 reads
+        # block 0's
+        bst = self._stalled()
+        stops = [bst.update_batch(8) for _ in range(3)]
+        assert stops == [False, True, True]
+
+    def test_one_block_later_when_the_last_block_is_in_flight(
+            self, monkeypatch):
+        from lightgbm_tpu.boosting.gbdt import GBDT
+        bst = self._stalled()
+        gb = bst.gbdt
+        real = GBDT._is_ready
+        # the host a block ahead: whatever was enqueued last is still
+        # running when the next dispatch looks
+        monkeypatch.setattr(
+            GBDT, "_is_ready",
+            staticmethod(lambda a: a is not gb._pending_nleaves
+                         and real(a)))
+        handles, stops = [], []
+        for _ in range(4):
+            h = bst.update_batch_dispatch(8)
+            handles.append(h)
+            if len(handles) > 1:
+                stops.append(bst.finalize_block(handles[-2]))
+        stops.append(bst.finalize_block(handles[-1]))
+        # block 1 cannot see block 0's count (in flight) and has none
+        # older; block 2 reads block 0's: one block later than above
+        assert stops == [False, False, True, True]
+        assert [h["in_flight"] for h in handles] == [False, True, True,
+                                                     True]
+        leaves = [int(t.num_leaves) for t in gb.trees]
+        assert len(leaves) == 32 and set(leaves) == {1}
 
 
 # ----------------------------------------------------------------------
