@@ -305,8 +305,13 @@ class Dataset:
         # dataset_bounds) and quantizing (dataset_quantize) nest in it
         with span("ingest.construct") as sp:
             self._construct()
+            cat = [m for m in self._binned.mappers if m.is_categorical]
             sp.attrs.update(rows=int(self._binned.num_data),
-                            features=int(self._binned.num_total_features))
+                            features=int(self._binned.num_total_features),
+                            categorical=len(cat),
+                            levels=sum(m.num_levels for m in cat),
+                            levels_dropped=sum(
+                                m.num_levels - (m.num_bin - 1) for m in cat))
         return self
 
     def _construct(self) -> "Dataset":

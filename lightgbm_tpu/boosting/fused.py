@@ -87,12 +87,19 @@ def split_block(stacked):
     leading axes of a stacked field are [k] or [k, num_class]), and
     the leaf count the stop poll reads: the block's last tree's, the
     largest over its classes (a model has stalled only if EVERY class
-    has). ONE program per block length and class count, where slicing
+    has), and [nodes, cat_nodes] of the block (the `entry.unpack_block`
+    span carries them). ONE program per block length and class count,
+    where slicing
     the fields tree by tree from the host was one program and one
     index transfer a field a tree (170 a block of ten)."""
     trees = [jax.tree_util.tree_map(lambda a: a[ix], stacked)
              for ix in np.ndindex(stacked.num_leaves.shape)]
-    return trees, jnp.max(stacked.num_leaves[-1])
+    # what the block's trees decide on: their internal nodes, and those
+    # of them that test a categorical column's bitset
+    internal = (stacked.split_feature >= 0) & ~stacked.is_leaf
+    decides = jnp.stack([jnp.sum(internal),
+                         jnp.sum(internal & stacked.is_cat)])
+    return trees, jnp.max(stacked.num_leaves[-1]), decides
 
 
 def build_fused_train(*, objective, bins, feature_mask_fn,

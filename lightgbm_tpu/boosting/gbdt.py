@@ -206,7 +206,10 @@ class GBDT:
             has_monotone=has_monotone,
             monotone_penalty=cfg.monotone_penalty,
             extra_trees=cfg.extra_trees,
-            has_categorical=bool(np.any(ds.is_categorical)))
+            has_categorical=bool(np.any(ds.is_categorical)),
+            cat_columns=tuple(
+                int(j) for j in np.flatnonzero(ds.is_categorical)))
+        self._cat_bins = int(ds.num_bins[ds.is_categorical].sum())
         # intermediate/advanced monotone methods need leaf-wise growth
         # with per-pass bound recomputation — portable grower only
         self._mono_nonbasic = (
@@ -662,6 +665,15 @@ class GBDT:
                     form == "grouped" and stage != "fixup"
                     for stage, _, form in self._hist_plan)}
 
+    def _cat_attrs(self) -> dict:
+        """What of the dataset is categorical, as attributes of a
+        boosting.build_program span: the program being built searches
+        and routes by category sets (`has_cat`) over `cat_columns`
+        columns of `cat_bins` bins together."""
+        return {"has_cat": self.hp.has_categorical,
+                "cat_columns": len(self.hp.cat_columns),
+                "cat_bins": self._cat_bins}
+
     def _trace_operand_builds(self, program, *args, **kwargs) -> None:
         """Before a jitted growth program's first run: trace it and
         count where it builds its row-sized kernel operands
@@ -793,7 +805,7 @@ class GBDT:
         with contextlib.nullcontext() if warm else span(
                 "boosting.build_program", iter=self.iter_, k=1,
                 program="sharded_grow" if sharded else "grow_tree",
-                **self._hist_plan_attrs()) as build:
+                **self._hist_plan_attrs(), **self._cat_attrs()) as build:
             out = retry_call(
                 _attempt, attempts=cfg.retry_max_attempts,
                 backoff_ms=cfg.retry_backoff_ms,
@@ -1442,6 +1454,11 @@ class GBDT:
                 jax.block_until_ready(views)
                 ready_at = time.perf_counter()
                 unpack.attrs["waited_ms"] = (ready_at - unpack.start) * 1e3
+                # the split program made these beside the views: they
+                # are there, reading them waits for nothing
+                nodes, cat_nodes = np.asarray(handle.pop("decides"))
+                unpack.attrs.update(nodes=int(nodes),
+                                    cat_nodes=int(cat_nodes))
                 for n, tree in enumerate(views):
                     with span("entry.unpack_tree", iter=it0, k=k,
                               tree=it0 * kcls + n):
@@ -1586,7 +1603,8 @@ class GBDT:
                 with contextlib.nullcontext() if self._fused_warm_at(k) \
                         else span("boosting.build_program",
                                   program="fused_train", iter=iter0, k=k,
-                                  **self._hist_plan_attrs()) as build:
+                                  **self._hist_plan_attrs(),
+                                  **self._cat_attrs()) as build:
                     if getattr(self, "_fused_run", None) is None:
                         self._fused_run = self._build_fused()
                         self._fused_warm = set()
@@ -1706,7 +1724,7 @@ class GBDT:
         # leaf count: ONE program, enqueued right behind the block, so
         # finalize_block finds them ready the moment the block is
         from .fused import split_block
-        views, pending = split_block(model_trees)
+        views, pending, decides = split_block(model_trees)
         # lagged stall poll (see train_one_iter): a stalled model keeps
         # producing all-zero trees, so checking a batch's last tree
         # roughly every _stop_poll_every ITERATIONS is enough — poll
@@ -1730,7 +1748,7 @@ class GBDT:
                     stop_hint = int(seen) <= 1
         self._note_nleaves(pending)
         return {"mode": "fused", "trees": views, "k": k, "kcls": kcls,
-                "stop": stop_hint, "iter": iter0,
+                "stop": stop_hint, "iter": iter0, "decides": decides,
                 "in_flight": dispatch.attrs["in_flight"]}
 
     @staticmethod

@@ -463,7 +463,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             return find_best_splits(
                 h, tree.sum_grad[sn], tree.sum_hess[sn], tree.count[sn],
                 tree.leaf_value[sn], num_bins, missing_is_nan, is_cat_feat,
-                fm, hp, **mono_kw)
+                fm, hp, cat_columns=hp.cat_columns, **mono_kw)
 
         if comm is None or (mono_rescan and comm.mode == "data"):
             bs = scan_hist(hist, slot_fmask)  # cache already merged

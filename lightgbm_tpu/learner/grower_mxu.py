@@ -927,7 +927,8 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 tree.leaf_value[sn], num_bins, missing_is_nan, is_cat_feat,
                 slot_fmask, hp, monotone=monotone, cons_min=cons_min[sn],
                 cons_max=cons_max[sn], depth=tree.depth[sn],
-                rand_bins=rand_bins, gain_penalty=gp)
+                rand_bins=rand_bins, gain_penalty=gp,
+                cat_columns=hp.cat_columns)
 
         if use_forced:
             # override gain-chosen splits on forced nodes with the

@@ -21,10 +21,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["SplitHyperParams", "BestSplits", "find_best_splits",
            "leaf_output", "leaf_gain"]
@@ -49,6 +50,10 @@ class SplitHyperParams:
     monotone_penalty: float = 0.0
     extra_trees: bool = False      # one random threshold per (slot, feature)
     has_categorical: bool = False  # enables the categorical scan paths
+    # used-feature indices of the categorical columns, for callers whose
+    # histogram holds every used feature in order (the growers pass it on
+    # as find_best_splits' `cat_columns`); None: not known
+    cat_columns: Optional[Tuple[int, ...]] = None
 
 
 class BestSplits(NamedTuple):
@@ -118,7 +123,7 @@ def _monotone_penalty_factor(depth: jax.Array, p: float) -> jax.Array:
     return jnp.where(p >= d + 1.0, eps, out)
 
 
-@functools.partial(jax.jit, static_argnames=("hp",))
+@functools.partial(jax.jit, static_argnames=("hp", "cat_columns"))
 def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
                      parent_hess: jax.Array, parent_count: jax.Array,
                      parent_output: jax.Array, num_bins: jax.Array,
@@ -130,7 +135,9 @@ def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
                      cons_max: jax.Array = None,
                      depth: jax.Array = None,
                      rand_bins: jax.Array = None,
-                     gain_penalty: jax.Array = None) -> BestSplits:
+                     gain_penalty: jax.Array = None,
+                     cat_columns: Optional[Tuple[int, ...]] = None
+                     ) -> BestSplits:
     """Find the best split per slot.
 
     Args:
@@ -146,6 +153,11 @@ def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
         SerialTreeLearner::FindBestSplitsFromHistograms subtracting
         CostEfficientGradientBoosting::DetlaGain,
         cost_effective_gradient_boosting.hpp:46-70).
+      cat_columns: static; the indices f with is_cat[f], where the caller
+        knows them (SplitHyperParams.cat_columns and a histogram over
+        every used feature): the categorical search then sorts, gathers
+        and scans those columns only. None: every column is searched and
+        is_cat masks the result.
     """
     s, f, b, _ = hist.shape
     l1, l2 = hp.lambda_l1, hp.lambda_l2
@@ -175,142 +187,197 @@ def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
     min_gain_shift = gain_shift + hp.min_gain_to_split
 
     # ---------- numerical features ----------
-    prefix = cumsum_bins(hist)                                     # [S,F,B,3]
-    nan_idx = jnp.maximum(num_bins - 1, 0)
-    nan_sums = jnp.take_along_axis(
-        hist, nan_idx[None, :, None, None].astype(jnp.int32),
-        axis=2)                                                    # [S,F,1,3]
-    nan_sums = jnp.where(missing_is_nan[None, :, None, None], nan_sums, 0.0)
+    with jax.named_scope("split.numerical"):
+        prefix = cumsum_bins(hist)  # [S,F,B,3]
+        nan_idx = jnp.maximum(num_bins - 1, 0)
+        nan_sums = jnp.take_along_axis(
+            hist, nan_idx[None, :, None, None].astype(jnp.int32),
+            axis=2)  # [S,F,1,3]
+        nan_sums = jnp.where(missing_is_nan[None, :, None, None], nan_sums,
+                             0.0)
 
-    # threshold t valid iff t <= num_bins-2 (-1 more when NaN bin present)
-    t_limit = num_bins - 2 - missing_is_nan.astype(jnp.int32)      # [F]
-    valid_t = bins_r[None, None, :] <= t_limit[None, :, None]      # [1,F,B]
-    valid_t = valid_t & (~is_cat[None, :, None]) & \
-        (fmask[:, :, None] > 0)                                    # [S,F,B]
-    if hp.extra_trees and rand_bins is not None:
-        # extra-trees: evaluate ONE random threshold per (slot, feature)
-        # (reference USE_RAND specialization, feature_histogram.hpp:85)
-        valid_t = valid_t & (bins_r[None, None, :] ==
-                             (rand_bins % jnp.maximum(t_limit + 1, 1)
-                              [None, :])[:, :, None])
+        # threshold t valid iff t <= num_bins-2 (-1 more when NaN bin present)
+        t_limit = num_bins - 2 - missing_is_nan.astype(jnp.int32)      # [F]
+        valid_t = bins_r[None, None, :] <= t_limit[None, :, None]  # [1,F,B]
+        valid_t = valid_t & (~is_cat[None, :, None]) & \
+            (fmask[:, :, None] > 0)  # [S,F,B]
+        if hp.extra_trees and rand_bins is not None:
+            # extra-trees: evaluate ONE random threshold per (slot, feature)
+            # (reference USE_RAND specialization, feature_histogram.hpp:85)
+            valid_t = valid_t & (bins_r[None, None, :] ==
+                                 (rand_bins % jnp.maximum(t_limit + 1, 1)
+                                  [None, :])[:, :, None])
 
-    def eval_option(left):                                         # [S,F,B,3]
-        right = tot - left
-        lg, lh, lc = left[..., 0], left[..., 1], left[..., 2]
-        rg, rh, rc = right[..., 0], right[..., 1], right[..., 2]
-        ok = ((lc >= hp.min_data_in_leaf) & (rc >= hp.min_data_in_leaf) &
-              (lh >= hp.min_sum_hessian_in_leaf) &
-              (rh >= hp.min_sum_hessian_in_leaf))
-        if hp.has_monotone:
-            # constrained-output gain path (GetSplitGains USE_MC branch,
-            # feature_histogram.hpp:806-824): clamp child outputs to the
-            # node's [min, max] constraint, kill order-violating splits
-            po = parent_output[:, None, None]
-            lout = leaf_output(lg, lh, l1, l2, hp.max_delta_step,
-                               hp.path_smooth, lc, po)
-            rout = leaf_output(rg, rh, l1, l2, hp.max_delta_step,
-                               hp.path_smooth, rc, po)
-            cmin = cons_min[:, None, None]
-            cmax = cons_max[:, None, None]
-            lout = jnp.clip(lout, cmin, cmax)
-            rout = jnp.clip(rout, cmin, cmax)
-            mc = monotone[None, :, None]
-            violate = ((mc > 0) & (lout > rout)) | \
-                      ((mc < 0) & (lout < rout))
-            g = _gain_given_output(lg, lh, l1, l2, lout) + \
-                _gain_given_output(rg, rh, l1, l2, rout)
-            if hp.monotone_penalty > 0:
-                pen = _monotone_penalty_factor(depth, hp.monotone_penalty)
-                g = jnp.where(mc != 0, g * pen[:, None, None], g)
-            g = jnp.where(violate, -jnp.inf, g)
-        else:
-            g = _split_gain(lg, lh, lc, rg, rh, rc, l1, l2, hp,
-                            parent_output[:, None, None])
-        return jnp.where(ok & valid_t, g, -jnp.inf)
+        def eval_option(left):  # [S,F,B,3]
+            right = tot - left
+            lg, lh, lc = left[..., 0], left[..., 1], left[..., 2]
+            rg, rh, rc = right[..., 0], right[..., 1], right[..., 2]
+            ok = ((lc >= hp.min_data_in_leaf) & (rc >= hp.min_data_in_leaf) &
+                  (lh >= hp.min_sum_hessian_in_leaf) &
+                  (rh >= hp.min_sum_hessian_in_leaf))
+            if hp.has_monotone:
+                # constrained-output gain path (GetSplitGains USE_MC branch,
+                # feature_histogram.hpp:806-824): clamp child outputs to the
+                # node's [min, max] constraint, kill order-violating splits
+                po = parent_output[:, None, None]
+                lout = leaf_output(lg, lh, l1, l2, hp.max_delta_step,
+                                   hp.path_smooth, lc, po)
+                rout = leaf_output(rg, rh, l1, l2, hp.max_delta_step,
+                                   hp.path_smooth, rc, po)
+                cmin = cons_min[:, None, None]
+                cmax = cons_max[:, None, None]
+                lout = jnp.clip(lout, cmin, cmax)
+                rout = jnp.clip(rout, cmin, cmax)
+                mc = monotone[None, :, None]
+                violate = ((mc > 0) & (lout > rout)) | \
+                          ((mc < 0) & (lout < rout))
+                g = _gain_given_output(lg, lh, l1, l2, lout) + \
+                    _gain_given_output(rg, rh, l1, l2, rout)
+                if hp.monotone_penalty > 0:
+                    pen = _monotone_penalty_factor(depth, hp.monotone_penalty)
+                    g = jnp.where(mc != 0, g * pen[:, None, None], g)
+                g = jnp.where(violate, -jnp.inf, g)
+            else:
+                g = _split_gain(lg, lh, lc, rg, rh, rc, l1, l2, hp,
+                                parent_output[:, None, None])
+            return jnp.where(ok & valid_t, g, -jnp.inf)
 
-    gain_na_right = eval_option(prefix)                       # NaN stays right
-    gain_na_left = jnp.where(
-        missing_is_nan[None, :, None],
-        eval_option(prefix + nan_sums), -jnp.inf)             # NaN joins left
+        gain_na_right = eval_option(prefix)  # NaN stays right
+        gain_na_left = jnp.where(
+            missing_is_nan[None, :, None],
+            eval_option(prefix + nan_sums), -jnp.inf)  # NaN joins left
 
     # ---------- categorical ----------
-    # One-hot branch for low-cardinality features, sorted-by-ratio two-way
-    # scan otherwise, mirroring FindBestThresholdCategoricalInner
-    # (feature_histogram.hpp:278-485): one-hot gains use the ORIGINAL l2,
-    # sorted gains use l2 + cat_l2, gain_shift uses the original l2 in both;
-    # sorted scan keeps bins with count >= cat_smooth, sorts ascending by
-    # g/(h + cat_smooth), scans from both ends up to
-    # min(max_cat_threshold, (used+1)/2) categories. Bin 0 (unseen/NaN)
-    # always stays right. For a threshold at sorted position p the left set
-    # is the first p+1 bins in scan direction, emitted as a bin bitset.
-    # Deviation from the reference: the min_data_per_group group-batching
-    # (which merges tiny categories between gain evaluations) is applied
-    # only as a right-side floor, not as evaluation batching.
+    # FindBestThresholdCategoricalInner (feature_histogram.hpp:278-485).
+    # A column of at most max_cat_to_onehot bins is searched one bin
+    # against the rest, with the ORIGINAL l2. Any other column: the bins
+    # with count >= cat_smooth, sorted by g / (h + cat_smooth), are
+    # scanned from both ends for at most min(max_cat_threshold,
+    # (used + 1) / 2) steps with l2 + cat_l2 in the gain (gain_shift keeps
+    # the original l2 in both). A step's bin joins the left set; a gain is
+    # EVALUATED at a step only once the bins added since the last
+    # evaluation hold min_data_per_group rows (the reference's
+    # cnt_cur_group: a step whose left side is still under
+    # min_data_in_leaf or min_sum_hessian_in_leaf adds to the group and
+    # evaluates nothing), and the right side keeps at least
+    # max(min_data_in_leaf, min_data_per_group) rows. Bin 0 (unseen,
+    # negative, NaN) always stays right. For a threshold at step p the left
+    # set is the first p + 1 bins in scan direction, emitted as a bin
+    # bitset. Counts are the histogram's own (the reference's newer
+    # versions estimate them from the hessian sums).
+    # Only the first max_cat_threshold bins of either order can enter a
+    # left set, so only those are gathered and summed; with `cat_columns`
+    # all of this runs over the categorical columns alone.
     cl2 = l2 + hp.cat_l2
     use_onehot_f = num_bins <= hp.max_cat_to_onehot                # [F]
     cat_basic_valid = (bins_r[None, None, :] >= 1) & \
         (bins_r[None, None, :] < num_bins[None, :, None])
     if hp.has_categorical:
-        po3 = parent_output[:, None, None]
-        # -- one-hot (original l2, feature_histogram.hpp:318-372) --
-        lg, lh, lc = hist[..., 0], hist[..., 1], hist[..., 2]
-        rg = tot[..., 0] - lg
-        rh = tot[..., 1] - lh
-        rc = tot[..., 2] - lc
-        oh_ok = ((lc >= hp.min_data_in_leaf) & (rc >= hp.min_data_in_leaf) &
-                 (lh >= hp.min_sum_hessian_in_leaf) &
-                 (rh >= hp.min_sum_hessian_in_leaf))
-        onehot_gain = (leaf_gain(lg, lh, l1, l2, hp.max_delta_step,
-                                 hp.path_smooth, lc, po3) +
-                       leaf_gain(rg, rh, l1, l2, hp.max_delta_step,
-                                 hp.path_smooth, rc, po3))
-        onehot_gain = jnp.where(oh_ok & cat_basic_valid, onehot_gain,
-                                -jnp.inf)
-        # -- sorted two-direction scan (l2 + cat_l2) --
-        cnt3 = hist[..., 2]
-        sort_ok = cat_basic_valid & (cnt3 >= hp.cat_smooth)
-        ratio = jnp.where(sort_ok,
-                          hist[..., 0] / (hist[..., 1] + hp.cat_smooth),
-                          jnp.inf)
-        used_bin = jnp.sum(sort_ok, axis=2)                        # [S,F]
-        max_num_cat = jnp.minimum(hp.max_cat_threshold,
-                                  (used_bin + 1) // 2)             # [S,F]
-        pos_limit = jnp.minimum(used_bin, max_num_cat)[:, :, None]
-        min_rc = max(hp.min_data_in_leaf, hp.min_data_per_group)
+        with jax.named_scope("split.categorical"):
+            if cat_columns is None:
+                cols = np.arange(f)
+                hist_c, onehot_c, valid_c = hist, use_onehot_f, \
+                    cat_basic_valid
+            else:
+                cols = np.asarray(cat_columns, np.int32)
+                hist_c = hist[:, cols]                          # [S,Fc,B,3]
+                onehot_c = use_onehot_f[cols]
+                valid_c = cat_basic_valid[:, cols]
+            fc = len(cols)
+            # column of the categorical block that holds feature f
+            col_of = np.zeros(f, np.int32)
+            col_of[cols] = np.arange(fc, dtype=np.int32)
+            k = min(int(hp.max_cat_threshold), b)      # steps of a scan
+            steps_r = jnp.arange(k, dtype=jnp.int32)
+            po3 = parent_output[:, None, None]
+            # -- one-hot (original l2, feature_histogram.hpp:318-372) --
+            lg, lh, lc = hist_c[..., 0], hist_c[..., 1], hist_c[..., 2]
+            rg = tot[..., 0] - lg
+            rh = tot[..., 1] - lh
+            rc = tot[..., 2] - lc
+            oh_ok = ((lc >= hp.min_data_in_leaf) &
+                     (rc >= hp.min_data_in_leaf) &
+                     (lh >= hp.min_sum_hessian_in_leaf) &
+                     (rh >= hp.min_sum_hessian_in_leaf))
+            onehot_gain = (leaf_gain(lg, lh, l1, l2, hp.max_delta_step,
+                                     hp.path_smooth, lc, po3) +
+                           leaf_gain(rg, rh, l1, l2, hp.max_delta_step,
+                                     hp.path_smooth, rc, po3))
+            onehot_gain = jnp.where(oh_ok & valid_c, onehot_gain, -jnp.inf)
+            # -- sorted two-direction scan (l2 + cat_l2) --
+            sort_ok = valid_c & (lc >= hp.cat_smooth)
+            ratio = jnp.where(sort_ok, lg / (lh + hp.cat_smooth), jnp.inf)
+            used_bin = jnp.sum(sort_ok, axis=2)                    # [S,Fc]
+            max_num_cat = jnp.minimum(hp.max_cat_threshold,
+                                      (used_bin + 1) // 2)         # [S,Fc]
+            pos_limit = jnp.minimum(used_bin, max_num_cat)[:, :, None]
+            min_rc = max(hp.min_data_in_leaf, hp.min_data_per_group)
+            tri_k = (steps_r[:, None] <= steps_r[None, :]).astype(
+                jnp.float32)
 
-        def scan_dir(order):
-            sh = jnp.take_along_axis(hist, order[..., None], axis=2)
-            sp = cumsum_bins(sh)                                   # [S,F,B,3]
-            slg, slh, slc = sp[..., 0], sp[..., 1], sp[..., 2]
-            srg = tot[..., 0] - slg
-            srh = tot[..., 1] - slh
-            src = tot[..., 2] - slc
-            ok = ((bins_r[None, None, :] < pos_limit) &
-                  (slc >= hp.min_data_in_leaf) &
-                  (slh >= hp.min_sum_hessian_in_leaf) &
-                  (src >= min_rc) & (srh >= hp.min_sum_hessian_in_leaf))
-            g = (leaf_gain(slg, slh, l1, cl2, hp.max_delta_step,
-                           hp.path_smooth, slc, po3) +
-                 leaf_gain(srg, srh, l1, cl2, hp.max_delta_step,
-                           hp.path_smooth, src, po3))
-            return jnp.where(ok, g, -jnp.inf), sp
+            def scan_dir(top):
+                sh = jnp.take_along_axis(
+                    hist_c, jnp.minimum(top, b - 1)[..., None],
+                    axis=2)                                      # [S,Fc,K,3]
+                sp = jnp.einsum("sfbc,bt->sftc", sh, tri_k,
+                                precision=jax.lax.Precision.HIGHEST)
+                slg, slh, slc = sp[..., 0], sp[..., 1], sp[..., 2]
+                srg = tot[..., 0] - slg
+                srh = tot[..., 1] - slh
+                src = tot[..., 2] - slc
+                left_ok = ((slc >= hp.min_data_in_leaf) &
+                           (slh >= hp.min_sum_hessian_in_leaf))
 
-        order_a = jnp.argsort(ratio, axis=2)
-        order_d = jnp.argsort(jnp.where(sort_ok, -ratio, jnp.inf), axis=2)
-        gain_a, sp_a = scan_dir(order_a)
-        gain_d, sp_d = scan_dir(order_d)
-        sorted_gain = jnp.maximum(gain_a, gain_d)
-        cat_dir_bwd = gain_d > gain_a                              # [S,F,B]
-        cat_gain = jnp.where(use_onehot_f[None, :, None], onehot_gain,
-                             sorted_gain)
-        cat_gain = jnp.where(
-            is_cat[None, :, None] & (fmask[:, :, None] > 0) &
-            (cat_gain > min_gain_shift[:, None, None]), cat_gain, -jnp.inf)
+                def step(group, x):
+                    cnt, may = x
+                    group = group + cnt
+                    evaluated = may & (group >= hp.min_data_per_group)
+                    return jnp.where(evaluated, 0.0, group), evaluated
+
+                _, evaluated = jax.lax.scan(
+                    step, jnp.zeros((s, fc), sh.dtype),
+                    (jnp.moveaxis(sh[..., 2], 2, 0),
+                     jnp.moveaxis(left_ok, 2, 0)))
+                ok = (jnp.moveaxis(evaluated, 0, 2) &
+                      (steps_r[None, None, :] < pos_limit) &
+                      (src >= min_rc) &
+                      (srh >= hp.min_sum_hessian_in_leaf))
+                g = (leaf_gain(slg, slh, l1, cl2, hp.max_delta_step,
+                               hp.path_smooth, slc, po3) +
+                     leaf_gain(srg, srh, l1, cl2, hp.max_delta_step,
+                               hp.path_smooth, src, po3))
+                return jnp.where(ok, g, -jnp.inf), sp
+
+            # ONE stable sort: the used bins come first, ascending; the
+            # scan from the high end walks the same order backwards, as
+            # the reference does (bins of equal ratio then enter in the
+            # opposite order, which a second sort would not give)
+            order = jnp.argsort(ratio, axis=2)                   # [S,Fc,B]
+            top_a = order[:, :, :k]
+            back = used_bin[:, :, None] - 1 - steps_r[None, None, :]
+            # a step past the used bins names no bin (b: out of range)
+            top_d = jnp.where(back >= 0, jnp.take_along_axis(
+                order, jnp.maximum(back, 0), axis=2), b)         # [S,Fc,K]
+            gain_a, sp_a = scan_dir(top_a)                       # [S,Fc,K]
+            gain_d, sp_d = scan_dir(top_d)
+            # the low end keeps a step both ends evaluate alike, as in the
+            # reference; where the two ends reach one gain at DIFFERENT
+            # steps the earlier step wins here and the low end there
+            cat_dir_bwd = gain_d > gain_a                        # [S,Fc,K]
+            sorted_gain = jnp.pad(
+                jnp.maximum(gain_a, gain_d), ((0, 0), (0, 0), (0, b - k)),
+                constant_values=-jnp.inf)                        # [S,Fc,B]
+            cat_gain = jnp.where(onehot_c[None, :, None], onehot_gain,
+                                 sorted_gain)
+            if cat_columns is not None:
+                cat_gain = jnp.full((s, f, b), -jnp.inf).at[:, cols].set(
+                    cat_gain)
+            cat_gain = jnp.where(
+                is_cat[None, :, None] & (fmask[:, :, None] > 0) &
+                (cat_gain > min_gain_shift[:, None, None]), cat_gain,
+                -jnp.inf)
     else:
         cat_gain = jnp.full((s, f, b), -jnp.inf)
-        cat_dir_bwd = jnp.zeros((s, f, b), bool)
-        sp_a = sp_d = None
-        order_a = order_d = None
 
     # ---------- combine & argmax ----------
     num_gain = jnp.maximum(gain_na_right, gain_na_left)
@@ -338,8 +405,11 @@ def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
     w = (b + 31) // 32
     if hp.has_categorical:
         use_oh = use_onehot_f[best_f]                              # [S]
-        dir_bwd = cat_dir_bwd[sel]                                 # [S]
-        sorted_left = jnp.where(dir_bwd[:, None], sp_d[sel], sp_a[sel])
+        # the winner's column and step in the categorical block
+        sel_c = (jnp.arange(s), jnp.asarray(col_of)[best_f],
+                 jnp.minimum(best_t, k - 1))
+        dir_bwd = cat_dir_bwd[sel_c]                               # [S]
+        sorted_left = jnp.where(dir_bwd[:, None], sp_d[sel_c], sp_a[sel_c])
         cat_left = jnp.where(use_oh[:, None], hist[sel], sorted_left)
         left = jnp.where(best_is_cat[:, None], cat_left, num_left)
         # best one-hot split uses original l2; sorted uses l2 + cat_l2
@@ -347,14 +417,14 @@ def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
         eff_l2 = jnp.where(best_is_cat & ~use_oh, cl2, l2)
         # bin bitset of the left set: one-hot -> {best_t}; sorted -> the
         # first best_t+1 bins in the winning scan direction. Only the best
-        # feature's row per slot is needed, so gather the [S, B] permutation
-        # first and invert that (not the full [S, F, B] orders).
-        order_sel = jnp.where(
-            dir_bwd[:, None],
-            order_d[jnp.arange(s), best_f], order_a[jnp.arange(s), best_f])
-        rank_sel = jnp.zeros((s, b), jnp.int32).at[
+        # feature's row per slot is needed, so gather its [S, K] steps
+        # first and invert those (a bin no step names keeps rank b).
+        order_sel = jnp.where(dir_bwd[:, None], top_d[sel_c[:2]],
+                              top_a[sel_c[:2]])
+        rank_sel = jnp.full((s, b), b, jnp.int32).at[
             jnp.arange(s)[:, None], order_sel].set(
-            jnp.broadcast_to(bins_r[None, :], (s, b)))  # bin -> sorted pos
+            jnp.broadcast_to(steps_r[None, :], (s, k)),
+            mode="drop")                                # bin -> sorted pos
         member_sorted = rank_sel <= best_t[:, None]                # [S, B]
         member_oh = bins_r[None, :] == best_t[:, None]
         member = best_is_cat[:, None] & jnp.where(

@@ -155,19 +155,27 @@ def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
     return sites
 
 
-def _synthetic(rows: int, features: int, seed: int = 17):
+def _synthetic(rows: int, features: int, seed: int = 17,
+               categorical: int = 0):
     rng = np.random.RandomState(seed)
     X = rng.randn(rows, features).astype(np.float32)
     y = (X[:, 0] - X[:, 1] + X[:, 2] * X[:, 3] > 0).astype(np.float32)
+    # the first `categorical` columns integer-coded, 300 levels each
+    X[:, :categorical] = rng.randint(0, 300, (rows, categorical))
     return X, y
 
 
 def _gbdt(params: dict, rows: int, features: int):
     """The GBDT ``lgb.Booster(params)`` builds here over synthetic rows
-    (on this CPU host: portable grower, one device)."""
+    (on this CPU host: portable grower, one device). `categorical` in
+    `params` (this module's own key) passes that many leading columns as
+    `categorical_feature`."""
     import lightgbm_tpu as lgb
-    X, y = _synthetic(rows, features)
-    ds = lgb.Dataset(X, label=y, params={"max_bin": params["max_bin"]})
+    params = dict(params)
+    ncat = int(params.pop("categorical", 0))
+    X, y = _synthetic(rows, features, categorical=ncat)
+    ds = lgb.Dataset(X, label=y, params={"max_bin": params["max_bin"]},
+                     categorical_feature=list(range(ncat)) or "auto")
     return lgb.Booster(params=dict(params, verbosity=-1),
                        train_set=ds).gbdt
 
@@ -274,6 +282,10 @@ def main(argv=None) -> int:
     ap.add_argument("--max-bin", type=int, default=255)
     ap.add_argument("--block", type=int, default=10,
                     help="fused block length (default: fused_block_size)")
+    ap.add_argument("--categorical", type=int, default=0,
+                    help="leading columns passed as categorical_feature "
+                         "in the training programs (the has_cat variants "
+                         "of the routing kernels, the sorted split scan)")
     ap.add_argument("--only", default="",
                     help="comma-separated substrings of group names "
                          "(kernel, defaults, bench/mxu, bench/pallas, "
@@ -281,7 +293,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     device = topology_devices()[0]
     base = {"objective": "binary", "num_leaves": args.leaves,
-            "max_bin": args.max_bin, "min_data_in_leaf": 20}
+            "max_bin": args.max_bin, "min_data_in_leaf": 20,
+            "categorical": args.categorical}
     bench = dict(base, use_quantized_grad=True, growth_overshoot=1.75,
                  growth_bridge_gate=0.93)
     shape = dict(rows=args.rows, features=args.features)

@@ -153,6 +153,15 @@ class HostTree:
             active = node >= 0
         return ~node  # leaf index
 
+    def cat_values_left(self, i: int) -> List[int]:
+        """The category values node i (a categorical one) sends left,
+        ascending: the set bits of its words of `cat_threshold`."""
+        c = int(self.threshold[i])
+        words = self.cat_threshold[
+            self.cat_boundaries[c]:self.cat_boundaries[c + 1]]
+        return [32 * w + bit for w, word in enumerate(words)
+                for bit in range(32) if (int(word) >> bit) & 1]
+
     # ---- text io ------------------------------------------------------
     def to_string(self) -> str:
         ni = self.num_leaves - 1
@@ -280,7 +289,12 @@ class HostTree:
                 "split_index": int(i),
                 "split_feature": int(self.split_feature[i]),
                 "split_gain": float(self.split_gain[i]),
-                "threshold": float(self.threshold[i]),
+                # a categorical node's threshold is the category
+                # values that go left, joined by "||" (reference
+                # Tree::NodeToJSON, tree.cpp)
+                "threshold": "||".join(
+                    str(v) for v in self.cat_values_left(i))
+                if dt & _CAT_BIT else float(self.threshold[i]),
                 "decision_type": "==" if dt & _CAT_BIT else "<=",
                 "default_left": bool(dt & _DEFAULT_LEFT_BIT),
                 "missing_type": ["None", "Zero", "NaN"][(dt >> 2) & 3],

@@ -697,8 +697,10 @@ class TestSpansOfATrainingRun:
         assert init["attrs"] == {"rows": 1500, "devices": 4}
         built = [s["attrs"] for s in captured["fused_spans"]
                  if s["name"] == "boosting.build_program"]
-        assert {"program": "fused_train", "iter": 1, "k": 4} in built
-        assert {"program": "fused_train", "iter": 5, "k": 5} in built
+        # (PR 35: every build says what of the data is categorical)
+        none = {"has_cat": False, "cat_columns": 0, "cat_bins": 0}
+        assert {"program": "fused_train", "iter": 1, "k": 4, **none} in built
+        assert {"program": "fused_train", "iter": 5, "k": 5, **none} in built
 
     def test_every_parent_is_an_enclosing_span(self, captured):
         recs = captured["all_spans"]
