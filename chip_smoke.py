@@ -164,10 +164,10 @@ def train_leg(name, params, rounds, dtrain, dvalid, clock, *, on_tpu,
         check(learner.device == "mxu",
               "leg %s ran the %s grower, not the MXU one"
               % (name, learner.device))
-        if not learner.is_parallel:
-            check(stats is not None and stats.blocks > 0,
-                  "leg %s did not go through the fused, pipelined "
-                  "executor" % name)
+        # (the data-parallel MXU learner too, since PR 36)
+        check(stats is not None and stats.blocks > 0,
+              "leg %s did not go through the fused, pipelined "
+              "executor" % name)
     if expect_backend is not None and on_tpu:
         check(rec["hist_backend"] == expect_backend,
               "leg %s ran hist_backend %r, not the pinned %r"
@@ -228,9 +228,12 @@ def serve_leg(bst, Xq, *, on_tpu, requests=32):
 
 
 def multichip_leg(params, dtrain, dvalid, clock, auc_a, *, on_tpu,
-                  ndev=4, rounds=11):
+                  ndev=4, rounds=20):
     """tree_learner=data over `ndev` devices; the rows must really be
-    spread, and the model must agree with the one-device leg."""
+    spread, and the model must agree with the one-device leg. Two
+    blocks like the other legs: on the chip the sharded MXU learner
+    compiles one growth program and the second block takes the first's
+    score as it lies on the mesh."""
     bst, rec = train_leg(
         "multichip", dict(params, tree_learner="data", num_devices=ndev),
         rounds, dtrain, dvalid, clock, on_tpu=on_tpu)
@@ -351,7 +354,8 @@ def main(argv=None) -> int:
     if device["count"] >= 4:
         legs["multichip"] = multichip_leg(
             base, dtrain, dvalid, clock,
-            legs["a_defaults"]["auc_by_tree"], on_tpu=on_tpu)
+            legs["a_defaults"]["auc_by_tree"], on_tpu=on_tpu,
+            rounds=rounds)
     else:
         say("multichip leg not run: %d device(s) visible"
             % device["count"])

@@ -20,9 +20,11 @@ the per-iteration path draws, goss.hpp:76-95), keeping the two paths
 bit-identical. Multiclass grows num_class trees per scan step
 (gbdt.cpp:371 TrainOneIter's per-class loop).
 
-Eligibility is decided by the caller (GBDT.train_many): serial MXU
-growth path, plain gbdt/goss boosting, no L1-family leaf renewal —
-every excluded feature falls back to the per-iteration path unchanged.
+Eligibility is decided by the caller (GBDT._fused_eligible): the MXU
+growth path, serial or data-parallel (the same scan inside shard_map,
+`mesh=`: GBDT._sharded_fused_ok), plain gbdt/goss boosting, no
+L1-family leaf renewal — every excluded feature falls back to the
+per-iteration path unchanged.
 Validation sets DO ride along (round 5): the stacked block is replayed
 over each valid set after the dispatch (stacked_score_traj), giving
 the exact per-iteration valid-score trajectory for metric evaluation
@@ -38,6 +40,8 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["build_fused_train", "split_block", "stacked_score_traj"]
 
@@ -106,9 +110,18 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
                       num_bins, missing_is_nan, is_cat, grower_kwargs,
                       shrinkage: float, extra_seed: int, needs_rng: bool,
                       sample_fn=None, num_class: int = 1,
-                      debug: bool = False):
+                      debug: bool = False, mesh=None, row_pad: int = 0):
     """Return run(score, it0, k, sample_keys=None) ->
     (score', stacked TreeArrays).
+
+    `mesh` (None: one device) makes it the data-parallel learner's
+    block: the SAME scan runs inside `shard_map` over the mesh's row
+    axis, on each device's rows, and the grower sums its histograms
+    over that axis in every pass (`psum_axis`). `bins` is then the
+    row-sharded matrix, `row_pad` rows longer than the objective's
+    state, which is padded and placed on the mesh here, once; the
+    score goes in and comes out row-sharded and the trees come out
+    replicated. A padded row has no gradient and no count.
 
     The bin matrix, the objective's per-row state (label, weight, ...)
     and the tables it names in `table_state` (a ranking objective's
@@ -144,7 +157,8 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     from ..learner.grower_mxu import grow_tree_mxu
     from ..learner.histogram_mxu import node_values_mxu
 
-    num_data = bins.shape[0]
+    axis = mesh.axis_names[0] if mesh is not None else None
+    num_data = bins.shape[0] - row_pad
     row_names, row_arrays = objective_row_state(objective, num_data)
     # what the objective laid out at init that is not one value a row
     # (a ranking objective's query buckets): arguments too, by name
@@ -159,7 +173,7 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
         out = grow_tree_mxu(
             bins, grad, hess, cnt, fmask, num_bins,
             missing_is_nan, is_cat, rng_key=rng, debug_info=debug,
-            **grower_kwargs)
+            psum_axis=axis, **grower_kwargs)
         tree, row_node = out[0], out[1]
         # device-side stand-in for the "no further splits" break: a tree
         # that made no split becomes all-zero and the scan carries on
@@ -174,10 +188,18 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
         it, key = xs
         with jax.named_scope("objective." + getattr(obj, "name", "custom")):
             grad, hess = obj.get_gradients(score)
+        # this device's rows; the rows past num_data pad the last shard
+        rows = score.shape[0]
+        real = None
+        if row_pad:
+            first = jax.lax.axis_index(axis) * rows
+            real = (first + jnp.arange(rows) < num_data) \
+                .astype(jnp.float32)
+            grad, hess = grad * real, hess * real
         if sample_fn is not None:
             grad, hess, cnt = sample_fn(grad, hess, it, key)
         else:
-            cnt = jnp.ones(num_data, jnp.float32)
+            cnt = jnp.ones(rows, jnp.float32) if real is None else real
         fmask = feature_mask_fn(it)
         if num_class == 1:
             tree, vals, dbg = one_tree(bins, grad, hess, cnt, fmask, it)
@@ -203,7 +225,6 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     # score'). GBDT.train_many reassigns self.train_score from the
     # result and its fault paths check .is_deleted() before reusing the
     # old buffer — tpulint JIT004 guards the bare-name discipline.
-    @functools.partial(jax.jit, donate_argnames=("score",))
     def program(score, it0, sample_keys, bins, row_state, tables):
         # the block length is sample_keys' leading axis: a static shape,
         # so each distinct length is its own compiled program
@@ -216,16 +237,36 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
         return jax.lax.scan(functools.partial(body, bins, obj), score,
                             (its, sample_keys))
 
+    if mesh is not None:
+        # score, bins and row state by rows; trees out whole
+        by_rows = NamedSharding(mesh, P(axis))
+        program = shard_map(
+            program, mesh=mesh, out_specs=(P(axis), P()), check_vma=False,
+            in_specs=(P(axis), P(), P(), P(axis), P(axis), P()))
+
+        def on_mesh(a):
+            return jax.device_put(
+                jnp.pad(a, (0, row_pad)) if row_pad else a, by_rows)
+
+        row_arrays = [on_mesh(a) for a in row_arrays]
+    program = jax.jit(program, donate_argnums=0)
     operands = (bins, tuple(row_arrays), table_arrays)
 
     def arguments(score, it0, *, k: int, sample_keys=None):
         if sample_keys is None:
             sample_keys = jnp.zeros((k, 2), jnp.uint32)
+        if mesh is not None and (row_pad or not score.sharding
+                                 .is_equivalent_to(by_rows, score.ndim)):
+            # a run's first block, or one whose rows do not divide the
+            # mesh: every other block takes the last one's score as it
+            # came out, on the mesh already
+            score = on_mesh(score)
         return (score, it0, sample_keys) + operands
 
     def run(score, it0, *, k: int, sample_keys=None):
-        return program(*arguments(score, it0, k=k,
-                                  sample_keys=sample_keys))
+        score, stacked = program(*arguments(score, it0, k=k,
+                                            sample_keys=sample_keys))
+        return (score[:num_data] if row_pad else score), stacked
 
     run.program = program
     run.operands = operands

@@ -413,8 +413,7 @@ class GBDT:
         # CreateTreeLearner factory), asked by every run: the MXU gate
         # picks the device row, cfg.distributed_hist_agg the
         # histogram-merge column, the downgrades applied in ONE place
-        from ..distributed.crossbar import (create_tree_learner,
-                                            resolve_learner)
+        from ..distributed.crossbar import resolve_learner
         platform = jax.default_backend()
         spec = self._learner = resolve_learner(
             cfg.tree_learner, platform=platform,
@@ -492,10 +491,27 @@ class GBDT:
             if rfu.shape[0] > 1:
                 rfu = self._shard_rows(rfu)
             self._cegb_state = (c, l, fu, rfu)
-        self._grower = create_tree_learner(
-            spec, self.mesh, self.comm, num_leaves=cfg.num_leaves,
-            max_depth=cfg.max_depth, hp=self.hp,
-            leafwise=self._mono_nonbasic,
+        self._grower = self._create_grower()
+        Log.info("Distributed learner: %s-parallel over %d devices%s "
+                 "(hist_agg=%s)", self.comm.mode, ndev,
+                 " (mxu)" if use_mxu else "", self.comm.hist_agg)
+        _obs.record_distributed_setup(
+            world=ndev * max(1, self._nproc),
+            feature_shard_width=(int(self._bins_ft.shape[1]) // ndev
+                                 if self._bins_ft is not None else 0),
+            wall_seconds=time.time() - _setup_t0)
+
+    def _create_grower(self):
+        """The per-tree grower of the parallel learner self._learner
+        over self.mesh: what train_one_iter dispatches (jitted, so
+        compiled only by a run that leaves the fused block)."""
+        from ..distributed.crossbar import create_tree_learner
+        cfg = self.config
+        use_mxu = self._learner.device == "mxu"
+        return create_tree_learner(
+            self._learner, self.mesh, self.comm,
+            num_leaves=cfg.num_leaves, max_depth=cfg.max_depth,
+            hp=self.hp, leafwise=self._mono_nonbasic,
             bmax=self.bmax, monotone=self._monotone,
             monotone_method=self._mono_method,
             interaction_groups=self._interaction_groups,
@@ -504,6 +520,7 @@ class GBDT:
             forced=self._forced, cegb_cfg=self._cegb_cfg,
             with_cegb_state=self._cegb_cfg is not None,
             efb=self._efb, with_bins_ft=self._bins_ft is not None,
+            interpret=getattr(self, "_mxu_interpret", False),
             mxu_kwargs=dict(
                 hist_double_prec=cfg.gpu_use_dp,
                 tail_split_cap=cfg.tail_split_cap,
@@ -521,14 +538,6 @@ class GBDT:
                 **({"hist_backend": self._resolved_hist_backend(),
                     "partition_impl": cfg.partition_impl}
                    if use_mxu else {})))
-        Log.info("Distributed learner: %s-parallel over %d devices%s "
-                 "(hist_agg=%s)", self.comm.mode, ndev,
-                 " (mxu)" if use_mxu else "", self.comm.hist_agg)
-        _obs.record_distributed_setup(
-            world=ndev * max(1, self._nproc),
-            feature_shard_width=(int(self._bins_ft.shape[1]) // ndev
-                                 if self._bins_ft is not None else 0),
-            wall_seconds=time.time() - _setup_t0)
 
     def _shard_rows(self, arr):
         """Row-sharded global array over the mesh. Single-process: a
@@ -730,7 +739,10 @@ class GBDT:
         cfg = self.config
         return dict(
             efb=self._efb, forced=self._forced, cegb_cfg=self._cegb_cfg,
-            const_hessian=self._const_hessian(),
+            # off for a sharded learner, whose per-tree grower is
+            # built before the objective has bound its weights
+            const_hessian=0.0 if self._learner.is_parallel
+            else self._const_hessian(),
             num_leaves=cfg.num_leaves, max_depth=cfg.max_depth,
             hp=self.hp, bmax=self.bmax, monotone=self._monotone,
             interaction_groups=self._interaction_groups,
@@ -1286,22 +1298,28 @@ class GBDT:
         #       score updates exactly
 
     def _sharded_fused_ok(self) -> bool:
-        """Whether the distributed crossbar's data-parallel row can run
-        the fused multi-tree scan (distributed/fused.py): the boosting
-        loop moves inside shard_map, so the pipelined executor
-        double-buffers multi-device training exactly like the serial MXU
-        path. Single-host, single-class, plain gbdt on the portable
-        grower — GOSS (global top-k over all rows) and EFB/CEGB/rescan
-        monotone (per-iteration host state) stay per-iteration."""
+        """Whether the data-parallel learner can run the fused
+        multi-tree scan: the boosting loop moves inside shard_map, so
+        the pipelined executor double-buffers multi-device training as
+        it does the serial MXU path. The MXU grower takes the serial
+        path's own scan with a `psum_axis` (boosting/fused.py), the
+        portable grower the one in distributed/fused.py. What stays
+        per-iteration, and why: more than one process (the watchdog's
+        guarded collective needs a host boundary), GOSS (a global top-k
+        over all rows), EFB, CEGB and rescan monotone (per-iteration
+        host state), feature- and voting-parallel (no sharded scan
+        body), several trees an iteration, and an objective whose
+        gradients read other rows (a ranking objective's query
+        tables)."""
         cfg = self.config
         return (self._learner.is_parallel
-                and self._learner.device != "mxu"
                 and getattr(self, "_nproc", 1) <= 1
                 and self.comm.mode == "data"
                 and cfg.boosting == "gbdt"
                 and self.num_tree_per_iteration == 1
                 and self._efb is None
-                and not self._mono_nonbasic)
+                and not self._mono_nonbasic
+                and not getattr(self.objective, "table_state", ()))
 
     def _fused_sample_fn(self):
         """In-scan bagging/GOSS (fused.py contract): returns
@@ -1352,6 +1370,14 @@ class GBDT:
                     mask = (u < frac).astype(jnp.float32)
                 else:
                     mask = (u < cfg.bagging_fraction).astype(jnp.float32)
+                if self._learner.is_parallel:
+                    # inside shard_map: every device drew the whole
+                    # mask, and takes its own rows of it (a padded row
+                    # is never in the bag)
+                    mask = jax.lax.dynamic_slice_in_dim(
+                        jnp.pad(mask, (0, self._row_pad)),
+                        jax.lax.axis_index(self.comm.axis)
+                        * grad.shape[0], grad.shape[0])
                 if grad.ndim == 2:
                     return grad * mask[:, None], hess * mask[:, None], mask
                 return grad * mask, hess * mask, mask
@@ -1360,9 +1386,9 @@ class GBDT:
         return None, False
 
     def _build_sharded_fused(self):
-        """Fused-scan builder for the sharded data-parallel grower
-        (distributed/fused.py) — the _build_fused analogue when the
-        crossbar resolved a row-sharded learner."""
+        """Fused-scan builder for the PORTABLE data-parallel grower
+        (distributed/fused.py); the sharded MXU grower takes
+        _build_fused's own."""
         from ..distributed.fused import build_sharded_fused_train
         cfg = self.config
         self._fused_needs_keys = False
@@ -1399,7 +1425,7 @@ class GBDT:
     def _build_fused(self, debug: bool = False):
         from .fused import build_fused_train
         cfg = self.config
-        if self._grower is not None:
+        if self._learner.device != "mxu":
             return self._build_sharded_fused()
         needs_rng = (cfg.feature_fraction_bynode < 1.0 or cfg.extra_trees
                      or cfg.use_quantized_grad)
@@ -1412,7 +1438,10 @@ class GBDT:
             is_cat=self.is_cat_d, grower_kwargs=self._mxu_grow_kwargs(),
             shrinkage=self.shrinkage_rate, extra_seed=cfg.extra_seed,
             needs_rng=needs_rng, sample_fn=sample_fn,
-            num_class=self.num_tree_per_iteration)
+            num_class=self.num_tree_per_iteration,
+            # None on one device; the data-parallel learner's scan runs
+            # inside shard_map over it
+            mesh=self.mesh, row_pad=self._row_pad)
 
     def train_many(self, k: int) -> bool:
         """K boosting iterations with one device dispatch (and at most
@@ -1537,20 +1566,21 @@ class GBDT:
         stop = False
         cfg = self.config
         # A block that starts at iteration 0 runs whole in the fused
-        # program on the serial MXU path: the boost_from_average
-        # constant goes onto the scores first and into tree 0's leaves
-        # afterwards, which is all train_one_iter does differently
-        # there. So such a run compiles ONE growth program, not the
-        # per-iteration grower plus the scan around it (minutes each at
-        # 255 leaves). Kept on the per-iteration path: the sharded
-        # learners, and boost_from_average=false without init scores,
-        # where a root that cannot split takes its value from the
-        # objective instead.
+        # program on the MXU path, serial or data-parallel: the
+        # boost_from_average constant goes onto the scores first and
+        # into tree 0's leaves afterwards, which is all train_one_iter
+        # does differently there. So such a run compiles ONE growth
+        # program, not the per-iteration grower plus the scan around it
+        # (minutes each at 255 leaves). Kept on the per-iteration path:
+        # the portable sharded grower (its byte-parity contract,
+        # distributed/fused.py), and boost_from_average=false without
+        # init scores, where a root that cannot split takes its value
+        # from the objective instead.
         fused_ok = self._fused_eligible() and not getattr(
             self, "_fused_disabled", False)
         fuse_first = (
             self.iter_ == 0 and k > 0 and fused_ok
-            and self._grower is None
+            and self._learner.device == "mxu"
             and (cfg.boost_from_average or self._has_init_score))
         if self.iter_ == 0 and k > 0 and not fuse_first:
             # the first iteration owns boost_from_average / init-score
@@ -1603,6 +1633,8 @@ class GBDT:
                 with contextlib.nullcontext() if self._fused_warm_at(k) \
                         else span("boosting.build_program",
                                   program="fused_train", iter=iter0, k=k,
+                                  devices=1 if self.mesh is None
+                                  else int(self.mesh.devices.size),
                                   **self._hist_plan_attrs(),
                                   **self._cat_attrs()) as build:
                     if getattr(self, "_fused_run", None) is None:
@@ -1617,8 +1649,8 @@ class GBDT:
                     run, it0 = self._fused_run, \
                         jnp.asarray(self.iter_, jnp.int32)
                     if build is not None and hasattr(run, "arguments"):
-                        # (the sharded scan grows with the portable
-                        # grower and has no such operands)
+                        # (the portable sharded scan has no such
+                        # operands)
                         self._trace_operand_builds(
                             run.program, *run.arguments(
                                 self.train_score, it0, k=k,
@@ -1876,6 +1908,11 @@ class GBDT:
                     row_node, tree.leaf_value,
                     interpret=getattr(self, "_mxu_interpret", False))
             else:
+                # an XLA gather, about 10 ns a row on a v5e where the
+                # one-hot lookup costs 0.6 (26 ms a tree at 2,625,000
+                # rows a chip: PERF.md, PR 36). The sharded MXU learner
+                # pays it only off the fused block (a spec
+                # _sharded_fused_ok refuses, a block that degraded)
                 vals = tree.leaf_value[row_node]
         else:
             from ..learner.linear import linear_leaf_values

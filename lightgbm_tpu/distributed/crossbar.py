@@ -47,7 +47,8 @@ class LearnerSpec:
     @property
     def serial_mxu(self) -> bool:
         """The un-sharded MXU grower: what the packed 4-bit bins and the
-        one-hot score update are wired for."""
+        per-iteration path's one-hot score update are wired for (the
+        fused block's is on the MXU for the sharded grower too)."""
         return self.mode == "serial" and self.device == "mxu"
 
 

@@ -1,16 +1,16 @@
-"""Row-sharded fused multi-tree training: K sharded boosting iterations
-per device dispatch.
+"""Row-sharded fused multi-tree training for the PORTABLE grower: K
+sharded boosting iterations per device dispatch.
 
 `boosting/fused.py` keeps the whole boosting loop on device as a
-`lax.scan` — but only for the serial MXU learner. This module is the
-same reformulation for the distributed crossbar's data-parallel row:
-the scan body runs INSIDE `shard_map`, so every iteration's gradients,
-bagging mask, sharded tree growth (with its reduce-scatter/psum
-histogram merge collectives) and score update happen on the row shard,
-and the host sees one dispatch per K trees. This is what lets the
-PR-5 pipelined executor double-buffer multi-device training unchanged:
-`GBDT.train_many_dispatch` calls the builder's `run` through the exact
-signature the serial fused path uses.
+`lax.scan` for the MXU learner, serial or (given a mesh) data-parallel.
+This module is the same reformulation for the data-parallel row of the
+crossbar's portable device (`grow_tree` with its reduce-scatter/psum
+histogram merge): the scan body runs INSIDE `shard_map`, so every
+iteration's gradients, bagging mask, sharded tree growth and score
+update happen on the row shard, and the host sees one dispatch per K
+trees. `GBDT.train_many_dispatch` calls the builder's `run` through
+the exact signature the serial fused path uses, so the pipelined
+executor double-buffers it unchanged.
 
 Parity contract: gradients are elementwise, the bagging mask is the
 identical global draw every shard recomputes and slices, and

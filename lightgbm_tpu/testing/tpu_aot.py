@@ -12,8 +12,8 @@ executes on a device, so nothing here is a speed.
 
 compiles every ``pallas_call`` site, the per-iteration grower, the fused
 multi-tree scan, the serving predictor and the four-device data-parallel
-grower the way chip_smoke.py will meet them, and prints one line per
-program. tests/test_tpu_aot.py runs
+grower and fused block the way chip_smoke.py will meet them, and prints
+one line per program. tests/test_tpu_aot.py runs
 the kernel sites at a small shape (slow tier).
 """
 
@@ -210,42 +210,60 @@ def training_programs(params: dict, *, rows: int, features: int,
     }
 
 
+def _data_parallel_gbdt(params: dict, rows: int, features: int,
+                        devices: Sequence):
+    """This host's serial booster over synthetic rows, given the chip's
+    ``tree_learner=data`` learner and the described mesh of `devices`
+    (rows split evenly), and the row-wise and replicated shardings on
+    that mesh."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from ..distributed.crossbar import CROSSBAR
+    from ..parallel import CommSpec
+    g = _gbdt(params, rows, features)
+    g._learner = CROSSBAR["mxu", "data"]
+    g.mesh = Mesh(np.array(devices), ("data",))
+    g.comm = CommSpec(axis="data", mode="data", num_devices=len(devices),
+                      top_k=g.config.top_k, hist_agg="psum")
+    g._sharded_rng = bool(g.config.use_quantized_grad)
+    return (g, NamedSharding(g.mesh, P("data")),
+            NamedSharding(g.mesh, P()))
+
+
 def sharded_grower_program(params: dict, *, rows: int, features: int,
                            devices: Sequence):
     """(jitted grower, placed arg specs) of ``tree_learner=data`` with
-    the MXU grower inside shard_map over `devices` — what the smoke's
-    four-device leg dispatches per tree, rows split evenly."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from ..parallel import CommSpec
-    from ..parallel.learner import make_sharded_grower
-    g = _gbdt(params, rows, features)    # for its Config and its hp
-    cfg = g.config
-    mesh = Mesh(np.array(devices), ("data",))
-    comm = CommSpec(axis="data", mode="data", num_devices=len(devices),
-                    top_k=cfg.top_k, hist_agg="psum")
-    grower = make_sharded_grower(
-        mesh, comm, num_leaves=cfg.num_leaves, max_depth=cfg.max_depth,
-        hp=g.hp, leafwise=False, bmax=g.bmax, use_mxu=True,
-        with_rng=bool(cfg.use_quantized_grad),
-        mxu_kwargs=dict(
-            hist_double_prec=cfg.gpu_use_dp,
-            tail_split_cap=cfg.tail_split_cap,
-            hist_subtraction=cfg.hist_subtraction,
-            overshoot=cfg.growth_overshoot,
-            bridge_gate=cfg.growth_bridge_gate,
-            quantized_grad=cfg.use_quantized_grad, const_hessian=0.0,
-            hist_backend=cfg.hist_backend,
-            partition_impl=cfg.partition_impl))
-    rowwise, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    the MXU grower inside shard_map over `devices`: what a run that
+    leaves the fused block dispatches per tree."""
+    g, rowwise, whole = _data_parallel_gbdt(params, rows, features,
+                                            devices)
     specs = [_sds((rows, features), jnp.uint8, rowwise)] + \
         [_sds((rows,), jnp.float32, rowwise)] * 3 + \
         [_sds((features,), jnp.float32, whole),
          _sds((features,), jnp.int32, whole),
          _sds((features,), jnp.bool_, whole),
          _sds((features,), jnp.bool_, whole)]
-    if cfg.use_quantized_grad:
+    if g._sharded_rng:
         specs.append(_sds((2,), jnp.uint32, whole))
-    return grower, specs
+    return g._create_grower(), specs
+
+
+def sharded_fused_program(params: dict, *, rows: int, features: int,
+                          block: int, devices: Sequence):
+    """(program, placed arg specs) of one fused block of `block` trees
+    of the same learner: what ``lgb.train`` dispatches per block on
+    four chips. A described device holds no array, so what the builder
+    places on the mesh becomes its spec."""
+    from unittest import mock
+    g, rowwise, whole = _data_parallel_gbdt(params, rows, features,
+                                            devices)
+    g.bins = _sds(g.bins.shape, g.bins.dtype, rowwise)
+    with mock.patch.object(jax, "device_put", lambda a, where: _sds(
+            a.shape, a.dtype, where)):
+        run = g._build_fused()
+    return run.program, [_sds((rows,), jnp.float32, rowwise),
+                         _sds((), jnp.int32, whole),
+                         _sds((block, 2), jnp.uint32, whole),
+                         *run.operands]
 
 
 def serving_programs(*, features: int, leaves: int, max_bin: int,
@@ -318,8 +336,10 @@ def main(argv=None) -> int:
         devices = topology_devices()
         grower, specs = sharded_grower_program(base, devices=devices,
                                                **shape)
-        return {"grow_tree_mxu_x%d" % len(devices):
-                (grower, specs)}
+        return {"grow_tree_mxu_x%d" % len(devices): (grower, specs),
+                "fused_block_k%d_x%d" % (args.block, len(devices)):
+                sharded_fused_program(base, block=args.block,
+                                      devices=devices, **shape)}
 
     groups.append(("data_parallel", sharded))
     only = [t for t in args.only.split(",") if t]

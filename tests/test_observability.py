@@ -698,7 +698,9 @@ class TestSpansOfATrainingRun:
         built = [s["attrs"] for s in captured["fused_spans"]
                  if s["name"] == "boosting.build_program"]
         # (PR 35: every build says what of the data is categorical)
-        none = {"has_cat": False, "cat_columns": 0, "cat_bins": 0}
+        # (PR 36: and over how many devices its program runs)
+        none = {"has_cat": False, "cat_columns": 0, "cat_bins": 0,
+                "devices": 4}
         assert {"program": "fused_train", "iter": 1, "k": 4, **none} in built
         assert {"program": "fused_train", "iter": 5, "k": 5, **none} in built
 
