@@ -85,15 +85,17 @@ def stacked_score_traj(stacked, score0, bins, num_bins, missing_is_nan,
 
 
 @jax.jit
-def split_block(stacked):
+def split_block(stacked, counters=None):
     """Every tree of a stacked block as TreeArrays of its own, in the
     order the tree list wants them (iteration-major, class-minor: the
     leading axes of a stacked field are [k] or [k, num_class]), and
     the leaf count the stop poll reads: the block's last tree's, the
     largest over its classes (a model has stalled only if EVERY class
-    has), and [nodes, cat_nodes] of the block (the `entry.unpack_block`
-    span carries them). ONE program per block length and class count,
-    where slicing
+    has), [nodes, cat_nodes] of the block and its trees' growth
+    `counters` as the scan stacked them ([k(, num_class), C]: handed
+    through, so that they are this program's outputs and ready with
+    the views; the `entry.unpack_block` span carries both). ONE
+    program per block length and class count, where slicing
     the fields tree by tree from the host was one program and one
     index transfer a field a tree (170 a block of ten)."""
     trees = [jax.tree_util.tree_map(lambda a: a[ix], stacked)
@@ -103,16 +105,24 @@ def split_block(stacked):
     internal = (stacked.split_feature >= 0) & ~stacked.is_leaf
     decides = jnp.stack([jnp.sum(internal),
                          jnp.sum(internal & stacked.is_cat)])
-    return trees, jnp.max(stacked.num_leaves[-1]), decides
+    return trees, jnp.max(stacked.num_leaves[-1]), decides, counters
 
 
 def build_fused_train(*, objective, bins, feature_mask_fn,
                       num_bins, missing_is_nan, is_cat, grower_kwargs,
                       shrinkage: float, extra_seed: int, needs_rng: bool,
                       sample_fn=None, num_class: int = 1,
-                      debug: bool = False, mesh=None, row_pad: int = 0):
+                      mesh=None, row_pad: int = 0):
     """Return run(score, it0, k, sample_keys=None) ->
-    (score', stacked TreeArrays).
+    (score', stacked TreeArrays, counters).
+
+    `counters` is what each tree of the block ran, counted inside the
+    growth program by the passes themselves and stacked by the scan
+    beside the trees: int32 [k(, num_class), C], C as
+    grower_mxu.GROWTH_COUNTERS names them (passes by formulation, the
+    bridge and the fixup iterations, the rows live in those passes,
+    the leaves before the prune). Under `mesh` the rows are the
+    mesh's and every device holds the same counts.
 
     `mesh` (None: one device) makes it the data-parallel learner's
     block: the SAME scan runs inside `shard_map` over the mesh's row
@@ -148,10 +158,6 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
 
     num_class > 1 grows one tree per class per step; stacked tree
     leaves gain a leading [k, num_class] shape and score is [N, K].
-
-    debug=True additionally stacks per-tree growth counters
-    (fixup_iters, pre_prune_leaves) — the decay instrumentation
-    (helpers/instrument_decay.py); stacked becomes (trees, counters).
     """
     from ..distributed.fused import objective_row_state
     from ..learner.grower_mxu import grow_tree_mxu
@@ -170,11 +176,10 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     def one_tree(bins, grad, hess, cnt, fmask, it):
         rng = jax.random.fold_in(jax.random.PRNGKey(extra_seed), it) \
             if needs_rng else None
-        out = grow_tree_mxu(
+        tree, row_node, counters = grow_tree_mxu(
             bins, grad, hess, cnt, fmask, num_bins,
-            missing_is_nan, is_cat, rng_key=rng, debug_info=debug,
+            missing_is_nan, is_cat, rng_key=rng, growth_counters=True,
             psum_axis=axis, **grower_kwargs)
-        tree, row_node = out[0], out[1]
         # device-side stand-in for the "no further splits" break: a tree
         # that made no split becomes all-zero and the scan carries on
         # (train_one_iter's ok-zeroing, gbdt.py)
@@ -182,7 +187,7 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
         tree = tree._replace(leaf_value=tree.leaf_value * (shrink * ok))
         vals = node_values_mxu(row_node, tree.leaf_value,
                                interpret=interpret)
-        return tree, vals, (out[2] if debug else None)
+        return tree, vals, counters
 
     def body(bins, obj, score, xs):
         it, key = xs
@@ -202,23 +207,17 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
             cnt = jnp.ones(rows, jnp.float32) if real is None else real
         fmask = feature_mask_fn(it)
         if num_class == 1:
-            tree, vals, dbg = one_tree(bins, grad, hess, cnt, fmask, it)
-            out = (tree, dbg) if debug else tree
-            return score + vals, out
-        trees, dbgs = [], []
+            tree, vals, counters = one_tree(bins, grad, hess, cnt, fmask,
+                                            it)
+            return score + vals, (tree, counters)
+        grown = []
         for cls in range(num_class):
-            t, vals, dbg = one_tree(bins, grad[:, cls], hess[:, cls], cnt,
-                                    fmask, it)
+            t, vals, counters = one_tree(bins, grad[:, cls], hess[:, cls],
+                                         cnt, fmask, it)
             score = score.at[:, cls].add(vals)
-            trees.append(t)
-            dbgs.append(dbg)
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *trees)
-        if debug:
-            return score, (stacked,
-                           jax.tree_util.tree_map(
-                               lambda *xs: jnp.stack(xs), *dbgs))
-        return score, stacked
+            grown.append((t, counters))
+        return score, jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *grown)
 
     # `score` is donated: the caller hands over its train-score buffer
     # and must treat the passed-in array as consumed (use the returned
@@ -238,7 +237,8 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
                             (its, sample_keys))
 
     if mesh is not None:
-        # score, bins and row state by rows; trees out whole
+        # score, bins and row state by rows; trees and their counters
+        # out whole
         by_rows = NamedSharding(mesh, P(axis))
         program = shard_map(
             program, mesh=mesh, out_specs=(P(axis), P()), check_vma=False,
@@ -264,9 +264,9 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
         return (score, it0, sample_keys) + operands
 
     def run(score, it0, *, k: int, sample_keys=None):
-        score, stacked = program(*arguments(score, it0, k=k,
-                                            sample_keys=sample_keys))
-        return (score[:num_data] if row_pad else score), stacked
+        score, (stacked, counters) = program(*arguments(
+            score, it0, k=k, sample_keys=sample_keys))
+        return (score[:num_data] if row_pad else score), stacked, counters
 
     run.program = program
     run.operands = operands

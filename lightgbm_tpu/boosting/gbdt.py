@@ -1422,7 +1422,7 @@ class GBDT:
             extra_seed=cfg.extra_seed, needs_rng=self._sharded_rng,
             bagging=bagging)
 
-    def _build_fused(self, debug: bool = False):
+    def _build_fused(self):
         from .fused import build_fused_train
         cfg = self.config
         if self._learner.device != "mxu":
@@ -1431,7 +1431,7 @@ class GBDT:
                      or cfg.use_quantized_grad)
         sample_fn, needs_keys = self._fused_sample_fn()
         self._fused_needs_keys = needs_keys
-        return build_fused_train(debug=debug,
+        return build_fused_train(
             objective=self.objective, bins=self.bins,
             feature_mask_fn=self._feature_mask_at,
             num_bins=self.num_bins_d, missing_is_nan=self.missing_is_nan_d,
@@ -1488,6 +1488,9 @@ class GBDT:
                 nodes, cat_nodes = np.asarray(handle.pop("decides"))
                 unpack.attrs.update(nodes=int(nodes),
                                     cat_nodes=int(cat_nodes))
+                ran = self._growth_counts(handle.pop("counters"))
+                unpack.attrs.update(ran)
+                handle["ran"] = ran
                 for n, tree in enumerate(views):
                     with span("entry.unpack_tree", iter=it0, k=k,
                               tree=it0 * kcls + n):
@@ -1497,6 +1500,25 @@ class GBDT:
             handle["host_s"] = unpack.end - ready_at
             self._obs_close_block(it0, unpack.end)
         return handle["stop"]
+
+    def _growth_counts(self, stacked) -> dict:
+        """What a fused block's trees ran, summed over the block on the
+        host from the counters the scan stacked ([k(, num_class), C]
+        int32, grower_mxu.GROWTH_COUNTERS; None from the portable
+        sharded scan, which counts nothing: no attribute then).
+        `passes` is the passes of either formulation, `trees` the
+        trees they are summed over and `rows` the rows ONE pass sweeps
+        (the dataset's, over the whole mesh), so that a reader of the
+        span needs nothing else to turn them into passes a tree and
+        live rows into a share."""
+        if stacked is None:
+            return {}
+        from ..learner.grower_mxu import GROWTH_COUNTERS
+        per_tree = np.asarray(stacked, np.int64) \
+            .reshape(-1, len(GROWTH_COUNTERS))
+        ran = dict(zip(GROWTH_COUNTERS, map(int, per_tree.sum(axis=0))))
+        return dict(passes=ran["onehot_passes"] + ran["grouped_passes"],
+                    **ran, trees=len(per_tree), rows=int(self.num_data))
 
     def _obs_close_block(self, iter0: Optional[int], now: float) -> None:
         """observe=true: the telemetry record of the fused block still
@@ -1671,7 +1693,7 @@ class GBDT:
             # first time, building its program): the scan is
             # asynchronous and nothing here waits for it
             with span("entry.dispatch", iter=iter0, k=k) as dispatch:
-                score, stacked = retry_call(
+                score, stacked, ran = retry_call(
                     _attempt, attempts=cfg.retry_max_attempts,
                     backoff_ms=cfg.retry_backoff_ms,
                     backoff_max_ms=cfg.retry_backoff_max_ms,
@@ -1752,11 +1774,12 @@ class GBDT:
                 trajs.append(traj)
             self._fused_valid_traj = trajs
         self.iter_ += k
-        # the block's trees as the tree list wants them, and its last
-        # leaf count: ONE program, enqueued right behind the block, so
-        # finalize_block finds them ready the moment the block is
+        # the block's trees as the tree list wants them, its last leaf
+        # count and what its trees ran: ONE program, enqueued right
+        # behind the block, so finalize_block finds them ready the
+        # moment the block is
         from .fused import split_block
-        views, pending, decides = split_block(model_trees)
+        views, pending, decides, ran = split_block(model_trees, ran)
         # lagged stall poll (see train_one_iter): a stalled model keeps
         # producing all-zero trees, so checking a batch's last tree
         # roughly every _stop_poll_every ITERATIONS is enough — poll
@@ -1781,6 +1804,7 @@ class GBDT:
         self._note_nleaves(pending)
         return {"mode": "fused", "trees": views, "k": k, "kcls": kcls,
                 "stop": stop_hint, "iter": iter0, "decides": decides,
+                "counters": ran,
                 "in_flight": dispatch.attrs["in_flight"]}
 
     @staticmethod

@@ -83,8 +83,9 @@ def build_sharded_fused_train(*, mesh, comm, objective, bins,
                               needs_rng: bool, bagging: Optional[dict]
                               = None):
     """Return run(score, it0, *, k, sample_keys=None) ->
-    (score'[:num_data], stacked TreeArrays) — the serial
-    `build_fused_train` contract, over the row-sharded mesh.
+    (score'[:num_data], stacked TreeArrays, None) — the serial
+    `build_fused_train` contract, over the row-sharded mesh (the
+    portable grower counts nothing of its growth: no counters).
 
     `bins` is the already-sharded [N_pad, F] binned matrix (P(axis)),
     `bins_ft` the optional feature-shard transpose from
@@ -202,6 +203,6 @@ def build_sharded_fused_train(*, mesh, comm, objective, bins,
         args += (bins,)
         with mesh:
             out_score, stacked = jit_run(*args)
-        return out_score[:num_data], stacked
+        return out_score[:num_data], stacked, None
 
     return run

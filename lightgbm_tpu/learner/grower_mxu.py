@@ -36,6 +36,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .grower import _init_tree, TreeArrays
 from .histogram import build_histograms
@@ -181,6 +182,47 @@ def _kernel_cap(s: int) -> int:
 
 #: index of the done flag in the growth state tuple
 _DONE = 9
+
+#: what a tree counts of its own growth, inside the program
+#: (grow_tree_mxu(growth_counters=True) returns them as one int32
+#: vector, in this order): the passes that RAN, by the formulation of
+#: their histogram build (whatever is not one-hot counts as grouped);
+#: those of them past the schedule (the bridge, 0 or 1, and the fixup
+#: while_loop's iterations); the rows that stood in a slot those passes
+#: built (the smaller siblings and the children of stale parents: the
+#: mesh's rows under psum_axis, as the tree counts rows); the leaves
+#: before the prune to best-first.
+GROWTH_COUNTERS = ("onehot_passes", "grouped_passes", "bridge_passes",
+                   "fixup_iters", "onehot_rows", "grouped_rows",
+                   "leaves_grown")
+_INT32_MAX = 2 ** 31 - 1
+
+
+def _count_pass(counts: jax.Array, kern: jax.Array, form: str,
+                stage: str) -> jax.Array:
+    """GROWTH_COUNTERS after one pass that ran: one more pass of its
+    formulation (and of its stage, past the schedule), and the rows of
+    the slots it built. The rows are the count channel of the built
+    histogram `kern` [slots, F, B, 3], summed over one column's bins:
+    every row of a built slot falls in exactly one of them, and under
+    psum_axis the histogram is all-reduced already, so the mesh's rows
+    are counted with no collective of their own. The sum is float32,
+    like the tree's own counts: exact up to 2^24 live rows a pass,
+    rounded beyond. The running total saturates instead of wrapping."""
+    live = jnp.minimum(jnp.round(jnp.sum(kern[:, 0, :, 2])),
+                       jnp.float32(2 ** 31 - 128)).astype(jnp.int32)
+    kind = "onehot" if form == "onehot" else "grouped"
+    ran = np.zeros(len(GROWTH_COUNTERS), np.int32)
+    ran[GROWTH_COUNTERS.index(kind + "_passes")] = 1
+    if stage != "pass":
+        ran[GROWTH_COUNTERS.index(
+            "bridge_passes" if stage == "bridge" else "fixup_iters")] = 1
+    rows_at = np.zeros(len(GROWTH_COUNTERS), np.int32)
+    rows_at[GROWTH_COUNTERS.index(kind + "_rows")] = 1
+    add = ran + live * rows_at
+    total = counts + add
+    # two non-negative int32 whose sum wrapped read negative
+    return jnp.where(total < 0, _INT32_MAX, total)
 
 
 def growth_plan(*, num_leaves: int, overshoot: float = 0.0,
@@ -463,7 +505,7 @@ def operand_builds(jaxpr, rows: Optional[int] = None) -> dict:
                      "psum_axis",
                      "quantized_grad", "packed4",
                      "const_hessian", "hist_backend", "partition_impl",
-                     "cegb_cfg", "debug_info"))
+                     "cegb_cfg", "growth_counters"))
 def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                   cnt_weight: jax.Array, feature_mask: jax.Array,
                   num_bins: jax.Array, missing_is_nan: jax.Array,
@@ -490,7 +532,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                   forced=None,
                   cegb_cfg=None,
                   cegb_state=None,
-                  debug_info: bool = False
+                  growth_counters: bool = False
                   ) -> Tuple[TreeArrays, jax.Array]:
     """Grow one tree; same contract as grower.grow_tree (serial mode).
 
@@ -542,7 +584,14 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     state win on wide-sparse data) and are expanded per pass back to
     original features for the split scan; routing decodes original
     local bins through efb.loc_table inside the kernels. Same math as
-    the portable grower's EFB path (grower.py), so trees match it."""
+    the portable grower's EFB path (grower.py), so trees match it.
+
+    growth_counters=True returns (tree, row_node, counters): what the
+    tree ran, counted in the pass state by every pass that runs
+    (GROWTH_COUNTERS, one int32 vector). The fused scan asks for them
+    with every tree and carries them out with the block
+    (boosting/fused.py); the per-iteration callers take the two values
+    and their program counts nothing."""
     n = bins.shape[0]
     f = int(num_bins.shape[0]) if (packed4 or efb is not None) \
         else bins.shape[1]
@@ -638,8 +687,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     use_interaction = interaction_groups is not None and \
         len(interaction_groups) > 0
     if use_interaction:
-        import numpy as _np
-        gm = _np.zeros((len(interaction_groups), f), _np.bool_)
+        gm = np.zeros((len(interaction_groups), f), np.bool_)
         for gi, grp in enumerate(interaction_groups):
             for fi in grp:
                 if 0 <= fi < f:
@@ -709,7 +757,8 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         the frontier histograms — fused single sweep when the histogram
         block fits VMEM, else the two-kernel fallback (wide datasets).
         Under psum_axis the local histograms are all-reduced, so the
-        subtraction/scan math downstream sees global sums.
+        subtraction/scan math downstream sees global sums. Returns
+        (histograms, row_node, formulation): the last is static.
 
         m_cap statically slices the node tables: pass p can only hold
         node ids < 2*S_p, so early passes route against a 128-wide
@@ -747,7 +796,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     h = h.at[..., 1].set(h[..., 2] * jnp.float32(ch))
             if quant:
                 h = h * hist_scale
-            return _allred(h), rn
+            return _allred(h), rn, form
         # small frontiers run cheaper at half blocks, large ones
         # prefer the wider block. EFB keeps rb=1024 in BOTH modes:
         # expansion's original-feature route side needs the VMEM
@@ -799,17 +848,18 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 **hist_cfg(nslots))
         if quant:
             h = h * hist_scale  # integer sums -> gradient units
-        return _allred(h), rn
+        return _allred(h), rn, form
 
     def one_pass(s, st, pass_idx, k_cap=None, sk_next=None, m_cap=None,
-                 sk_self=None):
+                 sk_self=None, stage="pass"):
         """One growth pass at scan capacity `s` (python int). sk_next is
         the kernel-slot capacity of the NEXT pass (selection is throttled
-        so committed splits' children fit it)."""
+        so committed splits' children fit it). `stage` is hist_pass_plan's
+        name for it ("pass", "bridge", "fixup"), for the counters."""
         (tree, row_node, tbl_c, member_c, slot_nodes, best, cons_min,
          cons_max, path_mask, done, parent_hist, pair_parent, pair_sleft,
          pair_kstart, node_force, forced_ok_st, feat_used,
-         was_forced) = st
+         was_forced, counts) = st
         sn = slot_nodes[:s]
         if sk_next is None:
             sk_next = _kernel_cap(min(2 * s, s_max)) if hist_subtraction \
@@ -819,8 +869,10 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # build only the slots assigned by the previous pass (smaller
             # siblings + both children of stale parents) ...
             sk = sk_self if sk_self is not None else _kernel_cap(s)
-            kern, row_node = sweep(row_node, tbl_c, member_c, sk,
-                                   m_cap=m_cap)
+            kern, row_node, form = sweep(row_node, tbl_c, member_c, sk,
+                                         m_cap=m_cap)
+            if growth_counters:
+                counts = _count_pass(counts, kern, form, stage)
             # ... and reconstruct the full scan tensor [s, F, B, 3] with
             # ONE 0/+-1 selection matmul against [kernel rows ;
             # parent-pair rows]: row s (pair i = s//2, left iff s even)
@@ -863,8 +915,10 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 preferred_element_type=jnp.float32) \
                 .reshape(s, fk, bk, 3)
         else:
-            hist, row_node = sweep(row_node, tbl_c, member_c, s,
-                                   m_cap=m_cap)
+            hist, row_node, form = sweep(row_node, tbl_c, member_c, s,
+                                         m_cap=m_cap)
+            if growth_counters:
+                counts = _count_pass(counts, hist, form, stage)
         if efb is not None and efb.scan is None:
             # expansion fallback: subtraction/parent state live in
             # bundle space (above); the split scan runs on original
@@ -1188,7 +1242,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         return (new_tree, row_node, tbl_c, member_c, slot_nodes, new_best,
                 cons_min, cons_max, path_mask, done, parent_hist,
                 pair_parent, pair_sleft, pair_kstart, node_force,
-                forced_ok_st, feat_used, was_forced)
+                forced_ok_st, feat_used, was_forced, counts)
 
     # initial tables: nothing split, root (node 0) sits in kernel slot 0,
     # so the first sweep is an identity route + a root histogram. Pair 0
@@ -1216,16 +1270,18 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
              jnp.full(P_all, -1, jnp.int32),               # pair_parent
              jnp.full(P_all, True),                        # pair_sleft
              jnp.full(P_all, -1, jnp.int32).at[0].set(0),  # pair_kstart
-             node_force0, forced_ok0, feat_used0, was_forced0)
+             node_force0, forced_ok0, feat_used0, was_forced0,
+             jnp.zeros(len(GROWTH_COUNTERS), jnp.int32))   # counts
 
-    def cond_pass(s, st, pass_idx, k_cap=None, sk_next=None, m_cap=None):
+    def cond_pass(s, st, pass_idx, k_cap=None, sk_next=None, m_cap=None,
+                  stage="pass"):
         # skip whole passes once growth is done — e.g. the full-capacity
         # bridge pass after a tree that completed on schedule (a free
-        # S=s_max histogram otherwise)
+        # S=s_max histogram otherwise). A skipped pass counts nothing
         return jax.lax.cond(
             st[_DONE], lambda st_: st_,
             lambda st_: one_pass(s, st_, pass_idx, k_cap, sk_next,
-                                 m_cap), st)
+                                 m_cap, stage=stage), st)
 
     # ---- unrolled doubling schedule (growth_plan) ----
     schedule = plan.schedule
@@ -1261,7 +1317,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         state = state[:_DONE] + (gated,) + state[_DONE + 1:]
     if schedule:
         state = cond_pass(s_max, state, len(schedule), k_cap=k_fix,
-                          sk_next=sk_fix)
+                          sk_next=sk_fix, stage="bridge")
 
     first_fix = len(schedule) + 1
 
@@ -1274,12 +1330,11 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # (traced) fixup iteration counter
         st, it = c
         return one_pass(s_fix, st, it + 1000, k_cap=k_fix,
-                        sk_next=sk_fix, sk_self=sk_fix), it + 1
+                        sk_next=sk_fix, sk_self=sk_fix,
+                        stage="fixup"), it + 1
 
-    state, it_final = jax.lax.while_loop(
+    state, _ = jax.lax.while_loop(
         cond, body, (state, jnp.asarray(first_fix, jnp.int32)))
-    fixup_iters = it_final - first_fix
-    pre_prune_leaves = state[0].num_leaves
 
     # ---- epilogue: flush routing, prune to best-first, exact refit ----
     # flush the routing of the last pass's splits (sweeps route at the
@@ -1330,8 +1385,12 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             sum_grad=jnp.where(lf, sums[:, 0], tree_out.sum_grad),
             sum_hess=jnp.where(lf, sums[:, 1], tree_out.sum_hess),
             count=jnp.where(lf, sums[:, 2], tree_out.count))
-    if debug_info:
-        return tree_out, row_node, (fixup_iters, pre_prune_leaves)
+    if growth_counters:
+        # (no caller asks for them beside the CEGB state: the fused
+        # scan, which does, is closed to CEGB)
+        return tree_out, row_node, state[-1].at[
+            GROWTH_COUNTERS.index("leaves_grown")].set(
+                state[0].num_leaves)
     if use_cegb:
         # feature-used flags persist across trees (portable contract,
         # grower.py:674); no lazy state here, flags pass through

@@ -74,6 +74,18 @@ class PipelineStats:
     dispatch to the next, which the unpack's wait for the block before
     holds to what the device takes. `host_ms` is the host's own work in
     the `entry.unpack_block` span inside it, its wait left out.
+    `passes` and `fixup_iters` are what the trees of the block unpacked
+    inside it ran, counted inside the growth program and read from that
+    same span (the histogram passes of either formulation, and those of
+    them that were iterations of the fixup loop, summed over the
+    block's trees; None where no block was unpacked or its program
+    counts nothing). That block is the one BEFORE, the very block whose
+    device time `device_ms` reads once the host runs ahead: so a slow
+    entry beside more passes is the trees' doing, and beside the same
+    passes the machine's. (In a run with valid sets `device_ms` is the
+    entry's own block and the counts are still the block before's; a
+    run's last block is unpacked after the loop and is on the ring
+    alone.)
     `in_flight` counts the blocks enqueued while the block before them
     was still running, and `overlap_frac` is their share: the share of
     block boundaries the device crossed without waiting for the host
@@ -90,11 +102,13 @@ class PipelineStats:
         self._recent = collections.deque(maxlen=self._KEEP)
 
     def add(self, k: int, host_ms: float, device_ms: float,
-            in_flight: bool = False) -> None:
+            in_flight: bool = False, passes: Optional[int] = None,
+            fixup_iters: Optional[int] = None) -> None:
         self.blocks += 1
         self.iterations += int(k)
         self.in_flight += bool(in_flight)
-        self._recent.append((int(k), float(host_ms), float(device_ms)))
+        self._recent.append((int(k), float(host_ms), float(device_ms),
+                             passes, fixup_iters))
 
     @property
     def block_sizes(self) -> List[int]:
@@ -111,6 +125,16 @@ class PipelineStats:
         return [r[2] for r in self._recent]
 
     @property
+    def passes(self) -> List[Optional[int]]:
+        """Histogram passes the trees of the block unpacked here ran."""
+        return [r[3] for r in self._recent]
+
+    @property
+    def fixup_iters(self) -> List[Optional[int]]:
+        """Fixup-loop iterations among them."""
+        return [r[4] for r in self._recent]
+
+    @property
     def overlap_frac(self) -> float:
         """Share of blocks enqueued behind a running one."""
         return self.in_flight / self.blocks if self.blocks else 0.0
@@ -123,6 +147,8 @@ class PipelineStats:
             "block_sizes": self.block_sizes,
             "host_ms": [round(v, 3) for v in self.host_ms],
             "device_ms": [round(v, 3) for v in self.device_ms],
+            "passes": self.passes,
+            "fixup_iters": self.fixup_iters,
             "overlap_frac": round(self.overlap_frac, 4),
         }
 
@@ -229,10 +255,11 @@ def run_pipelined(booster, *, start_iter: int, num_boost_round: int,
                 # this block's dispatch (entry.unpack_block, in
                 # finalize_block): it waits for THAT block, which is
                 # this loop's backpressure
-                host_s = 0.0
+                host_s, ran = 0.0, {}
                 if pending is not None:
                     booster.finalize_block(pending)
                     host_s = pending.get("host_s", 0.0)
+                    ran = pending.get("ran", {})
                     pending = None
                 # ---- explicit sync, where callbacks decide on this
                 # block's metrics: small metric arrays in device-eval
@@ -245,7 +272,8 @@ def run_pipelined(booster, *, start_iter: int, num_boost_round: int,
                         jax.block_until_ready(traj)
                 wall_s = sync.end - blk.start
                 in_flight = bool(handle.get("in_flight"))
-                stats.add(b, host_s * 1e3, wall_s * 1e3, in_flight)
+                stats.add(b, host_s * 1e3, wall_s * 1e3, in_flight,
+                          ran.get("passes"), ran.get("fixup_iters"))
                 if _obs.enabled:
                     _obs.record_pipeline_block(b, wall_s, host_s,
                                                in_flight)
