@@ -79,8 +79,9 @@ PARAMS = {"objective": "binary", "num_leaves": NUM_LEAVES,
           "hist_backend": os.environ.get("LGBM_TPU_HIST_BACKEND",
                                          "auto"),
           # row partition of the slot-grouped build: "auto" resolves
-          # to the rank sweep (byte-identical to the argsort oracle);
-          # pin LGBM_TPU_PARTITION_IMPL=argsort to measure the other.
+          # to the stream partition (byte-identical to the argsort
+          # oracle); pin LGBM_TPU_PARTITION_IMPL=rank or =argsort to
+          # measure the others.
           "partition_impl": os.environ.get("LGBM_TPU_PARTITION_IMPL",
                                            "auto")}
 # Bench posture vs library defaults (ROADMAP D3): quantized gradients

@@ -48,6 +48,9 @@ __all__ = ["PerfHotPathSortRule", "HOT_PATH_MANIFEST"]
 #: learner/" — so host-side preprocessing keeps its freedom.
 HOT_PATH_MANIFEST = {
     ("histogram_pallas.py", "partition_rows"),
+    ("histogram_pallas.py", "partition_table"),
+    ("histogram_pallas.py", "partition_stream"),
+    ("histogram_pallas.py", "_layout"),
     ("histogram_pallas.py", "_stable_positions"),
     ("histogram_pallas.py", "build_histograms_scatter"),
     ("histogram_pallas.py", "build_histograms_pallas"),
@@ -70,9 +73,9 @@ class PerfHotPathSortRule(Rule):
     id = "PERF001"
     severity = "error"
     doc = ("O(N log N) `argsort` inside a registered device hot-path "
-           "function (HOT_PATH_MANIFEST, rules_perf.py) — the rank "
+           "function (HOT_PATH_MANIFEST, rules_perf.py) — the stream "
            "partition made these paths row-linear; route the ordering "
-           "through partition_rows(impl='rank') or, for a retained "
+           "through partition_table or, for a retained "
            "parity oracle, suppress the exact line (lexical fallback; "
            "TRACE001 checks the traced program)")
 

@@ -658,18 +658,26 @@ class GBDT:
         self._hist_backend = hb
         self._hist_plan = self._hist_pass_plan(hb)
         self._operand_builds = None     # read from the next trace
-        _obs.record_hist_plan(hb, self._hist_plan)
+        _obs.record_hist_plan(hb, self._hist_plan, self._partition())
         return hb
+
+    def _partition(self) -> str:
+        """What config.partition_impl resolves to for this run's grouped
+        passes (stream | rank | argsort): static."""
+        from ..learner.histogram_pallas import resolve_partition
+        return resolve_partition(self.config.partition_impl)
 
     def _hist_plan_attrs(self) -> dict:
         """The per-pass plan as attributes of a boosting.build_program
         span: which formulation each pass of the program being built
-        uses. Empty off the MXU growth path."""
+        uses, and which partition its grouped passes were built with.
+        Empty off the MXU growth path."""
         if self._learner.device != "mxu":
             return {}
         self._resolved_hist_backend()
         return {"hist_plan": ",".join("%d:%s" % (sk, form)
                                       for _, sk, form in self._hist_plan),
+                "partition": self._partition(),
                 "grouped_passes_per_tree": sum(
                     form == "grouped" and stage != "fixup"
                     for stage, _, form in self._hist_plan)}

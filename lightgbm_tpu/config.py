@@ -475,13 +475,18 @@ _PARAMS: List[ParamSpec] = [
        "lands in observability and the bench JSON"),
     _p("partition_impl", str, "auto", (),
        lambda v: v in ("auto", "argsort", "rank"),
-       "row-partitioning algorithm behind the slot-grouped build "
-       "(histogram_pallas.py partition_rows): 'rank' = stable rank "
-       "from one sweep of triangular matmuls whose cost does not grow "
-       "with the frontier width, 'argsort' = the stable sort, retained "
-       "as the bit-parity oracle. 'auto' = rank. Both produce the "
-       "identical group-contiguous block layout, so the choice is "
-       "byte-neutral on model.txt"),
+       "how the slot-grouped build brings a pass's live rows into its "
+       "group-contiguous block layout (histogram_pallas.py "
+       "partition_table): 'auto' = the stream partition, ONE kernel "
+       "that sweeps the row table once, moves each tile's live rows on "
+       "the MXU and writes them to the layout by DMA; 'rank' = its "
+       "parent, kept as an A/B partner and second oracle (a rank sweep "
+       "of triangular matmuls, one scatter that inverts the rank, an "
+       "XLA gather); 'argsort' = the stable sort, retained as the "
+       "bit-parity oracle. All three produce the identical table, so "
+       "the choice is byte-neutral on model.txt; which one a program "
+       "was built with is the `partition` attribute of its "
+       "boosting.build_program span"),
     _p("fused_block_size", int, 10, (), lambda v: v >= 1,
        "iterations per fused on-device dispatch in engine.train when "
        "the config is fused-eligible (boosting/fused.py). Metrics, "

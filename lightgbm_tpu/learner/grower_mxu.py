@@ -348,9 +348,10 @@ OPERAND_EQUATIONS = ("bins_row_pad", "bins_lane_pad", "bins_transpose",
 def _operand_equation(eqn, rows: int) -> Optional[str]:
     """Which of OPERAND_EQUATIONS (or "rank_scatter") `eqn` is, by its
     primitive and its output's shape and dtype; None for the rest.
-    Operands with a row of the data per row ([R, k]: bins, the row
-    table) and those with the rows along lanes ([k, R]: the channels,
-    the transposed bins) are told apart by where the row count sits."""
+    Operands with a row of the data per row ([R, k]: the bins) and
+    those with the rows along lanes ([k, R]: the channels, the
+    transposed bins, the row table) are told apart by where the row
+    count sits."""
     out = eqn.outvars[0].aval
     shape = getattr(out, "shape", ())
     if not shape or len(shape) > 2 or max(shape) < rows:
@@ -364,7 +365,14 @@ def _operand_equation(eqn, rows: int) -> Optional[str]:
         return None
     if shape[0] < rows:                    # rows along lanes
         if name == "transpose" and jnp.issubdtype(dt, jnp.integer):
-            return "bins_transpose"        # _bins_t
+            return "bins_transpose"        # the bins' [F, N]
+        if dt == jnp.bfloat16:             # _row_table: [W, N (+ 1)]
+            if name == "concatenate":
+                return "table_concat"      # the table, its padding row
+            if name == "convert_element_type" and jnp.issubdtype(
+                    eqn.invars[0].aval.dtype, jnp.integer):
+                return "table_bins"        # the table's bin rows
+            return None
         if shape[0] != 8 or dt != jnp.float32:
             return None
         if name == "concatenate" and shape[1] == rows:
@@ -372,17 +380,9 @@ def _operand_equation(eqn, rows: int) -> Optional[str]:
         if name == "pad" and eqn.invars[0].aval.shape == (8, rows):
             return "channels_pad"
         return None
-    if name not in ("pad", "concatenate", "convert_element_type"):
-        return None
-    src = eqn.invars[0].aval
     if name == "pad" and jnp.issubdtype(dt, jnp.integer):
-        return "bins_row_pad" if shape[1] == src.shape[1] \
+        return "bins_row_pad" if shape[1] == eqn.invars[0].aval.shape[1] \
             else "bins_lane_pad"
-    if name == "concatenate" and dt == jnp.bfloat16:
-        return "table_concat"              # _row_table, its padding row
-    if name == "convert_element_type" and dt == jnp.bfloat16 and \
-            jnp.issubdtype(src.dtype, jnp.integer):
-        return "table_bins"                # _row_table's bin columns
     return None
 
 

@@ -255,7 +255,7 @@ class HistOperands(NamedTuple):
     bins: jax.Array                        # [R, fcols], rows padded
     lanes: Optional[jax.Array]             # [R, plane]: + 128-lane pad
     data: Optional[jax.Array]              # [8, R] f32 (_hist_channels)
-    table: Optional[jax.Array] = None      # [n + 1, W] bf16 (_row_table)
+    table: Optional[jax.Array] = None      # [W, n + 1] bf16 (_row_table)
     bins_t: Optional[jax.Array] = None     # [fsub, R]: bins transposed
 
 
@@ -279,34 +279,40 @@ def _lane_row(x, rows: int, **kw):
     return _pad_rows(x.astype(jnp.int32), rows, **kw)[None, :]
 
 
-def _bins_t(bins: jax.Array, rows: int) -> jax.Array:
-    """The routing kernels' view of the bins: [fsub, rows], a feature
-    per sublane row so that a row's split-feature bin is pulled across
-    sublanes with the row ids along lanes; the feature axis is padded
-    to the dtype's sublane tile (32 rows of uint8). Taken from the
-    bins as they come, not from a padded copy that the row-major
-    operands share: XLA would give that copy the transposed layout and
-    pay a relayout of the 128-lane operand in every pass."""
-    fsub = _round_up(bins.shape[1], 32 // bins.dtype.itemsize)
-    return jnp.pad(bins.T, ((0, fsub - bins.shape[1]),
-                            (0, rows - bins.shape[0])))
+def _bins_t(bins_t: jax.Array, rows: int) -> jax.Array:
+    """The routing kernels' view of the bins, from their transpose
+    [F, n]: [fsub, rows], a feature per sublane row so that a row's
+    split-feature bin is pulled across sublanes with the row ids along
+    lanes; the feature axis is padded to the dtype's sublane tile (32
+    rows of uint8). Taken from the bins as they come, not from a padded
+    copy that the row-major operands share: XLA would give that copy
+    the transposed layout and pay a relayout of the 128-lane operand in
+    every pass."""
+    fsub = _round_up(bins_t.shape[0], 32 // bins_t.dtype.itemsize)
+    return jnp.pad(bins_t, ((0, fsub - bins_t.shape[0]),
+                            (0, rows - bins_t.shape[1])))
 
 
-def _row_table(bins: jax.Array, data: jax.Array, nchan: int) -> jax.Array:
+def _row_table(bins_t: jax.Array, data: jax.Array, nchan: int) -> jax.Array:
     """Everything the slot-grouped kernel (histogram_pallas) reads of a
-    row as ONE bf16 row, so a pass gathers once: the bin columns (byte
-    values, exact in bf16), then the channels as the very bf16 operand
-    the one-hot kernels build from `data` ([8, n]; the MXU is fed bf16
-    either way, so nothing is lost), then a column for the row's slot
-    within its group, which is the only part a pass writes (after the
-    gather; 255: none). One extra all-zero, slot-less row at the end
-    stands for padding."""
-    n = bins.shape[0]
+    row as ONE bf16 column, so a pass moves a row once: [W, n + 1] from
+    the transposed bins [F, n], rows along lanes as in `data` and the
+    routing kernels' bins. A row's W values are its bins (byte values,
+    exact in bf16), then the channels as the very bf16 operand the
+    one-hot kernels build from `data` ([8, n]; the MXU is fed bf16
+    either way, so nothing is lost), then its slot within its group,
+    which is the only part a pass writes (with the move; 255: none).
+    One extra all-zero, slot-less row at the end stands for padding.
+    Lane-major because that is what HBM holds compactly: W values of a
+    row are W sublanes of one lane (96 bytes a row at W = 34), where a
+    [n, 34] array a kernel can address is tiled to 128 lanes, 256 bytes
+    a row; the stream partition transposes on the MXU as it moves."""
+    n = bins_t.shape[1]
     tab = jnp.concatenate(
-        [bins.astype(jnp.bfloat16), data[:nchan].T.astype(jnp.bfloat16),
-         jnp.full((n, 1), 255, jnp.bfloat16)], axis=1)
-    pad = jnp.zeros((1, tab.shape[1]), jnp.bfloat16).at[0, -1].set(255)
-    return jnp.concatenate([tab, pad])
+        [bins_t.astype(jnp.bfloat16), data[:nchan].astype(jnp.bfloat16),
+         jnp.full((1, n), 255, jnp.bfloat16)], axis=0)
+    pad = jnp.zeros((tab.shape[0], 1), jnp.bfloat16).at[-1, 0].set(255)
+    return jnp.concatenate([tab, pad], axis=1)
 
 
 def prepare_hist_operands(bins, grad, hess, cnt, *, double_prec=True,
@@ -335,14 +341,15 @@ def prepare_hist_operands(bins, grad, hess, cnt, *, double_prec=True,
             jnp.pad(bins, ((0, rows - bins.shape[0]),
                            (0, plane - bins.shape[1])))
     data = tab = None
+    bins_tr = bins.T if table or route else None     # one transpose
     if channels or table:
         data, nchan = _hist_channels(grad, hess, cnt, double_prec,
                                      quantized, const_hess)  # [8, N]
         if table:
-            tab = _row_table(bins, data, nchan)
+            tab = _row_table(bins_tr, data, nchan)
         data = _pad_rows(data, rows, axis=1) if channels else None
     return HistOperands(bins_p, lanes_p, data, tab,
-                        _bins_t(bins, rows) if route else None)
+                        _bins_t(bins_tr, rows) if route else None)
 
 
 def _kernel_operands(operands: Optional[HistOperands], nb: int, bins,
@@ -1267,7 +1274,7 @@ def route_rows_mxu(bins: jax.Array, row_node: jax.Array, tbl: jax.Array,
         nb = 1024
     rows = _round_up(bins_p.shape[0], nb)
     if bins_t is None:
-        bins_t = _bins_t(bins_p, rows)
+        bins_t = _bins_t(bins_p.T, rows)
     bins_t = _pad_rows(bins_t, rows, axis=1)
     fsub = bins_t.shape[0]
     tbl_t, member_t, feat_tbl, loc_t = _route_tables(

@@ -209,7 +209,9 @@ class ObservabilityRegistry:
         (grower_mxu.hist_pass_plan: static, read without a device
         sync); `grouped_passes_per_tree` counts the scheduled passes
         and the bridge built slot-grouped (the fixup body runs as often
-        as a tree needs, so it is listed and not counted). Once the
+        as a tree needs, so it is listed and not counted); `partition`
+        is what partition_impl resolved to for the grouped passes
+        (stream | rank | argsort). Once the
         growth program has been traced, `operand_builds_per_tree`
         ({bins_pad, bins_t, channels, row_table}) and
         `operand_builds_per_pass`
@@ -221,7 +223,8 @@ class ObservabilityRegistry:
         with self._lock:
             hb = dict(self._hist_backend)
         plan = [dict(p) for p in hb["plan"]]
-        out: Dict = {"choice": hb["choice"], "plan": plan}
+        out: Dict = {"choice": hb["choice"], "plan": plan,
+                     "partition": hb.get("partition", "")}
         if "operand_builds_per_pass" in hb:
             out["operand_builds_per_tree"] = \
                 dict(hb["operand_builds_per_tree"])
@@ -327,15 +330,17 @@ class ObservabilityRegistry:
                                clock_samples=self.clock_samples())
 
     # -- training hooks (called from boosting/gbdt.py) ------------------
-    def record_hist_plan(self, choice: str, plan) -> None:
-        """Pin the resolved histogram backend and the growth program's
-        per-pass plan, [(stage, kernel slots, formulation)]. Recorded
+    def record_hist_plan(self, choice: str, plan,
+                         partition: str = "") -> None:
+        """Pin the resolved histogram backend, the growth program's
+        per-pass plan, [(stage, kernel slots, formulation)], and the
+        partition its grouped passes are built with. Recorded
         even when disabled: this is one-shot startup configuration, not
         per-iteration telemetry, and the bench JSON tail reads it
         regardless of the enable flag."""
         with self._lock:
             self._hist_backend = {
-                "choice": str(choice),
+                "choice": str(choice), "partition": str(partition),
                 "plan": [{"stage": str(st), "sk": int(sk),
                           "formulation": str(form)}
                          for st, sk, form in plan]}

@@ -75,7 +75,9 @@ def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
     a growth pass over `rows` x `features` bins with `slots` frontier
     slots. The route tables are sized for 2*slots nodes."""
     from ..learner import histogram_mxu as hm
-    from ..learner.histogram_pallas import build_histograms_scatter
+    from ..learner.histogram_pallas import (GROUPED_ROW_BLOCK,
+                                            build_histograms_scatter,
+                                            partition_table)
 
     n, f, s = rows, features, slots
     m_pad = hm._round_up(2 * s, 128)
@@ -112,6 +114,25 @@ def kernel_sites(*, rows: int, features: int, bmax: int, slots: int,
         "node_values_mxu": (node_values, [ivec, _sds((2 * s,),
                                                      jnp.float32)]),
     }
+
+    # the stream partition at the row tables of the benchmark's cells
+    # (rows x table columns: Higgs, MS LTR with its two lane tiles,
+    # Expo) and at the fewest and the most groups a growth pass has
+    # (72 slots and the 511-slot fixup, 25 slots a group): whatever
+    # `rows` is, since the staging the kernel keeps in VMEM is what
+    # Mosaic has to accept
+    for trows, width in ((2_625_000, 34), (2_270_296, 143),
+                         (11_000_000, 23)):
+        for groups in (3, 21):
+            def stream(table, sl, counts, groups=groups):
+                return partition_table(
+                    table, sl, num_slots=25 * groups, group=25,
+                    row_block=GROUPED_ROW_BLOCK, counts=counts)
+
+            sites["partition_stream_%dx%d_g%d" % (trows, width, groups)] = (
+                stream, [_sds((width, trows + 1), jnp.bfloat16),
+                         _sds((trows,), jnp.int32),
+                         _sds((25 * groups,), jnp.int32)])
 
     # the routing kernels, one site per variant of _route_decide: what
     # differs between variants is static (the bins' storage, the tables)

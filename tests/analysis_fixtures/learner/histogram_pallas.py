@@ -29,3 +29,10 @@ def build_histograms_pallas(bins, row_slot):
     # the sanctioned oracle shape: visible, auditable suppression
     order = jnp.argsort(row_slot)  # tpulint: disable=PERF001
     return bins[order]
+
+
+def partition_table(table, row_slot):
+    # the stream partition's entry point is registered too: the kernel
+    # moves the rows, and a sort that comes back beside it fires (the
+    # oracle's stays where it is, suppressed, in partition_rows)
+    return table[jnp.argsort(row_slot)]

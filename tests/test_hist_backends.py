@@ -192,6 +192,54 @@ class TestScatterKernelParity:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+#: (rows, features, max_bin, slots, posture) of the grouped build under
+#: each partition: the layout is ONE, so the histograms are byte-equal
+#: in every posture, exact mode included (the same rows in the same
+#: blocks sum in the same order)
+_PARTITION_CASES = {
+    "five_channels_11_groups": (3100, 4, 31, 263, {}),
+    "five_channels_21_groups": (3100, 3, 15, 511, {}),
+    "three_channels_one_group": (1500, 6, 63, 40, {"quantized": True}),
+    "three_channels_7_groups": (2900, 5, 31, 263, {"quantized": True}),
+    "const_hessian_two_channels": (1700, 5, 31, 100,
+                                   {"quantized": True, "const_hess": 1.0}),
+    "packed4": (2300, 7, 16, 72, {"quantized": True, "packed4": True}),
+    "two_lane_tiles": (1300, 130, 15, 72, {"quantized": True}),
+    "rows_off_every_tile": (2048 + 257, 4, 31, 72, {"quantized": True}),
+}
+
+
+class TestPartitionImplParity:
+    """build_histograms_scatter under partition_impl auto (the stream
+    kernel), rank and argsort: byte-equal histograms."""
+
+    @pytest.mark.parametrize("case", sorted(_PARTITION_CASES))
+    @pytest.mark.parametrize("row_block", [256, 2048])
+    def test_histograms_are_byte_equal(self, case, row_block):
+        n, f, max_bin, slots, posture = _PARTITION_CASES[case]
+        posture = dict(posture)
+        rng = np.random.RandomState(len(case))
+        bins = rng.randint(0, max_bin, size=(n, f)).astype(np.uint8)
+        g = jnp.asarray(rng.randn(n).astype(np.float32))
+        h = jnp.asarray(rng.rand(n).astype(np.float32) + 0.1)
+        if posture.get("quantized"):
+            g, h = _quant(g, h)
+        kw = dict(num_slots=slots, bmax=max_bin, row_block=row_block,
+                  interpret=True)
+        if posture.pop("packed4", False):
+            bins = pack_bins_4bit(bins)
+            kw["num_features"] = f
+        # a fifth of the rows live, the rest parked, as in a tree
+        slot = np.where(rng.rand(n) < 0.2, rng.randint(0, slots, n), -1)
+        got = {impl: np.asarray(build_histograms_scatter(
+            jnp.asarray(bins), g, h, jnp.ones(n, jnp.float32),
+            jnp.asarray(slot, jnp.int32), partition_impl=impl, **kw,
+            **posture)) for impl in ("auto", "rank", "argsort")}
+        assert got["auto"].tobytes() == got["argsort"].tobytes()
+        assert got["rank"].tobytes() == got["argsort"].tobytes()
+        assert got["auto"].any()
+
+
 class TestPartitionRows:
     def test_padded_layout_invariants(self):
         rng = np.random.RandomState(1)
@@ -347,7 +395,25 @@ class TestBackendResolution:
         assert g._hist_plan_attrs() == {
             "hist_plan": "2:onehot,4:onehot,8:onehot,15:onehot,"
                          "15:onehot,15:onehot",
+            "partition": "stream",
             "grouped_passes_per_tree": 0}
+
+    @pytest.mark.parametrize("impl,resolved", [
+        ("auto", "stream"), ("rank", "rank"), ("argsort", "argsort")])
+    def test_the_span_and_the_snapshot_name_the_partition(self, impl,
+                                                          resolved):
+        # which partition a program's grouped passes were built with:
+        # static, beside the plan, whose text does not change
+        from lightgbm_tpu.observability import registry
+        registry.reset()
+        g = self._booster(hist_backend="pallas",
+                          partition_impl=impl).gbdt
+        g._hist_impl = "mxu"
+        attrs = g._hist_plan_attrs()
+        assert attrs["partition"] == resolved
+        assert attrs["hist_plan"].endswith("15:grouped")
+        assert registry.hist_backend_snapshot()["partition"] == resolved
+        assert "partition" not in registry.prometheus_text()
 
     def test_plan_without_subtraction_counts_every_child(self):
         # no sibling subtraction: a pass builds BOTH children from rows,
@@ -558,9 +624,12 @@ class TestOperandsPreparedOncePerTree:
         assert built["id_columns_per_tree"] == 0
         assert built["id_columns_per_pass"] == 0
         assert "id_column" not in tree
-        # the scatter that inverts the rank stays: one a grouped pass
+        # the stream partition leaves a pass NO row-sized XLA equation;
+        # under partition_impl=rank the scatter that inverts the rank
+        # stays: one a grouped pass
         scatters = [b["rank_scatter"] for b in built["passes"]]
-        assert scatters == [1] * forms.count("grouped")
+        rank = kw.get("partition_impl") == "rank"
+        assert scatters == [1] * (forms.count("grouped") if rank else 0)
         return built
 
     @pytest.mark.parametrize("posture", ["exact", "const_hessian",
@@ -573,12 +642,22 @@ class TestOperandsPreparedOncePerTree:
             assert built["per_tree"]["row_table"] == 1
             assert built["tree"]["bins_lane_pad"] == 1
 
-    def test_the_benchmark_cells_program(self):
+    @pytest.mark.parametrize("partition_impl,bodies", [("auto", 0),
+                                                       ("rank", 5)])
+    def test_the_benchmark_cells_program(self, partition_impl, bodies):
         # higgs_train's own shape and plan: six one-hot passes, then
-        # three grouped, the bridge and the fixup body
+        # three grouped, the bridge and the fixup body: five pass
+        # bodies that hold a row-sized equation under the rank path
+        # (its scatter), none under the stream partition
         built = self._check(2_625_000, 28, "auto", "exact",
-                            num_leaves=255, bmax=256)
-        assert len(built["passes"]) == 5
+                            num_leaves=255, bmax=256,
+                            partition_impl=partition_impl)
+        assert len(built["passes"]) == bodies
+
+    @pytest.mark.parametrize("posture", ["exact", "quantized"])
+    def test_the_rank_path_keeps_its_one_scatter_a_pass(
+            self, low_crossover, posture):
+        self._check(3001, 6, "pallas", posture, partition_impl="rank")
 
     def test_the_counter_is_this_walk(self, monkeypatch):
         # the registry and the boosting.build_program span carry what
@@ -820,7 +899,7 @@ class TestPerPassRuleGrowsTheSameTree:
         built = gm.operand_builds(jax.make_jaxpr(
             lambda *a: gm.grow_tree_mxu(*a, **kw))(*args))
         assert built["per_pass"] >= 4
-        grouped = [b for b in built["passes"] if "rank_scatter" in b]
+        grouped = [b for b in built["passes"] if "table_bins" in b]
         assert grouped and all(b["table_bins"] == 1 for b in grouped)
 
 

@@ -240,6 +240,27 @@ def test_rows_that_do_not_divide_the_mesh(as_on_the_chip, extra):
                                rtol=0, atol=1e-6)
 
 
+def test_four_devices_build_the_same_trees_under_every_partition(
+        as_on_the_chip):
+    # every pass slot-grouped (hist_backend=pallas), so each shard's
+    # live rows are moved by the stream partition inside shard_map
+    # (partition_impl=auto) or by the retained oracles: one layout, so
+    # the same trees to the bit
+    grown = {}
+    for impl in ("auto", "rank", "argsort"):
+        g = _booster(**DATA_PARALLEL, hist_backend="pallas",
+                     use_quantized_grad=True, partition_impl=impl).gbdt
+        assert g._fused_eligible()
+        handle = g.train_many_dispatch(3)
+        assert handle["mode"] == "fused"
+        g.finalize_block(handle)
+        grown[impl] = _structure(g)
+    for impl in ("auto", "rank"):
+        assert grown[impl][0] == grown["argsort"][0]
+        for a, b in zip(grown[impl][1], grown["argsort"][1]):
+            np.testing.assert_array_equal(a, b)
+
+
 def _with(**attrs):
     def change(g):
         for name, value in attrs.items():

@@ -150,9 +150,10 @@ def test_perf_hot_path_rule_fires():
         ("PERF001", 12),   # partition_rows: direct argsort
         ("PERF001", 18),   # build_histograms_scatter: nested sweep
         ("PERF001", 30),   # build_histograms_pallas: suppressed oracle
+        ("PERF001", 38),   # partition_table: a sort beside the kernel
     }
     assert {(f.line, f.suppressed) for f in findings} == {
-        (12, False), (18, False), (30, True)}
+        (12, False), (18, False), (30, True), (38, False)}
     assert all(f.rule == "PERF001" for f in findings)
 
 
