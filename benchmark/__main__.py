@@ -48,6 +48,12 @@ def main(argv=None) -> int:
     except Exception:
         traceback.print_exc()
         return 1
+    # the numbers `correct` compared are the last lines of stderr too
+    for name, got in line.get("compared", {}).items():
+        bound = "max" if "max" in got else "min"
+        print("compared: %s %r (%s %r)" % (name, got["value"], bound,
+                                           got[bound]),
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

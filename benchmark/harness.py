@@ -114,13 +114,17 @@ def rehearsal_overlay(cfg: dict, traffic: dict):
 
 
 def load_runner(kind: str):
+    """The runner of a kind: `runners/<kind>.py`'s `TASK` where it states
+    one (a training task, benchmark/training.py), else the module. Either
+    has `run`, `rehearsal_says` and `rehearsal_reads`."""
     try:
-        return importlib.import_module("benchmark.runners." + kind)
+        mod = importlib.import_module("benchmark.runners." + kind)
     except ModuleNotFoundError as exc:
         if exc.name != "benchmark.runners." + kind:
             raise
         raise BenchmarkError("no runner for configurations of kind %r "
                              "(benchmark/runners/%s.py)" % (kind, kind))
+    return getattr(mod, "TASK", mod)
 
 
 def layer_metric_readers() -> Dict[str, Any]:
@@ -337,7 +341,8 @@ def result_line(cell: dict, run: dict, device: dict, trace: bool) -> dict:
     """The contract's last line from a runner's readings: with tracing
     off the cell's end-to-end metrics, with it on its per-layer metrics
     (each from its own reader; one that finds nothing to read is left
-    out) and the device's busy seconds."""
+    out) and the device's busy seconds; and the numbers `correct`
+    compared (`compared`: (name, value, "max" | "min", limit))."""
     metrics: Dict[str, dict] = {}
     if not trace:
         for m in cell["end_to_end"]:
@@ -374,4 +379,9 @@ def result_line(cell: dict, run: dict, device: dict, trace: bool) -> dict:
         dev["window_s"] = reduced["window_s"]
         line["breakdown"] = {"device_ops": reduced["device_ops"][:10],
                              "idle_gaps": reduced["idle_gaps"][:10]}
+    # what `correct` compared, each number beside its limit ("max": at
+    # most, "min": above), last
+    if "compared" in run:
+        line["compared"] = {name: {"value": float(value), bound: float(limit)}
+                            for name, value, bound, limit in run["compared"]}
     return line

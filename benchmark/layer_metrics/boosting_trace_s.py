@@ -1,8 +1,8 @@
 """Seconds JAX spent tracing the programs built before the window: the
 compile ledger's trace events (lightgbm_tpu/observability/compiles.py,
 fed by /jax/core/compile/jaxpr_trace_duration), each with what nested
-in it. Beside boosting.lower_s this is what boosting.trace_lower_s
-estimates from outside as a residual."""
+in it. With boosting.lower_s, what the program spends preparing its
+programs before the window, cache or no cache."""
 
 from benchmark import program_readings as pr
 

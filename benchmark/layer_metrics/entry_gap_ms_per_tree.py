@@ -1,10 +1,9 @@
 """Milliseconds per tree in which the device ran nothing during the
 traced window: what the entry layer (engine.train and the pipelined
 executor: dispatching a block, unpacking the last one) costs that the
-device does NOT hide. PipelineStats.host_ms was meant to be this and is
-not: on the chip finalize_block's slice programs queue behind the
-running block, so it reads the block's wall (12.56 s a block, my chip
-run, PR 22)."""
+device does NOT hide. With device.idle_share it is the measure of the
+host's hold on the chip; the program's own spans around the host's work
+say where, not how much of it the device waited for."""
 
 NAME = "entry.gap_ms_per_tree"
 UNIT = "ms"

@@ -3,8 +3,8 @@ ran, in the traced window: the union of the intervals of the device
 operations the program names `objective.<name>` (`jax.named_scope`
 around `get_gradients` in the fused scan), averaged over the chips. The
 runner of a ranking job takes it from the capture itself
-(`runners/rank.py: objective_busy_s`): the ten names a breakdown keeps
-cannot carry it. A program that names no such scope gives nothing."""
+(`runners/rank.py`, by `training.scope_busy_s`): the ten names a
+breakdown keeps cannot carry it. A program that names no such scope gives nothing."""
 
 NAME = "objective.device_ms_per_tree"
 UNIT = "ms"

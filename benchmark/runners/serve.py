@@ -316,6 +316,29 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
         "trace": tracer.reduced,
         "memory_peak_bytes": harness.memory_peak_bytes(),
     }
+    compared = [("agreement_max_abs", worst, "max",
+                 expect["agreement_atol"]),
+                ("buckets_compiled_in_window", delta("buckets_compiled"),
+                 "max", 0),
+                ("programs_in_window",
+                 compiles1["programs"] - compiles0["programs"], "max", 0)]
     return {"correct": not problems, "attempted": got["attempted"],
             "failed": failed, "end_to_end": end_to_end,
-            "readings": readings}
+            "readings": readings, "compared": compared}
+
+
+def rehearsal_says(cell: dict) -> tuple:
+    """Words of a rehearsal's earlier lines: an open-loop stream reports
+    its tail's sample count, a closed loop its device batches."""
+    tail = "samples beyond the 99th" if any(
+        s["loop"] == "open" for s in cell["traffic"]["streams"]) \
+        else "device batches"
+    return ("leaf_depth_median", tail, "buckets compiled inside the window 0",
+            "agreement with forest_numpy")
+
+
+def rehearsal_reads(cell: dict) -> dict:
+    """Per-layer metrics a traced rehearsal reports, with their ranges:
+    none in particular (a short window of a CPU may hold no batch of one
+    stream)."""
+    return {}

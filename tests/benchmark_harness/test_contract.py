@@ -180,8 +180,13 @@ def _run(args, cwd=REPO, extra_path=None, timeout=300):
 def check_record(line, cell, trace):
     """What the driver reads off a run's last line. A rehearsal's line
     has every key and is refused here for its platform."""
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"} | ({"breakdown"} if trace else set())
+    assert set(line) - {"compared"} == {
+        "correct", "attempted", "failed", "metrics", "device"} | (
+        {"breakdown"} if trace else set())
+    # the numbers `correct` compared, each beside its limit, come last
+    assert "compared" not in line or list(line)[-1] == "compared"
+    for got in line.get("compared", {}).values():
+        assert set(got) in ({"value", "max"}, {"value", "min"}), got
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= \
         set(line["device"])
     owed = cell["per_layer"] if trace else cell["end_to_end"]
@@ -199,6 +204,66 @@ def check_record(line, cell, trace):
         assert set(line["metrics"]) == {m["name"] for m in owed}
     assert line["device"]["platform"] == "tpu", \
         "a record names the chip it was measured on"
+
+
+def _what_the_runner_says(name, cwd, extra_path):
+    """The cell as the benchmark in `cwd` loads it, and what its runner
+    says a rehearsal prints (`rehearsal_says`: words of the earlier
+    lines; `rehearsal_reads`: per-layer metrics of a traced line, each
+    with the range (lo, hi] it lies in)."""
+    code = ("import json; from benchmark import harness; "
+            "c = harness.load_cell(%r); "
+            "r = harness.load_runner(c['config']['kind']); "
+            "print(json.dumps(dict(chips=c['chips'], "
+            "end_to_end=c['end_to_end'], per_layer=c['per_layer'], "
+            "says=r.rehearsal_says(c), reads=r.rehearsal_reads(c))))" % name)
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        p for p in (extra_path, os.environ.get("PYTHONPATH")) if p))
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120, env=env, cwd=cwd)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def rehearse(name, trace, cwd=REPO, extra_path=None, seed="7"):
+    """One rehearsal of a cell as the benchmark's command runs it, held to
+    what every cell's rehearsal shows and to what its runner says it
+    prints. Names no kind and no cell."""
+    proc = _run(["--workload", name, "--seed", seed, "--seconds", "2",
+                 "--trace", str(trace), "--rehearse-cpu"], cwd=cwd,
+                extra_path=extra_path)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    assert all(ln.startswith("# ") for ln in lines[:-1])
+    line, out = json.loads(lines[-1]), proc.stdout
+    cell = _what_the_runner_says(name, cwd, extra_path)
+    assert line["correct"] is True and line["failed"] == 0, out[-3000:]
+    assert line["attempted"] > 0
+    assert line["device"]["platform"] == "cpu"
+    assert line["device"]["count"] >= cell["chips"]
+    if trace:
+        assert set(line["metrics"]) <= {m["name"] for m in cell["per_layer"]}
+        assert line["metrics"], out[-2000:]
+        for metric, (lo, hi) in cell["reads"].items():
+            value = line["metrics"][metric]["value"]
+            assert lo < value and (hi is None or value <= hi), (metric, value)
+        assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"] * \
+            max(1, line["device"]["count"])
+        assert line["breakdown"]["device_ops"]
+    else:
+        assert set(line["metrics"]) == {m["name"] for m in cell["end_to_end"]}
+        assert all(m["value"] > 0 for m in line["metrics"].values())
+    # the numbers compared, each beside its limit: last in the line, and
+    # the last lines of stderr
+    assert list(line)[-1] == "compared" and line["compared"]
+    tail = proc.stderr.strip().splitlines()[-len(line["compared"]):]
+    assert [ln.split()[1] for ln in tail] == list(line["compared"])
+    with pytest.raises(AssertionError, match="names the chip"):
+        check_record(line, cell, trace=bool(trace))
+    # what the last line may not hold is on the lines before it
+    for word in cell["says"]:
+        assert word in out, word
+    return line, out
 
 
 def test_a_platform_that_is_not_a_tpu_ends_the_run_with_no_record():
@@ -258,6 +323,67 @@ def run(cell, *, seed, seconds, trace, rehearsal):
 '''
 
 
+#: a throw-away TRAINING kind, written as a new deployment is: a task on
+#: the training core with its own data and its own reference check
+_NEW_TRAINING_RUNNER = '''"""A throw-away training kind: least squares on
+rows near a plane, each checked tree held against the labels' mean."""
+
+import numpy as np
+
+from ..harness import say
+from ..training import Task
+
+
+def leaves(structure):
+    """(rows, value) of every leaf of a dumped tree."""
+    if "left_child" not in structure:
+        return [(structure["leaf_count"], structure["leaf_value"])]
+    return leaves(structure["left_child"]) + leaves(structure["right_child"])
+
+
+class Plane(Task):
+    kind = "throwaway_l2"
+    reference = "the labels' mean"
+    flatten_tree = staticmethod(leaves)
+    limits = (("mean_gap", "mean_gap"),)
+    quality, quality_trees = "R2", "r2_trees"
+
+    def draw(self, cfg, seed):
+        rng = np.random.default_rng(seed)
+        n, m = int(cfg["num_data"]), int(cfg["held_out_rows"])
+        X = rng.standard_normal((n + m, int(cfg["num_features"])),
+                                dtype=np.float32)
+        y = (2.0 * X[:, 0] - X[:, 1] + 0.1 * rng.standard_normal(n + m)
+             ).astype(np.float32)
+        return {"X": X[:n], "y": y[:n], "Xho": X[n:], "yho": y[n:]}
+
+    def say_data(self, cfg, seed, data, data_s, binning_s, binned):
+        say("data: %d x %d rows near a plane %s"
+            % (*data["X"].shape, binned))
+
+    def check_step(self, k, trees, data, bins, cfg, resolved, routed):
+        # under least squares every tree keeps the rows' mean: tree 0's
+        # leaves carry the labels' mean, a later tree's sum to nothing
+        y = data["y"].astype(np.float64)
+        mean = sum(c * v for c, v in trees[k]) / len(y)
+        return {"mean_gap": abs(mean - (y.mean() if k == 0 else 0.0))
+                / y.std()}
+
+    def held_out(self, bst, data, n, expect):
+        yho = data["yho"].astype(np.float64)
+        r2 = 1.0 - np.mean((bst.predict(data["Xho"], num_iteration=n)
+                            - yho) ** 2) / yho.var()
+        say("held-out R2 after %d trees: %.5f" % (n, r2))
+        return "R2", r2, expect["r2_floor"]
+
+    def rehearsal_says(self, cell):
+        return super().rehearsal_says(cell) + ("rows near a plane",)
+
+
+TASK = Plane()
+'''
+
+
 def test_a_cell_a_configuration_a_traffic_mix_a_runner_and_a_metric_are_new_files(
         tmp_path):
     """What a later PR does: new files under benchmark/, new entries in
@@ -281,6 +407,16 @@ def test_a_cell_a_configuration_a_traffic_mix_a_runner_and_a_metric_are_new_file
     (bench_dir / "layer_metrics" / "throwaway_trees.py").write_text(
         _NEW_READER)
     (bench_dir / "runners" / "noop.py").write_text(_NEW_RUNNER)
+    (bench_dir / "runners" / "throwaway_l2.py").write_text(
+        _NEW_TRAINING_RUNNER)
+    json.dump({"name": "throwaway_plane", "kind": "throwaway_l2",
+               "objective": "regression", "num_data": 3000,
+               "num_features": 4, "num_leaves": 7, "max_bin": 31,
+               "learning_rate": 0.1, "held_out_rows": 1000, "params": {},
+               "expect": {"check_trees": [0, 1], "mean_gap": 1e-4,
+                          "r2_trees": 20, "r2_floor": 0.5},
+               "rehearsal": {}},
+              open(bench_dir / "configs" / "throwaway_plane.json", "w"))
     bench = json.load(open(root / "BENCHMARK.json"))
     bench["configs"] += [
         {"name": "throwaway_shape", "source": "https://example.org/a",
@@ -288,11 +424,16 @@ def test_a_cell_a_configuration_a_traffic_mix_a_runner_and_a_metric_are_new_file
          "reduced": ["num_data", "num_iterations"], "why": "a test"},
         {"name": "throwaway_thing", "source": "https://example.org/b",
          "file": "benchmark/configs/throwaway_thing.json", "reduced": [],
+         "why": "a test"},
+        {"name": "throwaway_plane", "source": "https://example.org/c",
+         "file": "benchmark/configs/throwaway_plane.json", "reduced": [],
          "why": "a test"}]
     bench["workloads"] += [
         {"name": "throwaway_train", "config": "throwaway_shape",
          "traffic": "throwaway_mix", "chips": 1, "why": "a test"},
         {"name": "throwaway_noop", "config": "throwaway_thing",
+         "traffic": "throwaway_mix", "chips": 1, "why": "a test"},
+        {"name": "throwaway_l2_train", "config": "throwaway_plane",
          "traffic": "throwaway_mix", "chips": 1, "why": "a test"}]
     bench["per_layer"].append(
         {"name": "throwaway.trees_in_window", "unit": "trees",
@@ -327,6 +468,12 @@ def test_a_cell_a_configuration_a_traffic_mix_a_runner_and_a_metric_are_new_file
     with pytest.raises(AssertionError, match="names the chip"):
         check_record(line, {"per_layer": bench["per_layer"],
                             "end_to_end": bench["end_to_end"]}, trace=True)
+    # a new KIND of training deployment, a task on the training core,
+    # through the same assertions as every cell's rehearsal
+    for trace in (0, 1):
+        line, _ = rehearse("throwaway_l2_train", trace, cwd=str(root),
+                           extra_path=REPO)
+        assert line["compared"]["tree1.mean_gap"]["value"] < 1e-6
     # and no file that was there has changed
     after = {p: open(p, "rb").read() for p in before}
     assert after == before

@@ -2,16 +2,16 @@
 same level frequencies and label share; the carriers' and airports'
 codes the seed's; the training table the same bins under every seed),
 the two readers,
-the question the runner asks of the program, `expo_categorical_train`
-rehearsed end to end on the CPU, traced and not, and the comparison
+the question the runner asks of the program, what the cell is (its
+rehearsal, traced and not, is a case of
+`test_rehearsals.test_a_cell_rehearses_end_to_end`), and the comparison
 that decides `correct` held against three programs that are not the
 published one: leaf sums in bfloat16, the rule without its batching, a
 model that does not read the categorical columns.
 
-CPU only; every record here says "platform": "cpu".
+CPU only.
 """
 
-import json
 import os
 import sys
 
@@ -28,7 +28,6 @@ from benchmark.generators import expo  # noqa: E402
 from benchmark.layer_metrics import (  # noqa: E402
     growth_categorical_scan_ms_per_tree, growth_categorical_split_share)
 from benchmark.reference import gbdt_cat_numpy as ref  # noqa: E402
-from test_contract import _run, check_record  # noqa: E402
 
 CELL = "expo_categorical_train"
 
@@ -180,6 +179,7 @@ def test_the_readers_read_what_the_runner_and_the_program_give():
 
 
 def test_the_scope_is_found_in_a_compiled_programs_text():
+    from benchmark import training
     from benchmark.runners import train_cat
     text = "\n".join([
         'HloModule jit_program',
@@ -193,7 +193,7 @@ def test_the_scope_is_found_in_a_compiled_programs_text():
         'metadata={op_name="jit(program)/while/body/find_best_splits/'
         'split.numerical/dot_general"}',
         '  %copy.1 = f32[8]{0} copy(%q)'])
-    names = {m.group(1) for m in map(train_cat._INSTRUCTION.match,
+    names = {m.group(1) for m in map(training.INSTRUCTION.match,
                                      text.splitlines())
              if m and train_cat.CATEGORICAL_SCOPE in m.group(2)}
     assert names == {"sort.7", "fusion.3"}
@@ -214,43 +214,25 @@ def test_a_program_whose_dump_names_no_categories_is_refused(monkeypatch):
 
 
 # ----------------------------------------------------------------------
-# the cell, end to end, as the command the driver gives
-@pytest.mark.parametrize("trace", [0, 1])
-def test_the_cell_rehearses_end_to_end(trace):
+# the cell (its rehearsal, untraced and traced, is a case of
+# test_rehearsals.test_a_cell_rehearses_end_to_end, held to what
+# runners/train_cat.py says it prints)
+def test_the_cell_is_the_published_job_on_one_chip():
     cell = harness.load_cell(CELL)
     assert cell["config"]["kind"] == "train_cat" and cell["chips"] == 1
     assert cell["config_entry"]["reduced"] == ["num_iterations"]
     assert cell["config"]["num_data"] == 11_000_000
     assert cell["config"]["num_leaves"] == cell["config"]["max_bin"] == 255
     assert cell["config"]["params"] == ref.DEFAULTS
-    proc = _run(["--workload", CELL, "--seed", "2147484401", "--seconds",
-                 "2", "--trace", str(trace), "--rehearse-cpu"])
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = proc.stdout.strip().splitlines()
-    assert all(ln.startswith("# ") for ln in lines[:-1])
-    line, out = json.loads(lines[-1]), proc.stdout
-    assert line["correct"] is True and line["failed"] == 0, out[-3000:]
-    assert line["attempted"] > 0 and line["device"]["platform"] == "cpu"
-    for word in ("(6 categorical; training rows of the fixed table 20092, "
-                 "codes of seed 2147484401", "'levels': 689", "'has_cat': True",
-                 "cache hits", "boundaries (unsynced)", "reference, tree 0",
-                 "reference, tree 1", "'cat_infeasible_nodes': 0",
-                 "decide on a categorical column", "held-out AUC"):
-        assert word in out, word
-    if trace:
-        owed = {m["name"] for m in cell["per_layer"]}
-        assert {"growth.categorical_scan_ms_per_tree",
-                "growth.categorical_split_share"} <= owed
-        assert set(line["metrics"]) <= owed
-        assert {"growth.device_ms_per_tree", "boosting.programs_built",
-                "ingest.binning_s"} <= set(line["metrics"])
-        assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
-    else:
-        assert set(line["metrics"]) == {m["name"]
-                                        for m in cell["end_to_end"]}
-        assert all(m["value"] > 0 for m in line["metrics"].values())
-    with pytest.raises(AssertionError, match="names the chip"):
-        check_record(line, cell, trace=bool(trace))
+    assert {"growth.categorical_scan_ms_per_tree",
+            "growth.categorical_split_share"} <= {
+        m["name"] for m in cell["per_layer"]}
+    from benchmark.runners import train_cat
+    says = train_cat.TASK.rehearsal_says(cell)
+    assert "(6 categorical; training rows of the fixed table 20092, codes " \
+        "of seed " in says
+    # the six columns of the rehearsal's 40,000 rows hold 689 levels
+    assert "'levels': 689," in says
 
 
 # ----------------------------------------------------------------------
@@ -281,7 +263,7 @@ def rehearsed():
     kw["slack_ulps"] = float(cfg["expect"]["cat_prefix_slack_ulps"])
     return dict(cfg=cfg, X=X, y=y, Xh=Xh, yh=yh, cats=cats, params=params,
                 bins=ds._binned.bins, bst=bst, trees=trees, kw=kw,
-                agrees=train_cat.agrees)
+                agrees=train_cat.TASK.agrees)
 
 
 def test_the_program_itself_reads_as_correct(rehearsed):

@@ -1,12 +1,12 @@
 """The ranking cell's own pieces: the plain reference against a literal
 double loop over pairs, NDCG@k against hand values, the data generator
 (every seed the same work on other rows), the objective's share read off a capture,
-and `mslr_rank_train` rehearsed end to end on the CPU, traced and not.
+and what the cell is (its rehearsal, traced and not, is a case of
+`test_rehearsals.test_a_cell_rehearses_end_to_end`).
 
-CPU only; every record here says "platform": "cpu".
+CPU only.
 """
 
-import json
 import os
 import sys
 
@@ -22,7 +22,6 @@ from benchmark.generators import mslr  # noqa: E402
 from benchmark.layer_metrics import (objective_device_ms_per_tree,  # noqa: E402
                                      objective_pair_fill)
 from benchmark.reference import lambdarank_numpy as ref  # noqa: E402
-from test_contract import _run, check_record  # noqa: E402
 
 CELL = "mslr_rank_train"
 
@@ -224,8 +223,9 @@ def test_leaf_sums_made_in_bfloat16_are_not_correct(how, correct):
         learning_rate=0.1, min_data_in_leaf=20, min_sum_hessian_in_leaf=1e-3,
         lambda_l2=0.0, sigmoid=1.0, lambdarank_truncation_level=30,
         lambdarank_norm=True)
-    assert rank._check_against_reference(
-        _Dumped([tree]), X, y, sizes, bins, cfg, resolved) is correct
+    assert rank.TASK.check_trees(
+        _Dumped([tree]), {"X": X, "y": y, "sizes": sizes}, bins, cfg,
+        resolved) is correct
 
 
 # ----------------------------------------------------------------------
@@ -316,6 +316,7 @@ def test_the_readers_read_what_the_runner_and_the_program_give():
 
 
 def test_the_scope_is_found_in_a_compiled_programs_text():
+    from benchmark import training
     from benchmark.runners import rank
     text = "\n".join([
         'HloModule jit_program',
@@ -328,7 +329,7 @@ def test_the_scope_is_found_in_a_compiled_programs_text():
         '  %fusion.13 = f32[8]{0} fusion(%p), kind=kLoop, calls=%d, '
         'metadata={op_name="jit(program)/while/body/grow/add"}',
         '  %copy.1 = f32[8]{0} copy(%q)'])
-    names = {m.group(1) for m in map(rank._INSTRUCTION.match,
+    names = {m.group(1) for m in map(training.INSTRUCTION.match,
                                      text.splitlines())
              if m and rank.OBJECTIVE_SCOPE in m.group(2)}
     assert names == {"fusion.12", "sort.4"}
@@ -364,36 +365,21 @@ def test_a_program_that_cannot_say_is_refused_before_anything_runs(
 
 
 # ----------------------------------------------------------------------
-# the cell, end to end, as the command the driver gives
-@pytest.mark.parametrize("trace", [0, 1])
-def test_the_cell_rehearses_end_to_end(trace):
+# the cell (its rehearsal, untraced and traced, is a case of
+# test_rehearsals.test_a_cell_rehearses_end_to_end, held to what
+# runners/rank.py says it prints and reads)
+def test_the_cell_is_the_published_job_on_one_chip():
     cell = harness.load_cell(CELL)
     assert cell["config"]["kind"] == "rank" and cell["chips"] == 1
     assert cell["config_entry"]["reduced"] == ["num_iterations"]
-    proc = _run(["--workload", CELL, "--seed", "2147483907", "--seconds",
-                 "2", "--trace", str(trace), "--rehearse-cpu"])
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = proc.stdout.strip().splitlines()
-    assert all(ln.startswith("# ") for ln in lines[:-1])
-    line, out = json.loads(lines[-1]), proc.stdout
-    assert line["correct"] is True and line["failed"] == 0, out[-3000:]
-    assert line["attempted"] > 0 and line["device"]["platform"] == "cpu"
-    for word in ("under 300 queries of 1 to 200 documents", "cache hits",
-                 "boundaries (unsynced)", "reference, tree 0",
-                 "reference, tree 1", "held-out NDCG@10"):
-        assert word in out, word
-    if trace:
-        owed = {m["name"] for m in cell["per_layer"]}
-        assert set(line["metrics"]) <= owed
-        assert {"objective.device_ms_per_tree", "objective.pair_fill",
-                "growth.device_ms_per_tree", "boosting.programs_built",
-                "boosting.init_s"} <= set(line["metrics"])
-        assert 25.0 < line["metrics"]["objective.pair_fill"]["value"] <= 100.0
-        assert line["metrics"]["objective.device_ms_per_tree"]["value"] > 0
-        assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
-    else:
-        assert set(line["metrics"]) == {m["name"]
-                                        for m in cell["end_to_end"]}
-        assert all(m["value"] > 0 for m in line["metrics"].values())
-    with pytest.raises(AssertionError, match="names the chip"):
-        check_record(line, cell, trace=bool(trace))
+    assert cell["config"]["num_data"] == 2_270_296
+    assert cell["config"]["num_features"] == 137
+    from benchmark.runners import rank
+    reads = rank.TASK.rehearsal_reads(cell)
+    assert set(reads) <= {m["name"] for m in cell["per_layer"]}
+    # most of a rehearsal's pair slots hold a pair, and none holds two
+    assert reads["objective.pair_fill"] == (25, 100)
+    assert reads["objective.device_ms_per_tree"] == (0, None)
+    says = rank.TASK.rehearsal_says(cell)
+    assert "under 300 queries of 1 to 200 documents" in says
+    assert "held-out NDCG" in says
