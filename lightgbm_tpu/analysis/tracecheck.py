@@ -395,10 +395,12 @@ def _probe_fused_train() -> Dict:
         shrinkage=0.1, extra_seed=3, needs_rng=False)
     score = jnp.zeros(n, jnp.float32)
     keys = jnp.zeros((2, 2), jnp.uint32)     # a block of k=2
-    traced = run.program.trace(score, 0, keys, *run.operands)
+    traced = run.program.trace(*run.arguments(score, 0, k=2,
+                                              sample_keys=keys))
     # it0 (global iteration offset) must not bake into the program —
     # the base trace above doubles as retrace argset 0
-    other = run.program.trace(score, 7, keys, *run.operands)
+    other = run.program.trace(*run.arguments(score, 7, k=2,
+                                             sample_keys=keys))
     stable = str(traced.jaxpr) == str(other.jaxpr)
     lowered = traced.lower().as_text()
     return {"jaxpr": traced.jaxpr, "stable": stable,

@@ -401,7 +401,13 @@ class BinMapper:
             return float(bin_idx)
         b = min(bin_idx, len(self.bin_upper_bound) - 1)
         v = float(self.bin_upper_bound[b])
-        if math.isinf(v) or math.isnan(v):
+        if math.isinf(v):
+            # the last bin with values, where a NaN bin follows it: every
+            # value goes left, as the reference's AvoidInf (1e300) sends
+            # it; the largest SAMPLED value would send the larger values
+            # of the rows outside the sample right
+            return math.copysign(1e300, v)
+        if math.isnan(v):
             v = float(self.max_val)
         return v
 

@@ -139,9 +139,11 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     trace closes over: a closed-over array is lowered as a literal, so
     the program would carry a second copy of the dataset, and its
     persistent-cache key would change with every dataset of the same
-    shape. `run.program` is the jitted function and `run.operands` the
-    arrays it is called with after (score, it0, sample_keys) — what an
-    ahead-of-time compile needs (testing/tpu_aot.py);
+    shape. So do the per-feature tables (num_bins, missing_is_nan,
+    is_cat), last. `run.program` is the jitted function, `run.operands`
+    the arrays it is called with after (score, it0, sample_keys) and
+    `run.feature_tables` those tables — what an ahead-of-time compile
+    needs (testing/tpu_aot.py);
     `run.arguments(score, it0, k=, sample_keys=)` is the whole argument
     tuple of a call, for `run.program.trace`.
 
@@ -173,13 +175,12 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     shrink = jnp.float32(shrinkage)
     interpret = bool(grower_kwargs.get("interpret", False))
 
-    def one_tree(bins, grad, hess, cnt, fmask, it):
+    def one_tree(bins, feats, grad, hess, cnt, fmask, it):
         rng = jax.random.fold_in(jax.random.PRNGKey(extra_seed), it) \
             if needs_rng else None
         tree, row_node, counters = grow_tree_mxu(
-            bins, grad, hess, cnt, fmask, num_bins,
-            missing_is_nan, is_cat, rng_key=rng, growth_counters=True,
-            psum_axis=axis, **grower_kwargs)
+            bins, grad, hess, cnt, fmask, *feats, rng_key=rng,
+            growth_counters=True, psum_axis=axis, **grower_kwargs)
         # device-side stand-in for the "no further splits" break: a tree
         # that made no split becomes all-zero and the scan carries on
         # (train_one_iter's ok-zeroing, gbdt.py)
@@ -189,7 +190,7 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
                                interpret=interpret)
         return tree, vals, counters
 
-    def body(bins, obj, score, xs):
+    def body(bins, feats, obj, score, xs):
         it, key = xs
         with jax.named_scope("objective." + getattr(obj, "name", "custom")):
             grad, hess = obj.get_gradients(score)
@@ -207,13 +208,13 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
             cnt = jnp.ones(rows, jnp.float32) if real is None else real
         fmask = feature_mask_fn(it)
         if num_class == 1:
-            tree, vals, counters = one_tree(bins, grad, hess, cnt, fmask,
-                                            it)
+            tree, vals, counters = one_tree(bins, feats, grad, hess, cnt,
+                                            fmask, it)
             return score + vals, (tree, counters)
         grown = []
         for cls in range(num_class):
-            t, vals, counters = one_tree(bins, grad[:, cls], hess[:, cls],
-                                         cnt, fmask, it)
+            t, vals, counters = one_tree(bins, feats, grad[:, cls],
+                                         hess[:, cls], cnt, fmask, it)
             score = score.at[:, cls].add(vals)
             grown.append((t, counters))
         return score, jax.tree_util.tree_map(
@@ -224,7 +225,7 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
     # score'). GBDT.train_many reassigns self.train_score from the
     # result and its fault paths check .is_deleted() before reusing the
     # old buffer — tpulint JIT004 guards the bare-name discipline.
-    def program(score, it0, sample_keys, bins, row_state, tables):
+    def program(score, it0, sample_keys, bins, row_state, tables, feats):
         # the block length is sample_keys' leading axis: a static shape,
         # so each distinct length is its own compiled program
         k = sample_keys.shape[0]
@@ -233,8 +234,8 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
         for name, arr in zip(row_names + list(table_names),
                              row_state + tables):
             setattr(obj, name, arr)
-        return jax.lax.scan(functools.partial(body, bins, obj), score,
-                            (its, sample_keys))
+        return jax.lax.scan(functools.partial(body, bins, feats, obj),
+                            score, (its, sample_keys))
 
     if mesh is not None:
         # score, bins and row state by rows; trees and their counters
@@ -242,7 +243,7 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
         by_rows = NamedSharding(mesh, P(axis))
         program = shard_map(
             program, mesh=mesh, out_specs=(P(axis), P()), check_vma=False,
-            in_specs=(P(axis), P(), P(), P(axis), P(axis), P()))
+            in_specs=(P(axis), P(), P(), P(axis), P(axis), P(), P()))
 
         def on_mesh(a):
             return jax.device_put(
@@ -251,6 +252,12 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
         row_arrays = [on_mesh(a) for a in row_arrays]
     program = jax.jit(program, donate_argnums=0)
     operands = (bins, tuple(row_arrays), table_arrays)
+    # the per-feature tables are arguments too: a value the trace closes
+    # over is a literal of the program, and these move with the data (a
+    # column whose bin sample holds fewer distinct values has fewer
+    # bins), so as literals every dataset of one shape was a program of
+    # its own, compiled again
+    feature_tables = (num_bins, missing_is_nan, is_cat)
 
     def arguments(score, it0, *, k: int, sample_keys=None):
         if sample_keys is None:
@@ -261,7 +268,7 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
             # mesh: every other block takes the last one's score as it
             # came out, on the mesh already
             score = on_mesh(score)
-        return (score, it0, sample_keys) + operands
+        return (score, it0, sample_keys) + operands + (feature_tables,)
 
     def run(score, it0, *, k: int, sample_keys=None):
         score, (stacked, counters) = program(*arguments(
@@ -270,5 +277,6 @@ def build_fused_train(*, objective, bins, feature_mask_fn,
 
     run.program = program
     run.operands = operands
+    run.feature_tables = feature_tables
     run.arguments = arguments
     return run

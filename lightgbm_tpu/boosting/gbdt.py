@@ -207,6 +207,7 @@ class GBDT:
             monotone_penalty=cfg.monotone_penalty,
             extra_trees=cfg.extra_trees,
             has_categorical=bool(np.any(ds.is_categorical)),
+            has_missing=bool(np.any(ds.missing_types == 2)),
             cat_columns=tuple(
                 int(j) for j in np.flatnonzero(ds.is_categorical)))
         self._cat_bins = int(ds.num_bins[ds.is_categorical].sum())

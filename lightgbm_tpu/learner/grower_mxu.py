@@ -40,8 +40,10 @@ import numpy as np
 
 from .grower import _init_tree, TreeArrays
 from .histogram import build_histograms
-from .histogram_mxu import (_round_up, build_histograms_mxu_auto, fits_v2,
-                            fused_route_hist_mxu, hist_num_channels,
+from .histogram_mxu import (TILED_ROW_BLOCK, _round_up,
+                            build_histograms_mxu_auto, feature_tile,
+                            fits_v2, fused_route_hist_mxu,
+                            hist_num_channels,
                             node_sums_mxu, node_values_mxu,
                             pack_route_tables, prepare_hist_operands,
                             quantize_gradients, route_rows_mxu,
@@ -191,10 +193,13 @@ _DONE = 9
 #: while_loop's iterations); the rows that stood in a slot those passes
 #: built (the smaller siblings and the children of stale parents: the
 #: mesh's rows under psum_axis, as the tree counts rows); the leaves
-#: before the prune to best-first.
+#: before the prune to best-first; the rows of the nodes that split
+#: (before the prune: every split grown) and of them those that went
+#: by their node's default direction, the rows of the split column's
+#: NaN bin (none without one).
 GROWTH_COUNTERS = ("onehot_passes", "grouped_passes", "bridge_passes",
                    "fixup_iters", "onehot_rows", "grouped_rows",
-                   "leaves_grown")
+                   "leaves_grown", "routed_rows", "default_rows")
 _INT32_MAX = 2 ** 31 - 1
 
 
@@ -222,6 +227,22 @@ def _count_pass(counts: jax.Array, kern: jax.Array, form: str,
     add = ran + live * rows_at
     total = counts + add
     # two non-negative int32 whose sum wrapped read negative
+    return jnp.where(total < 0, _INT32_MAX, total)
+
+
+def _count_routed(counts: jax.Array, routed: jax.Array,
+                  by_default: jax.Array) -> jax.Array:
+    """GROWTH_COUNTERS after a pass's splits: the rows of the nodes
+    that split and those of them in the NaN bin of the split column
+    (float32 count sums, as the tree's own), saturating."""
+    add = np.zeros(len(GROWTH_COUNTERS), np.int32)
+    add_d = np.zeros(len(GROWTH_COUNTERS), np.int32)
+    add[GROWTH_COUNTERS.index("routed_rows")] = 1
+    add_d[GROWTH_COUNTERS.index("default_rows")] = 1
+    cap = jnp.float32(2 ** 31 - 128)
+    total = counts + \
+        jnp.minimum(jnp.round(routed), cap).astype(jnp.int32) * add + \
+        jnp.minimum(jnp.round(by_default), cap).astype(jnp.int32) * add_d
     return jnp.where(total < 0, _INT32_MAX, total)
 
 
@@ -755,7 +776,9 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     def sweep(row_node, tbl_c, member_c, nslots, m_cap=None):
         """Route rows through the previous pass's packed tables and build
         the frontier histograms — fused single sweep when the histogram
-        block fits VMEM, else the two-kernel fallback (wide datasets).
+        block fits VMEM, the same sweep over the feature axis in tiles
+        where only a tile of it does (wide datasets), else the
+        two-kernel fallback.
         Under psum_axis the local histograms are all-reduced, so the
         subtraction/scan math downstream sees global sums. Returns
         (histograms, row_node, formulation): the last is static.
@@ -821,8 +844,16 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 if fits_v2(nslots, fk, bk, hist_double_prec, quant,
                            route_width=rw, row_block=rb, const_hess=ch):
                     break
-        if fits_v2(nslots, fk, bk, hist_double_prec, quant,
-                   route_width=rw, row_block=rb, const_hess=ch):
+        # too wide for one VMEM-resident histogram block: the same sweep
+        # over the feature axis in tiles (plain bins only)
+        tile = 0 if efb is not None or packed4 else feature_tile(
+            nslots, fk, bk, hist_double_prec, quant, row_block=rb,
+            const_hess=ch)
+        if tile > 0:
+            rb = min(rb, TILED_ROW_BLOCK)
+        if tile >= 0 and (tile or fits_v2(
+                nslots, fk, bk, hist_double_prec, quant, route_width=rw,
+                row_block=rb, const_hess=ch)):
             h, rn = fused_route_hist_mxu(
                 None, None, None, None, row_node, tbl_c,
                 member_c, feat_tbl, num_slots=nslots, bmax=bk,
@@ -830,7 +861,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 double_prec=hist_double_prec, num_features=nf_packed,
                 loc_table=None if efb_seg else loc_tbl,
                 efb_range=efb_seg, row_block=rb, const_hess=ch,
-                operands=ops, interpret=interpret)
+                feature_tile=tile, operands=ops, interpret=interpret)
         else:
             rn, rs = route_rows_mxu(None, row_node, tbl_c, member_c,
                                     feat_tbl, num_features=nf_packed,
@@ -859,7 +890,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         (tree, row_node, tbl_c, member_c, slot_nodes, best, cons_min,
          cons_max, path_mask, done, parent_hist, pair_parent, pair_sleft,
          pair_kstart, node_force, forced_ok_st, feat_used,
-         was_forced, counts) = st
+         was_forced, nan_rows, counts) = st
         sn = slot_nodes[:s]
         if sk_next is None:
             sk_next = _kernel_cap(min(2 * s, s_max)) if hist_subtraction \
@@ -1060,6 +1091,17 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             getattr(best, fld).at[sn].set(getattr(bs, fld))
             if fld != "per_feature_gain" else best.per_feature_gain
             for fld in BestSplits._fields])
+        if growth_counters and efb is None and hp.has_missing:
+            # the rows in the NaN bin of each scanned node's best column
+            # (its histogram's count channel): the rows that go by the
+            # node's default direction if it splits there
+            fs = jnp.clip(bs.feature, 0, f - 1)
+            nan_bin = jnp.clip(num_bins[fs] - 1, 0, hist_scan.shape[2] - 1)
+            by_default = (bs.feature >= 0) & missing_is_nan[fs] & \
+                ~is_cat_feat[fs]
+            nan_rows = nan_rows.at[sn].set(jnp.where(
+                by_default, hist_scan[jnp.arange(s), fs, nan_bin, 2],
+                0.0)).at[m].set(0.0)
 
         # ---- choose splits: top-budget by gain; children fit next pass
         eligible = tree.is_leaf & jnp.isfinite(best.gain) & (best.gain > 0)
@@ -1103,6 +1145,11 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         split_mask = jnp.zeros(m1, bool).at[top_idx].set(take)
         split_mask = split_mask.at[m].set(False)
         k = jnp.sum(split_mask.astype(jnp.int32))
+        if growth_counters:
+            counts = _count_routed(
+                counts, jnp.sum(jnp.where(split_mask, tree.count, 0.0)),
+                jnp.sum(jnp.where(split_mask, nan_rows, 0.0))
+                if efb is None and hp.has_missing else jnp.float32(0.0))
 
         # ---- apply splits
         order = jnp.cumsum(split_mask.astype(jnp.int32)) - 1
@@ -1242,7 +1289,7 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         return (new_tree, row_node, tbl_c, member_c, slot_nodes, new_best,
                 cons_min, cons_max, path_mask, done, parent_hist,
                 pair_parent, pair_sleft, pair_kstart, node_force,
-                forced_ok_st, feat_used, was_forced, counts)
+                forced_ok_st, feat_used, was_forced, nan_rows, counts)
 
     # initial tables: nothing split, root (node 0) sits in kernel slot 0,
     # so the first sweep is an identity route + a root histogram. Pair 0
@@ -1271,6 +1318,8 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
              jnp.full(P_all, True),                        # pair_sleft
              jnp.full(P_all, -1, jnp.int32).at[0].set(0),  # pair_kstart
              node_force0, forced_ok0, feat_used0, was_forced0,
+             jnp.zeros(m1 if growth_counters and hp.has_missing else 1,
+                       jnp.float32),                        # nan_rows
              jnp.zeros(len(GROWTH_COUNTERS), jnp.int32))   # counts
 
     def cond_pass(s, st, pass_idx, k_cap=None, sk_next=None, m_cap=None,

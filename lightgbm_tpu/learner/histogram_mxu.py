@@ -441,26 +441,37 @@ def _slot_lhs(slot, data, *, nb: int, s: int, nchan: int, mm_dtype):
 
 
 def _hist_accumulate(hist_ref, lhs, bins_i, *, nb: int, f: int, b: int,
-                     mm_dtype, fh: int = 0, rows_axis: int = 1):
+                     mm_dtype, fh: int = 0, rows_axis: int = 1,
+                     live=None):
     """Shared accumulation body of the v2/fused/grouped kernels:
     per-feature-group bin one-hots, accumulating dots against the
     slot-masked channel operand `lhs`, whose rows run along `rows_axis`:
     [C*S, nb] from _slot_lhs (a plain matmul), or [nb, C*S] where slot
     and channels come out of a row-major table (histogram_pallas).
     bins_i: [nb, lanes] i32 (fh > 0: 4-bit packed columns, feature j at
-    column j % fh, nibble j // fh)."""
+    column j % fh, nibble j // fh). live (a feature tile: a traced
+    count of the tile's features that are data): groups from there on
+    are padding lanes and are skipped."""
     iota_b = jax.lax.broadcasted_iota(jnp.int32, (nb, b), 1)
     for gj in range(0, f, _FGROUP):
         js = range(gj, min(gj + _FGROUP, f))
-        cols = _packed_cols(bins_i, js, fh) if fh else \
-            [bins_i[:, j:j + 1] for j in js]
-        oh = jnp.concatenate(
-            [(c == iota_b) for c in cols],
-            axis=1).astype(mm_dtype)                         # [nb, G*B]
-        part = jax.lax.dot_general(
-            lhs, oh, dimension_numbers=(((rows_axis,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [C*S, G*B]
-        hist_ref[0, :, gj * b:(gj + len(js)) * b] += part
+
+        def group(gj=gj, js=js):
+            cols = _packed_cols(bins_i, js, fh) if fh else \
+                [bins_i[:, j:j + 1] for j in js]
+            oh = jnp.concatenate(
+                [(c == iota_b) for c in cols],
+                axis=1).astype(mm_dtype)                     # [nb, G*B]
+            part = jax.lax.dot_general(
+                lhs, oh,
+                dimension_numbers=(((rows_axis,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [C*S, G*B]
+            hist_ref[0, :, gj * b:(gj + len(js)) * b] += part
+
+        if live is None:
+            group()
+        else:
+            pl.when(gj < live)(group)
 
 
 def _node_lookup(node, tbl_t, m: int, nb: int):
@@ -486,7 +497,7 @@ def _own_slot(gath):
 
 def _route_decide(node, node_oh, gath, bins_blk, ftbl, member_t, *,
                   nb: int, fh: int = 0, loc_t=None,
-                  efb_range: bool = False):
+                  efb_range: bool = False, split_bins=None):
     """Shared split-decision math of the route/fused kernels: numerical
     thresholds, NaN-bin default direction, categorical bitset membership.
     Every per-row value is a [1, nb] lane vector and every per-row table
@@ -503,7 +514,10 @@ def _route_decide(node, node_oh, gath, bins_blk, ftbl, member_t, *,
     (efb.py: default bin folded in for out-of-segment positions) — the
     decision math below then runs on original bins unchanged;
     member_t: [Bpad, M] categorical left-set membership per bin, or None
-    when the table holds no categorical splits. Returns (new node ids,
+    when the table holds no categorical splits;
+    split_bins (the feature-tiled sweep, in place of bins_blk and
+    ftbl): pf -> ([1, nb] f32 bin of each row's split feature, [1, nb]
+    f32 that feature's NaN bin, -1 without one). Returns (new node ids,
     next-pass kernel slot) as [1, nb] f32 pairs — rows of unsplit nodes
     keep their node and their own slot; routed rows take the chosen
     child's slot, carried in the PARENT row (_COL_SLOTL/_COL_SLOTR) so
@@ -551,6 +565,11 @@ def _route_decide(node, node_oh, gath, bins_blk, ftbl, member_t, *,
         num_gl = in_f * (nanp_f * defl_f + (one - nanp_f) * le_f) + \
             (one - in_f) * dbl
         binv = pval  # categorical columns are identity: bin == position
+    elif split_bins is not None:
+        binv, nan_bin = split_bins(pf)
+        nan_f = jnp.where(binv == nan_bin, one, zero)
+        le_f = jnp.where(binv <= thr, one, zero)
+        num_gl = nan_f * defl_f + (one - nan_f) * le_f
     else:
         # per-feature flags (num_bins, missing_is_nan) index the
         # full-width feature table regardless of bin packing/bundling
@@ -747,7 +766,16 @@ def fits_v2(num_slots: int, num_features: int, bmax: int,
     original-feature table width when it differs from the bins width
     (EFB: bins hold bundle columns but routing gathers original-feature
     one-hots + the loc_table decode); row_block: the block the caller
-    will actually use."""
+    will actually use.
+
+    Where it says no for the whole width, `feature_tile` asks it again
+    of a tile of the features: the grower's one-hot pass then routes
+    and builds in fused_route_hist_mxu(feature_tile=...) and the
+    slot-grouped kernel (histogram_pallas) builds tile by tile, both
+    over the feature axis as a grid axis. What still falls to the
+    route_rows_mxu + chunked v1 pair is a pass that no tile fits (a
+    frontier too wide for even one lane tile of features) and the EFB
+    and 4-bit packed postures, which the tiled sweep does not take."""
     b = ((bmax + 127) // 128) * 128
     nchan = hist_num_channels(double_prec, quantized, const_hess)
     out = nchan * num_slots * num_features * b * 4
@@ -761,6 +789,41 @@ def fits_v2(num_slots: int, num_features: int, bmax: int,
     inputs = row_block * (12 * plane + route_cost * flane_r +
                           2 * _FGROUP * b)
     return out + inputs <= _V2_BUDGET_BYTES
+
+
+#: features a one-hot kernel body unrolls at most: beyond this a pass
+#: is built over the feature axis in tiles even where its histograms
+#: fit VMEM whole. DERIVATION: the fused sweep's body at 968 features
+#: (242 unrolled groups) did not compile for v5e in 20 minutes, at 128
+#: features in 17 s (ahead-of-time, this repository's tpu_aot recipe)
+_UNROLL_FEATURES = 256
+#: rows a step of a kernel built in feature tiles takes: its unrolled
+#: body goes with rows x tile width. DERIVATION: the tiled fused sweep
+#: compiled for v5e in 9.3 s at 1024 rows and 17.3 s at 2048
+#: (ahead-of-time, 8 slots, 968 features)
+TILED_ROW_BLOCK = 1024
+
+
+def feature_tile(num_slots: int, num_features: int, bmax: int,
+                 double_prec: bool = True, quantized: bool = False,
+                 row_block: int = _V2_ROW_BLOCK,
+                 const_hess: float = 0.0) -> int:
+    """Features per tile of a pass built over the feature axis in
+    tiles: 0 where fits_v2 holds for the whole width and the kernel
+    unrolls at most _UNROLL_FEATURES features (no tiling); else one
+    lane tile of features, 128, where fits_v2 holds for that; else
+    -1. A kernel unrolls its feature groups, so a tile of fixed width
+    keeps what a kernel traces, lowers and compiles the same at any
+    number of features; the tiles cover the padded feature lanes
+    exactly and only the last one holds padding."""
+    if num_features <= _UNROLL_FEATURES and fits_v2(
+            num_slots, num_features, bmax, double_prec, quantized,
+            row_block=row_block, const_hess=const_hess):
+        return 0
+    if fits_v2(num_slots, 128, bmax, double_prec, quantized,
+               row_block=row_block, const_hess=const_hess):
+        return 128
+    return -1
 
 
 @functools.partial(
@@ -842,7 +905,14 @@ def build_histograms_mxu_auto(bins, grad, hess, cnt, row_slot, *,
     `bins` as 4-bit packed (the v1 fallback unpacks on device — packed
     storage targets small-bmax shapes, which always fit v2). operands:
     the tree's prepared operands, for the v2 kernel; the v1 fallback
-    pads to its own selector layout from the plain arguments."""
+    pads to its own selector layout from the plain arguments.
+
+    The grower no longer sends a wide one-hot pass of plain bins here:
+    where fits_v2 says no, fused_route_hist_mxu builds it over the
+    feature axis in tiles (feature_tile). The grower reaches this
+    dispatcher, and through it the v1 kernel, only with EFB or 4-bit
+    packed bins that do not fit, or with a frontier too wide for a
+    single lane tile of features (feature_tile -1)."""
     f = num_features if num_features else bins.shape[1]
     if fits_v2(num_slots, f, bmax, double_prec, quantized,
                const_hess=const_hess):
@@ -945,10 +1015,140 @@ def _fused_kernel(nb: int, f: int, b: int, s: int, m: int,
     return kernel
 
 
+#: feature rows of the transposed bins a tiled sweep's route reads at a
+#: time (the uint8 sublane tile)
+_ROUTE_CHUNK = 32
+
+
+def _split_bins_chunked(bins_t_ref, nan_ref, nb: int):
+    """_route_decide's split_bins for the feature-tiled sweep: the bin
+    of each row's split feature and that feature's NaN bin, pulled out
+    of the transposed bins block [fsub, nb] and the [fsub, 1] NaN-bin
+    column a _ROUTE_CHUNK-row slice at a time, so the route's working
+    set is the same at any width."""
+    chunks = bins_t_ref.shape[0] // _ROUTE_CHUNK
+    iota = jax.lax.broadcasted_iota(
+        jnp.int32, (_ROUTE_CHUNK, nb), 0).astype(jnp.float32)
+
+    def lookup(pf):
+        def body(c, acc):
+            at = pl.multiple_of(c * _ROUTE_CHUNK, _ROUTE_CHUNK)
+            rows = bins_t_ref[pl.ds(at, _ROUTE_CHUNK), :] \
+                .astype(jnp.int32).astype(jnp.float32)       # [32, nb]
+            hit = pf == iota + (c * _ROUTE_CHUNK).astype(jnp.float32)
+            binv = acc[0] + jnp.sum(jnp.where(hit, rows, 0.0), axis=0,
+                                    keepdims=True)
+            nanb = acc[1] + jnp.sum(
+                jnp.where(hit, nan_ref[pl.ds(at, _ROUTE_CHUNK), :], 0.0),
+                axis=0, keepdims=True)
+            return binv, nanb
+
+        zero = jnp.zeros((1, nb), jnp.float32)
+        return jax.lax.fori_loop(0, chunks, body, (zero, zero))
+
+    return lookup
+
+
+def _fused_tiled_kernel(nb: int, f: int, ft: int, b: int, s: int, m: int,
+                        mm_dtype=jnp.bfloat16, nchan: int = 5,
+                        has_cat: bool = True):
+    """_fused_kernel over a grid of (feature tile, row block): every
+    step routes its row block (the same decision in every tile: the
+    route reads all features, a chunk at a time) and builds the
+    histograms of the tile's `ft` features, into that tile's output
+    block, which stays in VMEM across the row blocks."""
+
+    def kernel(node_ref, bins_ref, bins_t_ref, data_ref, tbl_ref,
+               member_ref, nan_ref, hist_ref, node_out_ref, slot_ref):
+        ti = pl.program_id(0)
+        ri = pl.program_id(1)
+
+        @pl.when(ri == 0)
+        def _():
+            hist_ref[0] = jnp.zeros_like(hist_ref[0])
+
+        node = node_ref[:]                                   # [1, nb] i32
+        node_oh, gath = _node_lookup(node, tbl_ref[:], m, nb)
+        block_has_split = jnp.sum(gath[_COL_SPLIT:_COL_SPLIT + 1, :]) > 0.5
+
+        @pl.when(~block_has_split)
+        def _():
+            node_out_ref[:] = node
+            slot_ref[:] = _own_slot(gath).astype(jnp.int32)
+
+        @pl.when(block_has_split)
+        def _():
+            new_node_f, new_slot_f = _route_decide(
+                node, node_oh, gath, None, None,
+                member_ref[:] if has_cat else None, nb=nb,
+                split_bins=_split_bins_chunked(bins_t_ref, nan_ref, nb))
+            node_out_ref[:] = new_node_f.astype(jnp.int32)
+            slot_ref[:] = new_slot_f.astype(jnp.int32)
+
+        slot = slot_ref[:]                                   # [1, nb] i32
+
+        @pl.when(jnp.max(slot) >= 0)
+        def _():
+            _hist_accumulate(
+                hist_ref, _slot_lhs(slot, data_ref[:], nb=nb, s=s,
+                                    nchan=nchan, mm_dtype=mm_dtype),
+                bins_ref[:].astype(jnp.int32), nb=nb, f=ft, b=b,
+                mm_dtype=mm_dtype, live=f - ti * ft)
+
+    return kernel
+
+
+def _fused_tiled_call(ops: HistOperands, row_node, tbl, member, feat_tbl,
+                      *, f: int, ft: int, s: int, b: int, nb: int,
+                      nchan: int, has_cat: bool, interpret: bool):
+    """The feature-tiled sweep's pallas_call: (hist [1, nchan*s,
+    tiles*ft*b], node_out [1, rows])."""
+    rows, plane = ops.lanes.shape
+    fsub = ops.bins_t.shape[0]
+    m = tbl.shape[0]
+    tiles = plane // ft
+    tbl_t, member_t, _, _ = _route_tables(tbl, member, feat_tbl, None,
+                                          f_route=f, has_efb=False)
+    # a feature's NaN bin (num_bins - 1 where missing_is_nan, else -1),
+    # one feature per sublane like the transposed bins
+    nan_bin = jnp.where(feat_tbl[:f, 1] > 0.5, feat_tbl[:f, 0] - 1.0, -1.0)
+    nan_bin = _pad_rows(nan_bin.astype(jnp.float32)[:, None], fsub,
+                        constant_values=-1.0)
+    nblocks = rows // nb
+    return pl.pallas_call(
+        _fused_tiled_kernel(nb, f, ft, b, s, m, nchan=nchan,
+                            has_cat=has_cat),
+        grid=(tiles, nblocks),
+        in_specs=[
+            pl.BlockSpec((1, nb), lambda ti, ri: (0, ri)),
+            pl.BlockSpec((nb, ft), lambda ti, ri: (ri, ti)),
+            pl.BlockSpec((fsub, nb), lambda ti, ri: (0, ri)),
+            pl.BlockSpec((8, nb), lambda ti, ri: (0, ri)),
+            pl.BlockSpec(tbl_t.shape, lambda ti, ri: (0, 0)),
+            pl.BlockSpec(member_t.shape, lambda ti, ri: (0, 0)),
+            pl.BlockSpec(nan_bin.shape, lambda ti, ri: (0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, nchan * s, ft * b), lambda ti, ri: (0, 0, ti)),
+            pl.BlockSpec((1, nb), lambda ti, ri: (0, ri)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, nchan * s, tiles * ft * b),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((1, rows), jnp.int32),
+        ],
+        scratch_shapes=[pltpu.VMEM((1, nb), jnp.int32)],
+        name="fused_route_hist_tiled", interpret=interpret,
+        **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
+    )(_lane_row(row_node, rows), ops.lanes, ops.bins_t, ops.data, tbl_t,
+      member_t, nan_bin)
+
+
 @functools.partial(
     jax.jit, static_argnames=("num_slots", "bmax", "row_block", "has_cat",
                               "double_prec", "quantized", "num_features",
-                              "efb_range", "const_hess", "interpret"))
+                              "efb_range", "const_hess", "feature_tile",
+                              "interpret"))
 def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                          cnt: jax.Array, row_node: jax.Array,
                          tbl: jax.Array, member: jax.Array,
@@ -958,11 +1158,20 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                          num_features: int = 0, loc_table=None,
                          efb_range: bool = False,
                          const_hess: float = 0.0,
+                         feature_tile: int = 0,
                          operands: Optional[HistOperands] = None,
                          interpret: bool = False):
     """One sweep: route rows through the previous pass's packed split
     tables (pack_route_tables) AND build the per-slot histograms of the
     resulting frontier. Returns (hist [S, F, bmax, 3], new row_node [N]).
+
+    feature_tile > 0 (feature_tile's answer where fits_v2 says no for
+    the whole width; not with EFB or 4-bit packed bins): the sweep runs
+    over a grid of (feature tile, row block), each tile's histograms
+    resident in VMEM while the rows pass, every step routing its rows
+    with the features read a chunk at a time. The histogram equals the
+    untiled one bit for bit: the same dots over the same groups of
+    features, the rows in the same order.
 
     Rows whose node did not split keep their node and land in no slot
     (slot -1), matching route_rows_mxu + build_histograms_mxu. Routing is
@@ -1003,6 +1212,20 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     fsub = ops.bins_t.shape[0]
     m = tbl.shape[0]
     nchan = hist_num_channels(double_prec, quantized, const_hess)
+    if feature_tile:
+        if loc_table is not None or fh or plane % feature_tile:
+            raise ValueError("the feature-tiled sweep takes plain bins "
+                             "(no EFB, no 4-bit packing) in tiles that "
+                             "divide %d lanes, not %d"
+                             % (plane, feature_tile))
+        hist, node_out = _fused_tiled_call(
+            ops, row_node, tbl, member, feat_tbl, f=f, ft=feature_tile,
+            s=s, b=b, nb=nb, nchan=nchan, has_cat=has_cat,
+            interpret=interpret)
+        h3 = _combine_hist(hist[..., :f * b], nchan=nchan, s=s, f=f, b=b,
+                           bmax=bmax, double_prec=double_prec,
+                           const_hess=const_hess)
+        return h3, node_out[0, :n]
     # route tables are original-feature-indexed under decode-mode EFB
     tbl_t, member_t, feat_tbl, loc_t = _route_tables(
         tbl, member, feat_tbl, loc_table, has_efb=has_efb,

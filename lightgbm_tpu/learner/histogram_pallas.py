@@ -66,9 +66,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .histogram_mxu import (_COMPILER_PARAMS, HistOperands, _combine_hist,
-                            _hist_accumulate, hist_num_channels,
-                            prepare_hist_operands)
+from .histogram_mxu import (_COMPILER_PARAMS, TILED_ROW_BLOCK, HistOperands,
+                            _combine_hist, _hist_accumulate, feature_tile,
+                            hist_num_channels, prepare_hist_operands)
 
 __all__ = ["build_histograms_pallas", "build_histograms_scatter",
            "partition_table", "partition_stream", "partition_rows",
@@ -91,6 +91,11 @@ _RANK_TILES_PER_STEP = 8
 #: rows x tile); 32 tiles a step read as 8 do (8.7 / 11.8 at 256)
 _STREAM_TILE = 512
 _STREAM_TILES_PER_STEP = 8
+#: VMEM the groups' rings may take: the tile halves until they fit (a
+#: table of 974 columns in 21 groups takes 44 MiB at a tile of 256,
+#: where 512 asked for more than the scoped VMEM holds; 34 or 143
+#: columns take 11 or 22 MiB at 512)
+_STREAM_RING_BYTES = 48 * 1024 * 1024
 #: bf16 buffers the staged chunks leave VMEM from: a chunk's DMA is
 #: waited for when its buffer comes round again
 _STREAM_FLUSH_BUFFERS = 2
@@ -590,7 +595,8 @@ def partition_stream(table: jax.Array, grp: jax.Array,
     slot-less all-zero rows. Rows at and after the blocks in use are
     not written; the lanes past W are zero.
 
-    Per tile of _STREAM_TILE rows: the rows' ranks within their groups
+    Per tile of _STREAM_TILE rows (fewer where the groups' rings would
+    not fit VMEM): the rows' ranks within their groups
     come from a triangular matmul (as in _stable_positions); a [Tp, T]
     0/1 permutation operand then moves the tile's live rows on the MXU
     into a tile sorted by group (f32 accumulation of one bf16 value
@@ -605,13 +611,17 @@ def partition_stream(table: jax.Array, grp: jax.Array,
     n = grp.shape[0]
     w = table.shape[0]
     ng, nb = blk_start.shape[0] - 1, row_block
+    wp = -(-w // 128) * 128
     t = min(_STREAM_TILE, nb)
+    # the groups' rings ([ng, 2t, wp] f32) have to fit VMEM: a wide
+    # table of many groups moves its rows in shorter tiles
+    while t > 128 and ng * 2 * t * wp * 4 > _STREAM_RING_BYTES:
+        t //= 2
     tiles = _STREAM_TILES_PER_STEP
     if nb % t or t % 8:
         raise ValueError("row_block must be a multiple of 8 and, above "
-                         "%d, of %d" % (_STREAM_TILE, _STREAM_TILE))
+                         "%d, of %d" % (t, t))
     gpad = ((ng + 15) // 16) * 16            # bf16 sublane tile
-    wp = -(-w // 128) * 128
     # a group's run in the sorted tile: its phase (< 8), its rows, and
     # the rest of its last tile of eight
     tp = ((t + 14 * min(ng, t) + 15) // 16) * 16
@@ -730,13 +740,84 @@ def _grouped_kernel(nb: int, f: int, b: int, sg: int, nchan: int,
     return kernel
 
 
+def _channel_window(fcols: int, nchan: int) -> int:
+    """Lanes of the narrowest aligned window of the row table that holds
+    a row's channels and slot (columns fcols .. fcols + nchan)."""
+    cw = 128
+    while fcols // cw != (fcols + nchan) // cw:
+        cw *= 2
+    return cw
+
+
+def _grouped_tiled_kernel(nb: int, f: int, ft: int, b: int, sg: int,
+                          nchan: int, coff: int, mm_dtype=jnp.bfloat16):
+    """_grouped_kernel over a grid of (feature tile, block): the bins of
+    the tile's `ft` features come in one block of the table, the
+    channels and the slot in another (the lane tile that holds them,
+    from column `coff` of it)."""
+
+    def kernel(grp_ref, used_ref, tab_ref, ch_ref, out_ref):
+        ti = pl.program_id(0)
+        i = pl.program_id(1)
+        g = grp_ref[i]
+        prev = grp_ref[jnp.maximum(i - 1, 0)]
+
+        @pl.when((i == 0) | (g != prev))
+        def _():
+            out_ref[0] = jnp.zeros_like(out_ref[0])
+
+        @pl.when(i < used_ref[0])
+        def _():
+            ch = ch_ref[:].astype(jnp.float32)
+            slot = ch[:, coff + nchan:coff + nchan + 1].astype(jnp.int32)
+            slot_oh = slot == jax.lax.broadcasted_iota(
+                jnp.int32, (nb, sg), 1)                      # [Nb, Sg] bool
+            lhs = jnp.concatenate(
+                [jnp.where(slot_oh, ch[:, coff + c:coff + c + 1],
+                           jnp.float32(0.0)) for c in range(nchan)],
+                axis=1).astype(mm_dtype)                     # [Nb, C*Sg]
+            _hist_accumulate(
+                out_ref, lhs,
+                tab_ref[:].astype(jnp.float32).astype(jnp.int32), nb=nb,
+                f=ft, b=b, mm_dtype=mm_dtype, rows_axis=0,
+                live=f - ti * ft)
+
+    return kernel
+
+
 def _grouped_call(block_group: jax.Array, blocks_used: jax.Array,
                   tab_g: jax.Array, *, nb: int, f: int, b: int, sg: int,
                   ng: int, nchan: int, fcols: int, fh: int = 0,
+                  feature_tile: int = 0,
                   interpret: bool = False) -> jax.Array:
-    """The kernel over a gathered row table: [G, nchan*sg, F*B] f32.
-    Blocks at and after blocks_used are neither fetched (their index
-    repeats the last block in use) nor multiplied."""
+    """The kernel over a gathered row table: [G, nchan*sg, F*B] f32
+    (feature_tile > 0: [G, nchan*sg, tiles*feature_tile*B], the tiles
+    built one after the other, each over every block). Blocks at and
+    after blocks_used are neither fetched (their index repeats the last
+    block in use) nor multiplied."""
+    if feature_tile:
+        ft = feature_tile
+        tiles = -(-fcols // ft)
+        cw = _channel_window(fcols, nchan)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles, block_group.shape[0]),
+            in_specs=[
+                pl.BlockSpec((nb, ft), lambda ti, i, grp, used: (
+                    jnp.minimum(i, used[0] - 1), ti)),
+                pl.BlockSpec((nb, cw), lambda ti, i, grp, used: (
+                    jnp.minimum(i, used[0] - 1), fcols // cw))],
+            out_specs=pl.BlockSpec((1, nchan * sg, ft * b),
+                                   lambda ti, i, grp, used: (grp[i], 0, ti)))
+        return pl.pallas_call(
+            _grouped_tiled_kernel(nb, f, ft, b, sg, nchan,
+                                  fcols - fcols // cw * cw),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((ng, nchan * sg, tiles * ft * b),
+                                           jnp.float32),
+            name="grouped_hist_tiled", interpret=interpret,
+            **({} if interpret else {"compiler_params": _COMPILER_PARAMS}),
+        )(block_group, blocks_used[None], tab_g, tab_g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(block_group.shape[0],),
@@ -805,12 +886,28 @@ def build_histograms_scatter(bins: jax.Array, grad: jax.Array,
     sg = min(group_width(nchan), s)
     ng = -(-s // sg)
 
+    # a group's histograms too wide for VMEM, or too many features for
+    # one kernel body, are built a tile of features at a time
+    # (feature_tile, as for the one-hot sweep), TILED_ROW_BLOCK rows a
+    # step
+    ft = 0 if fh else max(0, feature_tile(
+        sg, f, bmax, double_prec, quantized, row_block=nb,
+        const_hess=const_hess))
+    if ft:
+        nb = min(nb, TILED_ROW_BLOCK)
     block_group, blocks_used, tab_g = partition_table(
         table, row_slot, num_slots=s, row_block=nb, group=sg,
         counts=slot_counts, impl=partition_impl, interpret=interpret)
+    if ft:
+        cw = _channel_window(fcols, nchan)
+        lanes = max(-(-fcols // ft) * ft, (fcols // cw + 1) * cw)
+        if tab_g.shape[1] < lanes:
+            # (the oracles' unpadded table; the stream's has its lane
+            # tiles already)
+            tab_g = jnp.pad(tab_g, ((0, 0), (0, lanes - tab_g.shape[1])))
     out = _grouped_call(block_group, blocks_used, tab_g, nb=nb, f=f, b=b,
                         sg=sg, ng=ng, nchan=nchan, fcols=fcols, fh=fh,
-                        interpret=interpret)
+                        feature_tile=ft, interpret=interpret)[..., :fb]
 
     # [G, C*sg, F*B] -> the shared postlude layout [1, C*S, F*B]
     out = jnp.transpose(out.reshape(ng, nchan, sg, fb), (1, 0, 2, 3)) \

@@ -50,6 +50,11 @@ class SplitHyperParams:
     monotone_penalty: float = 0.0
     extra_trees: bool = False      # one random threshold per (slot, feature)
     has_categorical: bool = False  # enables the categorical scan paths
+    # whether a column may have a NaN bin; False (a dataset without
+    # missing values) leaves the NaN-left scan out of find_best_splits
+    # and the default-direction count out of the growth counters
+    # (grower_mxu)
+    has_missing: bool = True
     # used-feature indices of the categorical columns, for callers whose
     # histogram holds every used feature in order (the growers pass it on
     # as find_best_splits' `cat_columns`); None: not known
@@ -189,26 +194,39 @@ def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
     # ---------- numerical features ----------
     with jax.named_scope("split.numerical"):
         prefix = cumsum_bins(hist)  # [S,F,B,3]
-        nan_idx = jnp.maximum(num_bins - 1, 0)
-        nan_sums = jnp.take_along_axis(
-            hist, nan_idx[None, :, None, None].astype(jnp.int32),
-            axis=2)  # [S,F,1,3]
-        nan_sums = jnp.where(missing_is_nan[None, :, None, None], nan_sums,
-                             0.0)
+        if hp.has_missing:
+            nan_idx = jnp.maximum(num_bins - 1, 0)
+            nan_sums = jnp.take_along_axis(
+                hist, nan_idx[None, :, None, None].astype(jnp.int32),
+                axis=2)  # [S,F,1,3]
+            nan_sums = jnp.where(missing_is_nan[None, :, None, None],
+                                 nan_sums, 0.0)
+        else:
+            nan_sums = jnp.zeros((s, f, 1, 3), hist.dtype)
 
-        # threshold t valid iff t <= num_bins-2 (-1 more when NaN bin present)
+        # With a NaN bin (the last, num_bins - 1) both directions are
+        # scanned, as FindBestThresholdSequentially does for
+        # MissingType::NaN: NaN to the right at thresholds up to
+        # num_bins - 2, the last bin with a value (the forward scan:
+        # there the right side is the NaN rows alone), NaN to the left
+        # at thresholds up to num_bins - 3 (the reverse scan keeps a
+        # valued bin on the right). Without one: thresholds up to
+        # num_bins - 2, NaN to the right.
         t_limit = num_bins - 2 - missing_is_nan.astype(jnp.int32)      # [F]
-        valid_t = bins_r[None, None, :] <= t_limit[None, :, None]  # [1,F,B]
-        valid_t = valid_t & (~is_cat[None, :, None]) & \
-            (fmask[:, :, None] > 0)  # [S,F,B]
+        feat_ok = (~is_cat[None, :, None]) & (fmask[:, :, None] > 0)
+        valid_t = (bins_r[None, None, :] <= (num_bins - 2)[None, :, None]) \
+            & feat_ok                                                # [S,F,B]
+        valid_l = (bins_r[None, None, :] <= t_limit[None, :, None]) & \
+            feat_ok
         if hp.extra_trees and rand_bins is not None:
             # extra-trees: evaluate ONE random threshold per (slot, feature)
             # (reference USE_RAND specialization, feature_histogram.hpp:85)
-            valid_t = valid_t & (bins_r[None, None, :] ==
-                                 (rand_bins % jnp.maximum(t_limit + 1, 1)
-                                  [None, :])[:, :, None])
+            rand_t = bins_r[None, None, :] == (
+                rand_bins % jnp.maximum(t_limit + 1, 1)[None, :])[:, :, None]
+            valid_t = valid_t & rand_t
+            valid_l = valid_l & rand_t
 
-        def eval_option(left):  # [S,F,B,3]
+        def eval_option(left, valid):  # [S,F,B,3]
             right = tot - left
             lg, lh, lc = left[..., 0], left[..., 1], left[..., 2]
             rg, rh, rc = right[..., 0], right[..., 1], right[..., 2]
@@ -240,12 +258,18 @@ def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
             else:
                 g = _split_gain(lg, lh, lc, rg, rh, rc, l1, l2, hp,
                                 parent_output[:, None, None])
-            return jnp.where(ok & valid_t, g, -jnp.inf)
+            return jnp.where(ok & valid, g, -jnp.inf)
 
-        gain_na_right = eval_option(prefix)  # NaN stays right
-        gain_na_left = jnp.where(
-            missing_is_nan[None, :, None],
-            eval_option(prefix + nan_sums), -jnp.inf)  # NaN joins left
+        gain_na_right = eval_option(prefix, valid_t)  # NaN stays right
+        if hp.has_missing:
+            gain_na_left = jnp.where(
+                missing_is_nan[None, :, None],
+                eval_option(prefix + nan_sums, valid_l), -jnp.inf)
+        else:
+            # no column has a NaN bin: the tables are arguments of the
+            # fused program, not literals XLA could fold, so the NaN-left
+            # scan ([S, F, B] a channel) is left out here
+            gain_na_left = jnp.full_like(gain_na_right, -jnp.inf)
 
     # ---------- categorical ----------
     # FindBestThresholdCategoricalInner (feature_histogram.hpp:278-485).
@@ -383,7 +407,8 @@ def find_best_splits(hist: jax.Array, parent_grad: jax.Array,
     num_gain = jnp.maximum(gain_na_right, gain_na_left)
     num_gain = jnp.where(num_gain > min_gain_shift[:, None, None],
                          num_gain, -jnp.inf)
-    all_gain = jnp.where(is_cat[None, :, None], cat_gain, num_gain)  # [S,F,B]
+    all_gain = jnp.where(is_cat[None, :, None], cat_gain, num_gain) \
+        if hp.has_categorical else num_gain                        # [S,F,B]
     if gain_penalty is not None:
         # constant across thresholds of one feature, so the per-feature
         # argmax is unchanged; only cross-feature competition and the

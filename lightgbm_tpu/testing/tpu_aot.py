@@ -227,7 +227,8 @@ def training_programs(params: dict, *, rows: int, features: int,
                                  _sds((2,), jnp.uint32)]),
         "fused_block_k%d" % block: (
             run.program, [vec, _sds((), jnp.int32),
-                          _sds((block, 2), jnp.uint32), *run.operands]),
+                          _sds((block, 2), jnp.uint32), *run.operands,
+                          run.feature_tables]),
     }
 
 
@@ -284,7 +285,9 @@ def sharded_fused_program(params: dict, *, rows: int, features: int,
     return run.program, [_sds((rows,), jnp.float32, rowwise),
                          _sds((), jnp.int32, whole),
                          _sds((block, 2), jnp.uint32, whole),
-                         *run.operands]
+                         *run.operands,
+                         tuple(_sds(a.shape, a.dtype, whole)
+                               for a in run.feature_tables)]
 
 
 def serving_programs(*, features: int, leaves: int, max_bin: int,

@@ -193,10 +193,10 @@ def test_a_count_saturates_instead_of_wrapping():
     kern = jnp.zeros((2, 1, 4, 3), jnp.float32).at[0, 0, 1, 2].set(600.) \
         .at[1, 0, 3, 2].set(600.)
     out = np.asarray(gm._count_pass(near, kern, "onehot", "fixup"))
-    assert out.tolist() == [1, 0, 0, 1, 2 ** 31 - 1, 0, 0]
+    assert out.tolist() == [1, 0, 0, 1, 2 ** 31 - 1, 0, 0, 0, 0]
     out = np.asarray(gm._count_pass(jnp.asarray(out), kern, "grouped",
                                     "bridge"))
-    assert out.tolist() == [1, 1, 1, 1, 2 ** 31 - 1, 1200, 0]
+    assert out.tolist() == [1, 1, 1, 1, 2 ** 31 - 1, 1200, 0, 0, 0]
     # one pass's rows past int32 (a mesh of 2^24-row shards could)
     big = jnp.full((1, 1, 1, 3), 3e9, jnp.float32)
     out = np.asarray(gm._count_pass(jnp.zeros(C, jnp.int32), big,
