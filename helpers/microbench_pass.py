@@ -1,11 +1,20 @@
 """One growth pass per histogram formulation, at a benchmark cell's
-shape (2,625,000 x 28 x 256 unless --rows / --features say otherwise):
-the derivation of histogram_pallas.GROUPED_MIN_WIDTH.
+shape (2,625,000 x 28 x 256 unless --rows / --features / --bmax say
+otherwise): the derivation of histogram_pallas.GROUPED_MIN_WIDTH.
 
 For each kernel width sk in {24, 40, 72, 136, 263} and for five (exact)
 and three (quantized) channels it times, on the chip:
 
   onehot   fused_route_hist_mxu, at the row block grower_mxu.sweep picks
+           (--feature-tile 128: the sweep over the feature axis in tiles
+           of 128 features, fused_route_hist_tiled, at TILED_ROW_BLOCK
+           rows a step, as the grower runs a pass too wide for one
+           kernel body)
+  onehot_fixed
+           onehot_prepared through route tables in which no node splits
+           and every row keeps its node's own slot: the histogram build
+           without the routing decision, which the tiled sweep runs
+           again in every tile
   route    route_rows_mxu(emit_counts=True), the grouped pass's first step
   partition_stream
            partition_table from the routed slots and the tree's row
@@ -56,15 +65,20 @@ gathered table, bit for bit; false makes the exit code 1). --stages
 check makes that comparison alone, on a table and slots drawn on the
 device, at every --live share under one build, and times nothing.
 
+Only the stages asked for are prepared: a run of the one-hot stages
+alone builds neither the row table nor the grouped layouts, which at
+Bosch's width (1,000,000 x 968) would take several GB of the device.
+
 Usage: python helpers/microbench_pass.py [--rows N] [--features F]
-       [--sk 24,40,...] [--nchan 5,3] [--live 0.18,0.5]
-       [--stages onehot,grouped,...] [--reps K] [--check] [--interpret]
-       [--out NAME.json]
+       [--bmax B] [--feature-tile 128] [--sk 24,40,...] [--nchan 5,3]
+       [--live 0.18,0.5] [--stages onehot,grouped,...] [--reps K]
+       [--check] [--interpret] [--out NAME.json]
 Writes chiprun_out/microbench_pass.json (or --out; after every pass, so
 a call cut short keeps what it measured) beside the table it prints.
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -80,30 +94,35 @@ import jax.numpy as jnp  # noqa: E402
 
 from lightgbm_tpu.learner import histogram_pallas as hp  # noqa: E402
 from lightgbm_tpu.learner.histogram_mxu import (  # noqa: E402
-    _hist_channels, _round_up, _row_table, fits_v2, fused_route_hist_mxu,
-    hist_num_channels, pack_route_tables, prepare_hist_operands,
-    route_rows_mxu)
+    TILED_ROW_BLOCK, _hist_channels, _round_up, _row_table, fits_v2,
+    fused_route_hist_mxu, hist_num_channels, pack_route_tables,
+    prepare_hist_operands, route_rows_mxu)
 
 F = 28
 BMAX = 256
+#: the stages that need no grouped-pass inputs (row table, layouts)
+ONEHOT_STAGES = {"onehot", "onehot_prepared", "onehot_fixed"}
 
 
-def timeit_chained(step, reps):
-    """Seconds per call of `step` (() -> a scalar that depends on all of
-    its work), timed as one jitted fori_loop of 1+reps repetitions minus
-    one of 1. The carry threads a value XLA cannot fold, so repetitions
-    neither fuse nor vanish."""
+def timeit_chained(step, reps, args=()):
+    """Seconds per call of `step` ((c, *args) -> a scalar that depends
+    on all of its work), timed as one jitted fori_loop of 1+reps
+    repetitions minus one of 1. The carry threads a value XLA cannot
+    fold, so repetitions neither fuse nor vanish. `args` reach the
+    program as its arguments: an array a step closes over instead is
+    lowered into the program as a constant, a copy on the host (3 GB
+    at Bosch's width)."""
 
     @jax.jit
-    def chain(k):
+    def chain(k, args):
         def body(_, c):
             # c is always 0, which XLA cannot know
-            return jnp.minimum(c + step(c), 0)
+            return jnp.minimum(c + step(c, *args), 0)
         return jax.lax.fori_loop(0, k, body, jnp.int32(0))
 
     def run(k):
         t0 = time.perf_counter()
-        int(chain(jnp.int32(k)))
+        int(chain(jnp.int32(k), args))
         return time.perf_counter() - t0
 
     run(1)  # compile + warm
@@ -148,8 +167,27 @@ def make_state(sk, n, rng, live):
     return tbl, member, row_node, thr
 
 
-def onehot_row_block(sk, quant):
+def fixed_slot_tables(sk):
+    """Route tables in which no node splits and each of the sk parents
+    owns kernel slot = parent: every row keeps its node and lands in
+    that node's slot, so a one-hot sweep skips the routing decision
+    (block_has_split is false in every block) and builds the same
+    number of slots from all rows."""
+    m_pad = _round_up(4 * sk, 128)
+    ids = np.arange(m_pad)
+    return pack_route_tables(
+        jnp.zeros(m_pad, bool), jnp.asarray(ids % F, jnp.int32),
+        jnp.zeros(m_pad, jnp.int32), jnp.zeros(m_pad, bool),
+        jnp.zeros(m_pad, bool), jnp.zeros(m_pad, jnp.int32),
+        jnp.zeros(m_pad, jnp.int32),
+        jnp.asarray(np.where(ids < sk, ids, -1), jnp.int32),
+        jnp.zeros((m_pad, (BMAX + 31) // 32), jnp.uint32), m_pad, BMAX)
+
+
+def onehot_row_block(sk, quant, tile=0):
     """The row block grower_mxu.sweep picks for a one-hot pass."""
+    if tile:
+        return TILED_ROW_BLOCK
     if sk <= 64:
         return 2048
     for rb in (8192, 4096, 2048):
@@ -220,45 +258,26 @@ def check_layouts(sk, nchan, n, lives, interpret):
     return rows
 
 
-def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
-               only=None, live=0.18):
+def grouped_stages(sk, nchan, n, interpret, bins, g, h, cnt, feat_tbl,
+                   tbl, member, row_node, ops, rs, cts, row, check, kw):
+    """The grouped pass's stages (partition, scatter, table, gather,
+    kernel, grouped), with their inputs computed once outside the
+    clock; the layout's sizes go into `row`."""
     quant = nchan == 3
-    bins = jnp.asarray(rng.randint(0, BMAX, (n, F)), jnp.uint8)
-    if quant:
-        g = jnp.asarray(rng.randint(-127, 128, n), jnp.float32)
-        h = jnp.asarray(rng.randint(0, 128, n), jnp.float32)
-    else:
-        g = jnp.asarray(rng.randn(n), jnp.float32)
-        h = jnp.asarray(rng.rand(n), jnp.float32)
-    cnt = jnp.ones(n, jnp.float32)
-    feat_tbl = jnp.stack([jnp.full(F, float(BMAX)), jnp.zeros(F)], axis=1)
-    tbl, member, row_node, thr = make_state(sk, n, rng, live)
-    assert hist_num_channels(True, quant) == nchan
-    kw = dict(quantized=quant, double_prec=True)
     nb = hp.GROUPED_ROW_BLOCK
     sg = min(hp.group_width(nchan), sk)
     ng = -(-sk // sg)
-    rb = onehot_row_block(sk, quant)
-
-    ops = jax.jit(lambda: prepare_hist_operands(
-        bins, g, h, cnt, lanes=True, table=True, route=True, **kw))()
-
-    def route(c):
-        return route_rows_mxu(None, row_node + c, tbl, member, feat_tbl,
-                              emit_counts=True, num_slots=sk,
-                              operands=ops, interpret=interpret)
-
-    # the stages' inputs, computed once outside the clock
-    rn0, rs, cts = jax.jit(route)(jnp.int32(0))
     if check:
         exact = layout_checker(sk, sg, nb, ops.table.shape[0], interpret)(
             ops.table, rs, cts)
+        row["layout_exact"] = exact
         print("  layout_exact %s" % exact, flush=True)
     block_group, used, src, src_slot = jax.jit(lambda: hp.partition_rows(
         rs, num_slots=sk, row_block=nb, group=sg, counts=cts,
         interpret=interpret))()
+    row.update(groups=ng, blocks_used=int(used),
+               blocks_static=int(block_group.shape[0]))
     data, _ = _hist_channels(g, h, cnt, True, quant)
-    live_share = float(jnp.mean((rs >= 0).astype(jnp.float32)))
     chunk = min(hp._GATHER_CHUNK_BLOCKS, block_group.shape[0]) * nb
 
     def table_of(c):
@@ -297,16 +316,9 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
             ops.table, rs + c, num_slots=sk, row_block=nb, group=sg,
             counts=cts, interpret=interpret)[2])
 
-    def plain(c):
-        """The plain arrays as a pass of a tree meets them when nothing
-        is prepared: not loop-invariant (c is a zero XLA cannot see), or
-        the chained repetitions would share one preparation, which is
-        what the passes of a tree, each in its own cond branch, cannot
-        do."""
-        return (bins + c.astype(bins.dtype), g + c.astype(g.dtype), h, cnt)
-
     def grouped(c, operands=None, partition_impl="auto"):
-        raw = plain(c) if operands is None else (None,) * 4
+        raw = (bins + c.astype(bins.dtype), g + c.astype(g.dtype), h,
+               cnt) if operands is None else (None,) * 4
         rn, rs_, cts_ = route_rows_mxu(
             raw[0], row_node + c, tbl, member, feat_tbl, emit_counts=True,
             num_slots=sk, operands=operands, interpret=interpret)
@@ -315,20 +327,10 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
             operands=operands, partition_impl=partition_impl,
             interpret=interpret, **kw)) + opaque(rn)
 
-    def onehot(c, operands=None):
-        raw = plain(c) if operands is None else (None,) * 4
-        hist, rn = fused_route_hist_mxu(
-            *raw, row_node + c, tbl, member, feat_tbl,
-            num_slots=sk, bmax=BMAX, has_cat=False, row_block=rb,
-            operands=operands, interpret=interpret, **kw)
-        return opaque(hist) + opaque(rn)
-
-    stages = {
-        "onehot": onehot,
-        "onehot_prepared": lambda c: onehot(c, ops),
-        "route": lambda c: opaque(route(c)[1]),
+    return {
         "partition_stream": stream,
         "part": part("rank"),
+        "part_argsort": part("argsort"),
         "scatter": scatter,
         "table": lambda c: opaque_all(table_of(c)),
         "gather": lambda c: opaque(gather(c)),
@@ -340,15 +342,83 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
         "grouped_prepared": lambda c: grouped(c, ops),
         "grouped_rank": lambda c: grouped(c, ops, "rank"),
     }
-    if "argsort" in extras:
-        stages["part_argsort"] = part("argsort")
+
+
+def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
+               only=None, live=0.18, tile=0):
+    quant = nchan == 3
+    # drawn on the device: at 1,000,000 x 968 a host draw is 7.7 GB of
+    # int64 before the cast
+    bins = jax.random.randint(jax.random.PRNGKey(rng.randint(2 ** 31)),
+                              (n, F), 0, BMAX, dtype=jnp.uint8)
+    if quant:
+        g = jnp.asarray(rng.randint(-127, 128, n), jnp.float32)
+        h = jnp.asarray(rng.randint(0, 128, n), jnp.float32)
+    else:
+        g = jnp.asarray(rng.randn(n), jnp.float32)
+        h = jnp.asarray(rng.rand(n), jnp.float32)
+    cnt = jnp.ones(n, jnp.float32)
+    feat_tbl = jnp.stack([jnp.full(F, float(BMAX)), jnp.zeros(F)], axis=1)
+    tbl, member, row_node, thr = make_state(sk, n, rng, live)
+    assert hist_num_channels(True, quant) == nchan
+    kw = dict(quantized=quant, double_prec=True)
+    rb = onehot_row_block(sk, quant, tile)
+    grouped_too = check or not only or bool(only - ONEHOT_STAGES)
+
+    # the big arrays enter every program as arguments (timeit_chained)
+    ops = jax.jit(functools.partial(
+        prepare_hist_operands, lanes=True, table=grouped_too, route=True,
+        **kw))(bins, g, h, cnt)
+
+    def route(c, ops=ops, row_node=row_node):
+        return route_rows_mxu(None, row_node + c, tbl, member, feat_tbl,
+                              emit_counts=True, num_slots=sk,
+                              operands=ops, interpret=interpret)
+
+    # the stages' inputs, computed once outside the clock
+    rn0, rs, cts = jax.jit(route)(jnp.int32(0), ops, row_node)
+    live_share = float(jnp.mean((rs >= 0).astype(jnp.float32)))
+    row = {"sk": sk, "nchan": nchan, "rows": n, "features": F,
+           "bmax": BMAX, "feature_tile": tile, "onehot_row_block": rb,
+           "live_share": round(live_share, 4)}
+
+    def plain(c, bins, g, h, cnt):
+        """The plain arrays as a pass of a tree meets them when nothing
+        is prepared: not loop-invariant (c is a zero XLA cannot see), or
+        the chained repetitions would share one preparation, which is
+        what the passes of a tree, each in its own cond branch, cannot
+        do."""
+        return (bins + c.astype(bins.dtype), g + c.astype(g.dtype), h, cnt)
+
+    def onehot(c, row_node, operands=None, raw=None, tables=(tbl, member)):
+        raw = plain(c, *raw) if operands is None else (None,) * 4
+        hist, rn = fused_route_hist_mxu(
+            *raw, row_node + c, *tables, feat_tbl,
+            num_slots=sk, bmax=BMAX, has_cat=False, row_block=rb,
+            feature_tile=tile, operands=operands, interpret=interpret,
+            **kw)
+        return opaque(hist) + opaque(rn)
+
+    fixed = fixed_slot_tables(sk)
+    # a stage is a step, or (step, the arrays it takes as arguments)
+    stages = {
+        "onehot": (lambda c, rn, *raw: onehot(c, rn, raw=raw),
+                   (row_node, bins, g, h, cnt)),
+        "onehot_prepared": (lambda c, rn, o: onehot(c, rn, o),
+                            (row_node, ops)),
+        "onehot_fixed": (lambda c, rn, o: onehot(c, rn, o, tables=fixed),
+                         (row_node, ops)),
+        "route": (lambda c, o, rn: opaque(route(c, o, rn)[1]),
+                  (ops, row_node)),
+    }
+    if grouped_too:
+        stages.update(grouped_stages(
+            sk, nchan, n, interpret, bins, g, h, cnt, feat_tbl, tbl,
+            member, row_node, ops, rs, cts, row, check, kw))
+    if "argsort" not in extras:
+        stages.pop("part_argsort", None)
     if only:
         stages = {k: v for k, v in stages.items() if k in only}
-    row = {"sk": sk, "nchan": nchan, "rows": n, "features": F,
-           "onehot_row_block": rb,
-           "groups": ng, "live_share": round(live_share, 4),
-           "blocks_used": int(used), "blocks_static": int(
-               block_group.shape[0])}
     if check:
         # the route stage against make_state's splits replayed in NumPy:
         # node and slot of every row, exactly, from the prepared and the
@@ -361,8 +431,8 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
             bins, row_node, tbl, member, feat_tbl, interpret=interpret))()
         h_one, rn2 = jax.jit(lambda: fused_route_hist_mxu(
             bins, g, h, cnt, row_node, tbl, member, feat_tbl, num_slots=sk,
-            bmax=BMAX, has_cat=False, row_block=rb, interpret=interpret,
-            **kw))()
+            bmax=BMAX, has_cat=False, row_block=rb, feature_tile=tile,
+            interpret=interpret, **kw))()
         h_one = np.asarray(h_one)
         row["route_exact"] = bool(
             all(np.array_equal(np.asarray(x), want_node)
@@ -372,7 +442,6 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
             np.array_equal(np.asarray(cts),
                            np.bincount(node[left], minlength=sk)))
         print("  route_exact %s" % row["route_exact"], flush=True)
-        row["layout_exact"] = exact
         h_grp = np.asarray(jax.jit(lambda: hp.build_histograms_scatter(
             bins, g, h, cnt, rs, num_slots=sk, bmax=BMAX, slot_counts=cts,
             interpret=interpret, **kw))())
@@ -383,7 +452,7 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
         p_one, _ = jax.jit(lambda: fused_route_hist_mxu(
             None, None, None, None, row_node, tbl, member, feat_tbl,
             num_slots=sk, bmax=BMAX, has_cat=False, row_block=rb,
-            operands=ops, interpret=interpret, **kw))()
+            feature_tile=tile, operands=ops, interpret=interpret, **kw))()
         p_grp = jax.jit(lambda: hp.build_histograms_scatter(
             None, None, None, None, rs, num_slots=sk, bmax=BMAX,
             slot_counts=cts, operands=ops, interpret=interpret, **kw))()
@@ -392,9 +461,11 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
             np.asarray(p_grp).tobytes() == h_grp.tobytes())
         print("  agree_rel %s prepared_identical %s" % (
             row["agree_rel"], row["prepared_identical"]), flush=True)
-    for name, fn in stages.items():
+    for name, stage in stages.items():
+        fn, args = stage if isinstance(stage, tuple) else (stage, ())
         try:
-            row[name + "_ms"] = round(timeit_chained(fn, reps) * 1e3, 3)
+            row[name + "_ms"] = round(
+                timeit_chained(fn, reps, args) * 1e3, 3)
         except Exception as e:      # a kernel that does not build: say so
             row[name + "_ms"] = None
             row[name + "_error"] = "%s: %s" % (type(e).__name__,
@@ -404,10 +475,15 @@ def bench_pass(sk, nchan, n, reps, interpret, rng, extras, check=False,
 
 
 def main():
-    global F
+    global F, BMAX
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=2_625_000)
     ap.add_argument("--features", type=int, default=F)
+    ap.add_argument("--bmax", type=int, default=BMAX,
+                    help="bins a feature (63 at Bosch's max_bin)")
+    ap.add_argument("--feature-tile", type=int, default=0,
+                    help="features a tile of the one-hot sweep (128: "
+                         "the tiled sweep; 0: one kernel body)")
     ap.add_argument("--live", default="0.18",
                     help="share(s) of the rows that are live")
     ap.add_argument("--sk", default="24,40,72,136,263")
@@ -425,6 +501,7 @@ def main():
                          "control flow; its times mean nothing")
     args = ap.parse_args()
     F = args.features
+    BMAX = args.bmax
     dev = jax.devices()[0]
     print("# device: %s %s" % (dev.platform, dev.device_kind), flush=True)
     rng = np.random.RandomState(0)
@@ -449,14 +526,15 @@ def main():
             rows.append(bench_pass(sk, nchan, args.rows, args.reps,
                                    args.interpret, rng, extras,
                                    check=args.check, only=only,
-                                   live=live))
+                                   live=live, tile=args.feature_tile))
         with open(os.path.join(out, args.out), "w") as fh:
             json.dump({"platform": dev.platform,
                        "device_kind": dev.device_kind, "rows": rows},
                       fh, indent=1)
     ok = all(r.get("route_exact", True) and r.get("layout_exact", True)
              for r in rows)
-    cols = ["sk", "nchan", "live_share", "onehot_ms", "onehot_prepared_ms",
+    cols = ["sk", "nchan", "bmax", "feature_tile", "live_share",
+            "onehot_ms", "onehot_prepared_ms", "onehot_fixed_ms",
             "grouped_ms", "grouped_prepared_ms", "grouped_rank_ms",
             "route_ms", "partition_stream_ms", "part_ms", "scatter_ms",
             "table_ms", "gather_ms", "kernel_ms", "part_argsort_ms",

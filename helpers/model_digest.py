@@ -2,7 +2,7 @@
 work" is checked by when a change claims to do a cell's work better.
 
 Draws the cell's training rows from --seed exactly as its runner does
-(benchmark/runners/train.py, train_cat.py), trains --trees trees with
+(benchmark/runners/train.py, train_cat.py, train_missing.py), trains --trees trees with
 one `lgb.train` under the cell's parameters (fused blocks, as the cell
 runs them) and prints, as one JSON line, the SHA-256 of the model text
 of those trees (the `parameters:` dump left out: it echoes options, not
@@ -14,7 +14,8 @@ change's, on the chip:
     python helpers/model_digest.py --workload higgs_train --seed 7
 
 --rehearse-cpu takes the cell's tiny rehearsal size on the CPU.
-Cells of kind `train` and `train_cat`.
+Cells of kind `train`, `train_cat` and `train_missing` (whose training
+rows are one fixed table under every seed).
 """
 
 import argparse
@@ -55,6 +56,11 @@ def main() -> int:
                                  rows_seed=int(cfg["train_rows_seed"]))
         dataset_kw = {"categorical_feature":
                       [int(c) for c in cfg["categorical_feature"]]}
+    elif cfg["kind"] == "train_missing":
+        from benchmark.generators.bosch import (TRAIN_ROWS_SEED,
+                                                make_bosch_like)
+        X, y, _ = make_bosch_like(rows, TRAIN_ROWS_SEED, stream=0)
+        dataset_kw = {}
     else:
         raise SystemExit("no digest for a cell of kind %r" % cfg["kind"])
     params = {"objective": cfg["objective"],

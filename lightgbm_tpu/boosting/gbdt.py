@@ -659,7 +659,8 @@ class GBDT:
         self._hist_backend = hb
         self._hist_plan = self._hist_pass_plan(hb)
         self._operand_builds = None     # read from the next trace
-        _obs.record_hist_plan(hb, self._hist_plan, self._partition())
+        _obs.record_hist_plan(hb, self._hist_plan, self._partition(),
+                              self._onehot_bin_rows())
         return hb
 
     def _partition(self) -> str:
@@ -676,12 +677,33 @@ class GBDT:
         if self._learner.device != "mxu":
             return {}
         self._resolved_hist_backend()
-        return {"hist_plan": ",".join("%d:%s" % (sk, form)
-                                      for _, sk, form in self._hist_plan),
-                "partition": self._partition(),
-                "grouped_passes_per_tree": sum(
-                    form == "grouped" and stage != "fixup"
-                    for stage, _, form in self._hist_plan)}
+        attrs = {"hist_plan": ",".join("%d:%s" % (sk, form)
+                                       for _, sk, form in self._hist_plan),
+                 "partition": self._partition(),
+                 "grouped_passes_per_tree": sum(
+                     form == "grouped" and stage != "fixup"
+                     for stage, _, form in self._hist_plan)}
+        bin_rows = self._onehot_bin_rows()
+        if bin_rows:
+            attrs["onehot_bin_rows"] = bin_rows
+        return attrs
+
+    def _onehot_bin_rows(self) -> int:
+        """The one-hot height of a feature in the program's
+        feature-tiled one-hot passes (grower_mxu.tiled_bin_rows), 0
+        where none is tiled: static, from the plan."""
+        from ..learner.grower_mxu import tiled_bin_rows
+        cfg = self.config
+        return tiled_bin_rows(
+            self._hist_plan, num_features=int(self.num_bins_d.shape[0]),
+            bmax=self.bmax, double_prec=cfg.gpu_use_dp,
+            quantized=cfg.use_quantized_grad,
+            const_hess=0.0 if self._learner.is_parallel
+            else self._const_hessian(),
+            efb=self._efb is not None,
+            # a sharded learner resolves its plan before packing is
+            # decided, and only the serial learner packs
+            packed4=getattr(self, "_packed4", False))
 
     def _cat_attrs(self) -> dict:
         """What of the dataset is categorical, as attributes of a

@@ -45,6 +45,7 @@ from .histogram_mxu import (TILED_ROW_BLOCK, _round_up,
                             fits_v2, fused_route_hist_mxu,
                             hist_num_channels,
                             node_sums_mxu, node_values_mxu,
+                            onehot_bin_rows,
                             pack_route_tables, prepare_hist_operands,
                             quantize_gradients, route_rows_mxu,
                             unpack_bins_4bit)
@@ -318,6 +319,60 @@ def pass_formulation(nslots: int, *, hist_backend: str, nchan: int,
                                                columns=columns):
         return "grouped"
     return "onehot"
+
+
+def onehot_pass_blocks(nslots: int, *, num_features: int, bmax: int,
+                       double_prec: bool, quantized: bool,
+                       const_hess: float, efb: bool = False,
+                       packed4: bool = False,
+                       route_width: int = 0) -> Tuple[int, int]:
+    """(row block, feature tile) of a one-hot pass at `nslots` kernel
+    slots, as sweep() runs it, from static shapes alone: a tile of 0
+    builds the pass in one kernel body, > 0 over the feature axis in
+    tiles of that many features (histogram_mxu.feature_tile), -1 in no
+    single sweep (the two-kernel fallback). `num_features` and `bmax`
+    are the kernels' (bundle columns and bins under EFB)."""
+    # small frontiers run cheaper at half blocks, large ones prefer the
+    # wider block. EFB keeps rb=1024 in BOTH modes: expansion's
+    # original-feature route side needs the VMEM headroom (a 2048 block
+    # compiled to a real 136 MB OOM at 250-column bundles), and
+    # bundle-range mode did not gain from larger adaptive blocks
+    if efb:
+        rb = 1024
+    elif nslots <= 64:
+        rb = int(os.environ.get("LGBM_TPU_RB_SMALL", 2048))
+    else:
+        # large frontiers: the chained per-pass microbench
+        # (helpers/microbench_pass.py, v5e round 5) measured 8192
+        # fastest at every sk > 64 (sk=72: 20.0 ms vs 26.9 at 4096;
+        # sk=136: 34.6 vs 38.9) — fewer grid steps re-visiting the
+        # VMEM-resident accumulator. Fall back block-by-block when the
+        # bigger input working set would bust the VMEM budget (e.g.
+        # 5-channel exact grads at wide frontiers).
+        for rb in (int(os.environ.get("LGBM_TPU_RB_LARGE", 8192)),
+                   4096, 2048):
+            if fits_v2(nslots, num_features, bmax, double_prec, quantized,
+                       route_width=route_width, row_block=rb,
+                       const_hess=const_hess):
+                break
+    # too wide for one VMEM-resident histogram block: the same sweep
+    # over the feature axis in tiles (plain bins only)
+    tile = 0 if efb or packed4 else feature_tile(
+        nslots, num_features, bmax, double_prec, quantized, row_block=rb,
+        const_hess=const_hess)
+    if tile > 0:
+        rb = min(rb, TILED_ROW_BLOCK)
+    return rb, tile
+
+
+def tiled_bin_rows(plan, *, bmax: int, **blocks) -> int:
+    """The one-hot height of a feature (histogram_mxu.onehot_bin_rows)
+    where a one-hot pass of `plan` (hist_pass_plan) is built in feature
+    tiles, else 0. `blocks`: onehot_pass_blocks' posture. Static."""
+    tiled = any(form == "onehot" and
+                onehot_pass_blocks(sk, bmax=bmax, **blocks)[1] > 0
+                for _, sk, form in plan)
+    return onehot_bin_rows(bmax) if tiled else 0
 
 
 def hist_pass_plan(*, rows: int, num_leaves: int, overshoot: float = 0.0,
@@ -820,37 +875,11 @@ def grow_tree_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             if quant:
                 h = h * hist_scale
             return _allred(h), rn, form
-        # small frontiers run cheaper at half blocks, large ones
-        # prefer the wider block. EFB keeps rb=1024 in BOTH modes:
-        # expansion's original-feature route side needs the VMEM
-        # headroom (a 2048 block compiled to a real 136 MB OOM at
-        # 250-column bundles), and bundle-range mode did not gain from
-        # larger adaptive blocks
         rw = f if (efb is not None and not efb_seg) else 0
-        if efb is not None:
-            rb = 1024
-        elif nslots <= 64:
-            rb = int(os.environ.get("LGBM_TPU_RB_SMALL", 2048))
-        else:
-            # large frontiers: the chained per-pass microbench
-            # (helpers/microbench_pass.py, v5e round 5) measured 8192
-            # fastest at every sk > 64 (sk=72: 20.0 ms vs 26.9 at 4096;
-            # sk=136: 34.6 vs 38.9) — fewer grid steps re-visiting the
-            # VMEM-resident accumulator. Fall back block-by-block when
-            # the bigger input working set would bust the VMEM budget
-            # (e.g. 5-channel exact grads at wide frontiers).
-            for rb in (int(os.environ.get("LGBM_TPU_RB_LARGE", 8192)),
-                       4096, 2048):
-                if fits_v2(nslots, fk, bk, hist_double_prec, quant,
-                           route_width=rw, row_block=rb, const_hess=ch):
-                    break
-        # too wide for one VMEM-resident histogram block: the same sweep
-        # over the feature axis in tiles (plain bins only)
-        tile = 0 if efb is not None or packed4 else feature_tile(
-            nslots, fk, bk, hist_double_prec, quant, row_block=rb,
-            const_hess=ch)
-        if tile > 0:
-            rb = min(rb, TILED_ROW_BLOCK)
+        rb, tile = onehot_pass_blocks(
+            nslots, num_features=fk, bmax=bk, double_prec=hist_double_prec,
+            quantized=quant, const_hess=ch, efb=efb is not None,
+            packed4=packed4, route_width=rw)
         if tile >= 0 and (tile or fits_v2(
                 nslots, fk, bk, hist_double_prec, quant, route_width=rw,
                 row_block=rb, const_hess=ch)):

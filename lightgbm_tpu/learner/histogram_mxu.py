@@ -1015,8 +1015,8 @@ def _fused_kernel(nb: int, f: int, b: int, s: int, m: int,
     return kernel
 
 
-#: feature rows of the transposed bins a tiled sweep's route reads at a
-#: time (the uint8 sublane tile)
+#: feature rows of the transposed bins a tiled sweep reads at a time,
+#: route and histograms alike (the uint8 sublane tile)
 _ROUTE_CHUNK = 32
 
 
@@ -1049,17 +1049,72 @@ def _split_bins_chunked(bins_t_ref, nan_ref, nb: int):
     return lookup
 
 
-def _fused_tiled_kernel(nb: int, f: int, ft: int, b: int, s: int, m: int,
+def onehot_bin_rows(bmax: int) -> int:
+    """Rows of a feature's bin one-hot in the feature-tiled sweep: bmax
+    rounded up to the bf16 sublane tile (16), so 64 at 63 bins, where
+    the untiled kernels pad a feature to a 128-lane tile."""
+    return _round_up(bmax, 16)
+
+
+def _tiled_hist_accumulate(hist_ref, lhs, bins_t_ref, *, at: jax.Array,
+                           live: jax.Array, nb: int, ft: int, bp: int,
+                           mm_dtype):
+    """The feature-tiled sweep's accumulation body, from the transposed
+    bins it already holds for its route ([fsub, nb]: a feature per
+    sublane row, rows along lanes). For each _ROUTE_CHUNK features from
+    row `at`, one load; a feature's one-hot is its row broadcast down
+    `bp` sublanes against a sublane iota ([bp, nb]: bins down, rows
+    along), with nothing broadcast across lanes; a group of features is
+    stacked down the sublanes and contracted on the lanes of both
+    operands against `lhs` ([C*S, nb], _slot_lhs), the q.k^T form,
+    giving [C*S, G*bp] in the tile's output layout. Groups hold 4
+    features, 8 where 4 * bp is not a whole lane tile, so that every
+    group's columns start on one. `live`: a traced count of the tile's
+    features that are data; chunks and groups from there on are
+    padding and are skipped."""
+    group = _FGROUP if (_FGROUP * bp) % 128 == 0 else 2 * _FGROUP
+    iota = jax.lax.broadcasted_iota(jnp.int32, (bp, nb), 0)
+    for c0 in range(0, ft, _ROUTE_CHUNK):
+
+        def chunk(c0=c0):
+            start = pl.multiple_of(at + c0, _ROUTE_CHUNK)
+            rows = bins_t_ref[pl.ds(start, _ROUTE_CHUNK), :] \
+                .astype(jnp.int32)                           # [32, nb]
+            for g0 in range(0, _ROUTE_CHUNK, group):
+
+                def build(g0=g0):
+                    oh = jnp.concatenate(
+                        [(rows[j:j + 1, :] == iota).astype(mm_dtype)
+                         for j in range(g0, g0 + group)],
+                        axis=0)                              # [G*bp, nb]
+                    part = jax.lax.dot_general(
+                        lhs, oh,
+                        dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # [C*S, G*bp]
+                    col = (c0 + g0) * bp
+                    hist_ref[0, :, col:col + group * bp] += part
+
+                pl.when(c0 + g0 < live)(build)
+
+        pl.when(c0 < live)(chunk)
+
+
+def _fused_tiled_kernel(nb: int, f: int, ft: int, bp: int, s: int, m: int,
                         mm_dtype=jnp.bfloat16, nchan: int = 5,
                         has_cat: bool = True):
     """_fused_kernel over a grid of (feature tile, row block): every
     step routes its row block (the same decision in every tile: the
     route reads all features, a chunk at a time) and builds the
     histograms of the tile's `ft` features, into that tile's output
-    block, which stays in VMEM across the row blocks."""
+    block, which stays in VMEM across the row blocks. The histograms
+    are built from the transposed bins the route reads
+    (_tiled_hist_accumulate): a feature's bins run down `bp`
+    (onehot_bin_rows) sublanes, rows along the lanes. The row-major
+    bins block (bins_ref) is an operand that nothing here reads."""
 
     def kernel(node_ref, bins_ref, bins_t_ref, data_ref, tbl_ref,
                member_ref, nan_ref, hist_ref, node_out_ref, slot_ref):
+        del bins_ref
         ti = pl.program_id(0)
         ri = pl.program_id(1)
 
@@ -1089,20 +1144,23 @@ def _fused_tiled_kernel(nb: int, f: int, ft: int, b: int, s: int, m: int,
 
         @pl.when(jnp.max(slot) >= 0)
         def _():
-            _hist_accumulate(
+            _tiled_hist_accumulate(
                 hist_ref, _slot_lhs(slot, data_ref[:], nb=nb, s=s,
                                     nchan=nchan, mm_dtype=mm_dtype),
-                bins_ref[:].astype(jnp.int32), nb=nb, f=ft, b=b,
-                mm_dtype=mm_dtype, live=f - ti * ft)
+                bins_t_ref, at=ti * ft, live=f - ti * ft, nb=nb, ft=ft,
+                bp=bp, mm_dtype=mm_dtype)
 
     return kernel
 
 
 def _fused_tiled_call(ops: HistOperands, row_node, tbl, member, feat_tbl,
-                      *, f: int, ft: int, s: int, b: int, nb: int,
+                      *, f: int, ft: int, s: int, bp: int, nb: int,
                       nchan: int, has_cat: bool, interpret: bool):
     """The feature-tiled sweep's pallas_call: (hist [1, nchan*s,
-    tiles*ft*b], node_out [1, rows])."""
+    tiles*ft*bp], node_out [1, rows]). The row-major bins [rows, plane]
+    stay an operand, read by no step: its lane count is how a reader of
+    the call's text (benchmark/layer_metrics) tells a histogram's
+    feature columns from its bin columns."""
     rows, plane = ops.lanes.shape
     fsub = ops.bins_t.shape[0]
     m = tbl.shape[0]
@@ -1116,12 +1174,13 @@ def _fused_tiled_call(ops: HistOperands, row_node, tbl, member, feat_tbl,
                         constant_values=-1.0)
     nblocks = rows // nb
     return pl.pallas_call(
-        _fused_tiled_kernel(nb, f, ft, b, s, m, nchan=nchan,
+        _fused_tiled_kernel(nb, f, ft, bp, s, m, nchan=nchan,
                             has_cat=has_cat),
         grid=(tiles, nblocks),
         in_specs=[
             pl.BlockSpec((1, nb), lambda ti, ri: (0, ri)),
-            pl.BlockSpec((nb, ft), lambda ti, ri: (ri, ti)),
+            # unread: one uint8 tile, fetched once
+            pl.BlockSpec((32, 128), lambda ti, ri: (0, 0)),
             pl.BlockSpec((fsub, nb), lambda ti, ri: (0, ri)),
             pl.BlockSpec((8, nb), lambda ti, ri: (0, ri)),
             pl.BlockSpec(tbl_t.shape, lambda ti, ri: (0, 0)),
@@ -1129,11 +1188,12 @@ def _fused_tiled_call(ops: HistOperands, row_node, tbl, member, feat_tbl,
             pl.BlockSpec(nan_bin.shape, lambda ti, ri: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, nchan * s, ft * b), lambda ti, ri: (0, 0, ti)),
+            pl.BlockSpec((1, nchan * s, ft * bp),
+                         lambda ti, ri: (0, 0, ti)),
             pl.BlockSpec((1, nb), lambda ti, ri: (0, ri)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, nchan * s, tiles * ft * b),
+            jax.ShapeDtypeStruct((1, nchan * s, tiles * ft * bp),
                                  jnp.float32),
             jax.ShapeDtypeStruct((1, rows), jnp.int32),
         ],
@@ -1169,9 +1229,15 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     the whole width; not with EFB or 4-bit packed bins): the sweep runs
     over a grid of (feature tile, row block), each tile's histograms
     resident in VMEM while the rows pass, every step routing its rows
-    with the features read a chunk at a time. The histogram equals the
-    untiled one bit for bit: the same dots over the same groups of
-    features, the rows in the same order.
+    with the features read a chunk at a time. Its histograms are built
+    from those transposed bins: a feature's bin one-hot runs down
+    onehot_bin_rows(bmax) sublanes (64 at 63 bins, where the untiled
+    kernels pad a feature to a 128-lane tile) with the rows along the
+    lanes, so the kernel's result is [1, C*S, tiles*feature_tile*Bp]
+    and _combine_hist cuts each feature's Bp columns to bmax. Each bin
+    sums the same exact products (0/1 x a bf16 channel) in f32, the
+    rows in the same order: interpret mode gives the untiled kernel's
+    bits.
 
     Rows whose node did not split keep their node and land in no slot
     (slot -1), matching route_rows_mxu + build_histograms_mxu. Routing is
@@ -1218,12 +1284,13 @@ def fused_route_hist_mxu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                              "(no EFB, no 4-bit packing) in tiles that "
                              "divide %d lanes, not %d"
                              % (plane, feature_tile))
+        bp = onehot_bin_rows(bmax)
         hist, node_out = _fused_tiled_call(
             ops, row_node, tbl, member, feat_tbl, f=f, ft=feature_tile,
-            s=s, b=b, nb=nb, nchan=nchan, has_cat=has_cat,
+            s=s, bp=bp, nb=nb, nchan=nchan, has_cat=has_cat,
             interpret=interpret)
-        h3 = _combine_hist(hist[..., :f * b], nchan=nchan, s=s, f=f, b=b,
-                           bmax=bmax, double_prec=double_prec,
+        h3 = _combine_hist(hist[..., :f * bp], nchan=nchan, s=s, f=f,
+                           b=bp, bmax=bmax, double_prec=double_prec,
                            const_hess=const_hess)
         return h3, node_out[0, :n]
     # route tables are original-feature-indexed under decode-mode EFB

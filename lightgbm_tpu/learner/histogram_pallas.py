@@ -163,7 +163,10 @@ def group_width(nchan: int) -> int:
 
 def hist_columns(num_features: int, bmax: int) -> int:
     """Columns of one slot's histogram as the kernels lay it out: every
-    feature's bins padded to a lane tile."""
+    feature's bins padded to a lane tile. The feature-tiled one-hot
+    sweep pads a feature less (histogram_mxu.onehot_bin_rows: 64 rows
+    at 63 bins), but this stays the plan's measure of a pass: the
+    crossover of grouped_min_width was fitted to it."""
     return num_features * (-(-bmax // 128) * 128)
 
 

@@ -211,7 +211,9 @@ class ObservabilityRegistry:
         and the bridge built slot-grouped (the fixup body runs as often
         as a tree needs, so it is listed and not counted); `partition`
         is what partition_impl resolved to for the grouped passes
-        (stream | rank | argsort). Once the
+        (stream | rank | argsort); `onehot_bin_rows`, present only where
+        one-hot passes are built in feature tiles, the rows a feature's
+        bin one-hot takes there (64 at 63 bins). Once the
         growth program has been traced, `operand_builds_per_tree`
         ({bins_pad, bins_t, channels, row_table}) and
         `operand_builds_per_pass`
@@ -225,6 +227,8 @@ class ObservabilityRegistry:
         plan = [dict(p) for p in hb["plan"]]
         out: Dict = {"choice": hb["choice"], "plan": plan,
                      "partition": hb.get("partition", "")}
+        if "onehot_bin_rows" in hb:
+            out["onehot_bin_rows"] = hb["onehot_bin_rows"]
         if "operand_builds_per_pass" in hb:
             out["operand_builds_per_tree"] = \
                 dict(hb["operand_builds_per_tree"])
@@ -330,11 +334,13 @@ class ObservabilityRegistry:
                                clock_samples=self.clock_samples())
 
     # -- training hooks (called from boosting/gbdt.py) ------------------
-    def record_hist_plan(self, choice: str, plan,
-                         partition: str = "") -> None:
+    def record_hist_plan(self, choice: str, plan, partition: str = "",
+                         onehot_bin_rows: int = 0) -> None:
         """Pin the resolved histogram backend, the growth program's
-        per-pass plan, [(stage, kernel slots, formulation)], and the
-        partition its grouped passes are built with. Recorded
+        per-pass plan, [(stage, kernel slots, formulation)], the
+        partition its grouped passes are built with and, where its
+        one-hot passes are built in feature tiles, a feature's one-hot
+        height there (0: none is). Recorded
         even when disabled: this is one-shot startup configuration, not
         per-iteration telemetry, and the bench JSON tail reads it
         regardless of the enable flag."""
@@ -344,6 +350,8 @@ class ObservabilityRegistry:
                 "plan": [{"stage": str(st), "sk": int(sk),
                           "formulation": str(form)}
                          for st, sk, form in plan]}
+            if onehot_bin_rows:
+                self._hist_backend["onehot_bin_rows"] = int(onehot_bin_rows)
 
     def record_operand_builds(self, per_tree: Dict, per_pass: int) -> None:
         """What the traced growth program builds of its row-sized

@@ -5,7 +5,11 @@ against the plain reference benchmark/reference/gbdt_missing_numpy.py:
 - a pass built over the feature axis in tiles (fused_route_hist_mxu
   with feature_tile, the slot-grouped build under a tile) gives the
   untiled histograms bit for bit at one, two and three tiles, the last
-  one ragged;
+  one ragged, and at bin counts whose one-hot takes 48 to 256 sublane
+  rows a feature;
+- the tiled sweep's call keeps the layout its HLO text is read by
+  (benchmark/layer_metrics/kernels_wide_hist_roofline.py), and the
+  program says how tall a feature's one-hot is there;
 - NaN rows route by their node's default direction, either way;
 - the split search picks the reference's direction when the NaN rows'
   gradients favour either side, and may send the NaN rows alone right;
@@ -30,7 +34,8 @@ from lightgbm_tpu.learner import histogram_pallas as hp  # noqa: E402
 from lightgbm_tpu.learner.grower_mxu import (  # noqa: E402
     GROWTH_COUNTERS, _count_routed)
 from lightgbm_tpu.learner.histogram_mxu import (  # noqa: E402
-    feature_tile, fits_v2, fused_route_hist_mxu, pack_route_tables)
+    feature_tile, fits_v2, fused_route_hist_mxu, onehot_bin_rows,
+    pack_route_tables)
 from lightgbm_tpu.learner.split import (  # noqa: E402
     SplitHyperParams, find_best_splits)
 from benchmark.reference import gbdt_missing_numpy as ref  # noqa: E402
@@ -39,18 +44,18 @@ B = 63                       # bins, the NaN bin the last of them
 ROWS = 2600
 
 
-def _bins(f, seed=0, nan_share=0.6):
-    """[ROWS, f] uint8 bins, half the columns with a NaN bin (B - 1)
+def _bins(f, seed=0, nan_share=0.6, bmax=B):
+    """[ROWS, f] uint8 bins, half the columns with a NaN bin (bmax - 1)
     that holds `nan_share` of their rows; and which columns those are."""
     rng = np.random.RandomState(seed)
-    bins = rng.randint(0, B - 1, (ROWS, f)).astype(np.uint8)
+    bins = rng.randint(0, bmax - 1, (ROWS, f)).astype(np.uint8)
     nan_col = rng.rand(f) < 0.5
     miss = (rng.rand(ROWS, f) < nan_share) & nan_col[None, :]
-    bins[miss] = B - 1
+    bins[miss] = bmax - 1
     return bins, nan_col
 
 
-def _tables(split_feature, threshold, default_left, m1=8):
+def _tables(split_feature, threshold, default_left, m1=8, bmax=B):
     """Route tables of a root split on `split_feature`: rows go to node 1
     (kernel slot 0) or node 2 (slot 1)."""
     split = np.zeros(m1, bool)
@@ -70,22 +75,22 @@ def _tables(split_feature, threshold, default_left, m1=8):
         jnp.asarray(split), jnp.asarray(feat), jnp.asarray(thr),
         jnp.asarray(dl), jnp.zeros(m1, bool), jnp.asarray(child_l),
         jnp.asarray(child_r), jnp.asarray(slot),
-        jnp.zeros((m1, 2), jnp.uint32), 128, B)
+        jnp.zeros((m1, (bmax + 31) // 32), jnp.uint32), 128, bmax)
 
 
 def _sweep(bins, nan_col, split_feature, threshold, default_left, tile,
-           slots=2):
+           slots=2, bmax=B):
     rng = np.random.RandomState(1)
     g = jnp.asarray(rng.randn(ROWS).astype(np.float32))
     h = jnp.asarray(rng.rand(ROWS).astype(np.float32))
     cnt = jnp.ones(ROWS, jnp.float32)
     f = bins.shape[1]
-    ftbl = jnp.stack([jnp.full(f, B, jnp.float32),
+    ftbl = jnp.stack([jnp.full(f, bmax, jnp.float32),
                       jnp.asarray(nan_col.astype(np.float32))], axis=1)
-    tbl, member = _tables(split_feature, threshold, default_left)
+    tbl, member = _tables(split_feature, threshold, default_left, bmax=bmax)
     return fused_route_hist_mxu(
         jnp.asarray(bins), g, h, cnt, jnp.zeros(ROWS, jnp.int32), tbl,
-        member, ftbl, num_slots=slots, bmax=B, has_cat=False,
+        member, ftbl, num_slots=slots, bmax=bmax, has_cat=False,
         row_block=1024, feature_tile=tile, interpret=True)
 
 
@@ -98,6 +103,90 @@ def test_the_tiled_sweep_is_the_untiled_one_bit_for_bit(features, tiles):
     got = _sweep(bins, nan_col, f0, 20, True, tile=128)
     assert np.asarray(want[0]).tobytes() == np.asarray(got[0]).tobytes()
     assert np.asarray(want[1]).tobytes() == np.asarray(got[1]).tobytes()
+
+
+@pytest.mark.parametrize("slots", [2, 8])
+@pytest.mark.parametrize("bmax,rows", [(63, 64), (64, 64), (33, 48),
+                                       (255, 256)])
+def test_the_tiled_one_hot_is_bins_down_the_sublanes(bmax, rows, slots):
+    # a feature's one-hot takes bmax rounded up to 16 sublane rows; the
+    # sums are the untiled kernel's bit for bit, the count channel and
+    # the float channels alike (0/1 x a bf16 channel is exact, and each
+    # bin sums its block's rows in row order)
+    assert onehot_bin_rows(bmax) == rows
+    bins, nan_col = _bins(200, seed=7, bmax=bmax)
+    f0 = int(np.flatnonzero(nan_col)[-1])
+    kw = dict(slots=slots, bmax=bmax)
+    want = _sweep(bins, nan_col, f0, bmax // 3, False, tile=0, **kw)
+    got = _sweep(bins, nan_col, f0, bmax // 3, False, tile=128, **kw)
+    assert got[0].shape == (slots, 200, bmax, 3)
+    assert np.asarray(got[0])[..., 2].sum() == 200 * ROWS
+    assert np.asarray(want[0]).tobytes() == np.asarray(got[0]).tobytes()
+    assert np.asarray(want[1]).tobytes() == np.asarray(got[1]).tobytes()
+
+
+def _tiled_call_text(features, bmax, slots, rows=4096):
+    """The HLO text of the tiled sweep's kernel call, lowered for a TPU
+    (nothing compiles, no chip is needed)."""
+    tbl, member = _tables(0, 10, True, bmax=bmax)
+    ftbl = jnp.zeros((features, 2), jnp.float32)
+
+    def sweep(bins, g, h, cnt, node):
+        return fused_route_hist_mxu(
+            bins, g, h, cnt, node, tbl, member, ftbl, num_slots=slots,
+            bmax=bmax, has_cat=False, row_block=1024, feature_tile=128)
+
+    vec = jax.ShapeDtypeStruct((rows,), jnp.float32)
+    text = jax.jit(sweep).trace(
+        jax.ShapeDtypeStruct((rows, features), jnp.uint8), vec, vec, vec,
+        jax.ShapeDtypeStruct((rows,), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1
+    return calls[0]
+
+
+def test_the_tiled_call_is_read_at_its_one_hot_height():
+    # what the roofline's reader parses: results (hist f32[1, channels
+    # x slots, lanes x bin rows], node ids s32[1, rows]) and the
+    # row-major bins u8[rows, lanes] among the operands; it reads the
+    # one-hot height (64 at 63 bins), the work the kernel now does
+    from benchmark.layer_metrics.kernels_wide_hist_roofline import (
+        onehot_work)
+    rows, features, slots = 4096, 300, 8
+    text = _tiled_call_text(features, 63, slots, rows)
+    head = text.partition("custom-call(")[0]
+    assert "f32[1,40,%d]" % (3 * 128 * 64) in head
+    assert "s32[1,%d]" % rows in head
+    assert "u8[%d,384]" % rows in text.partition("custom-call(")[2]
+    ops, size = onehot_work(text, features)
+    assert ops == 2.0 * rows * 5 * slots * features * 64
+    assert size > rows * 384
+
+
+def test_the_program_says_how_tall_a_tiled_one_hot_is():
+    from lightgbm_tpu.observability import registry
+
+    def booster(features):
+        rng = np.random.RandomState(8)
+        X = rng.randn(300, features).astype(np.float32)
+        y = (X[:, 0] > 0).astype(np.float32)
+        params = {"objective": "binary", "num_leaves": 7, "max_bin": 63,
+                  "verbosity": -1, "min_data_in_leaf": 5}
+        g = lgb.Booster(params=params, train_set=lgb.Dataset(
+            X, label=y, params={"max_bin": 63})).gbdt
+        g._hist_impl = "mxu"
+        return g
+
+    registry.reset()
+    wide = booster(300)
+    assert wide._hist_plan_attrs()["onehot_bin_rows"] == 64
+    assert registry.hist_backend_snapshot()["onehot_bin_rows"] == 64
+    registry.reset()
+    narrow = booster(20)
+    assert "onehot_bin_rows" not in narrow._hist_plan_attrs()
+    assert "onehot_bin_rows" not in registry.hist_backend_snapshot()
 
 
 @pytest.mark.parametrize("default_left", [True, False])
